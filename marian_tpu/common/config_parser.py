@@ -369,7 +369,6 @@ _TRANSLATION = [
     _f("fleet-hbm-budget-mb", float, 0.0, "With --fleet: shared HBM budget in MB for resident tenant executors (estimated as bundle member bytes x an overhead factor); warming a tenant past the budget evicts the coldest idle tenant's executors first (never one with in-flight batches). 0 = unbudgeted — every tenant stays resident (TPU extension)", "translate"),
     _f("fleet-default-tenant", str, "", "With --fleet: tenant tag for requests that send no '#model:' header (must name a configured tenant); empty = un-tagged requests are rejected with !!SERVER-ERROR (TPU extension)", "translate"),
     _f("fleet-watch", float, 0.0, "With --fleet: poll each RESIDENT tenant's <model>.bundles/ every N seconds and hot-swap new committed bundles through that tenant's own canary/rollback lifecycle (the per-tenant --model-watch; 0 = off, tenants still warm-on-demand) (TPU extension)", "translate"),
-    _f("compile-cache", str, "", "Persistent XLA compilation cache directory (jax_compilation_cache_dir with the persistence thresholds zeroed): compiled serving/training programs are reused across process restarts, and the directory is what checkpoint bundles pack as their xla_cache.zip member so a fleet cold start (or --model-watch swap) is load+verify instead of full jit (docs/PERFORMANCE.md compile-telemetry ledger; empty = off) (TPU extension)", "translate"),
     _f("slo-availability", float, 0.0, "Declare an availability SLO (e.g. 0.999): the in-process burn-rate engine (obs/slo.py) evaluates ok-vs-(failure|timeout|stalled) outcomes over fast/slow windows, exports marian_slo_* gauges and GET /sloz, emits timeline events on threshold crossings and fires a flight dump on fast burn (0 = off) (TPU extension)", "translate"),
     _f("slo-p99-ms", float, 0.0, "Declare a latency SLO: 99% of requests must resolve under this many milliseconds (evaluated against the request-latency histogram buckets, conservatively rounded DOWN to a bucket edge). Same burn-rate machinery and exports as --slo-availability (0 = off) (TPU extension)", "translate"),
     _f("slo-window", float, 60.0, "SLO engine short (fast-burn) window in seconds; the slow window is 10x this (TPU extension)", "translate"),

@@ -332,8 +332,7 @@ def batch_to_arrays(batch, compact: bool = False,
     """CorpusBatch → dict of device arrays for the jitted loss. Extra
     source streams (multi-source) become src{i}_ids/src{i}_mask.
 
-    ``compact=True`` slims the host→device transfer (which crosses a
-    network tunnel in some deployments, and PCIe everywhere): token ids
+    ``compact=True`` slims the host→device transfer: token ids
     ship as uint16 when they fit, and the 0/1 float masks ship as per-row
     int32 LENGTHS (padding is terminal, so the mask is a prefix of ones)
     — ~4× fewer bytes per step. The jitted step rebuilds int32 ids and

@@ -30,7 +30,8 @@ Exported series (docs/OBSERVABILITY.md "The perf plane"):
   ``marian_perf_device_busy_ratio`` — rolling throughput / utilization;
 - ``marian_perf_mfu`` {model_version} — rolling model-FLOPs utilization
   against the analytic roofline for the configured geometry
-  (``set_geometry``); 0 when the chip generation is unknown (CPU);
+  (``set_geometry``); 0 on a device with no peak (CPU) — a TPU kind
+  the peak table does not list is an error at ``set_geometry``;
 - ``marian_capacity_headroom_ratio`` — one scrape-time gauge combining
   device utilization and admission-queue pressure (see ``headroom``);
 - ``marian_compile_total`` / ``marian_compile_seconds_total``
@@ -280,7 +281,7 @@ class PerfMeter:
         self.m_train_mfu = r.gauge(
             "marian_train_mfu",
             "Training: rolling model-FLOPs utilization of the last "
-            "display window vs the analytic roofline (0 = unknown chip "
+            "display window vs the analytic roofline (0 = no peak (CPU) "
             "/ no geometry)")
 
     # -- configuration ------------------------------------------------------
@@ -292,7 +293,8 @@ class PerfMeter:
         """Model geometry + device peak for the MFU gauges. When
         ``peak_flops`` (per device) is not given, it is resolved from
         ``device_kind`` — or from the live jax device when neither is
-        given (guarded: obs stays importable without jax)."""
+        given. CPU has no peak (the gauges read 0); a TPU kind missing
+        from common/flops.py's table raises."""
         if peak_flops is None:
             if device_kind is None or n_devices is None:
                 kind, n = self._probe_devices()
@@ -311,12 +313,9 @@ class PerfMeter:
 
     @staticmethod
     def _probe_devices() -> Tuple[str, int]:
-        try:
-            import jax
-            devs = jax.devices()
-            return devs[0].device_kind, len(devs)
-        except Exception:  # noqa: BLE001 — no jax / no backend: CPU-grade
-            return "", 1
+        import jax
+        devs = jax.devices()
+        return devs[0].device_kind, len(devs)
 
     def set_capacity_inputs(self, depth_fn: Optional[Callable[[], int]],
                             max_queue_units: int) -> None:

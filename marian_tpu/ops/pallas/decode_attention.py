@@ -43,13 +43,9 @@ from typing import Optional, Tuple
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
-from .flash_attention import MASK_VALUE, _HAS_PLTPU, _interpret_default
-
-if _HAS_PLTPU:
-    from jax.experimental.pallas import tpu as pltpu
-else:  # pragma: no cover — CPU-only envs without TPU lowering registration
-    pltpu = None
+from .flash_attention import MASK_VALUE, _interpret_default
 
 
 def _kernel(src_ref, pos_ref, q_ref, kn_ref, vn_ref, ck_ref, cv_ref,
@@ -59,11 +55,12 @@ def _kernel(src_ref, pos_ref, q_ref, kn_ref, vn_ref, ck_ref, cv_ref,
     # step, the contract the paged iteration path (kv_pool.py) relies on
     pos = pos_ref[pl.program_id(0)]
     # the gathered source row arrived via the block index map; fold the
-    # new position in and materialize the reordered cache in one write
-    kc = jax.lax.dynamic_update_slice(
-        ck_ref[0, 0], kn_ref[0, 0].astype(ck_ref.dtype), (pos, 0))
-    vc = jax.lax.dynamic_update_slice(
-        cv_ref[0, 0], vn_ref[0, 0].astype(cv_ref.dtype), (pos, 0))
+    # new position in and materialize the reordered cache in one write.
+    # The insert is a select on a row iota (the TPU lowering has no
+    # dynamic_update_slice, and the whole block is rewritten anyway)
+    at_pos = jax.lax.broadcasted_iota(jnp.int32, ck_ref.shape[2:], 0) == pos
+    kc = jnp.where(at_pos, kn_ref[0, 0].astype(ck_ref.dtype), ck_ref[0, 0])
+    vc = jnp.where(at_pos, vn_ref[0, 0].astype(cv_ref.dtype), cv_ref[0, 0])
     nk_ref[0, 0] = kc
     nv_ref[0, 0] = vc
     qv = q_ref[0, 0].astype(jnp.float32)              # [1, dh]
@@ -82,9 +79,9 @@ def _kernel(src_ref, pos_ref, q_ref, kn_ref, vn_ref, ck_ref, cv_ref,
 
 
 def _reference(q, k_new, v_new, cache_k, cache_v, pos, src_rows, scale):
-    """Pure-jnp fallback (oversized caches past the VMEM cap, or a
-    backend without pltpu): the exact unfused sequence the kernel
-    replaces — flat row gather, DUS at pos, masked softmax read.
+    """Pure-jnp fallback (oversized caches past the VMEM cap): the exact
+    unfused sequence the kernel replaces — flat row gather, DUS at pos,
+    masked softmax read.
     ``pos`` may be a scalar or a per-row [R] vector."""
     if src_rows is not None:
         cache_k = cache_k[src_rows]
@@ -137,7 +134,7 @@ def decode_attention(q: jax.Array, k_new: jax.Array, v_new: jax.Array,
         interpret = _interpret_default()
 
     from ..auto_tuner import decode_attention_max_len
-    if not _HAS_PLTPU or max_len > decode_attention_max_len(dh):
+    if max_len > decode_attention_max_len(dh):
         # degrade, don't OOM: a [L, dh] block per grid cell must fit the
         # VMEM budget (auto_tuner scales the cap down for wide heads)
         return _reference(q, k_new, v_new, cache_k, cache_v, pos,
@@ -176,6 +173,7 @@ def decode_attention(q: jax.Array, k_new: jax.Array, v_new: jax.Array,
     )
     out, new_k, new_v = pl.pallas_call(
         kernel,
+        name="decode_attention",
         grid_spec=grid_spec,
         out_shape=[
             jax.ShapeDtypeStruct((r, h, 1, dh), q.dtype),

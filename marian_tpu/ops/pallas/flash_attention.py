@@ -27,16 +27,7 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
-
-try:
-    # On CPU-only processes (tests force jax_platforms=cpu and drop the TPU
-    # backend factory) this import can fail while registering TPU lowerings;
-    # the interpret path below works without it.
-    from jax.experimental.pallas import tpu as pltpu
-    _HAS_PLTPU = True
-except Exception:  # noqa: BLE001 — ImportError or NotImplementedError
-    pltpu = None
-    _HAS_PLTPU = False
+from jax.experimental.pallas import tpu as pltpu
 
 MASK_VALUE = -1e9       # additive bias for masked scores (matches ops.NEG_INF)
 STATS_INIT = -1e30      # running-max init; NOT -inf so exp() stays finite
@@ -64,12 +55,6 @@ def _env_block(name: str, default: int) -> int:
                  "block size {}", name, raw, default)
         return default
     return v
-
-
-def _vmem(shape, dtype):
-    if _HAS_PLTPU:
-        return pltpu.VMEM(shape, dtype)
-    return pl.MemoryRef(shape, dtype)  # pragma: no cover
 
 
 # ---------------------------------------------------------------------------
@@ -236,8 +221,6 @@ def _interpret_default() -> bool:
 def _compiler_params(n_seq_dims: int = 1):
     """Grid dims (B, H, outer-block) are embarrassingly parallel; only the
     innermost (accumulating) dim is order-dependent."""
-    if not _HAS_PLTPU:  # pragma: no cover
-        return None
     return pltpu.CompilerParams(
         dimension_semantics=("parallel", "parallel", "parallel", "arbitrary"))
 
@@ -251,6 +234,7 @@ def _fwd_call(q, k, v, kvm, scale, causal, block_q, block_k, interpret):
                                block_q=block_q, block_k=block_k, n_k=n_k)
     return pl.pallas_call(
         kernel,
+        name="flash_attention_fwd",
         grid=grid,
         in_specs=[
             pl.BlockSpec((1, 1, block_q, dh), lambda b_, h_, i, j: (b_, h_, i, 0)),
@@ -267,9 +251,9 @@ def _fwd_call(q, k, v, kvm, scale, causal, block_q, block_k, interpret):
             jax.ShapeDtypeStruct((b, h, tq, 1), jnp.float32),
         ],
         scratch_shapes=[
-            _vmem((block_q, _LANES), jnp.float32),
-            _vmem((block_q, _LANES), jnp.float32),
-            _vmem((block_q, dh), jnp.float32),
+            pltpu.VMEM((block_q, _LANES), jnp.float32),
+            pltpu.VMEM((block_q, _LANES), jnp.float32),
+            pltpu.VMEM((block_q, dh), jnp.float32),
         ],
         interpret=interpret,
         compiler_params=None if interpret else _compiler_params(),
@@ -286,6 +270,7 @@ def _bwd_call(q, k, v, kvm, do, lse, delta, scale, causal, block_q, block_k,
                                   block_q=block_q, block_k=block_k, n_k=n_k)
     dq = pl.pallas_call(
         dq_kernel,
+        name="flash_attention_dq",
         grid=(b, h, n_q, n_k),
         in_specs=[
             pl.BlockSpec((1, 1, block_q, dh), lambda b_, h_, i, j: (b_, h_, i, 0)),
@@ -299,7 +284,7 @@ def _bwd_call(q, k, v, kvm, do, lse, delta, scale, causal, block_q, block_k,
         out_specs=pl.BlockSpec((1, 1, block_q, dh),
                                lambda b_, h_, i, j: (b_, h_, i, 0)),
         out_shape=jax.ShapeDtypeStruct((b, h, tq, dh), q.dtype),
-        scratch_shapes=[_vmem((block_q, dh), jnp.float32)],
+        scratch_shapes=[pltpu.VMEM((block_q, dh), jnp.float32)],
         interpret=interpret,
         compiler_params=None if interpret else _compiler_params(),
     )(q, k, v, kvm, do, lse, delta)
@@ -308,6 +293,7 @@ def _bwd_call(q, k, v, kvm, do, lse, delta, scale, causal, block_q, block_k,
                                    block_q=block_q, block_k=block_k, n_q=n_q)
     dk, dv = pl.pallas_call(
         dkv_kernel,
+        name="flash_attention_dkv",
         grid=(b, h, n_k, n_q),
         in_specs=[
             pl.BlockSpec((1, 1, block_q, dh), lambda b_, h_, j, i: (b_, h_, i, 0)),
@@ -326,8 +312,8 @@ def _bwd_call(q, k, v, kvm, do, lse, delta, scale, causal, block_q, block_k,
             jax.ShapeDtypeStruct((b, h, tk, dh), k.dtype),
             jax.ShapeDtypeStruct((b, h, tk, dh), v.dtype),
         ],
-        scratch_shapes=[_vmem((block_k, dh), jnp.float32),
-                        _vmem((block_k, dh), jnp.float32)],
+        scratch_shapes=[pltpu.VMEM((block_k, dh), jnp.float32),
+                        pltpu.VMEM((block_k, dh), jnp.float32)],
         interpret=interpret,
         compiler_params=None if interpret else _compiler_params(),
     )(q, k, v, kvm, do, lse, delta)

@@ -37,13 +37,7 @@ from typing import Optional, Tuple
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
-
-try:  # same fallback as flash_attention.py (CPU-only test processes)
-    from jax.experimental.pallas import tpu as pltpu
-    _HAS_PLTPU = True
-except Exception:  # noqa: BLE001
-    pltpu = None
-    _HAS_PLTPU = False
+from jax.experimental.pallas import tpu as pltpu
 
 MASK_VALUE = -1e9       # bias for padded vocab rows: exp() == 0 in f32
 STATS_INIT = -1e30
@@ -54,15 +48,7 @@ def _round_up(x: int, m: int) -> int:
     return ((x + m - 1) // m) * m
 
 
-def _vmem(shape, dtype):
-    if _HAS_PLTPU:
-        return pltpu.VMEM(shape, dtype)
-    return pl.MemoryRef(shape, dtype)  # pragma: no cover
-
-
 def _compiler_params():
-    if not _HAS_PLTPU:  # pragma: no cover
-        return None
     # Large-ish blocks (the vocab table is re-streamed once per token block,
     # so bigger token blocks cut HBM traffic) need more than the default
     # 16MB scoped-VMEM allowance; v5e/v4 have 128MB physical VMEM.
@@ -210,6 +196,7 @@ def _fwd_call(x, w, b, labels, block_n, block_v, v_real, interpret):
                                v_real=v_real)
     return pl.pallas_call(
         kernel,
+        name="fused_ce_fwd",
         grid=(n_n, n_v),
         in_specs=[
             pl.BlockSpec((block_n, e), lambda i, j: (i, 0)),
@@ -223,7 +210,7 @@ def _fwd_call(x, w, b, labels, block_n, block_v, v_real, interpret):
             pl.BlockSpec((block_n, 1), lambda i, j: (i, 0)),
         ],
         out_shape=[jax.ShapeDtypeStruct((n, 1), jnp.float32)] * 3,
-        scratch_shapes=[_vmem((block_n, _LANES), jnp.float32)
+        scratch_shapes=[pltpu.VMEM((block_n, _LANES), jnp.float32)
                         for _ in range(4)],
         interpret=interpret,
         compiler_params=None if interpret else _compiler_params(),
@@ -251,11 +238,12 @@ def _bwd_call(x, w, b, labels, lse, g_lse, g_lab, g_tot,
     dx = pl.pallas_call(
         functools.partial(_dx_kernel, block_v=block_v, n_v=n_v,
                           v_real=v_real),
+        name="fused_ce_dx",
         grid=(n_n, n_v),
         in_specs=in_specs,
         out_specs=pl.BlockSpec((block_n, e), tok),
         out_shape=jax.ShapeDtypeStruct((n, e), x.dtype),
-        scratch_shapes=[_vmem((block_n, e), jnp.float32)],
+        scratch_shapes=[pltpu.VMEM((block_n, e), jnp.float32)],
         interpret=interpret,
         compiler_params=None if interpret else _compiler_params(),
     )(x, w, b, labels, lse, g_lse, g_lab, g_tot)
@@ -276,6 +264,7 @@ def _bwd_call(x, w, b, labels, lse, g_lse, g_lab, g_tot,
     dw, db = pl.pallas_call(
         functools.partial(_dw_kernel, block_v=block_v, n_n=n_n,
                           v_real=v_real),
+        name="fused_ce_dw",
         grid=(n_v, n_n),
         in_specs=in_specs2,
         out_specs=[
@@ -286,8 +275,8 @@ def _bwd_call(x, w, b, labels, lse, g_lse, g_lab, g_tot,
             jax.ShapeDtypeStruct((v, e), w.dtype),
             jax.ShapeDtypeStruct((v, 1), jnp.float32),
         ],
-        scratch_shapes=[_vmem((block_v, e), jnp.float32),
-                        _vmem((block_v, _LANES), jnp.float32)],
+        scratch_shapes=[pltpu.VMEM((block_v, e), jnp.float32),
+                        pltpu.VMEM((block_v, _LANES), jnp.float32)],
         interpret=interpret,
         compiler_params=None if interpret else _compiler_params(),
     )(x, w, b, labels, lse, g_lse, g_lab, g_tot)

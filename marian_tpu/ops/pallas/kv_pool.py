@@ -52,13 +52,9 @@ from typing import Dict, List, Optional, Sequence, Tuple
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
-from .flash_attention import MASK_VALUE, _HAS_PLTPU, _interpret_default
-
-if _HAS_PLTPU:
-    from jax.experimental.pallas import tpu as pltpu
-else:  # pragma: no cover — CPU-only envs without TPU lowering registration
-    pltpu = None
+from .flash_attention import MASK_VALUE, _interpret_default
 
 
 # ---------------------------------------------------------------------------
@@ -67,7 +63,8 @@ else:  # pragma: no cover — CPU-only envs without TPU lowering registration
 # ---------------------------------------------------------------------------
 
 # active-row buckets for the iteration engine's per-step compiled shapes:
-# n_active rounds UP to the next entry (one jit specialization per bucket)
+# n_active rounds UP to the next entry (one jit specialization per bucket).
+# The largest must stay under paged_kernel_max_rows (checked per engine)
 ROW_BUCKETS: Tuple[int, ...] = (1, 2, 4, 8, 16, 32, 64, 128, 256, 512)
 
 # tokens per page. 16 × dh=64 × 4 B = 4 KiB per (page, head) K block —
@@ -87,6 +84,33 @@ def bucket_rows(n: int, buckets: Sequence[int] = ROW_BUCKETS) -> int:
     buckets = sorted(buckets)
     i = bisect.bisect_left(buckets, max(1, int(n)))
     return buckets[min(i, len(buckets) - 1)]
+
+
+# SMEM one paged_decode_attention call may fill with its scalar-prefetched
+# page table and positions: the 1 MiB the v5e compiler grants a program
+# (its refusal: "RESOURCE_EXHAUSTED ... space=smem ... prefetched SMEM
+# operand 0"), less a reserve for the compiler's own scalars
+SMEM_BUDGET_BYTES = (1 << 20) - (16 << 10)
+
+
+def paged_kernel_max_rows(max_pages: int) -> int:
+    """Most rows one paged_decode_attention call takes. A row's int32
+    page-table entries pad to whole 128-word SMEM lines (512 B per 128
+    pages), beside 4 B of position: 2000 rows at <= 128 pages/row."""
+    row_bytes = 512 * -(-int(max_pages) // 128) + 4
+    return SMEM_BUDGET_BYTES // row_bytes
+
+
+def check_kernel_rows(rows: int, max_pages: int) -> None:
+    """Refuse a row count the chip compiler would refuse in warm-up."""
+    bound = paged_kernel_max_rows(max_pages)
+    if rows > bound:
+        raise ValueError(
+            f"{rows} rows x {max_pages} pages/row exceeds the paged "
+            f"decode kernel's SMEM bound of {bound} rows (the page table "
+            f"is scalar-prefetched: {SMEM_BUDGET_BYTES} B at 512 B per "
+            f"row per 128 pages) - lower --iteration-rows or raise "
+            f"--kv-page-len")
 
 
 def state_key_groups(state_keys) -> Tuple[Tuple[str, ...], Tuple[str, ...],
@@ -688,8 +712,8 @@ def beam_table_reorder(page_table: jax.Array, parent: jax.Array,
 
 
 def _reference(q, pool_k, pool_v, page_table, row_pos, scale):
-    """Pure-jnp paged attention read (backends without pltpu, or rows
-    past the VMEM token cap). Gathers each row's pages and then runs the
+    """Pure-jnp paged attention read (interpret mode, or rows past the
+    VMEM token cap). Gathers each row's pages and then runs the
     EXACT op sequence of the dense reference (decode_attention._reference)
     over the assembled [R, H, MP*PL, dh] view — elementwise-identical
     inputs at unmasked positions + identical ops = bitwise-identical
@@ -786,7 +810,7 @@ def paged_decode_attention(q: jax.Array, k_new: jax.Array, v_new: jax.Array,
             out = _reference(q, new_k, new_v, page_table, row_pos,
                              float(scale))
             return out, new_k, new_v
-    if not _HAS_PLTPU or mp * page_len > kv_pool_max_tokens(dh):
+    if mp * page_len > kv_pool_max_tokens(dh):
         # degrade, don't OOM: the scratch row [MP*PL, dh] x2 must fit
         # the VMEM budget (auto_tuner scales the cap down for wide heads)
         out = _reference(q, new_k, new_v, page_table, row_pos,
@@ -817,6 +841,7 @@ def paged_decode_attention(q: jax.Array, k_new: jax.Array, v_new: jax.Array,
     )
     out, = pl.pallas_call(
         kernel,
+        name="paged_decode_attention",
         grid_spec=grid_spec,
         out_shape=[jax.ShapeDtypeStruct((r, h, 1, dh), q.dtype)],
         interpret=bool(interpret),

@@ -124,24 +124,6 @@ def zero1_leaf_spec(shape: Tuple[int, ...], mesh: Mesh) -> P:
     return P()
 
 
-def compat_shard_map(f, mesh: Mesh, in_specs, out_specs,
-                     check: bool = False):
-    """shard_map across jax versions: jax.shard_map (≥0.8, kwarg
-    check_vma) vs jax.experimental.shard_map (older, kwarg check_rep).
-    pyproject pins no jax version, so every call site goes through this
-    shim (shared by zero.py and sequence.py)."""
-    import inspect
-    try:
-        from jax import shard_map
-    except ImportError:                     # older jax
-        from jax.experimental.shard_map import shard_map
-    ck = ("check_vma"
-          if "check_vma" in inspect.signature(shard_map).parameters
-          else "check_rep")
-    return shard_map(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                     **{ck: check})
-
-
 def replicate_tree(tree, mesh: Mesh):
     return jax.device_put(tree, replicated(mesh))
 

@@ -135,8 +135,6 @@ def ring_attention_sharded(mesh: Mesh, q, k, v, kv_mask=None,
                            causal: bool = False, mode: str = "ring"):
     """Run ring/ulysses attention on full [B,H,T,Dh] arrays over `mesh`'s
     'seq' axis (the entry point for long-context encoders; jit-compatible)."""
-    from .mesh import compat_shard_map
-
     if kv_mask is None:
         kv_mask = jnp.ones((k.shape[0], k.shape[2]), jnp.float32)
     # batch rides 'data', heads ride 'model' (TP), time rides 'seq' — all
@@ -147,6 +145,6 @@ def ring_attention_sharded(mesh: Mesh, q, k, v, kv_mask=None,
         return sequence_attention(q_, k_, v_, kv_mask=mask_, causal=causal,
                                   mode=mode)
 
-    return compat_shard_map(
-        run, mesh, in_specs=(qkv, qkv, qkv, P("data", "seq")),
-        out_specs=qkv)(q, k, v, kv_mask)
+    return jax.shard_map(
+        run, mesh=mesh, in_specs=(qkv, qkv, qkv, P("data", "seq")),
+        out_specs=qkv, check_vma=False)(q, k, v, kv_mask)

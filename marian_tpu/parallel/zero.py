@@ -350,10 +350,10 @@ class _GradMachinery:
                    for k, v in batch.items()}
         g_out = {k: (P() if ax is None else P(*([None] * ax + ["data"])))
                  for k, ax in self.data_axes.items()}
-        return M.compat_shard_map(
-            self._scatter_reduce_body, self.mesh,
+        return jax.shard_map(
+            self._scatter_reduce_body, mesh=self.mesh,
             in_specs=(P(), b_specs, P()),
-            out_specs=(g_out, P(), P()))(p, batch, rng)
+            out_specs=(g_out, P(), P()), check_vma=False)(p, batch, rng)
 
 
 def build_grad_fn(model, mesh: Mesh, params: Params, frozen=(),
@@ -399,9 +399,8 @@ def build_train_step(model, opt_cfg: OptimizerConfig, schedule, cost_type: str,
     be the RAW training stream key: scan iteration i folds it by the
     absolute step number step+i-1 — the same derivation the sequential
     path uses on the host — so trajectories are bit-identical no matter
-    how updates group into windows; metrics come back stacked [K]. The point is amortizing
-    host→device dispatch latency (a network-tunneled chip, or host-bound
-    dispatch on a pod) over K real updates — the reference has no
+    how updates group into windows; metrics come back stacked [K]. The
+    point is amortizing host-bound dispatch latency over K real updates — the reference has no
     equivalent lever because its per-update host loop is mandatory
     (graph_group_sync.cpp :: SyncGraphGroup::update returns to the host
     scheduler every update). Requires delay == 1.

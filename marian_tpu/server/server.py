@@ -237,14 +237,6 @@ class ServingApp:
             options.get("batching-mode", "request") or "request")
         if self.batching_mode == "iteration":
             self._validate_iteration_options(options)
-        # persisted compile cache (ISSUE 20): --compile-cache DIR points
-        # jax's persistent compilation cache there at boot, so this
-        # process both reuses prior compiles AND has a cache directory
-        # to pack into bundles (compile_cache.pack_member)
-        cc_dir = str(options.get("compile-cache", "") or "")
-        if cc_dir:
-            from ..serving.lifecycle import compile_cache as mcc
-            mcc.enable(cc_dir)
         # multi-tenant fleet serving (ISSUE 20): --fleet replaces the
         # single boot model with N tenants warmed on demand; requests
         # route by the #model: header. Request mode only — the paged
@@ -346,6 +338,10 @@ class ServingApp:
         self.request_timeout = float(options.get("request-timeout", 0) or 0)
         self.metrics_server: Optional[msm.MetricsServer] = None
         self._started = False
+        # whether the request port accepts connections. An embedded app
+        # (tests, fleet drills) has no listener of its own; _serve owns
+        # one and holds /readyz back until it is bound
+        self.listening = True
         # perf/capacity plane (ISSUE 9, obs/perf.py): wire the headroom
         # gauge's admission-pressure inputs and the MFU geometry; both
         # no-ops when --perf-accounting is off
@@ -661,19 +657,15 @@ class ServingApp:
         0 rather than a guess."""
         if self.service is None:
             return
-        try:
-            cfg = getattr(self.service.translator.model, "cfg", None)
-            if cfg is None or not hasattr(cfg, "dim_ffn"):
-                return            # RNN family: no priced decode path
-            obs.PERF.set_geometry(
-                emb=int(cfg.dim_emb), ffn=int(cfg.dim_ffn),
-                enc_depth=int(getattr(cfg, "enc_depth", 6)),
-                dec_depth=int(getattr(cfg, "dec_depth", 6)),
-                vocab=len(self.service.translator.trg_vocab),
-                beam=int(self.options.get("beam-size", 12) or 12))
-        except Exception as e:  # noqa: BLE001 — observability is optional
-            log.warn("perf accounting: could not derive model geometry "
-                     "({}); MFU gauge stays 0", e)
+        cfg = getattr(self.service.translator.model, "cfg", None)
+        if cfg is None or not hasattr(cfg, "dim_ffn"):
+            return            # RNN family: no priced decode path
+        obs.PERF.set_geometry(
+            emb=int(cfg.dim_emb), ffn=int(cfg.dim_ffn),
+            enc_depth=int(getattr(cfg, "enc_depth", 6)),
+            dec_depth=int(getattr(cfg, "dec_depth", 6)),
+            vocab=len(self.service.translator.trg_vocab),
+            beam=int(self.options.get("beam-size", 12) or 12))
 
     def _model_path(self) -> str:
         models = self.options.get("models", []) or []
@@ -900,7 +892,8 @@ class ServingApp:
         the lifecycle — a warmed live version is routing; a replica
         still warming its first model reads 503 so load balancers hold
         traffic)."""
-        if not self._started or self.admission.draining:
+        if not self._started or not self.listening \
+                or self.admission.draining:
             return False
         return self.lifecycle is None or self.lifecycle.has_live()
 
@@ -955,24 +948,22 @@ class ServingApp:
         executor BEFORE the first client lands, reported as
         trigger=boot-warmup compile telemetry (ISSUE 9) — without it the
         first request of every width bucket pays the jit inline and
-        shows up as a steady-state recompile incident. Failure degrades
-        to a warning: a cold-but-correct server beats no server."""
+        shows up as a steady-state recompile incident. A failure here
+        (a compile the chip refuses, a model that cannot decode) stops
+        the boot: the same failure would otherwise meet the first
+        client."""
         from ..serving.lifecycle.warmup import (DEFAULT_GOLDEN,
                                                 load_golden, smoke_buckets)
-        try:
-            golden = load_golden(
-                self.options.get("warmup-golden", "") or None) \
-                or list(DEFAULT_GOLDEN)
-            # warm under the EXACT label the scheduler will stamp on
-            # batches (its version_fn — "unversioned" without a
-            # lifecycle): a mismatched label would leave every warmed
-            # bucket reading as a steady-state recompile incident
-            version = self.scheduler._version_label()
-            smoke_buckets(self.scheduler.translate_lines, golden,
-                          version, "boot-warmup", "boot model")
-        except Exception as e:  # noqa: BLE001
-            log.warn("--warmup-on-boot failed ({}); first requests pay "
-                     "the jit compile inline", e)
+        golden = load_golden(
+            self.options.get("warmup-golden", "") or None) \
+            or list(DEFAULT_GOLDEN)
+        # warm under the EXACT label the scheduler will stamp on batches
+        # (its version_fn — "unversioned" without a lifecycle): a
+        # mismatched label would leave every warmed bucket reading as a
+        # steady-state recompile incident
+        version = self.scheduler._version_label()
+        smoke_buckets(self.scheduler.translate_lines, golden,
+                      version, "boot-warmup", "boot model")
 
     async def handle_text(self, text: str, priority: int = 0) -> str:
         """One protocol frame in, one reply frame out — the transport-
@@ -1363,10 +1354,15 @@ async def _serve(options, ready: Optional[asyncio.Future] = None) -> None:
     """Serve forever. `ready` (tests): resolved with the bound port once
     listening — pass --port 0 to bind an ephemeral port."""
     app = ServingApp(options)
+    # /readyz must not say ready between start() and the bind below: a
+    # client that trusts it would be refused (seen on the chip, where the
+    # gap is wide enough to hit)
+    app.listening = False
     await app.start()
     port = int(options.get("port", 8080))
 
     def _announce(bound: int, transport: str) -> None:
+        app.listening = True
         log.info("Server is listening on port {} ({})", bound, transport)
         if ready is not None and not ready.cancelled():
             ready.set_result(bound)
@@ -1406,6 +1402,9 @@ async def _serve(options, ready: Optional[asyncio.Future] = None) -> None:
 
 
 def serve_main(options) -> None:
+    from ..common.profiling import enable_compilation_cache
+    enable_compilation_cache()
+
     async def _main():
         import signal
         loop = asyncio.get_event_loop()

@@ -13,18 +13,21 @@ Mechanism: jax's persistent compilation cache
 executables by (computation, compile options, backend). This module adds
 the bundle plumbing around it:
 
-- :func:`enable` points the process at a cache directory (thresholds
-  zeroed so every serving-shape program persists, not just slow ones).
+- The process has ONE cache directory, decided by
+  common/profiling.py::enable_compilation_cache
+  ($JAX_COMPILATION_CACHE_DIR, else ``<checkout>/.cache/xla``); every
+  entry point enables it at start-up.
 - :func:`pack_member` is a ``write_bundle``-compatible member writer
-  that zips the live cache directory plus a :func:`cache_key` record
-  into the bundle (member ``xla_cache.zip`` —
+  that zips that directory plus a :func:`cache_key` record into the
+  bundle (member ``xla_cache.zip`` —
   training/bundle.py :: COMPILE_CACHE_MEMBER).
 - :func:`adopt` (called by warmup before the executor factory runs)
-  unpacks a candidate bundle's cache member, VERIFIES its recorded key
-  against the current (chip, geometry, flags), and only then enables
-  it — a cache built for different silicon or XLA flags must never be
-  installed (jax would re-key and miss anyway; the refusal makes the
-  mismatch visible in the hit/miss ledger instead of silent).
+  VERIFIES a candidate bundle's recorded key against the current (chip,
+  geometry, flags) and only then unpacks its entries INTO the active
+  directory, beside what the process already compiled — a cache built
+  for different silicon or XLA flags must never be installed (jax would
+  re-key and miss anyway; the refusal makes the mismatch visible in the
+  hit/miss ledger instead of silent).
 
 The key is deliberately coarse — chip kind + device count + platform +
 jax version + XLA-flags hash + the bundle compat hash. jax's own cache
@@ -42,11 +45,11 @@ import hashlib
 import json
 import os
 import shutil
-import tempfile
 import zipfile
 from typing import Callable, Dict, Optional, Tuple
 
 from ...common import logging as log
+from ...common.profiling import enable_compilation_cache
 from .. import metrics as msm
 
 # bundle member name (mirrored as training/bundle.py::COMPILE_CACHE_MEMBER
@@ -117,78 +120,25 @@ def key_matches(recorded: Dict, current: Dict) -> Tuple[bool, str]:
     return True, ""
 
 
-_enabled_dir: Optional[str] = None
-
-
-def enable(cache_dir: str) -> bool:
-    """Point jax's persistent compilation cache at ``cache_dir``
-    (created if missing), with the persistence thresholds zeroed so the
-    small CPU-sized serving programs tier-1 runs under persist too.
-    Idempotent; returns False (loudly) when jax is unavailable."""
-    global _enabled_dir
-    try:
-        import jax
-        os.makedirs(cache_dir, exist_ok=True)
-        jax.config.update("jax_compilation_cache_dir", cache_dir)
-        jax.config.update("jax_persistent_cache_min_compile_time_secs",
-                          0.0)
-        jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)
-        # CRITICAL for adoption: by default jax parks XLA's own side
-        # caches (e.g. xla_gpu_per_fusion_autotune_cache_dir) INSIDE the
-        # cache dir and serializes those absolute paths into the compile
-        # options — which are hashed into every cache key. A cache
-        # unpacked at any other path (adopt() from a bundle — the whole
-        # feature) would then miss on every single entry. "none" keeps
-        # the key path-independent, so packed caches are portable across
-        # directories and processes.
-        try:
-            jax.config.update("jax_persistent_cache_enable_xla_caches",
-                              "none")
-        except Exception as e:  # noqa: BLE001 — option absent in old jax
-            log.warn("compile cache: cannot pin "
-                     "jax_persistent_cache_enable_xla_caches=none ({}); "
-                     "adopted caches may miss if the unpack dir differs "
-                     "from the producer's cache dir", e)
-        # jax memoizes its cache instance on first use; without a reset
-        # a mid-process dir switch (adopt() at swap time — the whole
-        # point) is silently ignored and the swap pays the full jit.
-        # Private API, so absence degrades to a loud warning: a server
-        # that enables the cache BEFORE its first compile is unaffected.
-        try:
-            from jax._src.compilation_cache import reset_cache
-            reset_cache()
-        except Exception as e:  # noqa: BLE001 — jax moved the hook
-            log.warn("compile cache: could not reset jax's cache "
-                     "instance ({}); a cache dir switched after first "
-                     "use may not take effect until restart", e)
-    except Exception as e:  # noqa: BLE001
-        log.warn("compile cache: could not enable persistent cache at "
-                 "{}: {}", cache_dir, e)
-        return False
-    _enabled_dir = cache_dir
-    log.info("compile cache: persistent XLA cache enabled at {}",
-             cache_dir)
-    return True
-
-
 def active_dir() -> Optional[str]:
-    """The enabled cache directory, or None."""
-    return _enabled_dir
+    """The directory this process's persistent cache writes to, or None
+    while it is off."""
+    import jax
+    return jax.config.jax_compilation_cache_dir or None
 
 
-def pack_member(cache_dir: Optional[str] = None, compat_hash: str = ""
-                ) -> Callable[[str], None]:
+def pack_member(compat_hash: str = "") -> Callable[[str], None]:
     """A ``write_bundle`` member writer for ``xla_cache.zip``: zips the
-    (enabled or given) cache directory with the current
-    :func:`cache_key` record. The writer raises if no cache is enabled
-    or the key cannot be derived — a producer asking to persist a cache
-    it does not have is a config error, not a silent empty member."""
+    active cache directory with the current :func:`cache_key` record.
+    The writer raises if no cache is enabled or the key cannot be
+    derived — a producer asking to persist a cache it does not have is
+    a config error, not a silent empty member."""
     def _write(path: str) -> None:
-        src = cache_dir or _enabled_dir
+        src = active_dir()
         if not src or not os.path.isdir(src):
             raise RuntimeError(
                 "compile cache: no persistent cache directory to pack "
-                "(call compile_cache.enable() / --compile-cache first)")
+                "(enable_compilation_cache() has not run)")
         key = cache_key(compat_hash)
         if key is None:
             raise RuntimeError("compile cache: no jax backend — cannot "
@@ -207,15 +157,14 @@ def pack_member(cache_dir: Optional[str] = None, compat_hash: str = ""
     return _write
 
 
-def adopt(bundle_dir: str, compat_hash: str = "",
-          into_dir: Optional[str] = None) -> Tuple[bool, str]:
+def adopt(bundle_dir: str, compat_hash: str = "") -> Tuple[bool, str]:
     """Warm-on-demand entry point (warmup.py calls this BEFORE the
     executor factory): if the bundle carries ``xla_cache.zip`` and its
-    recorded key matches this process, unpack and enable it — the
-    subsequent jit compiles become load+verify from disk. Returns
-    (adopted, why). Never raises: a bad/missing/mismatched member
-    degrades to the pre-cache full-jit warmup, counted in the event
-    ledger."""
+    recorded key matches this process, unpack its entries into the
+    active cache directory — the subsequent jit compiles become
+    load+verify from disk. Returns (adopted, why: the directory when
+    adopted). Never raises: a bad/missing/mismatched member degrades to
+    the pre-cache full-jit warmup, counted in the event ledger."""
     member = os.path.join(bundle_dir, CACHE_MEMBER)
     if not os.path.isfile(member):
         _events().labels("miss").inc()
@@ -237,8 +186,7 @@ def adopt(bundle_dir: str, compat_hash: str = "",
                 log.warn("compile cache: NOT adopting {} ({}) — warmup "
                          "pays the full jit", member, why)
                 return False, why
-            dest = into_dir or tempfile.mkdtemp(prefix="marian-xla-cache-")
-            os.makedirs(dest, exist_ok=True)
+            dest = enable_compilation_cache()
             for info in zf.infolist():
                 if info.filename == KEY_FILE or info.is_dir():
                     continue
@@ -255,9 +203,6 @@ def adopt(bundle_dir: str, compat_hash: str = "",
         _events().labels("error").inc()
         log.warn("compile cache: could not adopt {}: {}", member, e)
         return False, str(e)
-    if not enable(dest):
-        _events().labels("error").inc()
-        return False, "could not enable the unpacked cache"
     _events().labels("adopted").inc()
     log.info("compile cache: adopted {} — swap warmup is load+verify "
              "(chip {}, {} device(s))", member, current["chip"],

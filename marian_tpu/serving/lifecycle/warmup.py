@@ -208,13 +208,11 @@ def warm_executor(bundle_dir: str, manifest: Optional[Dict],
         from . import compile_cache as _cc
         import os as _os
         if _os.path.isdir(bundle_dir):
-            # merge into the already-enabled dir when there is one, so a
-            # server running with --compile-cache keeps its accumulated
-            # entries; otherwise adopt() unpacks into a fresh tempdir
+            # entries unpack into the process's one cache directory,
+            # beside what it has compiled itself
             adopted, _why = _cc.adopt(
                 bundle_dir,
-                compat_hash=bdl.compat_hash(bdl.manifest_compat(manifest)),
-                into_dir=_cc.active_dir())
+                compat_hash=bdl.compat_hash(bdl.manifest_compat(manifest)))
             if adopted:
                 log.info("warmup: adopted persisted compile cache from "
                          "{} — expecting cache-hit compiles only",

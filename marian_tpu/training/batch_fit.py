@@ -14,6 +14,7 @@ assignment is the ground truth and is not predictable analytically.
 
 from __future__ import annotations
 
+import re
 from typing import Optional
 
 import numpy as np
@@ -24,8 +25,21 @@ _WORDS_MIN = 256
 _WORDS_CAP = 131072
 
 
+# a Pallas kernel refused for its ON-CHIP memory is RESOURCE_EXHAUSTED too
+# ("Ran out of memory in memory space vmem ... exceeded scoped vmem limit",
+# "... space=smem ... prefetched SMEM operand 0")
+_ON_CHIP = re.compile(r"memory space (vmem|smem)|space=(vmem|smem)|"
+                      r"scoped vmem", re.IGNORECASE)
+
+
 def _oom(err: Exception) -> bool:
+    """Whether ``err`` says the batch does not fit DEVICE memory (HBM).
+    A kernel's VMEM/SMEM refusal is a compile failure, not a fit result:
+    no smaller batch cures it, so it is not read as "too big" but
+    re-raised by the caller."""
     s = str(err)
+    if _ON_CHIP.search(s):
+        return False
     return "RESOURCE_EXHAUSTED" in s or "Out of memory" in s \
         or "out of memory" in s
 

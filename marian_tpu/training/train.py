@@ -375,16 +375,12 @@ class Train:
         if obs.PERF.enabled:
             # geometry for the live train-MFU gauge (obs/perf.py); the
             # per-window chip-seconds/token gauge needs no geometry
-            try:
-                obs.PERF.set_geometry(
-                    emb=int(opts.get("dim-emb", 512)),
-                    ffn=int(opts.get("transformer-dim-ffn", 2048)),
-                    enc_depth=int(opts.get("enc-depth", 6)),
-                    dec_depth=int(opts.get("dec-depth", 6)),
-                    vocab=len(vocabs[-1]))
-            except Exception as e:  # noqa: BLE001 — observability only
-                log.warn("perf accounting: no train geometry ({}); "
-                         "train MFU gauge stays 0", e)
+            obs.PERF.set_geometry(
+                emb=int(opts.get("dim-emb", 512)),
+                ffn=int(opts.get("transformer-dim-ffn", 2048)),
+                enc_depth=int(opts.get("enc-depth", 6)),
+                dec_depth=int(opts.get("dec-depth", 6)),
+                vocab=len(vocabs[-1]))
         # --metrics-port: Prometheus scrape of the train-side series the
         # Scheduler/StepTimer publish (serving/metrics.py — same registry
         # and types as marian-server, one metrics vocabulary end to end);

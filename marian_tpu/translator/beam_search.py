@@ -120,11 +120,11 @@ def _topk_rows(flat, k: int, mesh):
     step (caught by test_mesh_decode_is_collective_free)."""
     if mesh is None:
         return jax.lax.top_k(flat, k)
-    from ..parallel.mesh import compat_shard_map
     nones = (None,) * (flat.ndim - 1)
     spec = P("data", *nones)
-    return compat_shard_map(lambda f: tuple(jax.lax.top_k(f, k)), mesh,
-                            in_specs=(spec,), out_specs=(spec, spec))(flat)
+    return jax.shard_map(  # mtlint: ok -- k is --beam-size: a launch flag, one value per process
+        lambda f: tuple(jax.lax.top_k(f, k)), mesh=mesh,
+        in_specs=(spec,), out_specs=(spec, spec), check_vma=False)(flat)
 
 
 def beam_search_jit(model, params_list: List[Dict[str, jax.Array]],
@@ -376,14 +376,13 @@ def beam_search_jit(model, params_list: List[Dict[str, jax.Array]],
             # single-device gather's measured speed per shard. Left to
             # GSPMD, the flat global gather all-gathers the entire cache
             # every step instead.
-            from ..parallel.mesh import compat_shard_map
             row_axis_spec = ["data" if d == axis else None
                              for d in range(v.ndim)]
             spec_v = P(*row_axis_spec)
-            return compat_shard_map(
-                lambda vv, idx: flat_gather(vv, idx), mesh,
+            return jax.shard_map(
+                lambda vv, idx: flat_gather(vv, idx), mesh=mesh,
                 in_specs=(spec_v, P("data")),
-                out_specs=spec_v)(v, beam_idx)
+                out_specs=spec_v, check_vma=False)(v, beam_idx)
 
         def reorder_state(st):
             out = {}

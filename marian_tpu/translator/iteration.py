@@ -58,7 +58,7 @@ from ..common import logging as log
 from ..data.vocab import EOS_ID
 from ..ops.pallas.kv_pool import (DEFAULT_PAGE_LEN, KVPool, PoolCorruption,
                                   PoolExhausted, ROW_BUCKETS, bucket_rows,
-                                  pages_for_tokens)
+                                  check_kernel_rows, pages_for_tokens)
 from .decode_features import RowFeatures
 from .prefix_cache import PrefixCache
 
@@ -199,6 +199,8 @@ class PagedDecodeEngine:
                 f"lower --iteration-rows)")
         self.max_pages = pages_for_tokens(self.max_length_cap,
                                           self.page_len)
+        # the beam engine's buckets (blocks x k) stay under max_rows too
+        check_kernel_rows(self.max_rows, self.max_pages)
         # decode steps per round, run as ONE jitted lax.scan: joins are
         # still admitted every round, so admission granularity is
         # steps_per_round steps (default 1 = pure iteration-level).

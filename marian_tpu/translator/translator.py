@@ -116,11 +116,8 @@ class Translate:
         cfg = getattr(self.model, "cfg", None)
         if cfg is None or not hasattr(cfg, "dim_ffn"):
             return                       # RNN family: no int8 decode path
-        try:
-            import jax
-            kind = jax.devices()[0].device_kind
-        except Exception:                # noqa: BLE001 — hint only
-            return
+        import jax
+        kind = jax.devices()[0].device_kind
         from ..common.flops import decode_defaults_hint
         from ..ops.quantization import QTensor
         int8_on = any(isinstance(v, QTensor)
@@ -263,4 +260,6 @@ class Translate:
 
 
 def translate_main(options) -> None:
+    from ..common.profiling import enable_compilation_cache
+    enable_compilation_cache()
     Translate(options).run()

@@ -1,6 +1,6 @@
 #!/usr/bin/env python
-"""Micro load generator for marian-server (ISSUE 1 CI/tooling satellite;
-bench_when_up use).
+"""Micro load generator for marian-server (ISSUE 1 CI/tooling satellite).
+Imports no JAX, so a server in another process keeps the chip.
 
 Drives N concurrent clients against a running server, each sending R
 requests of S sentences, and reports client-side p50/p99/mean latency and

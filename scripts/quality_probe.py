@@ -116,8 +116,6 @@ def main():
     if os.environ.get("JAX_PLATFORMS", "") == "cpu":
         from marian_tpu.common.hermetic import force_cpu_devices
         force_cpu_devices(1)
-    from marian_tpu.common.hermetic import watchdog_devices
-    watchdog_devices(label="quality_probe")
     import jax
 
     from marian_tpu.common.options import Options

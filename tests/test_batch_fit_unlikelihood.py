@@ -20,6 +20,41 @@ def rng():
 
 
 class TestMiniBatchFit:
+    @pytest.mark.parametrize("message,fit_result", [
+        # HBM: at compile time, and from the runtime allocator
+        ("RESOURCE_EXHAUSTED: XLA:TPU compile permanent error. Ran out of "
+         "memory in memory space hbm. Used 24.60G of 15.75G hbm.", True),
+        ("RESOURCE_EXHAUSTED: Out of memory while trying to allocate "
+         "1073741824 bytes.", True),
+        # a kernel's on-chip refusal: a compile failure, never "too big"
+        ("RESOURCE_EXHAUSTED: Allocation (size=1572864) would exceed memory "
+         "(size=1048576) :: #allocation5 [shape = 'u8[1572864]{0}', "
+         "space=smem, size = 0x180000, tag = 'prefetched SMEM operand 0']",
+         False),
+        ("RESOURCE_EXHAUSTED: Ran out of memory in memory space vmem while "
+         "allocating on stack: Scoped allocation with size 19.09M and limit "
+         "16.00M exceeded scoped vmem limit by 3.09M.", False),
+        ("INVALID_ARGUMENT: Unimplemented primitive in Pallas TPU lowering",
+         False),
+    ])
+    def test_only_hbm_exhaustion_is_a_fit_result(self, message, fit_result):
+        from marian_tpu.training.batch_fit import _oom
+        assert _oom(RuntimeError(message)) is fit_result
+
+    def test_kernel_refusal_propagates_instead_of_shrinking(self):
+        from marian_tpu.training import batch_fit
+
+        class Refused:
+            delay = 1
+            params = {}
+
+            def update(self, *a):
+                raise RuntimeError(
+                    "RESOURCE_EXHAUSTED: Ran out of memory in memory space "
+                    "smem. Used 1.50M of 1.00M smem.")
+        with pytest.raises(RuntimeError, match="memory space smem"):
+            batch_fit._try_budget(Refused(), 2048, 50, 100)
+
     def test_search_converges_to_cap_when_memory_suffices(self):
         opts = Options({
             "type": "transformer", "dim-emb": 16, "transformer-heads": 2,

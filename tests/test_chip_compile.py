@@ -1,0 +1,179 @@
+"""The five Pallas kernels compiled for the real chip, without the chip.
+
+The TPU compiler is installed beside the CPU backend and compiles for a
+described v5e (`jax.experimental.topologies`), so these cases run under
+conftest's forced-CPU platform and cost no chip time. Interpret-mode
+parity (test_*_attention.py, test_kv_pool.py, test_fused_ce.py) says a
+kernel computes the right thing; this file says Mosaic accepts it —
+block shapes against the (8, 128) tiling rule, VMEM/SMEM budgets,
+primitives the TPU lowering implements — at transformer-big widths
+(16 heads x dh 64, emb 1024, vocab 32000, bf16) and at the shapes the
+main path produces: training length buckets 32/64 and the packed cap,
+flash at 2048, dense beam decode at 64 sentences x beam 6, the paged
+engine at its smallest and largest row bucket and at its SMEM row bound.
+
+Nothing runs: a passing compile is not a chip run (chip_smoke.py is).
+The file sorts early on purpose — tier-1 runs into its wall-clock box
+and sheds whatever sorts late.
+"""
+
+import os
+import re
+
+os.environ.setdefault("TPU_LOG_DIR", "disabled")  # compiler logs off /tmp
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+from marian_tpu.ops import auto_tuner
+from marian_tpu.ops.pallas import kv_pool
+from marian_tpu.ops.pallas.decode_attention import decode_attention
+from marian_tpu.ops.pallas.flash_attention import flash_attention
+from marian_tpu.ops.pallas.fused_ce import fused_softmax_xent
+from marian_tpu.ops.pallas.packed_attention import packed_attention
+
+H, DH, EMB, VOCAB = 16, 64, 1024, 32000     # transformer-big
+DT = jnp.bfloat16
+PAGE_LEN = kv_pool.DEFAULT_PAGE_LEN
+MAX_LEN = 256                               # iteration.py's output cap
+PAGES_ROW = MAX_LEN // PAGE_LEN
+PACKED_CAP = auto_tuner.packed_attention_max_t(DH)
+
+
+@pytest.fixture(scope="session")
+def chip():
+    """One described v5e chip's sharding (session-scoped: describing the
+    topology loads libtpu once)."""
+    from jax.experimental import topologies
+    try:
+        topo = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:  # noqa: BLE001 — no libtpu on this machine
+        pytest.skip(f"cannot describe a v5e topology here: {e}")
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.fixture(autouse=True)
+def _no_persistent_cache():
+    """A compile for a described chip is written to the persistent cache
+    but cannot be read back without a chip (the next run warns and
+    recompiles) — keep these compiles out of it."""
+    from jax.experimental.compilation_cache import compilation_cache as cc
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    cc.reset_cache()
+    yield
+    jax.config.update("jax_enable_compilation_cache", was)
+    cc.reset_cache()
+
+
+_PACKED = (packed_attention, "packed_attention_fwd", "packed_attention_bwd")
+_FLASH = (flash_attention, "flash_attention_fwd", "flash_attention_dq",
+          "flash_attention_dkv")
+
+
+def _attn(kernel, b, tq, tk, causal, grad):
+    """(callable, shapes, pallas_call names) for a self-/cross-attention
+    call with a key padding mask: forward, or the summed-output gradient
+    wrt q/k/v (which holds the forward and the backward kernels)."""
+    fn, fwd_name, *bwd_names = kernel
+
+    def fwd(q, k, v, m):
+        return fn(q, k, v, kv_mask=m, causal=causal, interpret=False)
+
+    def loss(q, k, v, m):
+        return fwd(q, k, v, m).astype(jnp.float32).sum()
+
+    shapes = [((b, H, tq, DH), DT), ((b, H, tk, DH), DT),
+              ((b, H, tk, DH), DT), ((b, tk), jnp.float32)]
+    if grad:
+        return (jax.grad(loss, argnums=(0, 1, 2)), shapes,
+                [fwd_name] + bwd_names)
+    return fwd, shapes, [fwd_name]
+
+
+def _decode(rows, per_row_pos):
+    def f(q, kn, vn, ck, cv, pos, src):
+        return decode_attention(q, kn, vn, ck, cv, pos, src_rows=src,
+                                interpret=False)
+    new = ((rows, H, 1, DH), DT)
+    cache = ((rows, H, MAX_LEN, DH), DT)
+    pos = ((rows,) if per_row_pos else (), jnp.int32)
+    return (f, [new, new, new, cache, cache, pos, ((rows,), jnp.int32)],
+            ["decode_attention"])
+
+
+def _paged(rows):
+    def f(q, kn, vn, pk, pv, table, pos):
+        return kv_pool.paged_decode_attention(q, kn, vn, pk, pv, table, pos,
+                                              interpret=False)
+    new = ((rows, H, 1, DH), DT)
+    pool = ((rows * PAGES_ROW + 1, H, PAGE_LEN, DH), DT)
+    return (f, [new, new, new, pool, pool,
+                ((rows, PAGES_ROW), jnp.int32), ((rows,), jnp.int32)],
+            ["paged_decode_attention"])
+
+
+def _xent(n, grad):
+    def fwd(x, w, b, y):
+        return fused_softmax_xent(x, w, b, y, label_smoothing=0.1,
+                                  interpret=False)
+
+    def loss(x, w, b, y):
+        return fwd(x, w, b, y).sum()
+
+    shapes = [((n, EMB), DT), ((VOCAB, EMB), DT), ((VOCAB,), jnp.float32),
+              ((n,), jnp.int32)]
+    if grad:
+        return (jax.grad(loss, argnums=(0, 1, 2)), shapes,
+                ["fused_ce_fwd", "fused_ce_dx", "fused_ce_dw"])
+    return fwd, shapes, ["fused_ce_fwd"]
+
+
+_ROWS_LO, _ROWS_HI = kv_pool.ROW_BUCKETS[0], kv_pool.ROW_BUCKETS[-1]
+_ROWS_BOUND = kv_pool.paged_kernel_max_rows(PAGES_ROW)
+
+CASES = {
+    # trainer: encoder self (mask), decoder self (causal), cross (tq != tk)
+    "packed-fwd-t32-mask": lambda: _attn(_PACKED, 8, 32, 32, False, False),
+    "packed-grad-t32-causal": lambda: _attn(_PACKED, 8, 32, 32, True, True),
+    "packed-fwd-t64-causal": lambda: _attn(_PACKED, 8, 64, 64, True, False),
+    "packed-grad-t64-cross": lambda: _attn(_PACKED, 8, 64, 32, False, True),
+    f"packed-fwd-t{PACKED_CAP}-mask":
+        lambda: _attn(_PACKED, 2, PACKED_CAP, PACKED_CAP, False, False),
+    f"packed-grad-t{PACKED_CAP}-causal":
+        lambda: _attn(_PACKED, 2, PACKED_CAP, PACKED_CAP, True, True),
+    "flash-fwd-t2048": lambda: _attn(_FLASH, 1, 2048, 2048, True, False),
+    "flash-grad-t2048": lambda: _attn(_FLASH, 1, 2048, 2048, True, True),
+    # offline decoder: 64 sentences x beam 6, scalar and per-row positions
+    "decode-r384-scalar-pos": lambda: _decode(64 * 6, False),
+    "decode-r384-row-pos": lambda: _decode(64 * 6, True),
+    # paged server: smallest row bucket x beam 1 / 6, the largest, and the
+    # SMEM bound the engine checks at start-up (one row more is refused)
+    f"paged-r{_ROWS_LO}": lambda: _paged(_ROWS_LO),
+    f"paged-r{_ROWS_LO * 6}": lambda: _paged(_ROWS_LO * 6),
+    f"paged-r{_ROWS_HI}": lambda: _paged(_ROWS_HI),
+    f"paged-r{_ROWS_BOUND}-bound": lambda: _paged(_ROWS_BOUND),
+    # E and V are the widths; N is shrunk for compile time — the gradient
+    # case keeps one real row block (block_n 1024) and holds the forward too
+    "xent-fwd": lambda: _xent(256, False),
+    "xent-grad": lambda: _xent(1024, True),
+}
+
+
+@pytest.mark.parametrize("case", CASES)
+def test_kernel_compiles_for_v5e(chip, case):
+    fn, shapes, kernels = CASES[case]()
+    args = [jax.ShapeDtypeStruct(s, d, sharding=chip) for s, d in shapes]
+    text = jax.jit(fn).lower(*args).compile().as_text()
+    # a named pallas_call's op_name is ".../<name>/pallas_call", wrapped
+    # as "jvp(<name>)" / "transpose(jvp(<name>))" under differentiation
+    found = {part for op_name in re.findall(
+        r'custom_call_target="tpu_custom_call"[^\n]*?op_name="([^"]*)"', text)
+        for part in re.split(r"[/()]", op_name)}
+    missing = [k for k in kernels if k not in found]
+    assert not missing, (
+        f"{missing} not among the compiled program's Pallas kernels — "
+        f"something gave way to a reference")

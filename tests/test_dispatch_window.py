@@ -1,7 +1,7 @@
 """--dispatch-window: K full optimizer updates inside ONE jitted dispatch
 (lax.scan over a leading window axis — parallel/zero.py build_train_step
-n_updates>1). The lever amortizes per-dispatch host latency (a network-
-tunneled chip, host-bound pods); the reference has no equivalent because
+n_updates>1). The lever amortizes per-dispatch host latency (host-bound
+pods); the reference has no equivalent because
 its SyncGraphGroup host loop runs per update
 (src/training/graph_group_sync.cpp :: SyncGraphGroup::update)."""
 

@@ -1,10 +1,10 @@
-"""MFU accounting (common/flops.py) + XLA-cache manifest hardening
-(profiling.check_cache_manifest) — VERDICT r2 next-steps #3 and #6."""
+"""MFU accounting (common/flops.py): FLOP counts, the decode roofline,
+and the peak tables — CPU has no peak, an unlisted TPU is an error."""
 
-import json
-import os
+import pytest
 
-from marian_tpu.common.flops import peak_bf16_flops, transformer_train_flops
+from marian_tpu.common.flops import (hbm_bandwidth, peak_bf16_flops,
+                                     transformer_train_flops)
 
 
 class TestPeakTable:
@@ -21,10 +21,34 @@ class TestPeakTable:
     def test_v4_lite_not_confused_with_v4(self):
         assert peak_bf16_flops("TPU v4 lite") == 138e12
 
-    def test_unknown_returns_none(self):
+    def test_no_tpu_has_no_peak(self):
         assert peak_bf16_flops("cpu") is None
-        assert peak_bf16_flops("TPU v99") is None
         assert peak_bf16_flops("") is None
+        assert hbm_bandwidth("cpu") is None
+
+    @pytest.mark.parametrize("lookup", [peak_bf16_flops, hbm_bandwidth])
+    def test_unlisted_tpu_is_an_error_not_a_default(self, lookup):
+        with pytest.raises(ValueError, match="TPU v99"):
+            lookup("TPU v99")
+
+    def test_roofline_report_refuses_a_device_without_peaks(self):
+        from marian_tpu.common.flops import decode_lever_report
+        with pytest.raises(ValueError, match="needs a TPU kind"):
+            decode_lever_report(1024, 4096, 6, 32000, 16, 24, 256,
+                                device_kind="cpu")
+
+    def test_perf_geometry_unlisted_tpu_raises_cpu_reads_zero(self):
+        from marian_tpu.obs.perf import PerfMeter
+        from marian_tpu.serving import metrics as msm
+        meter = PerfMeter()
+        meter.enable(msm.Registry())
+        dims = dict(emb=64, ffn=128, enc_depth=1, dec_depth=1, vocab=100)
+        meter.set_geometry(device_kind="cpu", n_devices=1, **dims)
+        assert meter.m_peak.value == 0.0
+        meter.set_geometry(device_kind="TPU v5 lite", n_devices=4, **dims)
+        assert meter.m_peak.value == 4 * 197e12
+        with pytest.raises(ValueError, match="TPU v99"):
+            meter.set_geometry(device_kind="TPU v99", n_devices=1, **dims)
 
 
 class TestTrainFlops:
@@ -67,31 +91,6 @@ class TestTrainFlops:
 
     def test_deeper_costs_more(self):
         assert self._f(enc_depth=12) > self._f() > self._f(enc_depth=3)
-
-
-class TestCacheManifest:
-    def test_write_then_check_roundtrip(self, tmp_path):
-        from marian_tpu.common.profiling import check_cache_manifest
-        p = str(tmp_path)
-        assert check_cache_manifest(write=True, path=p) is True
-        assert os.path.exists(os.path.join(p, "MANIFEST.json"))
-        assert check_cache_manifest(path=p) is True
-
-    def test_missing_manifest_is_cold(self, tmp_path):
-        from marian_tpu.common.profiling import check_cache_manifest
-        assert check_cache_manifest(path=str(tmp_path / "nope")) is False
-
-    def test_drift_detected(self, tmp_path):
-        from marian_tpu.common.profiling import check_cache_manifest
-        p = str(tmp_path)
-        check_cache_manifest(write=True, path=p)
-        mp = os.path.join(p, "MANIFEST.json")
-        with open(mp) as fh:
-            fp = json.load(fh)
-        fp["platform_version"] = "libtpu-from-another-era"
-        with open(mp, "w") as fh:
-            json.dump(fp, fh)
-        assert check_cache_manifest(path=p) is False
 
 
 class TestDecodeRoofline:

@@ -227,6 +227,14 @@ class TestEngine:
         r = eng.admit_and_step([(0, TEXTS[0])])   # cap 12 -> 3 pages
         assert r.rejected and r.rejected[0][1] in FATAL_REASONS
 
+    def test_rows_past_the_kernel_smem_bound_fail_at_startup(self, tiny):
+        # tests/test_chip_compile.py compiles the kernel AT the bound;
+        # past it the chip compiler refuses, so the engine must first
+        from marian_tpu.ops.pallas.kv_pool import paged_kernel_max_rows
+        bound = paged_kernel_max_rows(3)
+        with pytest.raises(ValueError, match=f"SMEM bound of {bound} rows"):
+            make_engine(tiny, max_rows=bound + 1, row_buckets=(bound + 1,))
+
     def test_src_too_long_is_fatal(self, tiny):
         eng = make_engine(tiny)
         long_text = " ".join("w3" for _ in range(50))

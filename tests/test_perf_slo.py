@@ -436,6 +436,25 @@ class TestBootWarmup:
         assert calls == [["hello", "a b c d"],
                          ["the quick brown fox jumps over the lazy dog"]]
 
+    def test_boot_warmup_failure_stops_the_boot(self):
+        """A warm-up that cannot translate is the failure the first
+        client would meet — it is not logged and carried on from."""
+        def refused(lines):
+            raise RuntimeError("RESOURCE_EXHAUSTED: memory space smem")
+
+        async def main():
+            app = ServingApp(
+                Options({"metrics-port": 0, "max-queue": 64,
+                         "warmup-on-boot": True}),
+                translate_lines=refused, registry=msm.Registry())
+            try:
+                await app.start()
+            finally:
+                await app.shutdown(drain_timeout=2)
+
+        with pytest.raises(Exception, match="memory space smem"):
+            run(main())
+
 
 # ---------------------------------------------------------------------------
 # scheduler exports (CPU stub): chip-seconds/token + headroom

@@ -96,6 +96,28 @@ def test_server_e2e_websocket(tmp_path):
     assert r2.count("\n") == 1          # two sentences → two reply lines
 
 
+def test_readyz_waits_for_the_listener(tmp_path, monkeypatch):
+    """/readyz must not read ready between ServingApp.start() and the
+    request port's bind: a client that trusts it would be refused (the
+    chip run of chip_smoke.py hit exactly that window)."""
+    from marian_tpu.server import server as srv
+    seen = {}
+    start = srv.ServingApp.start
+
+    async def start_and_look(self):
+        await start(self)
+        seen["app"], seen["ready_before_bind"] = self, self.ready()
+    monkeypatch.setattr(srv.ServingApp, "start", start_and_look)
+
+    async def after_bind(port):
+        return seen["app"].ready()
+
+    ready_after_bind = asyncio.run(
+        _drive_serve(_tiny_server_options(tmp_path), after_bind))
+    assert seen["ready_before_bind"] is False
+    assert ready_after_bind is True
+
+
 def test_server_e2e_tcp_fallback(tmp_path, monkeypatch):
     """Real model over the dependency-free TCP framing — the transport
     _serve falls back to without websockets (forced here so the test is
