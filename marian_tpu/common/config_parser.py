@@ -359,10 +359,9 @@ _TRANSLATION = [
     _f("canary-min-batches", int, 8, "With --model-watch and --canary-fraction > 0: promote the canary to live after this many canary batches without tripping a rollback threshold (TPU extension)", "translate"),
     _f("warmup-golden", str, "", "With --model-watch: file of golden source sentences (one per line) each candidate model must translate during off-path warmup before it can serve — forces jit compilation of the serving shapes and proves the checkpoint decodes (empty = a built-in probe set) (TPU extension)", "translate"),
     # observability (marian_tpu/obs/ — docs/OBSERVABILITY.md)
-    _f("trace", bool, False, "Enable the request-scoped span tracer: every request's path (ingest, admission, queue wait, batch formation, dispatch, translate, reply write — and train-loop phases) is recorded into a bounded in-memory ring, exported as Chrome trace JSON at /tracez on the metrics port (open in Perfetto). Off = zero overhead: no ring allocation, no lock on the hot path (TPU extension)", "translate"),
+    _f("trace", bool, False, "Enable the request-scoped span tracer: every request's path (ingest, admission, queue wait, batch formation, dispatch, translate, reply write — and the trainer's data.* / train.* spans) is recorded into a bounded in-memory ring, exported as Chrome trace JSON at /tracez on the metrics port (open in Perfetto). Off = zero overhead: no ring allocation, no lock on the hot path (TPU extension)", "translate"),
     _f("trace-ring", int, 4096, "With --trace: span ring capacity — how many most-recent spans /tracez and flight-recorder dumps can see (TPU extension)", "translate"),
     _f("trace-dump", str, "", "Arm the crash flight recorder (implies --trace): on a dispatch-watchdog trip, a canary/live auto-rollback, a poison-request isolation, or an injected MARIAN_FAULTS kill, snapshot the span ring + event timeline + /metrics to a timestamped JSON file in this directory (docs/OBSERVABILITY.md runbook) (TPU extension)", "translate"),
-    _f("trace-sync-phases", bool, False, "Honest train-loop phase timing: drain the device (block_until_ready) at every StepTimer phase boundary so async dispatch cannot shift device seconds into whichever later phase blocks first. Serializes host and device — a diagnosis mode, not a throughput config (TPU extension)", "translate"),
     _f("perf-accounting", bool, True, "Live performance & capacity plane (obs/perf.py): per-batch chip-seconds/token, tokens/s, MFU-vs-analytic-roofline and capacity-headroom gauges on /metrics, plus per-shape-bucket jit-compile telemetry (boot/swap warmup vs steady-state recompiles — a steady-state recompile is a latency incident and lands on the event timeline). One counter update per device batch; `--perf-accounting false` restores the strictly lock-free batch path (TPU extension)", "translate"),
     _f("warmup-on-boot", bool, False, "marian-server: golden-warm every serving width bucket BEFORE accepting the first request (one jit compile per bucket off the serving path, reported as trigger=boot-warmup compile telemetry) instead of letting the first request of each bucket pay the compile inline (TPU extension)", "translate"),
     _f("fleet", str, "", "marian-server multi-tenant fleet serving: comma-separated <tag>=<model-path> tenants (e.g. 'en-de=/m/ende.npz,en-fr=/m/enfr.npz') served concurrently by ONE process — per-tenant lifecycle stacks (bundle watcher, canary, rollback) under the shared --fleet-hbm-budget-mb with evict-coldest + warm-on-demand; clients pick a tenant with the '#model:<tag>' protocol header. Request batching mode only; mutually exclusive with --model-watch (docs/DEPLOYMENT.md 'Fleet serving') (TPU extension)", "translate"),

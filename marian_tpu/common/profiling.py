@@ -2,7 +2,8 @@
 nvtx ranges + nvprof hooks in src/common/profiler.h; the TPU-native
 equivalents are jax.profiler device traces and HLO dumps).
 
-Three surfaces:
+Two surfaces (where host wall-clock goes is the span tracer's business:
+obs/trace.py, live under either of them):
 
 - ``--profile [dir]``: capture a jax.profiler trace (TensorBoard / xprof
   format) around a window of training updates. The trace records every XLA
@@ -10,22 +11,16 @@ Three surfaces:
   locating the throughput gap.
 - ``--dump-hlo path``: write the jaxpr and the optimized HLO of the jitted
   train step (the ExpressionGraph::graphviz debugging equivalent).
-- ``StepTimer``: lightweight host-side wall-clock histogram of the train
-  loop phases (data, step dispatch, host bookkeeping) — finds host-bound
-  gaps a device trace doesn't show.
 
-``StepTimer`` and ``TraceWindow`` were folded onto the span-tracer API
-(ISSUE 8) and now live in ``marian_tpu/obs/profiling.py`` — the names
-below are re-export shims so existing call sites keep importing from
-here. StepTimer additionally gained the ``sync_fn`` device-sync honesty
-fix (see its module docstring / docs/OBSERVABILITY.md).
+``TraceWindow`` lives in ``marian_tpu/obs/profiling.py``; the name below
+is a re-export so existing call sites keep importing from here.
 """
 
 from __future__ import annotations
 
 import os
 
-from ..obs.profiling import StepTimer, TraceWindow  # noqa: F401 — shims
+from ..obs.profiling import TraceWindow  # noqa: F401 — re-export
 from . import logging as log
 
 

@@ -23,6 +23,7 @@ Batch layout is batch-major ``[batch, time]`` (the reference is time-major
 from __future__ import annotations
 
 import dataclasses
+import itertools
 import queue
 import threading
 from typing import Iterator, List, Optional, Sequence, Tuple
@@ -31,6 +32,7 @@ import numpy as np
 
 from .corpus import Corpus, SentenceTuple, CorpusState
 from ..common import logging as log
+from ..obs import trace as obs_trace
 
 # Default sequence-length buckets: fine steps early (NMT sentences are short),
 # geometric later. Snapping to these keeps compile count ~O(10).
@@ -269,11 +271,16 @@ class BatchGenerator:
                 fixed = max(self.batch_multiple,
                             (words_budget // w) // self.batch_multiple
                             * self.batch_multiple)
-            batches.append(make_batch(cur, self.n_streams, self.length_buckets,
-                                      self.batch_multiple, self.pad_batch,
-                                      corpus_state=state,
-                                      weighting_type=self.weighting_type,
-                                      fixed_rows=fixed))
+            with obs_trace.span("data.make_batch") as sp:
+                b = make_batch(cur, self.n_streams, self.length_buckets,
+                               self.batch_multiple, self.pad_batch,
+                               corpus_state=state,
+                               weighting_type=self.weighting_type,
+                               fixed_rows=fixed)
+                if sp:
+                    sp.set_attrs(rows=b.batch_size,
+                                 width=b.trg.batch_width)
+            batches.append(b)
 
         scale = 1.0
         if self.budget_scale is not None:
@@ -306,31 +313,38 @@ class BatchGenerator:
 
     def _generate(self) -> Iterator[CorpusBatch]:
         from ..common import faultpoints as fp
-        buf: List[SentenceTuple] = []
         cap = self.maxi_batch * self.mini_batch
         it = iter(self.corpus)
-        for t in it:
-            buf.append(t)
-            if len(buf) >= cap:
+        first = True
+        while True:
+            # the epoch's FIRST window carries the corpus' shuffle (run by
+            # Corpus.__iter__ on its first next()), the read and encode of
+            # up to a whole maxi-batch, and its sort: the stall every
+            # epoch opens with, as one span on the prefetch thread
+            with (obs_trace.span("data.epoch_prepare") if first
+                  else obs_trace.NOOP_SPAN) as sp:
+                buf = list(itertools.islice(it, cap))
+                if not buf:
+                    break
                 # POST-window snapshot: the corpus position once every
-                # sentence of this maxi window has been consumed. A save
-                # taken after applying this window's batches resumes
+                # sentence of this maxi window has been consumed (after
+                # the epoch rolled over, when the window ended it). A
+                # save taken after applying this window's batches resumes
                 # HERE — exact at window boundaries, window-granular in
                 # between (docs/ROBUSTNESS.md). The LIVE corpus.state is
                 # no resume point at all: the prefetch thread runs it
                 # arbitrarily far ahead of what training has applied.
                 state = self.corpus.state.as_dict()
-                for b in self._split_maxi(buf, state):
-                    # chaos harness hook: a corpus/pipeline failure (bad
-                    # shard, fs hiccup) surfaces HERE, mid-epoch — the
-                    # crash-resume protocol must cover it like any kill
-                    fp.fault_point("data.batch.next")
-                    yield b
-                buf = []
-        state = self.corpus.state.as_dict()
-        for b in self._split_maxi(buf, state):
-            fp.fault_point("data.batch.next")
-            yield b
+                batches = self._split_maxi(buf, state)
+                if sp:
+                    sp.set_attrs(lines=len(buf), batches=len(batches))
+            first = False
+            for b in batches:
+                # chaos harness hook: a corpus/pipeline failure (bad
+                # shard, fs hiccup) surfaces HERE, mid-epoch — the
+                # crash-resume protocol must cover it like any kill
+                fp.fault_point("data.batch.next")
+                yield b
 
     def __iter__(self) -> Iterator[CorpusBatch]:
         if not self.prefetch:
@@ -353,7 +367,8 @@ class BatchGenerator:
         th = threading.Thread(target=worker, daemon=True, name="batchgen-prefetch")
         th.start()
         while True:
-            b = q.get()
+            with obs_trace.span("data.wait"):
+                b = q.get()
             if b is _END:
                 break
             yield b

@@ -21,6 +21,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from ..ops.ops import named_scope
+
 
 @dataclasses.dataclass(eq=False)
 class FactorTables:
@@ -73,6 +75,7 @@ def factored_embed_concat(lemma_table: jax.Array, factor_table: jax.Array,
     return jnp.concatenate(parts, axis=-1)
 
 
+@named_scope("output")
 def factored_log_probs(unit_logits: jax.Array, ft: FactorTables,
                        shortlist: Optional[jax.Array] = None,
                        factor_weight: float = 1.0) -> jax.Array:

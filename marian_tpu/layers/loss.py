@@ -15,7 +15,7 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 
-from ..ops.ops import cross_entropy
+from ..ops.ops import cross_entropy, named_scope
 
 
 @dataclasses.dataclass
@@ -36,6 +36,7 @@ class RationalLoss:
         raise ValueError(f"Unknown cost-type {cost_type}")
 
 
+@named_scope("loss")
 def cross_entropy_loss(logits: jax.Array, labels: jax.Array,
                        mask: jax.Array, label_smoothing: float = 0.0,
                        data_weights: Optional[jax.Array] = None,

@@ -16,6 +16,7 @@ import jax
 import jax.numpy as jnp
 
 from ..layers.loss import RationalLoss, cross_entropy_loss, guided_alignment_loss
+from ..obs import trace as obs_trace
 from . import transformer as T
 
 Params = Dict[str, jax.Array]
@@ -125,7 +126,8 @@ class EncoderDecoder:
              key: Optional[jax.Array] = None, train: bool = True
              ) -> Tuple[jax.Array, Dict[str, jax.Array]]:
         """Returns (ce_sum_plus_aux, aux dict with loss_sum/labels)."""
-        cparams = T.cast_params(params, self.cfg.compute_dtype)
+        with jax.named_scope("cast"):
+            cparams = T.cast_params(params, self.cfg.compute_dtype)
         k_enc = jax.random.fold_in(key, 1) if key is not None else None
         k_dec = jax.random.fold_in(key, 2) if key is not None else None
         src_ids, src_mask = self._batch_sources(batch)
@@ -154,7 +156,9 @@ class EncoderDecoder:
             moe_aux = moe_aux + parts.pop(0)
         if table is not None and not (self.unlikelihood
                                       and "data_weights" in batch):
-            rl = self._fused_ce_loss(cparams, table, hidden, batch)
+            # output projection and loss are ONE streaming kernel here
+            with jax.named_scope("loss"):
+                rl = self._fused_ce_loss(cparams, table, hidden, batch)
         else:
             if table is not None:      # fused path skipped for unlikelihood
                 hidden = self._mod.output_logits(self.cfg, cparams, hidden)
@@ -366,12 +370,19 @@ def batch_to_arrays(batch, compact: bool = False,
                 f"{prefix}_mask": jnp.asarray(sb.mask)}
 
     out = {}
-    out.update(stream(0, "src", batch.src))
-    out.update(stream(len(batch.sub) - 1, "trg", batch.trg))
-    for i, sb in enumerate(batch.sub[1:-1], start=2):
-        out.update(stream(i - 1, f"src{i}", sb))
-    if batch.guided_alignment is not None:
-        out["guided"] = jnp.asarray(batch.guided_alignment)
-    if batch.data_weights is not None:
-        out["data_weights"] = jnp.asarray(batch.data_weights)
+    # host arrays become device arrays here: the step's H2D transfer
+    with obs_trace.span("train.h2d") as sp:
+        out.update(stream(0, "src", batch.src))
+        out.update(stream(len(batch.sub) - 1, "trg", batch.trg))
+        for i, sb in enumerate(batch.sub[1:-1], start=2):
+            out.update(stream(i - 1, f"src{i}", sb))
+        if batch.guided_alignment is not None:
+            out["guided"] = jnp.asarray(batch.guided_alignment)
+        if batch.data_weights is not None:
+            out["data_weights"] = jnp.asarray(batch.data_weights)
+        if sp:
+            sp.set_attrs(rows=batch.batch_size,
+                         src_width=batch.src.batch_width,
+                         trg_width=batch.trg.batch_width,
+                         bytes=sum(int(v.nbytes) for v in out.values()))
     return out

@@ -35,7 +35,7 @@ import jax.numpy as jnp
 
 from ..layers import initializers as inits
 from ..ops.ops import (activation, affine, dropout, layer_norm,
-                       logits_matmul)
+                       logits_matmul, named_scope)
 from ..ops.attention import (attention, causal_mask,
                              dense_attention_with_weights)
 
@@ -607,6 +607,7 @@ def init_params(cfg: TransformerConfig, key: jax.Array) -> Params:
 # Building blocks
 # ---------------------------------------------------------------------------
 
+@named_scope("pre_post")
 def _pre_post(cfg: TransformerConfig, ops: str, x: jax.Array,
               residual: Optional[jax.Array], prefix: str, params: Params,
               key, train: bool) -> jax.Array:
@@ -723,6 +724,8 @@ def fused_decode_active(cfg: TransformerConfig) -> bool:
     return jax.default_backend() == "tpu"
 
 
+@named_scope(lambda _cfg, _params, prefix, *_a, **_kw:
+         "self_attn" if prefix.endswith("_self") else "cross_attn")
 def _mha(cfg: TransformerConfig, params: Params, prefix: str,
          q_in: jax.Array, kv_in: jax.Array, mask: Optional[jax.Array],
          key, train: bool,
@@ -972,6 +975,7 @@ def _autoreg_train(cfg: TransformerConfig, params: Params, lp: str,
     return out
 
 
+@named_scope("ffn")
 def _ffn(cfg: TransformerConfig, params: Params, prefix: str, x: jax.Array,
          dim_ffn: int, depth: int, key, train: bool) -> jax.Array:
     act = activation(cfg.ffn_activation)
@@ -984,6 +988,7 @@ def _ffn(cfg: TransformerConfig, params: Params, prefix: str, x: jax.Array,
     return x
 
 
+@named_scope("ffn")
 def _moe_ffn(cfg: TransformerConfig, params: Params, prefix: str,
              x: jax.Array, train: bool = False,
              key=None, mask: Optional[jax.Array] = None
@@ -1178,6 +1183,7 @@ def _ulr_embed(cfg: TransformerConfig, params: Params, ids: jax.Array,
     return u.astype(cfg.compute_dtype)
 
 
+@named_scope("embed")
 def _embed(cfg: TransformerConfig, params: Params, ids: jax.Array,
            side: str, key, train: bool, start_pos=0,
            enc_idx: int = 0) -> jax.Array:
@@ -1318,6 +1324,7 @@ def _stacked_layer_params(cfg: TransformerConfig, params: Params,
     return out
 
 
+@named_scope("encoder")
 def encode(cfg: TransformerConfig, params: Params, src_ids,
            src_mask, train: bool = False,
            key: Optional[jax.Array] = None, with_aux: bool = False):
@@ -1418,6 +1425,7 @@ def _encode_one(cfg: TransformerConfig, params: Params, src_ids: jax.Array,
 # Decoder (teacher-forced training path)
 # ---------------------------------------------------------------------------
 
+@named_scope("decoder")
 def decode_train(cfg: TransformerConfig, params: Params, enc_out: jax.Array,
                  src_mask: jax.Array, trg_ids: jax.Array,
                  trg_mask: jax.Array, train: bool = True,
@@ -1431,10 +1439,11 @@ def decode_train(cfg: TransformerConfig, params: Params, enc_out: jax.Array,
     Input embeddings are the gold embeddings shifted right with a zero vector
     at t=0 (reference: TransformerDecoder::step on full groundTruth)."""
     kk = (lambda i: jax.random.fold_in(key, i)) if key is not None else (lambda i: None)
-    we = _embed_words(cfg, params, trg_ids, "trg")
-    we = shift_right_embeddings(we)
-    we = _word_dropout(cfg, we, cfg.dropout_trg, kk(0), train)
-    x = _add_pos(cfg, params, we, 0)
+    with jax.named_scope("embed"):
+        we = _embed_words(cfg, params, trg_ids, "trg")
+        we = shift_right_embeddings(we)
+        we = _word_dropout(cfg, we, cfg.dropout_trg, kk(0), train)
+        x = _add_pos(cfg, params, we, 0)
     x = _pre_post(cfg, cfg.postprocess_emb, x, None, "decoder_emb", params,
                   kk(1), train)
     tt = trg_ids.shape[1]
@@ -1583,6 +1592,7 @@ def _lemma_conditioned_units(cfg: TransformerConfig, params: Params,
     return jnp.concatenate([lemma_units, fac_units], axis=-1)
 
 
+@named_scope("output")
 def output_logits(cfg: TransformerConfig, params: Params, x: jax.Array,
                   shortlist: Optional[jax.Array] = None) -> jax.Array:
     """Output projection with tied embeddings and optional shortlist slice

@@ -7,10 +7,9 @@ turns the same analytic cost model (common/flops.py) into **live
 gauges**, fed by the layers that actually spend device time:
 
 - the serving scheduler reports every device batch (rows, width bucket,
-  real tokens, device seconds measured to the host-side result fence —
-  the StepTimer sync-honesty discipline: ``translate_lines`` returns
-  host strings, so the return IS the drain; the timestamp is taken
-  after it, never at enqueue);
+  real tokens, seconds measured to the host-side result fence:
+  ``translate_lines`` returns host strings, so the return IS the drain;
+  the timestamp is taken after it, never at enqueue);
 - the training scheduler reports every display window (whose duration
   is already clocked after the window's one deferred device sync);
 - the lifecycle warmup and the scheduler report jit-compile activity
@@ -41,6 +40,13 @@ Exported series (docs/OBSERVABILITY.md "The perf plane"):
   compile seconds via jax.monitoring, when jax is live (the bucket
   telemetry above is inferred at the serving layer and works with stub
   executors; this series is ground truth on a real device).
+
+What the "device seconds" are: HOST WALL seconds on the worker thread
+around the translate call (or the trainer's display window), not seconds
+the chip was busy — they hold dispatch, transfers and the host's share of
+every round. The busy-ratio, chip-seconds and MFU series are therefore
+upper bounds of utilisation from the host's side; seconds the device was
+busy come only from a profiler trace (cli/profile_summary.py).
 
 Granularity honesty: serving "shape bucket" means the WIDTH bucket of
 the repo's length-bucket table (``data/batch_generator.py``). The row
@@ -203,8 +209,9 @@ class PerfMeter:
         r = self._registry
         self.m_device_s = r.counter(
             "marian_perf_device_seconds_total",
-            "Device-worker seconds spent in translate calls, measured to "
-            "the host-side result fence (sync-honest)",
+            "HOST WALL seconds the device worker spent in translate "
+            "calls, measured to the host-side result fence (not seconds "
+            "the chip was busy)",
             labels=("model_version",))
         self.m_tokens = r.counter(
             "marian_perf_tokens_total",
@@ -216,9 +223,10 @@ class PerfMeter:
             labels=("model_version",))
         self.m_cspt = r.gauge(
             "marian_perf_chip_seconds_per_token",
-            "Rolling chip-seconds per real source token (device seconds x "
-            "device count / tokens over the last window) — the capacity / "
-            "autoscaling signal (ROADMAP 4)",
+            "Rolling chip-seconds per real source token (worker host wall "
+            "seconds x device count / tokens over the last window; not "
+            "device-busy seconds) — the capacity / autoscaling signal "
+            "(ROADMAP 4)",
             labels=("model_version",))
         self.m_tps = r.gauge(
             "marian_perf_tokens_per_second",
@@ -227,8 +235,9 @@ class PerfMeter:
             labels=("model_version",))
         self.m_busy = r.gauge(
             "marian_perf_device_busy_ratio",
-            "Rolling fraction of wall-clock the device worker spent "
-            "inside translate calls (scrape-time over the window — "
+            "Rolling fraction of wall-clock the device worker THREAD "
+            "spent inside translate calls: host wall seconds, not time "
+            "the chip was busy (scrape-time over the window — "
             "decays to 0 at idle, so an autoscaler never sees phantom "
             "saturation on an idle replica)")
         self.m_busy.set_function(self._busy_now)
@@ -275,9 +284,9 @@ class PerfMeter:
             labels=("trigger",))
         self.m_train_cspt = r.gauge(
             "marian_train_chip_seconds_per_token",
-            "Training: wall seconds x device count per target label over "
-            "the last display window (window duration is clocked after "
-            "the window's deferred device sync — honest)")
+            "Training: host wall seconds x device count per target label "
+            "over the last display window (clocked after the window's "
+            "deferred device sync; not device-busy seconds)")
         self.m_train_mfu = r.gauge(
             "marian_train_mfu",
             "Training: rolling model-FLOPs utilization of the last "

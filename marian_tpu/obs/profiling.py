@@ -1,116 +1,23 @@
-"""Training-loop profiling folded onto the span API (ISSUE 8 satellite):
-``StepTimer`` (host-side phase accounting, now span-emitting) and
-``TraceWindow`` (jax.profiler device-trace window, now timeline-stamped).
-``common/profiling.py`` re-exports both, so existing call sites keep
-importing from there.
-
-StepTimer's device-sync honesty fix
------------------------------------
-
-JAX dispatch is asynchronous: ``gg.update(...)`` returns as soon as the
-step is ENQUEUED, and the host blocks only when something later reads a
-device value (the display-window sync, a checkpoint snapshot). The old
-StepTimer stamped phase boundaries with bare ``perf_counter`` reads, so
-under async dispatch the "dispatch" phase measured enqueue cost (~µs)
-while the device seconds it caused were billed to whichever later phase
-happened to block first — phase shares that LOOK precise and are
-systematically wrong.
-
-The fix is placement: when a ``sync_fn`` is provided (``marian-train
---trace-sync-phases`` wires ``jax.block_until_ready`` over the params),
-``phase()`` drains the device BEFORE taking the boundary timestamp, so
-each phase absorbs the device work it issued. This serializes host and
-device — it is a diagnosis mode, off by default, and the throughput cost
-is the reason it is a flag and not the default (docs/OBSERVABILITY.md
-"Honest phase timing").
+"""``TraceWindow``: the program's ``--profile``, a jax.profiler session
+around a window of training updates. While it is open every span of
+obs/trace.py is live and lands in the trace's ``/host:CPU`` plane beside
+the device ops, on one clock (``cli/profile_summary.py`` reads the
+result); window open and close are stamped onto the event timeline.
+``common/profiling.py`` re-exports it.
 """
 
 from __future__ import annotations
 
 import os
-import time
-from typing import Callable, Dict, Optional
+from typing import Optional
 
 from ..common import logging as log
 from .trace import TRACER
 
 
-class StepTimer:
-    """Host-side phase timer: where does wall-clock go between device
-    steps? ``phase(name)`` closes the previous phase and opens ``name``;
-    ``report()`` logs a one-line summary and mirrors the totals into the
-    metrics registry. With the tracer enabled, every closed phase is
-    also recorded as a ``train.<phase>`` span, so /tracez shows the
-    train loop on the same timeline as serving."""
-
-    def __init__(self, enabled: bool = True,
-                 sync_fn: Optional[Callable[[], None]] = None,
-                 span_prefix: str = "train"):
-        self.enabled = enabled
-        # called BEFORE each boundary timestamp when set — see the
-        # module docstring for why placement (before, not after) is the
-        # honesty fix
-        self.sync_fn = sync_fn
-        self.span_prefix = span_prefix
-        self.spans: Dict[str, float] = {}
-        self.counts: Dict[str, int] = {}
-        self._t: Optional[float] = None
-        self._phase: Optional[str] = None
-
-    def phase(self, name: str) -> None:
-        if not self.enabled:
-            return
-        if self.sync_fn is not None:
-            # drain pending device work into the CLOSING phase — the
-            # whole point of --trace-sync-phases (module docstring)
-            self.sync_fn()
-        now = time.perf_counter()
-        if self._phase is not None and self._t is not None:
-            self.spans[self._phase] = self.spans.get(self._phase, 0.0) \
-                + (now - self._t)
-            self.counts[self._phase] = self.counts.get(self._phase, 0) + 1
-            if TRACER.enabled and self._phase != "__end__":
-                TRACER.record(f"{self.span_prefix}.{self._phase}",
-                              self._t, now)
-        self._phase, self._t = name, now
-
-    def stop(self) -> None:
-        self.phase("__end__")
-        self._phase = None
-
-    def report(self) -> Dict[str, float]:
-        total = sum(v for k, v in self.spans.items() if k != "__end__")
-        out = {}
-        for k, v in sorted(self.spans.items(), key=lambda kv: -kv[1]):
-            if k == "__end__":
-                continue
-            out[k] = v
-        if self.enabled and total > 0:
-            line = " ".join(f"{k}={v:.2f}s({100*v/total:.0f}%)"
-                            for k, v in out.items())
-            log.info("Step phases: {}", line)
-            # mirror the phase totals into the process-wide metrics
-            # registry (serving/metrics.py — ISSUE 1): with --metrics-port
-            # a Prometheus scrape sees where train-loop wall-clock goes
-            # (data vs dispatch vs host) without grepping logs
-            try:
-                from ..serving import metrics as msm
-                g = msm.gauge("marian_step_phase_seconds",
-                              "Host wall-clock per train-loop phase since "
-                              "the last report", labels=("phase",))
-                for k, v in out.items():
-                    g.labels(k).set(v)
-            except Exception:  # noqa: BLE001 — observability is optional
-                pass
-        return out
-
-
 class TraceWindow:
-    """Capture a jax.profiler trace for updates [start, stop). The
-    device-level complement of the span tracer: spans say where HOST
-    wall-clock went, the profiler trace says what the chip ran. Window
-    open/close are stamped onto the span timeline so the two exports can
-    be aligned."""
+    """Capture a jax.profiler trace for updates [start, stop): what the
+    chip ran, and the program's spans for the same updates."""
 
     def __init__(self, options):
         prof = options.get("profile", None)
@@ -132,7 +39,14 @@ class TraceWindow:
         import jax
         if not self._active and update >= self.start_update:
             os.makedirs(self.dir, exist_ok=True)
-            jax.profiler.start_trace(self.dir)
+            # host side through TraceMe only: the program's spans and the
+            # runtime's. The Python tracer adds an event per call, nested
+            # inside every span (a 5.3 ms `train.dispatch` kept 0.1 ms of
+            # self time, and cost 1.6 ms more than untraced; my chip
+            # runs, PR 24)
+            popts = jax.profiler.ProfileOptions()
+            popts.python_tracer_level = 0
+            jax.profiler.start_trace(self.dir, profiler_options=popts)
             self._active = True
             self._started_at = update
             TRACER.event("profile.window_start", update=update,
@@ -145,8 +59,8 @@ class TraceWindow:
             self._done = True
             TRACER.event("profile.window_stop", update=update)
             log.info("Profiler trace stopped after update {} ({} updates); "
-                     "view with tensorboard --logdir {}", update,
-                     self.n_updates, self.dir)
+                     "read with python -m marian_tpu.cli.profile_summary {}",
+                     update, self.n_updates, self.dir)
 
     def close(self) -> None:
         if self._active:

@@ -14,12 +14,23 @@ flight recorder (obs/flight.py).
 Design constraints (docs/OBSERVABILITY.md):
 
 - **Stdlib-only**, importable from any layer (the scheduler, the
-  trainer, the analysis tooling) with no jax.
-- **Zero overhead when disabled** (the default): ``start_span`` returns
-  the NOOP_SPAN singleton after one attribute check — no ring is ever
-  allocated, no lock is ever acquired, no dict is built. The tier-1
-  overhead-guard test asserts exactly this on the scheduler's per-batch
-  hot path.
+  trainer, the data loader, the analysis tooling): this module never
+  imports jax. Where the process already has (``jax.profiler`` in
+  ``sys.modules``) it binds ``jax.profiler.TraceAnnotation`` lazily.
+- **"On" follows the profiler**: a span is LIVE when the tracer is
+  enabled (``--trace``) OR a ``jax.profiler`` session is collecting
+  (``marian-train --profile``, a capture attached through
+  ``--profile-server``, the benchmark's ``--trace 1``). A live span is
+  also a TraceMe on the calling thread, so it lands in the session's
+  ``/host:CPU`` plane on the SAME CLOCK as the device ops; and it feeds
+  the per-name totals (calls, seconds, self seconds) of
+  :meth:`Tracer.totals`. The ring (``/tracez``, flight dumps) fills only
+  while the tracer is enabled.
+- **Zero overhead when off** (the default): ``start_span`` returns the
+  NOOP_SPAN singleton after two flag reads — no ring is ever allocated,
+  no lock is ever acquired, no dict is built. The tier-1 overhead-guard
+  tests assert exactly this on the scheduler's per-batch hot path and
+  on the trainer's loop objects.
 - **Lock-free-ish when enabled**: spans are recorded once, at END time,
   with a single bounded-deque append under a lockdep-named lock
   (``Tracer._lock``) held for nanoseconds; exports snapshot under the
@@ -47,6 +58,7 @@ import itertools
 import json
 import os
 import random
+import sys
 import threading
 import time
 from typing import Dict, Iterator, List, Optional, Tuple
@@ -65,6 +77,24 @@ _CURRENT: contextvars.ContextVar = contextvars.ContextVar(
 
 DEFAULT_RING = 4096
 DEFAULT_EVENT_RING = 2048
+
+# jax.profiler.TraceAnnotation once the process has imported jax.profiler
+# (never imported from here: loadgen and the data layer stay off JAX)
+_ANNOTATION = None
+
+
+def profiler_collecting() -> bool:
+    """Whether a jax.profiler session is collecting right now (a static
+    flag read, ~30 ns); False in a process that never imported jax."""
+    global _ANNOTATION
+    ann = _ANNOTATION
+    if ann is None:
+        mod = sys.modules.get("jax.profiler")
+        ann = getattr(mod, "TraceAnnotation", None)
+        if ann is None:
+            return False
+        _ANNOTATION = ann
+    return ann.is_enabled()
 
 
 def new_trace_id() -> str:
@@ -85,6 +115,12 @@ class _NoopSpan:
     def set_attrs(self, **kw) -> "_NoopSpan":
         return self
 
+    def __enter__(self) -> "_NoopSpan":
+        return self         # `with tracer.span(..)` while off
+
+    def __exit__(self, *exc) -> bool:
+        return False
+
     def __bool__(self) -> bool:
         return False        # `if span:` guards read naturally
 
@@ -102,7 +138,7 @@ class Span:
     silently rewrite history)."""
 
     __slots__ = ("name", "trace_id", "span_id", "parent_id", "start",
-                 "end_t", "attrs", "thread")
+                 "end_t", "attrs", "thread", "_up", "_child_s", "_ann")
 
     def __init__(self, name: str, trace_id: str, span_id: str,
                  parent_id: str, start: float,
@@ -115,9 +151,14 @@ class Span:
         self.end_t: Optional[float] = None
         self.attrs: Dict = attrs if attrs is not None else {}
         self.thread = threading.current_thread().name
+        self._up: Optional["Span"] = None   # live parent, until end
+        self._child_s = 0.0     # seconds same-thread children covered
+        self._ann = None        # the open TraceMe, while a session runs
 
     def set_attrs(self, **kw) -> "Span":
         self.attrs.update(kw)
+        if self._ann is not None:
+            self._ann.set_metadata(**kw)
         return self
 
     def duration(self) -> float:
@@ -129,6 +170,29 @@ class Span:
     def __repr__(self) -> str:
         return (f"<span {self.name} trace={self.trace_id} "
                 f"id={self.span_id} parent={self.parent_id or '-'}>")
+
+
+class _SpanScope:
+    """``with`` form of one live span: makes it the context's current
+    span, records an escaping exception, always ends it."""
+
+    __slots__ = ("_tracer", "_span", "_token")
+
+    def __init__(self, tracer: "Tracer", span: Span):
+        self._tracer = tracer
+        self._span = span
+        self._token = None
+
+    def __enter__(self) -> Span:
+        self._token = _CURRENT.set(self._span)
+        return self._span
+
+    def __exit__(self, _etype, exc, _tb) -> bool:
+        if exc is not None:
+            self._span.attrs.setdefault("error", repr(exc))
+        _CURRENT.reset(self._token)
+        self._tracer.end(self._span)
+        return False
 
 
 class Tracer:
@@ -144,6 +208,9 @@ class Tracer:
         # no ring allocation, not an empty ring (tier-1 overhead guard)
         self._ring: Optional[collections.deque] = None   # guarded-by: _lock
         self._events: Optional[collections.deque] = None  # guarded-by: _lock
+        # name -> [calls, seconds, self seconds, thread]; allocated by
+        # the first live span that ends, like the rings by enable()
+        self._totals: Optional[Dict[str, List]] = None   # guarded-by: _lock
         self._lock = lockdep.make_lock("Tracer._lock")
         self._seq = itertools.count(1)   # span ids; count() is GIL-atomic
 
@@ -178,6 +245,18 @@ class Tracer:
         with self._lock:
             self._ring = None
             self._events = None
+            self._totals = None
+
+    def totals(self) -> Dict[str, Dict]:
+        """Per span name, over every live span ended since reset():
+        ``calls``, ``seconds``, ``self_seconds`` (the duration less what
+        child spans on the same thread covered: same-thread self times
+        add up to the outermost spans' durations) and the ``thread`` of
+        the first call. Empty while nothing was live."""
+        with self._lock:
+            return {name: {"calls": t[0], "seconds": t[1],
+                           "self_seconds": t[2], "thread": t[3]}
+                    for name, t in (self._totals or {}).items()}
 
     # -- recording ----------------------------------------------------------
     def start_span(self, name: str, parent: Optional[Span] = None,
@@ -185,8 +264,12 @@ class Tracer:
         """Open a span. ``parent=None`` inherits the context's current
         span (same task/thread); pass the parent explicitly when
         crossing threads. Not recorded until :meth:`end`."""
-        if not self._enabled:
+        if not self._enabled and not profiler_collecting():
             return NOOP_SPAN
+        return self._open(name, parent, trace_id, attrs)
+
+    def _open(self, name: str, parent, trace_id, attrs: Dict,
+              past_start: Optional[float] = None) -> Span:
         if parent is None:
             parent = _CURRENT.get(None)
         if parent is NOOP_SPAN:
@@ -194,39 +277,62 @@ class Tracer:
         if trace_id is None:
             trace_id = parent.trace_id if parent is not None \
                 else new_trace_id()
-        return Span(name, trace_id, f"{next(self._seq):x}",
-                    parent.span_id if parent is not None else "",
-                    time.perf_counter(), dict(attrs) if attrs else None)
+        sp = Span(name, trace_id, f"{next(self._seq):x}",
+                  parent.span_id if parent is not None else "",
+                  time.perf_counter() if past_start is None else past_start,
+                  dict(attrs) if attrs else None)
+        sp._up = parent
+        if past_start is None and profiler_collecting():
+            # the same interval as a TraceMe on this thread: the
+            # session's /host:CPU plane, on the device ops' clock
+            sp._ann = _ANNOTATION(name, **attrs)
+            sp._ann.__enter__()
+        return sp
 
     def end(self, span, **attrs) -> None:
-        """Close ``span`` and record it into the ring. Idempotent; a
-        NOOP_SPAN or None is ignored."""
+        """Close ``span``: its TraceMe, the totals, and (tracer enabled)
+        the ring. Idempotent; a NOOP_SPAN or None is ignored."""
         if span is None or span is NOOP_SPAN or not isinstance(span, Span):
             return
         if span.end_t is not None:
             return
         if attrs:
-            span.attrs.update(attrs)
+            span.set_attrs(**attrs)
         span.end_t = time.perf_counter()
+        ann, span._ann = span._ann, None
+        if ann is not None:
+            ann.__exit__(None, None, None)
+        self._account(span)
+
+    def _account(self, span: Span) -> None:
+        """Totals (always, while live) and the ring (tracer enabled)."""
+        dur = span.end_t - span.start
+        up, span._up = span._up, None
+        if up is not None and up.thread == span.thread:
+            up._child_s += dur
         with self._lock:
-            if self._ring is not None:
+            if self._totals is None:
+                self._totals = {}
+            t = self._totals.get(span.name)
+            if t is None:
+                t = self._totals[span.name] = [0, 0.0, 0.0, span.thread]
+            t[0] += 1
+            t[1] += dur
+            t[2] += max(0.0, dur - span._child_s)
+            if self._enabled and self._ring is not None:
                 self._ring.append(span)
 
     def record(self, name: str, start: float, end: float,
                parent: Optional[Span] = None, trace_id: Optional[str] = None,
                **attrs) -> None:
         """Record a retroactive complete span from two perf_counter
-        timestamps (phase timers, reply writes measured after the fact)."""
-        if not self._enabled:
+        timestamps (reply writes measured after the fact). Totals and
+        ring only: a TraceMe cannot be opened in the past."""
+        if not self._enabled and not profiler_collecting():
             return
-        sp = self.start_span(name, parent=parent, trace_id=trace_id, **attrs)
-        if sp is NOOP_SPAN:
-            return
-        sp.start = start
+        sp = self._open(name, parent, trace_id, attrs, past_start=start)
         sp.end_t = end
-        with self._lock:
-            if self._ring is not None:
-                self._ring.append(sp)
+        self._account(sp)
 
     def event(self, name: str, **attrs) -> None:
         """Record an instant event onto the timeline (lifecycle
@@ -273,25 +379,16 @@ class Tracer:
         finally:
             _CURRENT.reset(token)
 
-    @contextlib.contextmanager
     def span(self, name: str, parent: Optional[Span] = None,
-             trace_id: Optional[str] = None, **attrs) -> Iterator:
+             trace_id: Optional[str] = None, **attrs):
         """``with tracer.span("name"):`` — start, set context, always
         end. The safe default; manual start_span/end pairs are for spans
-        whose lifetime crosses callbacks (MT-SPAN-UNCLOSED lints those)."""
-        sp = self.start_span(name, parent=parent, trace_id=trace_id, **attrs)
-        if sp is NOOP_SPAN:
-            yield sp
-            return
-        token = _CURRENT.set(sp)
-        try:
-            yield sp
-        except BaseException as e:
-            sp.attrs.setdefault("error", repr(e))
-            raise
-        finally:
-            _CURRENT.reset(token)
-            self.end(sp)
+        whose lifetime crosses callbacks (MT-SPAN-UNCLOSED lints those).
+        While off this returns the NOOP_SPAN singleton, itself a context
+        manager: a span site costs the two flag reads and no object."""
+        if not self._enabled and not profiler_collecting():
+            return NOOP_SPAN
+        return _SpanScope(self, self._open(name, parent, trace_id, attrs))
 
     # -- export -------------------------------------------------------------
     def snapshot(self, last: Optional[int] = None

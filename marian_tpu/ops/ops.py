@@ -13,6 +13,7 @@ Numerics conventions kept from the reference:
 
 from __future__ import annotations
 
+import functools
 from functools import partial
 from typing import Optional
 
@@ -20,6 +21,23 @@ import jax
 import jax.numpy as jnp
 
 NEG_INF = -1e9  # large-negative mask value; safe in bf16 (min normal ~ -3.4e38)
+
+
+def named_scope(name):
+    """Run the function under ``jax.named_scope(name)`` (`name` may be a
+    function of the call's arguments). Metadata only: every op traced
+    inside carries the scope in its HLO ``op_name``, which is how a
+    profile attributes device time to encoder/decoder/ffn/...; the
+    compiled program is the same. No layer index goes into a name:
+    scanned layers have none, and like ops must add up."""
+    def deco(fn):
+        @functools.wraps(fn)
+        def in_scope(*args, **kwargs):
+            with jax.named_scope(name(*args, **kwargs) if callable(name)
+                                 else name):
+                return fn(*args, **kwargs)
+        return in_scope
+    return deco
 
 
 def layer_norm(x: jax.Array, scale: jax.Array, bias: Optional[jax.Array] = None,

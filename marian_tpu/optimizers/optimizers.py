@@ -172,20 +172,21 @@ def apply_update(cfg: OptimizerConfig, state: Dict[str, Any], params: Params,
 
     out: Params = {}
     if cfg.name == "adam":
-        bc1 = 1.0 - jnp.power(cfg.beta1, t)
-        bc2 = 1.0 - jnp.power(cfg.beta2, t)
-        m_new, v_new = {}, {}
-        m_dtype = jnp.dtype(cfg.state_dtype)
-        for k, p in params.items():
-            g = grads[k].astype(jnp.float32)
-            m = cfg.beta1 * state["m"][k].astype(jnp.float32) \
-                + (1.0 - cfg.beta1) * g
-            v = cfg.beta2 * state["v"][k] + (1.0 - cfg.beta2) * jnp.square(g)
-            m_new[k], v_new[k] = m.astype(m_dtype), v
-            mhat = m / bc1
-            vhat = v / bc2
-            out[k] = (p.astype(jnp.float32)
-                      - lr * mhat / (jnp.sqrt(vhat) + eps)).astype(p.dtype)
+        with jax.named_scope("adam"):     # metadata only (profiles)
+            bc1 = 1.0 - jnp.power(cfg.beta1, t)
+            bc2 = 1.0 - jnp.power(cfg.beta2, t)
+            m_new, v_new = {}, {}
+            m_dtype = jnp.dtype(cfg.state_dtype)
+            for k, p in params.items():
+                g = grads[k].astype(jnp.float32)
+                m = cfg.beta1 * state["m"][k].astype(jnp.float32) \
+                    + (1.0 - cfg.beta1) * g
+                v = cfg.beta2 * state["v"][k] + (1.0 - cfg.beta2) * jnp.square(g)
+                m_new[k], v_new[k] = m.astype(m_dtype), v
+                mhat = m / bc1
+                vhat = v / bc2
+                out[k] = (p.astype(jnp.float32)
+                          - lr * mhat / (jnp.sqrt(vhat) + eps)).astype(p.dtype)
         new_state["m"], new_state["v"] = m_new, v_new
     elif cfg.name == "adagrad":
         gt_new = {}
@@ -215,9 +216,11 @@ def apply_update(cfg: OptimizerConfig, state: Dict[str, Any], params: Params,
         # effectively scaled by batch size when using labels-based decay; we
         # use the plain per-update form.
         tau = cfg.smoothing
-        new_state["avg"] = {
-            k: state["avg"][k] + tau * (out[k].astype(jnp.float32) - state["avg"][k])
-            for k in params}
+        with jax.named_scope("ema"):
+            new_state["avg"] = {
+                k: state["avg"][k]
+                + tau * (out[k].astype(jnp.float32) - state["avg"][k])
+                for k in params}
     if "gstat" in state:
         # dynamic-gradient-scaling statistics are updated by the caller
         # (zero.py step_fn, which owns the gradient norm) — pass through

@@ -21,6 +21,8 @@ import jax
 import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
+from ..obs import trace as obs_trace
+
 AXES = ("data", "model", "seq", "pipe", "expert")
 
 
@@ -131,8 +133,12 @@ def replicate_tree(tree, mesh: Mesh):
 def shard_batch(batch, mesh: Mesh, micro: bool = False):
     """Place batch leaves on the mesh with name-aware specs. `micro=True`
     for stacked [delay, B, T] micro-batches (build_train_step delay>1)."""
-    return {k: jax.device_put(
-                v, NamedSharding(mesh,
-                                 batch_leaf_spec(k, getattr(v, "ndim", 2),
-                                                 micro)))
-            for k, v in batch.items()}
+    with obs_trace.span("train.h2d") as sp:
+        if sp:
+            sp.set_attrs(bytes=sum(int(getattr(v, "nbytes", 0))
+                                   for v in batch.values()))
+        return {k: jax.device_put(
+                    v, NamedSharding(mesh,
+                                     batch_leaf_spec(k, getattr(v, "ndim", 2),
+                                                     micro)))
+                for k, v in batch.items()}

@@ -47,12 +47,14 @@ def finalize_update(opt_cfg: OptimizerConfig, opt_state, p, grads,
     --check-gradient-nan (non-finite norm reverts params + every
     optimizer-state part). Returns (new_p, new_opt, raw_gnorm,
     skipped)."""
+    # jax.named_scope below is metadata only (HLO op_name): a profile
+    # attributes device time by it, the compiled program is the same
     if opt_cfg.normalize_gradient:
         # reference: update normalizer x= updateTrgWords
         denom = denom * jnp.maximum(labels, 1.0)
-    grads = jax.tree_util.tree_map(lambda g: g / denom, grads)
-
-    gnorm = global_norm(grads)
+    with jax.named_scope("clip"):
+        grads = jax.tree_util.tree_map(lambda g: g / denom, grads)
+        gnorm = global_norm(grads)
     post_dyn_norm = gnorm
     opt_in = opt_state
     if opt_cfg.dyn_scale_factor > 0:
@@ -78,8 +80,9 @@ def finalize_update(opt_cfg: OptimizerConfig, opt_state, p, grads,
         opt_in = {**opt_state, "gstat": {"avg": avg, "n": n}}
 
     if opt_cfg.clip_norm > 0:
-        grads = clip_by_global_norm(grads, opt_cfg.clip_norm,
-                                    post_dyn_norm)
+        with jax.named_scope("clip"):
+            grads = clip_by_global_norm(grads, opt_cfg.clip_norm,
+                                        post_dyn_norm)
 
     new_opt, new_p = apply_update(opt_cfg, opt_in, p, grads, lr, labels)
     skipped = jnp.zeros((), jnp.float32)
@@ -301,8 +304,9 @@ class _GradMachinery:
                 acc, tot, lab = carry
                 micro, i = sl
                 g, aux = self._grads_of(p, micro, _k(rng, i))
-                acc = jax.tree_util.tree_map(
-                    jnp.add, acc, self._scatter(g))
+                with jax.named_scope("collectives"):
+                    g = self._scatter(g)
+                acc = jax.tree_util.tree_map(jnp.add, acc, g)
                 return (acc, tot + aux["ce_sum"], lab + aux["labels"]), None
             zeros = {k: jnp.zeros(self._shard_shape(k), jnp.float32)
                      for k in p}
@@ -312,7 +316,8 @@ class _GradMachinery:
                 (batch, jnp.arange(self.delay)))
         else:
             g, aux = self._grads_of(p, batch, _k(rng))
-            grads = self._scatter(g)
+            with jax.named_scope("collectives"):
+                grads = self._scatter(g)
             ce_sum, labels = aux["ce_sum"], aux["labels"]
         return (grads, jax.lax.psum(ce_sum, "data"),
                 jax.lax.psum(labels, "data"))
@@ -429,8 +434,12 @@ def build_train_step(model, opt_cfg: OptimizerConfig, schedule, cost_type: str,
                   else step.astype(jnp.int32))
         rng = jax.random.fold_in(rng, step_i - 1)
         step = step_i.astype(jnp.float32)     # schedule/metrics math
-        batch = expand_compact_batch(batch)
-        grads, ce_sum, labels = machinery.grads(p, batch, rng)
+        with jax.named_scope("expand_batch"):
+            batch = expand_compact_batch(batch)
+        # autodiff marks the forward ops jvp(..) and the backward ops
+        # transpose(jvp(..)) inside this scope by itself
+        with jax.named_scope("grads"):
+            grads, ce_sum, labels = machinery.grads(p, batch, rng)
 
         # cost normalization → gradient scale (Marian's costScaleFactor)
         if cost_type in ("ce-mean-words", "perplexity"):
@@ -441,9 +450,10 @@ def build_train_step(model, opt_cfg: OptimizerConfig, schedule, cost_type: str,
             denom = jnp.asarray(float(bsz), jnp.float32)
         else:
             denom = jnp.asarray(1.0, jnp.float32)
-        lr = schedule(step)
-        new_p, new_opt, gnorm, skipped = finalize_update(
-            opt_cfg, opt_state, p, grads, lr, labels, denom)
+        with jax.named_scope("optimizer"):
+            lr = schedule(step)
+            new_p, new_opt, gnorm, skipped = finalize_update(
+                opt_cfg, opt_state, p, grads, lr, labels, denom)
         metrics = {"ce_sum": ce_sum, "labels": labels, "gnorm": gnorm,
                    "lr": lr}
         if opt_cfg.check_gradient_nan:

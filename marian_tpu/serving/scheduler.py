@@ -1535,8 +1535,7 @@ class ContinuousScheduler:
                 # live perf/capacity accounting (obs/perf.py): device
                 # seconds are measured to the host-side result fence on
                 # the worker thread — translate_lines returns host
-                # strings, so the return IS the drain (the StepTimer
-                # sync-honesty discipline) — and include bisection
+                # strings, so the return IS the drain — and include bisection
                 # retries: poison isolation costs real device time
                 obs.PERF.record_batch(
                     self._version_label(), rows=rows, width=width,

@@ -35,6 +35,7 @@ from jax.sharding import NamedSharding, PartitionSpec as P
 
 from ..common import logging as log
 from ..models.encoder_decoder import EncoderDecoder
+from ..obs import trace as obs_trace
 from ..optimizers.optimizers import (OptimizerConfig, apply_update, init_state,
                                      smoothed_params)
 from ..optimizers.schedule import LRSchedule
@@ -280,6 +281,20 @@ class GraphGroup:
             donate_argnums=(0, 1, 2) if self._donate else ())
 
     # -- one (macro-)update --------------------------------------------------
+    @staticmethod
+    def _dispatch(fn, step: int, *args):
+        """The jitted call alone, as the span ``train.dispatch``:
+        ``step`` is the update number the spans of one update share,
+        ``retraced`` whether the step function's cache grew in this call
+        (a trace and a compile or cache load hid in the dispatch)."""
+        with obs_trace.span("train.dispatch", step=int(step)) as sp:
+            if not sp:
+                return fn(*args)
+            before = fn._cache_size()
+            out = fn(*args)
+            sp.set_attrs(retraced=int(fn._cache_size() > before))
+            return out
+
     def update(self, batches, step: int, rng) -> TrainOutput:
         """batches: one batch dict, or a list of `delay` micro-batch
         dicts. `rng` is the RAW training stream key — the per-step fold
@@ -301,8 +316,9 @@ class GraphGroup:
                 dump_lowered(self._dump_hlo, self._fused.lower(
                     self.params, self.opt_state, b, step_f, rng))
                 self._dump_hlo = None
-            self.params, self.opt_state, metrics = self._fused(
-                self.params, self.opt_state, b, step_f, rng)
+            self.params, self.opt_state, metrics = self._dispatch(
+                self._fused, step, self.params, self.opt_state, b, step_f,
+                rng)
             return TrainOutput(metrics["ce_sum"], metrics["labels"],
                                metrics["gnorm"], metrics.get("skipped"))
         if (self._fused_delay is not None and len(batches) == self.delay
@@ -321,8 +337,9 @@ class GraphGroup:
                 dump_lowered(self._dump_hlo, self._fused_delay.lower(
                     self.params, self.opt_state, stacked, step_f, rng))
                 self._dump_hlo = None
-            self.params, self.opt_state, metrics = self._fused_delay(
-                self.params, self.opt_state, stacked, step_f, rng)
+            self.params, self.opt_state, metrics = self._dispatch(
+                self._fused_delay, step, self.params, self.opt_state,
+                stacked, step_f, rng)
             return TrainOutput(metrics["ce_sum"], metrics["labels"],
                                metrics["gnorm"], metrics.get("skipped"))
         total_loss = total_labels = 0.0
@@ -340,7 +357,9 @@ class GraphGroup:
                 dump_lowered(self._dump_hlo, self._grad_fn.lower(
                     self.params, M.shard_batch(b, self.mesh), r))
                 self._dump_hlo = None
-            grads, aux = self._grad_fn(self.params, M.shard_batch(b, self.mesh), r)
+            sharded = M.shard_batch(b, self.mesh)
+            grads, aux = self._dispatch(self._grad_fn, step, self.params,
+                                        sharded, r)
             total_loss = total_loss + aux["ce_sum"]        # lazy device adds
             total_labels = total_labels + aux["labels"]
             # rows from whichever target form shipped (compact batches
@@ -358,9 +377,9 @@ class GraphGroup:
                 jax.tree_util.tree_map(
                     lambda a, g: a + g.astype(jnp.float32),
                     grads_acc, grads))
-        self.params, self.opt_state, gnorm, _lr, skipped = self._update_fn(
-            self.params, self.opt_state, grads_acc, np.float32(step),
-            jnp.asarray(total_labels, jnp.float32),
+        self.params, self.opt_state, gnorm, _lr, skipped = self._dispatch(
+            self._update_fn, step, self.params, self.opt_state, grads_acc,
+            np.float32(step), jnp.asarray(total_labels, jnp.float32),
             jnp.asarray(n_sents, jnp.float32))
         return TrainOutput(
             total_loss, total_labels, gnorm,
@@ -389,8 +408,9 @@ class GraphGroup:
             dump_lowered(self._dump_hlo, self._fused_window.lower(
                 self.params, self.opt_state, stacked, np.int32(step), rng))
             self._dump_hlo = None
-        self.params, self.opt_state, metrics = self._fused_window(
-            self.params, self.opt_state, stacked, np.int32(step), rng)
+        self.params, self.opt_state, metrics = self._dispatch(
+            self._fused_window, step, self.params, self.opt_state, stacked,
+            np.int32(step), rng)
         skipped = metrics.get("skipped")
         return [TrainOutput(metrics["ce_sum"][i], metrics["labels"][i],
                             metrics["gnorm"][i],

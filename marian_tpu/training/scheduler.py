@@ -175,32 +175,33 @@ class Scheduler:
         `skipped` is the optimizer's lazy 0/1 --check-gradient-nan flag for
         this update (None when the guard is off): queued and drained with
         bounded lag by _drain_skips, never a per-step sync."""
-        s = self.state
-        s.batches += 1
-        s.batches_epoch += 1
-        s.samples_epoch += sentences
-        s.labels_total += int(labels)
-        self._m_updates.inc()
-        self._m_labels.inc(int(labels))
-        self._max_labels_update = max(self._max_labels_update, int(labels))
-        if lr is not None:
-            s.eta = float(lr)
-        if skipped is not None:
-            self._pending_skips.append((s.batches, skipped))
-        self._drain_skips()
-        self._cost_sum += loss_sum
-        self._label_sum += labels
-        self._words_sum += (src_words or labels)
-        self._sent_sum += sentences
-        self._disp_count += 1
+        with obs.span("train.bookkeep", step=self.state.batches + 1):
+            s = self.state
+            s.batches += 1
+            s.batches_epoch += 1
+            s.samples_epoch += sentences
+            s.labels_total += int(labels)
+            self._m_updates.inc()
+            self._m_labels.inc(int(labels))
+            self._max_labels_update = max(self._max_labels_update, int(labels))
+            if lr is not None:
+                s.eta = float(lr)
+            if skipped is not None:
+                self._pending_skips.append((s.batches, skipped))
+            self._drain_skips()
+            self._cost_sum += loss_sum
+            self._label_sum += labels
+            self._words_sum += (src_words or labels)
+            self._sent_sum += sentences
+            self._disp_count += 1
 
-        show = False
-        if self.disp_first and s.batches <= self.disp_first:
-            show = True
-        elif self._hit(self.disp_freq):
-            show = True
-        if show and self._disp_count:
-            self._display()
+            show = False
+            if self.disp_first and s.batches <= self.disp_first:
+                show = True
+            elif self._hit(self.disp_freq):
+                show = True
+            if show and self._disp_count:
+                self._display()
 
     def _hit(self, freq: SchedulingParameter) -> bool:
         if not freq:
@@ -298,7 +299,10 @@ class Scheduler:
     def _display(self) -> None:
         s = self.state
         cost_type = self.options.get("cost-type", "ce-sum")
-        self._cost_sum = float(self._cost_sum)   # the one deferred sync
+        # the one deferred sync: the host blocked on the device, as the
+        # span `train.sync` (a child of `train.bookkeep`)
+        with obs.span("train.sync", step=s.batches):
+            self._cost_sum = float(self._cost_sum)
         # clock read AFTER the cost sync (mtlint MT-SYNC-TIMER): forcing
         # the accumulated device scalar completes every update in the
         # display window, so words/s divides by real execution time.

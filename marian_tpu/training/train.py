@@ -364,12 +364,14 @@ class Train:
                      "first {} updates", wu_n)
 
         # -- epoch loop ------------------------------------------------------
-        from ..common.profiling import (StepTimer, TraceWindow,
+        from ..common.profiling import (TraceWindow,
                                         maybe_start_profile_server)
         maybe_start_profile_server(opts)
-        # observability (ISSUE 8): --trace records train-loop phase spans
-        # into the same process-wide tracer serving uses; --trace-dump
-        # arms the flight recorder (a MARIAN_FAULTS kill dumps the ring)
+        # observability: the loop's phases are spans opened by the objects
+        # it calls (data.wait, train.h2d, train.dispatch, train.bookkeep,
+        # train.sync — docs/OBSERVABILITY.md), live whenever --trace is on
+        # or a profiler session collects; --trace-dump arms the flight
+        # recorder (a MARIAN_FAULTS kill dumps the ring)
         from .. import obs
         obs.configure(opts)
         if obs.PERF.enabled:
@@ -382,19 +384,11 @@ class Train:
                 dec_depth=int(opts.get("dec-depth", 6)),
                 vocab=len(vocabs[-1]))
         # --metrics-port: Prometheus scrape of the train-side series the
-        # Scheduler/StepTimer publish (serving/metrics.py — same registry
+        # Scheduler publishes (serving/metrics.py — same registry
         # and types as marian-server, one metrics vocabulary end to end);
         # /tracez rides the same port, like marian-server
         from ..serving.metrics import maybe_start_metrics_server
         maybe_start_metrics_server(opts, routes=obs.trace_routes())
-        # unified phase timer (data wait vs device dispatch vs host
-        # bookkeeping). --trace-sync-phases drains the device at every
-        # boundary so async dispatch cannot shift device seconds into
-        # whichever later phase blocks first — the honest-but-slower
-        # diagnosis mode (obs/profiling.py docstring).
-        stimer = StepTimer(
-            sync_fn=(lambda: jax.block_until_ready(gg.params))
-            if opts.get("trace-sync-phases", False) else None)
         trace = TraceWindow(opts)
         train_key = prng.stream(key, prng.STREAM_DROPOUT)
         # --compact-transfer: ship uint16 tokens + row lengths instead of
@@ -518,7 +512,6 @@ class Train:
             equal to the updates baked into the params."""
             if not win:
                 return None
-            stimer.phase("dispatch")
             trace.tick(state.batches + 1)
             # dispatch may block on a LEGITIMATE jit compile (first step,
             # new bucket shape) — not a stall. Execution hangs are still
@@ -541,7 +534,6 @@ class Train:
                     watchdog.resume()
             win.clear()
             win_key.clear()
-            stimer.phase("host")
             before_b, before_l = state.batches, state.labels_total
             if pairs[-1][1].corpus_state is not None:
                 last_corpus_state[0] = pairs[-1][1].corpus_state
@@ -555,7 +547,6 @@ class Train:
                 do_validate()
             if scheduler.should_save_since(before_b, before_l):
                 do_save()
-            stimer.phase("data")
             return _check_stop()
 
         def _epoch_loop() -> Optional[str]:
@@ -566,7 +557,6 @@ class Train:
                                         budget_scale=budget_scale)
                 micro: List = []
                 rc = None
-                stimer.phase("data")
                 for batch in bg:
                     if watchdog is not None:
                         watchdog.beat()
@@ -613,7 +603,6 @@ class Train:
                         micro.append(batch)
                         if len(micro) < delay:
                             continue
-                        stimer.phase("dispatch")
                         arrays = [_arrays(b) for b in micro]
                         trace.tick(state.batches + 1)
                         # same compile-is-not-a-stall pause as
@@ -626,10 +615,8 @@ class Train:
                         finally:
                             if watchdog is not None:
                                 watchdog.resume()
-                        stimer.phase("host")
                         rc = _after_update(out, micro)
                         micro = []
-                        stimer.phase("data")
                     if rc == "exit":
                         return "exit"
                     if rc is not None:
@@ -751,8 +738,6 @@ class Train:
             if watchdog is not None:
                 watchdog.stop()
         trace.close()
-        stimer.stop()
-        stimer.report()         # phase breakdown + metrics mirror
         scheduler.close()       # flush buffered TensorBoard scalars
         log.info("Training finished")
         do_save()

@@ -17,6 +17,7 @@ The acceptance-critical properties covered tier-1:
 import asyncio
 import json
 import os
+import threading
 import time
 import urllib.request
 
@@ -602,49 +603,77 @@ class TestExemplars:
 
 
 # ---------------------------------------------------------------------------
-# StepTimer / TraceWindow fold (obs/profiling.py; common.profiling shims)
+# totals, the profiler as a second switch, and what went (ISSUE 24)
 # ---------------------------------------------------------------------------
 
-class TestStepTimer:
-    def test_shim_import_points_at_obs(self):
-        from marian_tpu.common.profiling import StepTimer, TraceWindow
-        assert StepTimer.__module__ == "marian_tpu.obs.profiling"
-        assert TraceWindow.__module__ == "marian_tpu.obs.profiling"
+class TestTotalsAndProfilerSink:
+    def test_totals_self_time_same_thread_only(self):
+        """Self seconds = duration less same-thread children: a child
+        recorded from another thread does not shrink its parent."""
+        t = Tracer()
+        t.enable()
+        with t.span("outer") as outer:
+            with t.span("inner"):
+                time.sleep(0.01)
+            th = threading.Thread(
+                target=lambda: t.end(t.start_span("elsewhere",
+                                                  parent=outer)))
+            th.start()
+            th.join()
+        tot = t.totals()
+        assert tot["outer"]["calls"] == tot["inner"]["calls"] == 1
+        assert tot["inner"]["self_seconds"] == tot["inner"]["seconds"]
+        assert tot["outer"]["self_seconds"] == pytest.approx(
+            tot["outer"]["seconds"] - tot["inner"]["seconds"], abs=1e-9)
+        assert tot["elsewhere"]["thread"] != tot["outer"]["thread"]
+        t.reset()
+        assert t.totals() == {} and t._totals is None
 
-    def test_phases_aggregate_and_emit_spans(self):
-        from marian_tpu.common.profiling import StepTimer
-        obs.TRACER.enable()
-        st = StepTimer()
-        st.phase("data")
-        st.phase("dispatch")
-        st.phase("data")
-        st.stop()
-        rep = st.report()
-        assert set(rep) == {"data", "dispatch"}
-        assert st.counts["data"] == 2
-        spans, _ = obs.TRACER.snapshot()
-        names = [s.name for s in spans]
-        assert names.count("train.data") == 2
-        assert names.count("train.dispatch") == 1
+    def test_record_counts_toward_its_parent(self):
+        t = Tracer()
+        t.enable()
+        with t.span("outer"):
+            now = time.perf_counter()
+            t.record("past", now - 0.5, now)
+        tot = t.totals()
+        assert tot["past"]["seconds"] == pytest.approx(0.5)
+        assert tot["outer"]["self_seconds"] == 0.0      # clamped, not < 0
 
-    def test_sync_fn_called_before_each_boundary(self):
-        """The device-sync honesty fix: sync_fn runs BEFORE the boundary
-        timestamp, so async device work drains into the phase that
-        issued it (obs/profiling.py module docstring)."""
-        from marian_tpu.common.profiling import StepTimer
-        calls = []
-        st = StepTimer(sync_fn=lambda: calls.append(1))
-        st.phase("a")
-        st.phase("b")
-        st.stop()
-        assert len(calls) == 3               # every boundary, stop incl.
+    def test_profiler_session_turns_spans_on(self, tmp_path):
+        """No --trace: a collecting jax.profiler session makes spans live
+        (a TraceMe each, totals kept), the ring stays unallocated, and
+        they go quiet again when it stops."""
+        import jax
+        from marian_tpu.obs import trace as tr
+        assert not tr.profiler_collecting()
+        assert obs.start_span("off") is NOOP_SPAN
+        popts = jax.profiler.ProfileOptions()
+        popts.python_tracer_level = 0
+        jax.profiler.start_trace(str(tmp_path), profiler_options=popts)
+        try:
+            assert tr.profiler_collecting()
+            with obs.span("on.profiler", k=1) as sp:
+                assert sp and sp._ann is not None
+                sp.set_attrs(late=2)
+        finally:
+            jax.profiler.stop_trace()
+        assert sp._ann is None and sp.attrs == {"k": 1, "late": 2}
+        assert obs.TRACER._ring is None and not obs.enabled()
+        assert obs.TRACER.totals()["on.profiler"]["calls"] == 1
+        assert obs.start_span("off") is NOOP_SPAN
 
-    def test_disabled_records_nothing(self):
-        from marian_tpu.common.profiling import StepTimer
-        st = StepTimer(enabled=False)
-        st.phase("a")
-        st.stop()
-        assert st.report() == {}
+    def test_step_timer_and_its_flag_are_gone(self):
+        """--trace-sync-phases serialised host and device to make up for
+        host-clock stamps; spans on the profiler's clock replace it."""
+        from marian_tpu.common import config_parser as cp
+        from marian_tpu.common import profiling
+        from marian_tpu.obs import profiling as obs_profiling
+        assert "trace-sync-phases" not in cp.ConfigParser("training").flags
+        assert not hasattr(profiling, "StepTimer")
+        assert not hasattr(obs_profiling, "StepTimer")
+        assert profiling.TraceWindow is obs_profiling.TraceWindow
+        with pytest.raises(SystemExit):
+            cp.parse_options(["--trace-sync-phases"], mode="training")
 
 
 # ---------------------------------------------------------------------------

@@ -923,15 +923,6 @@ class TestMetricCensus:
         assert msm.REGISTRY.get(
             "marian_train_chip_seconds_per_token").value > 0
 
-    def test_step_timer_phase_series_render(self):
-        from marian_tpu.common.profiling import StepTimer
-        st = StepTimer()
-        st.phase("data")
-        st.phase("dispatch")
-        st.stop()
-        st.report()
-        assert "marian_step_phase_seconds" in msm.REGISTRY.render()
-
     def test_lifecycle_controller_series_render(self):
         r = msm.Registry()
         ctrl = SwapController(lambda d, m: (lambda lines: list(lines)),
