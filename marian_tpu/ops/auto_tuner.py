@@ -76,11 +76,14 @@ class AutoTuner:
 # ---------------------------------------------------------------------------
 
 # per-kernel base entries at dh<=64: the sequence-side capacity each
-# kernel holds per grid cell (packed: full padded Tq=Tk per (b, head-
-# group) cell; decode: the whole [L, dh] cache row per (row, head) cell)
+# kernel holds per grid cell (packed: full padded Tq=Tk per (row, head-
+# group) TILE — a grid cell is a block of rows x all heads that the
+# kernel sizes itself from the shapes, packed_attention.py::cell_plan,
+# down to one row's single head group at the cap; decode: the whole
+# [L, dh] cache row per (row, head) cell)
 KERNEL_BLOCKS = {
-    # packed fwd cell peak ~ g*T x g*T f32 scores + operands; T=256 at
-    # g=2/dh=64 is ~2.5 MB — comfortably under the ~16 MB VMEM budget,
+    # packed fwd tile peak ~ g*T x g*T f32 scores + operands; T=256 at
+    # g=2/dh=64 is ~2.5 MB — comfortably under the kernel's VMEM budget,
     # and the target regime (T 48-64) is far below the cap anyway
     "packed_attention": {"max_t": 256},
     # decode cell holds 2 x [L, dh] cache blocks + the [1, L] score row;

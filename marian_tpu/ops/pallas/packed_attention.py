@@ -28,6 +28,19 @@ the same block-diagonal K/V, dk/dv contract the packed probs against the
 lane-concatenated q/do over Tq and read each head's gradient off the
 diagonal block of the [g*Tk, g*dh] output tile.
 
+Grid geometry (PR 26): one grid step takes a CELL — a block of batch
+rows with all their heads, blocks [rows, H, T, dh] — and loops over its
+(row, head group) tiles inside, four to a loop body, the tile's math as
+above. One tile is a chain of small dependent ops that costs its latency
+(~0.7 us forward on a v5e) whether a grid step or a loop step holds it;
+four independent tiles in one body overlap (~0.37 us a tile). The first
+geometry, one tile a grid step, left the kernel at 5.5 % of its
+roofline. `cell_plan` sizes the cell from the call's shapes against a
+fixed VMEM budget, rows a divisor of the batch's; where not even one row
+fits (T toward the cap) a cell is one row's head groups, as many as fit,
+down to one. The backward takes delta = rowsum(do * out) tile by tile
+from `do` and `out` (a [T, 1] operand would pad 1 -> 128 lanes).
+
 This kernel owns the T <= packed-cap regime (NMT sentence lengths);
 flash_attention.py owns the long-sequence end. Same structured-mask
 interface as flash: kv_mask [B, Tk] (1.0 = attend) and/or causal.
@@ -41,6 +54,7 @@ f32 on the MXU regardless of input dtype.
 from __future__ import annotations
 
 import functools
+import math
 from typing import Optional
 
 import jax
@@ -48,6 +62,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from ...common import logging as log
 from .flash_attention import MASK_VALUE, _interpret_default, _round_up
 
 # Sequence dims pad to multiples of 64 so a g=2 pack lands on exactly
@@ -108,44 +123,49 @@ def _packed_scores(qc, kd, kvm, scale, causal, g, bq, bk):
     return jnp.concatenate(ps, axis=1)
 
 
-def _group(ref, g):
-    return [ref[0, j].astype(jnp.float32) for j in range(g)]
+def _heads(ref, r, first, g):
+    """Heads first .. first+g-1 of the cell's row r, upcast to f32."""
+    return [ref[r, first + j].astype(jnp.float32) for j in range(g)]
 
 
-def _fwd_kernel(q_ref, k_ref, v_ref, kvm_ref, o_ref, *, scale, causal, g,
-                bq, bk, dh):
-    qc = jnp.concatenate(_group(q_ref, g), axis=1)    # [bq, g*dh]
-    kd = _block_diag(_group(k_ref, g))                # [g*bk, g*dh]
-    vd = _block_diag(_group(v_ref, g))
-    p2 = _packed_scores(qc, kd, kvm_ref[0], scale, causal, g, bq, bk)
+def _fwd_tile(q, k, v, kvm, *, scale, causal, g, bq, bk, dh):
+    """One (row, head group): q/k/v are the group's g heads [t, dh] f32,
+    kvm the row's [1, bk] key mask; returns the g outputs [bq, dh] f32."""
+    qc = jnp.concatenate(q, axis=1)                   # [bq, g*dh]
+    kd = _block_diag(k)                               # [g*bk, g*dh]
+    vd = _block_diag(v)
+    p2 = _packed_scores(qc, kd, kvm, scale, causal, g, bq, bk)
     o2 = jax.lax.dot_general(
         p2, vd, (((1,), (0,)), ((), ())),
         preferred_element_type=jnp.float32)           # [bq, g*dh]
-    for j in range(g):
-        o_ref[0, j] = o2[:, j * dh:(j + 1) * dh].astype(o_ref.dtype)
+    return [o2[:, j * dh:(j + 1) * dh] for j in range(g)]
 
 
-def _bwd_kernel(q_ref, k_ref, v_ref, kvm_ref, do_ref, delta_ref,
-                dq_ref, dk_ref, dv_ref, *, scale, causal, g, bq, bk, dh):
-    """One pass per (b, head-group): recomputes the packed probs, then
-    runs all four backward dots on full tiles. dp and dq reuse the
-    forward's dh-/Tk-contraction packing against the same block-diagonal
-    K/V; dk and dv contract the packed [bq, g*bk] probs against the
-    lane-concatenated q/do over Tq, which fills the OUTPUT tile
-    [g*bk, g*dh] — head j's gradient is its diagonal block."""
-    qc = jnp.concatenate(_group(q_ref, g), axis=1)    # [bq, g*dh]
-    doc = jnp.concatenate(_group(do_ref, g), axis=1)
-    kd = _block_diag(_group(k_ref, g))                # [g*bk, g*dh]
-    vd = _block_diag(_group(v_ref, g))
-    p2 = _packed_scores(qc, kd, kvm_ref[0], scale, causal, g, bq, bk)
+def _bwd_tile(q, k, v, kvm, do, o, *, scale, causal, g, bq, bk, dh):
+    """One (row, head group) of the backward: recomputes the packed
+    probs, then runs all four backward dots on full tiles. dp and dq
+    reuse the forward's dh-/Tk-contraction packing against the same
+    block-diagonal K/V; dk and dv contract the packed [bq, g*bk] probs
+    against the lane-concatenated q/do over Tq, which fills the OUTPUT
+    tile [g*bk, g*dh] — head j's gradient is its diagonal block.
+    Returns (dq, dk, dv), each a list of the g heads' [t, dh] f32."""
+    qc = jnp.concatenate(q, axis=1)                   # [bq, g*dh]
+    doc = jnp.concatenate(do, axis=1)
+    kd = _block_diag(k)                               # [g*bk, g*dh]
+    vd = _block_diag(v)
+    p2 = _packed_scores(qc, kd, kvm, scale, causal, g, bq, bk)
 
     # dp: [do_0 | do_1] against diag(v_0, v_1) — forward-score geometry
     dp2 = jax.lax.dot_general(
         doc, vd, (((1,), (1,)), ((), ())),
         preferred_element_type=jnp.float32)           # [bq, g*bk]
+    # delta = rowsum(do * o) per query row, in f32, from the tile's own
+    # do and out (a [T, 1] f32 operand pads its lane 1 -> 128: twice a
+    # bf16 [T, 64] block in VMEM, four times in HBM)
     ds2 = jnp.concatenate(
         [p2[:, j * bk:(j + 1) * bk]
-         * (dp2[:, j * bk:(j + 1) * bk] - delta_ref[0, j]) * scale
+         * (dp2[:, j * bk:(j + 1) * bk]
+            - jnp.sum(do[j] * o[j], axis=-1, keepdims=True)) * scale
          for j in range(g)], axis=1)                  # [bq, g*bk]
 
     # dq: [ds_0 | ds_1] @ diag(k_0, k_1) — forward-apply geometry
@@ -158,40 +178,184 @@ def _bwd_kernel(q_ref, k_ref, v_ref, kvm_ref, do_ref, delta_ref,
     dv2 = jax.lax.dot_general(
         p2, doc, (((0,), (0,)), ((), ())),
         preferred_element_type=jnp.float32)
+    dq, dk, dv = [], [], []
     for j in range(g):
         rows, cols = slice(j * bk, (j + 1) * bk), slice(j * dh, (j + 1) * dh)
-        dq_ref[0, j] = dq2[:, cols].astype(dq_ref.dtype)
-        dk_ref[0, j] = dk2[rows, cols].astype(dk_ref.dtype)
-        dv_ref[0, j] = dv2[rows, cols].astype(dv_ref.dtype)
+        dq.append(dq2[:, cols])
+        dk.append(dk2[rows, cols])
+        dv.append(dv2[rows, cols])
+    return dq, dk, dv
+
+
+def _each_tile(rows, heads, g, tile):
+    """Run tile(r, first_head) over the cell's rows and head groups: two
+    nested loops whose body is a few independent tiles (see
+    _TILES_A_STEP)."""
+    groups, some = heads // g, _tiles_a_step(heads, g)
+
+    def row(r, carry):
+        def step(i, carry):
+            for u in range(some):
+                tile(r, (i * some + u) * g)
+            return carry
+        return jax.lax.fori_loop(0, groups // some, step, carry)
+    jax.lax.fori_loop(0, rows, row, 0)
+
+
+def _fwd_kernel(q_ref, k_ref, v_ref, kvm_ref, o_ref, *, rows, heads, g,
+                **tile_kw):
+    def tile(r, first):
+        o = _fwd_tile(_heads(q_ref, r, first, g), _heads(k_ref, r, first, g),
+                      _heads(v_ref, r, first, g), kvm_ref[r], g=g, **tile_kw)
+        for j in range(g):
+            o_ref[r, first + j] = o[j].astype(o_ref.dtype)
+    _each_tile(rows, heads, g, tile)
+
+
+def _bwd_kernel(q_ref, k_ref, v_ref, kvm_ref, do_ref, o_ref,
+                dq_ref, dk_ref, dv_ref, *, rows, heads, g, **tile_kw):
+    def tile(r, first):
+        dq, dk, dv = _bwd_tile(
+            _heads(q_ref, r, first, g), _heads(k_ref, r, first, g),
+            _heads(v_ref, r, first, g), kvm_ref[r],
+            _heads(do_ref, r, first, g), _heads(o_ref, r, first, g),
+            g=g, **tile_kw)
+        for j in range(g):
+            dq_ref[r, first + j] = dq[j].astype(dq_ref.dtype)
+            dk_ref[r, first + j] = dk[j].astype(dk_ref.dtype)
+            dv_ref[r, first + j] = dv[j].astype(dv_ref.dtype)
+    _each_tile(rows, heads, g, tile)
+
+
+# ---------------------------------------------------------------------------
+# The cell: how many (row, head group) tiles one grid step takes, and how
+# many of them one loop step inside it. A tile alone is a chain of small
+# dependent ops that costs its latency, ~0.7 us forward on a v5e, whether
+# a grid step or a loop step holds it; _TILES_A_STEP independent tiles in
+# one loop body overlap (4: 0.37 us a tile; 8 spill and lose it again).
+# How many rows a cell takes hardly matters beyond one (PERF.md section 6,
+# PR 26: 1 to 14 rows read within 1 %), so the budget is a modest one.
+# ---------------------------------------------------------------------------
+
+# what Mosaic may use in all (the v5e has 128 MiB; the default scoped
+# limit of 16 MiB is why this is explicit) and what the plan gives the
+# cell's blocks and the tiles' intermediates of it
+_VMEM_LIMIT = 48 << 20
+_CELL_BUDGET = 16 << 20
+_TILES_A_STEP = 4
+
+
+def _tiles_a_step(heads, g):
+    """Tiles in one loop body: _TILES_A_STEP, or the largest divisor of
+    the cell's head groups under it."""
+    return math.gcd(heads // g, _TILES_A_STEP)
+
+
+def _block_vmem(t, dh, itemsize):
+    """Bytes of one head's [t, dh] block as Mosaic lays it out in VMEM:
+    lanes pad to 128, sublanes to 8 words of 32 bits (16 rows of bf16).
+    16 KB for a bf16 [64, 64]."""
+    return (_round_up(t, 8 * max(1, 4 // itemsize))
+            * _round_up(dh, 128) * itemsize)
+
+
+def cell_vmem(rows, heads, g, tq, tk, dh, itemsize, backward):
+    """VMEM bytes of a (rows, heads) cell: every operand's block twice
+    (the pipeline's double buffer) — forward q, out | k, v, backward q,
+    do, out, dq | k, v, dk, dv — the rows' [1, tk] f32 masks (8
+    sublanes each), and the f32 intermediates of the tiles in one loop
+    body ([tq, g*tk] scores and probs, [g*tk, g*dh] block diagonals
+    and gradient tiles, [tq, g*dh] packed operands)."""
+    n = 4 if backward else 2
+    blocks = 2 * n * heads * (_block_vmem(tq, dh, itemsize)
+                              + _block_vmem(tk, dh, itemsize))
+    mask = 2 * 8 * _round_up(tk, 128) * 4
+    wide, tall = _round_up(g * tk, 128), _round_up(g * dh, 128)
+    tile = 4 * ((6 if backward else 4) * tq * wide
+                + (4 if backward else 2) * g * tk * tall
+                + (4 if backward else 2) * tq * tall)
+    return rows * (blocks + mask) + _tiles_a_step(heads, g) * tile
+
+
+def cell_plan(b, h, tq, tk, dh, itemsize, backward, budget=None):
+    """(rows, heads) of one grid cell, from the call's shapes alone: the
+    most rows, all h heads each, whose cell_vmem fits the budget AND
+    that divide b; when not even one row fits (T toward the cap), one
+    row and as many head groups as fit and divide the heads, down to
+    (1, g) — the geometry this kernel was first written with.
+
+    Only divisors of b: a ragged last cell (Pallas reads past the batch's
+    end and drops the writes) is right in interpret mode and hung the
+    v5e twice (PERF.md section 6, PR 26). The trainer's rows are
+    multiples of 8, so its cells hold 2 to 7 rows; a prime b is one row
+    a cell, which costs no more than the first geometry did."""
+    g = pack_group(h, dh)
+    budget = _CELL_BUDGET if budget is None else budget
+
+    def fits(rows, heads):
+        return cell_vmem(rows, heads, g, tq, tk, dh, itemsize,
+                         backward) <= budget
+
+    if fits(1, h):
+        return max(r for r in range(1, b + 1)
+                   if b % r == 0 and fits(r, h)), h
+    groups = h // g
+    n = max((n for n in range(1, groups + 1)
+             if groups % n == 0 and fits(1, n * g)), default=1)
+    return 1, n * g
+
+
+def _plan(b, h, tq, tk, dh, itemsize, backward):
+    """cell_plan, said at DEBUG (once per shape: the calls below are
+    traced once per shape)."""
+    rows, heads = cell_plan(b, h, tq, tk, dh, itemsize, backward)
+    log.log("debug", "packed_attention{}: b={} h={} tq={} tk={} -> {} rows "
+            "x {} heads, {} steps", "_bwd" if backward else "", b, h, tq,
+            tk, rows, heads, pl.cdiv(b, rows) * (h // heads))
+    return rows, heads
 
 
 def _compiler_params():
     return pltpu.CompilerParams(
-        dimension_semantics=("parallel", "parallel"))
+        dimension_semantics=("parallel", "parallel"),
+        vmem_limit_bytes=_VMEM_LIMIT)
 
 
-def _specs(b, g, tq, tk, dh):
-    """Block specs shared by fwd and bwd: one (batch, head-group) cell
-    per grid point, full (padded) sequences per cell — the kernel owns
-    the short-T regime, so no k-streaming is needed."""
-    qspec = pl.BlockSpec((1, g, tq, dh), lambda b_, hg: (b_, hg, 0, 0))
-    kspec = pl.BlockSpec((1, g, tk, dh), lambda b_, hg: (b_, hg, 0, 0))
+def _specs(rows, heads, tq, tk, dh):
+    """Block specs shared by fwd and bwd: a cell is `rows` batch rows x
+    `heads` heads, full (padded) sequences — the kernel owns the short-T
+    regime, so no k-streaming is needed. The grid is (b // rows,
+    h // heads); cell_plan's rows divide b. (Rows are independent, so on
+    a ragged last cell what is read past the batch's end would reach
+    only writes past the end, which Pallas drops — true in interpret
+    mode, tests/test_packed_attention.py holds it — but the chip hung.)"""
+    qspec = pl.BlockSpec((rows, heads, tq, dh), lambda c, hc: (c, hc, 0, 0))
+    kspec = pl.BlockSpec((rows, heads, tk, dh), lambda c, hc: (c, hc, 0, 0))
     # the mask rides as [B, 1, Tk] so its block's last two dims equal the
     # array's (the TPU (8, 128) block rule)
-    mspec = pl.BlockSpec((1, 1, tk), lambda b_, hg: (b_, 0, 0))
+    mspec = pl.BlockSpec((rows, 1, tk), lambda c, hc: (c, 0, 0))
     return qspec, kspec, mspec
 
 
+# Both calls sit under a jit of their own: a train step calls the kernel 18
+# times forward and 18 backward, and JAX then traces and lowers each
+# DISTINCT call once (two of each in a step, not 18). A cell's loop body of
+# four tiles is four times the first geometry's kernel to trace and lower;
+# without this every start of the trainer paid 140 s more for it, compile
+# cache warm or not (PERF.md section 6, PR 26).
+@functools.partial(jax.jit, static_argnums=(4, 5, 6, 7))
 def _fwd_call(q, k, v, kvm, scale, causal, g, interpret):
     b, h, tq, dh = q.shape
     tk = k.shape[2]
-    qspec, kspec, mspec = _specs(b, g, tq, tk, dh)
-    kernel = functools.partial(_fwd_kernel, scale=scale, causal=causal,
-                               g=g, bq=tq, bk=tk, dh=dh)
+    rows, heads = _plan(b, h, tq, tk, dh, q.dtype.itemsize, False)
+    qspec, kspec, mspec = _specs(rows, heads, tq, tk, dh)
+    kernel = functools.partial(_fwd_kernel, rows=rows, heads=heads, g=g,
+                               scale=scale, causal=causal, bq=tq, bk=tk,
+                               dh=dh)
     return pl.pallas_call(
         kernel,
         name="packed_attention_fwd",
-        grid=(b, h // g),
+        grid=(pl.cdiv(b, rows), h // heads),
         in_specs=[qspec, kspec, kspec, mspec],
         out_specs=qspec,
         out_shape=jax.ShapeDtypeStruct((b, h, tq, dh), q.dtype),
@@ -200,18 +364,20 @@ def _fwd_call(q, k, v, kvm, scale, causal, g, interpret):
     )(q, k, v, kvm)
 
 
-def _bwd_call(q, k, v, kvm, do, delta, scale, causal, g, interpret):
+@functools.partial(jax.jit, static_argnums=(6, 7, 8, 9))
+def _bwd_call(q, k, v, kvm, do, out, scale, causal, g, interpret):
     b, h, tq, dh = q.shape
     tk = k.shape[2]
-    qspec, kspec, mspec = _specs(b, g, tq, tk, dh)
-    dspec = pl.BlockSpec((1, g, tq, 1), lambda b_, hg: (b_, hg, 0, 0))
-    kernel = functools.partial(_bwd_kernel, scale=scale, causal=causal,
-                               g=g, bq=tq, bk=tk, dh=dh)
+    rows, heads = _plan(b, h, tq, tk, dh, q.dtype.itemsize, True)
+    qspec, kspec, mspec = _specs(rows, heads, tq, tk, dh)
+    kernel = functools.partial(_bwd_kernel, rows=rows, heads=heads, g=g,
+                               scale=scale, causal=causal, bq=tq, bk=tk,
+                               dh=dh)
     return pl.pallas_call(
         kernel,
         name="packed_attention_bwd",
-        grid=(b, h // g),
-        in_specs=[qspec, kspec, kspec, mspec, qspec, dspec],
+        grid=(pl.cdiv(b, rows), h // heads),
+        in_specs=[qspec, kspec, kspec, mspec, qspec, qspec],
         out_specs=[qspec, kspec, kspec],
         out_shape=[
             jax.ShapeDtypeStruct((b, h, tq, dh), q.dtype),
@@ -220,7 +386,7 @@ def _bwd_call(q, k, v, kvm, do, delta, scale, causal, g, interpret):
         ],
         interpret=interpret,
         compiler_params=None if interpret else _compiler_params(),
-    )(q, k, v, kvm, do, delta)
+    )(q, k, v, kvm, do, out)
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6, 7))
@@ -235,12 +401,10 @@ def _packed_fwd(q, k, v, kvm, scale, causal, g, interpret):
 
 def _packed_bwd(scale, causal, g, interpret, res, do):
     q, k, v, kvm, out = res
-    # delta = rowsum(do * o) per (b,h,row) — cheap elementwise outside
-    # the kernel (the bwd kernel recomputes probs, flash-style, so no
-    # stats ride the residuals)
-    delta = jnp.sum(do.astype(jnp.float32) * out.astype(jnp.float32),
-                    axis=-1, keepdims=True)           # [B,H,Tq,1]
-    dq, dk, dv = _bwd_call(q, k, v, kvm, do, delta, scale, causal, g,
+    # the bwd kernel recomputes probs, flash-style, and takes delta =
+    # rowsum(do * o) from do and out tile by tile, so no stats ride the
+    # residuals
+    dq, dk, dv = _bwd_call(q, k, v, kvm, do, out, scale, causal, g,
                            interpret)
     return dq, dk, dv, jnp.zeros_like(kvm)
 

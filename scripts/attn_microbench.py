@@ -18,6 +18,8 @@ Run it on the chip:
     MARIAN_ATTNBENCH_BWD=1 python scripts/attn_microbench.py
     MARIAN_ATTNBENCH_SHAPES=2,16,48,64 python scripts/attn_microbench.py
                                                  # one b,h,t,dh override
+    MARIAN_ATTNBENCH_SHAPES='512,16,8,64;64,16,64,64' ...
+                                                 # several, ';' between
 
 On CPU this degrades to a correctness-checked wall-time table (the MXU
 share column reads n/a): interpret-mode Pallas is not a performance
@@ -85,12 +87,15 @@ def main():
     override = os.environ.get("MARIAN_ATTNBENCH_SHAPES")
     if override:
         try:
-            b, h, t, dh = (int(x) for x in override.split(","))
-            shapes = [(b, h, t, dh)]
+            parsed = [tuple(int(x) for x in one.split(","))
+                      for one in override.split(";") if one.strip()]
+            if not parsed or any(len(one) != 4 for one in parsed):
+                raise ValueError(override)
+            shapes = parsed
         except ValueError:
             print(f"attn_microbench: bad MARIAN_ATTNBENCH_SHAPES="
-                  f"{override!r} (want b,h,t,dh) — using the default set",
-                  file=sys.stderr, flush=True)
+                  f"{override!r} (want b,h,t,dh[;b,h,t,dh...]) — using "
+                  f"the default set", file=sys.stderr, flush=True)
 
     kind = jax.devices()[0].device_kind
     peak = _peak_flops(kind)

@@ -8,8 +8,9 @@ kernel computes the right thing; this file says Mosaic accepts it —
 block shapes against the (8, 128) tiling rule, VMEM/SMEM budgets,
 primitives the TPU lowering implements — at transformer-big widths
 (16 heads x dh 64, emb 1024, vocab 32000, bf16) and at the shapes the
-main path produces: training length buckets 32/64 and the packed cap,
-flash at 2048, dense beam decode at 64 sentences x beam 6, the paged
+main path produces: training length buckets 32/64, the packed cap (where
+a cell falls to part of a row's heads) and the benchmark cell's six
+batches at 4096 words, flash at 2048, dense beam decode at 64 sentences x beam 6, the paged
 engine at its smallest and largest row bucket and at its SMEM row bound.
 
 Nothing runs: a passing compile is not a chip run (chip_smoke.py is).
@@ -145,6 +146,24 @@ CASES = {
         lambda: _attn(_PACKED, 2, PACKED_CAP, PACKED_CAP, False, False),
     f"packed-grad-t{PACKED_CAP}-causal":
         lambda: _attn(_PACKED, 2, PACKED_CAP, PACKED_CAP, True, True),
+    # big.train's own batches (rows x width at 4096 words): a cell is a
+    # block of rows x all 16 heads, the rows a divisor of the batch's
+    "packed-grad-512x8-mask": lambda: _attn(_PACKED, 512, 8, 8, False, True),
+    "packed-grad-256x16-causal":
+        lambda: _attn(_PACKED, 256, 16, 16, True, True),
+    "packed-grad-168x24-mask":
+        lambda: _attn(_PACKED, 168, 24, 24, False, True),
+    "packed-grad-128x32-causal":
+        lambda: _attn(_PACKED, 128, 32, 32, True, True),
+    "packed-grad-80x48-mask": lambda: _attn(_PACKED, 80, 48, 48, False, True),
+    "packed-grad-64x64-causal":
+        lambda: _attn(_PACKED, 64, 64, 64, True, True),
+    # cross attention past one pad (Tq 72 -> 128, Tk 40 -> 64), 45 rows:
+    # no multiple of 8, cells of 5 or 3
+    "packed-grad-45x72x40-cross":
+        lambda: _attn(_PACKED, 45, 72, 40, False, True),
+    # the encoder at decode time: fewer rows than a cell holds
+    "packed-fwd-3x20-mask": lambda: _attn(_PACKED, 3, 20, 20, False, False),
     "flash-fwd-t2048": lambda: _attn(_FLASH, 1, 2048, 2048, True, False),
     "flash-grad-t2048": lambda: _attn(_FLASH, 1, 2048, 2048, True, True),
     # offline decoder: 64 sentences x beam 6, scalar and per-row positions

@@ -8,14 +8,49 @@ edges (g=1 wide heads, g=8 narrow heads), padding, bf16, and the custom
 VJP in both backward orientations.
 """
 
+import functools
+import importlib
+
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
+from jax.experimental import pallas as pl
 
 from marian_tpu.ops.attention import (attention, causal_mask, combine_masks,
                                       dense_attention)
-from marian_tpu.ops.pallas.packed_attention import pack_group, packed_attention
+from marian_tpu.ops.pallas.packed_attention import (cell_plan, cell_vmem,
+                                                    pack_group,
+                                                    packed_attention)
+
+from tests.time_limit import time_limit
+
+# the module (the package exports the function under the same name)
+pa = importlib.import_module("marian_tpu.ops.pallas.packed_attention")
+
+
+def _forget_plans():
+    """The budget is read when a call is traced: drop what was traced
+    under another."""
+    pa._fwd_call.clear_cache()
+    pa._bwd_call.clear_cache()
+
+
+@pytest.fixture
+def rows_a_cell(monkeypatch):
+    """Hold the kernel's cells to at most a given number of rows (all
+    heads) for a [b, h, t, dh] f32 call by shrinking the budget cell_plan
+    works to — the only way to several cells at test sizes."""
+    def hold(rows, h, t, dh, heads=None, backward=True):
+        g = pack_group(h, dh)
+        tp = -(-t // 64) * 64
+        monkeypatch.setattr(pa, "_CELL_BUDGET", cell_vmem(
+            rows, heads or h, g, tp, tp, dh, 4, backward))
+        _forget_plans()
+        assert cell_plan(4 * rows, h, tp, tp, dh, 4, backward) == (
+            rows, heads or h)
+    yield hold
+    _forget_plans()
 
 
 def _rand(rng, *shape):
@@ -39,12 +74,17 @@ class TestPackGroup:
         assert pack_group(6, 64) == 2
 
 
-@pytest.mark.parametrize("tq,tk", [
-    (48, 48), (50, 70),
+@pytest.mark.parametrize("tq,tk,b,rows", [
+    (48, 48, 2, None), (50, 70, 2, None),
+    # 6 rows in cells of 3; 7 under the same most: one row a cell (no
+    # ragged last cell, see cell_plan); 2 rows, fewer than a cell holds
+    (48, 48, 6, 3), (48, 48, 7, 3), (50, 70, 2, 3),
     # multi-bucket asymmetric Tk (200 pads to 256) — slow tier
-    pytest.param(64, 200, marks=pytest.mark.slow)])
-def test_packed_matches_dense_padding_mask(rng, tq, tk):
-    b, h, dh = 2, 4, 64                     # the bench regime: g = 2
+    pytest.param(64, 200, 2, None, marks=pytest.mark.slow)])
+def test_packed_matches_dense_padding_mask(rng, rows_a_cell, tq, tk, b, rows):
+    h, dh = 4, 64                           # the bench regime: g = 2
+    if rows:
+        rows_a_cell(rows, h, max(tq, tk), dh, backward=False)
     q, k, v = (_rand(rng, b, h, tq, dh), _rand(rng, b, h, tk, dh),
                _rand(rng, b, h, tk, dh))
     m = _kv_mask(rng, b, tk)
@@ -54,12 +94,15 @@ def test_packed_matches_dense_padding_mask(rng, tq, tk):
                                rtol=2e-5, atol=2e-5)
 
 
-@pytest.mark.parametrize("t", [
-    100,
+@pytest.mark.parametrize("t,b,rows", [
+    (100, 2, None),
+    (40, 6, 2), (40, 5, 2),                 # three cells of 2; five of 1
     # single-pad 48->64 causal geometry — slow tier
-    pytest.param(48, marks=pytest.mark.slow)])
-def test_packed_matches_dense_causal(rng, t):
-    b, h, dh = 2, 4, 64
+    pytest.param(48, 2, None, marks=pytest.mark.slow)])
+def test_packed_matches_dense_causal(rng, rows_a_cell, t, b, rows):
+    h, dh = 4, 64
+    if rows:
+        rows_a_cell(rows, h, t, dh, backward=False)
     q, k, v = (_rand(rng, b, h, t, dh), _rand(rng, b, h, t, dh),
                _rand(rng, b, h, t, dh))
     m = _kv_mask(rng, b, t)
@@ -71,10 +114,13 @@ def test_packed_matches_dense_causal(rng, t):
                                rtol=2e-5, atol=2e-5)
 
 
-@pytest.mark.parametrize("h,dh", [(8, 16), (2, 128)])
+@pytest.mark.parametrize("h,dh", [(8, 16), (2, 128), (8, 32), (8, 64),
+                                  (16, 16)])
 def test_pack_group_edges_match_dense(rng, h, dh):
     """g=8 (narrow heads) and the g=1 wide-head degenerate pack must
-    stay numerically exact (g=2/4 are covered by the other tests)."""
+    stay numerically exact; (8, 32) and (8, 64) are transformer-base's
+    8 heads as g=4 x 2 groups and g=2 x 4 groups a cell, (16, 16) two
+    g=8 groups."""
     b, t = 2, 48
     q, k, v = (_rand(rng, b, h, t, dh), _rand(rng, b, h, t, dh),
                _rand(rng, b, h, t, dh))
@@ -97,12 +143,22 @@ def test_packed_no_mask(rng):
                                rtol=2e-5, atol=2e-5)
 
 
-@pytest.mark.parametrize("causal", [False, True])
-def test_packed_gradients_match_dense(rng, causal):
+@pytest.mark.parametrize("causal,b,t,rows,heads", [
+    (False, 2, 48, None, None), (True, 2, 48, None, None),
+    # 4 rows in cells of 2, 2 rows under a most of 3 (fewer than a cell)
+    (False, 4, 48, 2, None), (True, 4, 48, 2, None),
+    (False, 2, 48, 3, None), (True, 2, 48, 3, None),
+    # the T cap at dh 32 (128), a cell down to one row's single head
+    # group: today's floor
+    (True, 2, 128, 1, 4)])
+def test_packed_gradients_match_dense(rng, rows_a_cell, causal, b, t, rows,
+                                      heads):
     """The custom VJP: both backward orientations (dq via the packed
     Tk contraction, dk/dv via the packed Tq contraction) against the
     dense path's autodiff."""
-    b, h, t, dh = 2, 4, 48, 32
+    h, dh = 4, 32
+    if rows:
+        rows_a_cell(rows, h, t, dh, heads)
     q, k, v = (_rand(rng, b, h, t, dh), _rand(rng, b, h, t, dh),
                _rand(rng, b, h, t, dh))
     m = _kv_mask(rng, b, t)
@@ -172,6 +228,164 @@ def test_packed_under_jit(rng):
                                              m[:, None, None, :]))
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
                                rtol=2e-5, atol=2e-5)
+
+
+# ---- the cell: cell_plan alone, and the kernel against one tile a step ----
+
+_PLAN_SHAPES = [  # (b, h, tq, tk, dh, itemsize)
+    (512, 16, 64, 64, 64, 2), (128, 16, 64, 64, 64, 2),
+    (168, 16, 64, 64, 64, 2), (3, 16, 64, 64, 64, 2),
+    (45, 16, 128, 64, 64, 2), (2, 16, 256, 256, 64, 2),
+    (64, 8, 64, 64, 64, 2), (64, 8, 128, 128, 32, 4),
+    (9, 8, 64, 64, 16, 4), (4, 2, 128, 128, 128, 2)]
+
+
+@pytest.mark.parametrize("backward", [False, True])
+@pytest.mark.parametrize("shape", _PLAN_SHAPES)
+@time_limit(30)
+def test_cell_plan_fits_its_budget(shape, backward):
+    """Never zero rows or heads, rows that divide the batch (no ragged
+    last cell), whole head groups that divide the heads, and within the
+    budget unless the cell is already the floor (1, g)."""
+    b, h, tq, tk, dh, itemsize = shape
+    g = pack_group(h, dh)
+    for budget in (0, 1 << 20, 4 << 20, 16 << 20, None, 100 << 20):
+        rows, heads = cell_plan(b, h, tq, tk, dh, itemsize, backward,
+                                budget=budget)
+        assert 1 <= rows <= b and b % rows == 0
+        assert g <= heads <= h and heads % g == 0 and h % heads == 0
+        assert rows == 1 or heads == h      # part of a row only alone
+        vmem = cell_vmem(rows, heads, g, tq, tk, dh, itemsize, backward)
+        assert (vmem <= (pa._CELL_BUDGET if budget is None else budget)
+                or (rows, heads) == (1, g))
+    assert cell_plan(b, h, tq, tk, dh, itemsize, backward,
+                     budget=0) == (1, g)
+    assert pa._CELL_BUDGET < pa._VMEM_LIMIT
+
+
+@pytest.mark.parametrize("backward", [False, True])
+@pytest.mark.parametrize("h,dh,itemsize", [(16, 64, 2), (8, 64, 2),
+                                           (8, 32, 4), (8, 16, 4)])
+@time_limit(30)
+def test_cell_plan_is_monotone_in_t(h, dh, itemsize, backward):
+    """A longer sequence never gets a larger cell; the backward never a
+    larger one than the forward; at the cap a cell is a row or less."""
+    from marian_tpu.ops.auto_tuner import packed_attention_max_t
+    b, cells = 4096, []
+    for t in range(64, packed_attention_max_t(dh) + 1, 64):
+        rows, heads = cell_plan(b, h, t, t, dh, itemsize, backward)
+        cells.append(rows * heads)
+        if backward:
+            fr, fh = cell_plan(b, h, t, t, dh, itemsize, False)
+            assert rows * heads <= fr * fh
+    assert cells == sorted(cells, reverse=True)
+    assert cell_plan(b, 16, 256, 256, 64, 2, True)[0] == 1
+
+
+@time_limit(30)
+def test_cell_plan_takes_divisors_of_the_batch():
+    """Under a most of 4 rows a cell: 3 go in one cell, 6 in 3 + 3, 7
+    (prime) one row a cell, 10 in fives of 2; the benchmark cell's 168
+    and 80 rows under a most of 7 go in 7s and 5s."""
+    g = pack_group(16, 64)
+    plan = functools.partial(cell_plan, h=16, tq=64, tk=64, dh=64,
+                             itemsize=2, backward=False)
+    most4 = cell_vmem(4, 16, g, 64, 64, 64, 2, False)
+    assert [plan(b, budget=most4)[0]
+            for b in (3, 4, 6, 7, 8, 10, 512)] == [3, 4, 3, 1, 4, 2, 4]
+    most7 = cell_vmem(7, 16, g, 64, 64, 64, 2, False)
+    assert [plan(b, budget=most7)[0]
+            for b in (512, 256, 168, 128, 80, 64)] == [4, 4, 7, 4, 5, 4]
+
+
+def _tile_a_step(q, k, v, kvm, do=None, out=None, *, causal):
+    """Today's geometry before PR 26, kept here and not in the package:
+    one grid step per (row, head group), blocks (1, g, T, dh), the
+    package's own tile functions called once a step."""
+    b, h, tq, dh = q.shape
+    tk, g = k.shape[2], pack_group(h, dh)
+    kw = dict(scale=1.0 / dh ** 0.5, causal=causal, g=g, bq=tq, bk=tk, dh=dh)
+    qspec = pl.BlockSpec((1, g, tq, dh), lambda r, hg: (r, hg, 0, 0))
+    kspec = pl.BlockSpec((1, g, tk, dh), lambda r, hg: (r, hg, 0, 0))
+    mspec = pl.BlockSpec((1, 1, tk), lambda r, hg: (r, 0, 0))
+
+    def fwd(q_ref, k_ref, v_ref, kvm_ref, o_ref):
+        o = pa._fwd_tile(pa._heads(q_ref, 0, 0, g), pa._heads(k_ref, 0, 0, g),
+                         pa._heads(v_ref, 0, 0, g), kvm_ref[0], **kw)
+        for j in range(g):
+            o_ref[0, j] = o[j].astype(o_ref.dtype)
+
+    def bwd(q_ref, k_ref, v_ref, kvm_ref, do_ref, o_ref, *grads):
+        tiles = pa._bwd_tile(
+            pa._heads(q_ref, 0, 0, g), pa._heads(k_ref, 0, 0, g),
+            pa._heads(v_ref, 0, 0, g), kvm_ref[0],
+            pa._heads(do_ref, 0, 0, g), pa._heads(o_ref, 0, 0, g), **kw)
+        for ref, tile in zip(grads, tiles):
+            for j in range(g):
+                ref[0, j] = tile[j].astype(ref.dtype)
+
+    if do is None:
+        return pl.pallas_call(
+            fwd, grid=(b, h // g), in_specs=[qspec, kspec, kspec, mspec],
+            out_specs=qspec, out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype),
+            interpret=True)(q, k, v, kvm)
+    return pl.pallas_call(
+        bwd, grid=(b, h // g),
+        in_specs=[qspec, kspec, kspec, mspec, qspec, qspec],
+        out_specs=[qspec, kspec, kspec],
+        out_shape=[jax.ShapeDtypeStruct(x.shape, x.dtype) for x in (q, k, v)],
+        interpret=True)(q, k, v, kvm, do, out)
+
+
+@pytest.mark.parametrize("b,h,tq,tk,dh,causal,dtype,rows,heads", [
+    (6, 4, 64, 64, 64, False, "float32", 3, None),     # 3 + 3
+    (8, 4, 64, 64, 64, True, "bfloat16", 4, None),     # 4 + 4
+    (4, 16, 64, 64, 64, True, "bfloat16", 2, None),    # big's 8 groups a row
+    (2, 4, 64, 64, 64, False, "float32", 3, None),     # fewer rows than a cell
+    (6, 4, 128, 64, 64, False, "bfloat16", 2, None),   # cross
+    (3, 8, 64, 64, 32, True, "float32", 3, None),      # g = 4, two groups
+    (3, 8, 64, 64, 32, True, "float32", 1, 4),         # the floor (1, g)
+    (2, 4, 256, 256, 64, True, "bfloat16", 1, 2),      # the cap, part of a row
+    # a ragged last cell, which cell_plan never gives (it hung the chip):
+    # forced here, 3 + 3 + 1 and 2 + 2 + 1, to hold that the kernel
+    # itself is right on it
+    (7, 4, 64, 64, 64, True, "bfloat16", -3, None),
+    (5, 4, 128, 64, 64, False, "float32", -2, None),
+])
+@time_limit(120)
+def test_cells_equal_one_tile_a_step_bit_for_bit(rng, monkeypatch, b, h, tq,
+                                                 tk, dh, causal, dtype, rows,
+                                                 heads):
+    """The refactor's pin: taking a block of rows and all heads a grid
+    step and looping over the tiles inside gives, bit for bit, what one
+    (row, head group) a step gives — output and all three gradients. On
+    a ragged last cell too (rows < 0: forced): what a cell reads past
+    the batch's end reaches only writes past the end, and those are
+    dropped."""
+    g, dt = pack_group(h, dh), jnp.dtype(dtype)
+    q, k, v, do = (jnp.asarray(rng.randn(b, h, t, dh), dt)
+                   for t in (tq, tk, tk, tq))
+    kvm = _kv_mask(rng, b, tk).reshape(b, 1, tk)
+    scale = 1.0 / dh ** 0.5
+    if rows < 0:
+        monkeypatch.setattr(pa, "cell_plan", lambda *a, **kw: (-rows, h))
+    for backward in (False, True):
+        monkeypatch.setattr(pa, "_CELL_BUDGET", cell_vmem(
+            abs(rows), heads or h, g, tq, tk, dh, dt.itemsize, backward))
+        _forget_plans()
+        assert pa._plan(b, h, tq, tk, dh, dt.itemsize, backward) == (
+            min(abs(rows), b), heads or h)
+        if not backward:
+            out = pa._fwd_call(q, k, v, kvm, scale, causal, g, True)
+            ref = _tile_a_step(q, k, v, kvm, causal=causal)
+            np.testing.assert_array_equal(np.asarray(out), np.asarray(ref))
+        else:
+            got = pa._bwd_call(q, k, v, kvm, do, out, scale, causal, g, True)
+            want = _tile_a_step(q, k, v, kvm, do, out, causal=causal)
+            for a, r in zip(got, want):
+                assert np.isfinite(np.asarray(a, np.float32)).all()
+                np.testing.assert_array_equal(np.asarray(a), np.asarray(r))
+    _forget_plans()
 
 
 class TestDispatcherGate:
