@@ -36,7 +36,10 @@ LEVEL1 = ("optimizer", "collectives", "expand_batch")
 COLLECTIVE_OPS = ("all-gather", "all-reduce", "reduce-scatter", "all-to-all",
                   "collective-permute", "reduce_scatter", "psum")
 LEVEL2 = ("encoder", "decoder", "embed", "self_attn", "cross_attn", "ffn",
-          "pre_post", "output", "loss", "cast", "clip", "adam", "ema")
+          "pre_post", "output", "loss", "cast", "clip", "adam", "ema",
+          # a layer plan's scopes (models/layer_plan.py)
+          "kda", "mla", "experts.route", "experts.compute",
+          "experts.shared")
 
 
 # -- protobuf wire format ------------------------------------------------------

@@ -143,6 +143,23 @@ _MODEL = [
     _f("transformer-moe-top-k", int, 2, "MoE router top-k (1 = Switch, 2 = GShard)", "model"),
     _f("moe-capacity-factor", float, 1.25, "MoE expert capacity factor (tokens beyond capacity fall through the residual)", "model"),
     _f("moe-aux-weight", float, 0.01, "Weight of the MoE load-balancing auxiliary loss", "training"),
+    # a decoder-only stack whose layers differ (models/layer_plan.py; TPU extension)
+    _f("transformer-layer-plan", str, [], "--type transformer-lm only: one <mixing>:<feed-forward> entry per layer, mixing kda (delta rule with per-channel decay) or mla (latent attention, no rotation), feed-forward dense (gated MLP of --transformer-dim-ffn) or experts; pre-norm RMSNorm, no positions, untied tables", "model", "*"),
+    _f("plan-norm-eps", float, 1e-5, "RMSNorm epsilon of a layer plan", "model"),
+    _f("plan-kda-dim-head", int, 128, "kda: key and value channels per head", "model"),
+    _f("plan-kda-conv", int, 4, "kda: width of the depthwise causal convolution on q, k, v", "model"),
+    _f("plan-kda-low-rank", int, 128, "kda: rank of the decay and output-gate projections", "model"),
+    _f("plan-kda-head-groups", int, 1, "kda: mix the heads in this many groups, one after another, each rematerialised in the backward: every intermediate is a group wide (memory for time; must divide --transformer-heads)", "model"),
+    _f("plan-mla-dim-nope", int, 128, "mla: per-head key channels expanded from the latent", "model"),
+    _f("plan-mla-dim-shared", int, 64, "mla: key channels shared by all heads (not rotated: the plan has no positions)", "model"),
+    _f("plan-mla-dim-v", int, 128, "mla: value channels per head", "model"),
+    _f("plan-mla-latent", int, 512, "mla: width of the key-value latent", "model"),
+    _f("plan-experts", int, 0, "experts: the router's width (all experts of the layer, held here or not)", "model"),
+    _f("plan-experts-held", int, [], "experts: FIRST COUNT, the experts this process holds and computes (default: all); the rest are other chips' part of the result", "model", "*"),
+    _f("plan-experts-top-k", int, 8, "experts: experts per token", "model"),
+    _f("plan-experts-dim-ffn", int, 1024, "experts: hidden width of one expert's gated MLP", "model"),
+    _f("plan-experts-shared", int, 1, "experts: shared experts added to every token", "model"),
+    _f("plan-experts-scale", float, 1.0, "experts: factor on the renormalised routing weights", "model"),
 ]
 
 _TRAINING = [
@@ -206,6 +223,9 @@ _TRAINING = [
     _f("mini-batch-fit-step", int, 10, "Step for mini-batch-fit search", "training"),
     _f("maxi-batch", int, 100, "Number of minibatches to preload and sort", "training"),
     _f("maxi-batch-sort", str, "trg", "Sorting within maxi-batch: trg, src, none", "training"),
+    _f("batch-row-multiple", int, 8, "Rows of a padded batch snap up to a multiple of this (pad rows are masked); 1 for long rows, where 8 rows would be several times the token budget (TPU extension)", "training"),
+    _f("length-buckets", int, [], "Padded widths a batch snaps up to, ascending; beyond the last, steps of 512 (default: 8 16 24 32 48 64 96 128 ... 4096) (TPU extension)", "training", "*"),
+    _f("precompile-buckets", int, 0, "With --length-buckets and --mini-batch-words the train step has one shape a bucket: compile them ahead on this many host threads from the first update on, so that one shape's compile overlaps the next's (0: each compiles when its first batch arrives) (TPU extension)", "training"),
     _f("shuffle-in-ram", bool, False, "Shuffle corpus in RAM instead of temp files", "training"),
     _f("data-threads", int, 8, "Host threads for data pipeline", "training"),
     _f("all-caps-every", int, 0, "Upper-case every Nth batch (data augmentation)", "training"),

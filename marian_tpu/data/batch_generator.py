@@ -66,6 +66,21 @@ def padded_batch_cost(n_rows: int, max_len: int,
             * bucket_length(max_len, length_buckets))
 
 
+def budget_shapes(options) -> List[Tuple[int, int]]:
+    """The (width, rows) a one-stream batch can have under a token budget
+    (--mini-batch-words) and an explicit bucket table (--length-buckets):
+    one canonical row count a width, as _split_maxi's flush derives it.
+    Empty where either is missing (the default table is open-ended, and
+    two streams make pairs of widths)."""
+    buckets = [int(b) for b in options.get("length-buckets", None) or []]
+    words = int(options.get("mini-batch-words", 0) or 0)
+    multiple = int(options.get("batch-row-multiple", 8) or 8)
+    if not buckets or words <= 0:
+        return []
+    return [(w, max(multiple, words // w // multiple * multiple))
+            for w in buckets]
+
+
 @dataclasses.dataclass
 class SubBatch:
     """One stream of a batch (reference: SubBatch: indices + mask)."""
@@ -219,6 +234,13 @@ class BatchGenerator:
             seed = int(options.get("seed", seed)) or seed
             if shuffle_batches is None:
                 shuffle_batches = options.get("shuffle", "data") in ("data", "batches")
+            # the padded shapes follow the configuration: long rows want a
+            # row multiple of 1 and a bucket table that starts wide
+            batch_multiple = int(options.get("batch-row-multiple",
+                                             batch_multiple)
+                                 or batch_multiple)
+            length_buckets = tuple(int(b) for b in options.get(
+                "length-buckets", None) or length_buckets)
         self.weighting_type = (str(options.get("data-weighting-type",
                                                "sentence"))
                                if options is not None

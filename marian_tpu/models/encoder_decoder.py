@@ -54,8 +54,17 @@ class EncoderDecoder:
         self.use_guided = bool(ga and ga != "none") and not inference
         src_vocab_size, src_factors = _vocab_info(src_vocab)
         trg_vocab_size, trg_factors = _vocab_info(trg_vocab)
-        if self.model_type in ("transformer", "multi-transformer",
-                               "transformer-lm", "lm-transformer", "lm"):
+        if self.model_type in ("transformer-lm", "lm-transformer", "lm") \
+                and options.get("transformer-layer-plan", None):
+            # a decoder-only stack whose layers differ: a per-layer plan
+            # of (mixing, feed-forward) kinds, a function family of its own
+            from . import layer_plan as P
+            self.cfg = P.config_from_options(options, src_vocab_size,
+                                             trg_vocab_size, inference,
+                                             trg_factors=trg_factors)
+            self._mod = P
+        elif self.model_type in ("transformer", "multi-transformer",
+                                 "transformer-lm", "lm-transformer", "lm"):
             seq_mesh = None
             if str(options.get("sequence-parallel", "none") or "none") != "none":
                 from ..parallel import mesh as _mesh
@@ -108,6 +117,12 @@ class EncoderDecoder:
         return self._mod.init_params(self.cfg, key)
 
     @property
+    def step_counters(self) -> Tuple[str, ...]:
+        """Names of the counts `loss` returns as aux["counters"] (one lazy
+        float32 vector, summed over the layers); () for most families."""
+        return tuple(getattr(self._mod, "COUNTERS", ()))
+
+    @property
     def beam_carried_suffixes(self) -> Tuple[str, ...]:
         """Decode-state key suffixes that ride the beam (reordered by
         backpointers); model-family specific (KV caches vs RNN states)."""
@@ -127,7 +142,8 @@ class EncoderDecoder:
              ) -> Tuple[jax.Array, Dict[str, jax.Array]]:
         """Returns (ce_sum_plus_aux, aux dict with loss_sum/labels)."""
         with jax.named_scope("cast"):
-            cparams = T.cast_params(params, self.cfg.compute_dtype)
+            cparams = getattr(self._mod, "cast_params", T.cast_params)(
+                params, self.cfg.compute_dtype)
         k_enc = jax.random.fold_in(key, 1) if key is not None else None
         k_dec = jax.random.fold_in(key, 2) if key is not None else None
         src_ids, src_mask = self._batch_sources(batch)
@@ -154,6 +170,9 @@ class EncoderDecoder:
         align = parts.pop(0) if want_align else None
         if moe:
             moe_aux = moe_aux + parts.pop(0)
+        # a family that counts inside the step (its COUNTERS names the
+        # entries) hands the counts back as one lazy vector
+        counters = parts.pop(0) if self.step_counters else None
         if table is not None and not (self.unlikelihood
                                       and "data_weights" in batch):
             # output projection and loss are ONE streaming kernel here
@@ -168,6 +187,8 @@ class EncoderDecoder:
                                     unlikelihood=self.unlikelihood)
         total = rl.loss_sum
         aux = {"ce_sum": rl.loss_sum, "labels": rl.labels}
+        if counters is not None:
+            aux["counters"] = counters
         if moe and getattr(self.cfg, "moe_aux_weight", 0.0) > 0:
             # load-balance aux joins at label scale like the guided loss
             # (cost normalization divides by labels → effective weight is
@@ -196,7 +217,8 @@ class EncoderDecoder:
         (→ dense logits + layers/loss.py). Applies for plain-tensor output
         projections of the transformer family; factored/quantized vocabs and
         non-TPU backends (unless --fused-ce on) use the dense path."""
-        if self._fused_ce_mode == "off" or self._mod is not T:
+        if self._fused_ce_mode == "off" \
+                or not hasattr(self._mod, "_plain_output_table"):
             return None
         if self._fused_ce_mode == "auto" and jax.default_backend() != "tpu":
             return None
@@ -204,7 +226,7 @@ class EncoderDecoder:
         from ..ops.pallas.fused_ce import fused_available
         if not fused_available(int(cfg.dim_emb)):
             return None
-        return T._plain_output_table(cfg, cparams)
+        return self._mod._plain_output_table(cfg, cparams)
 
     def _fused_ce_loss(self, cparams, table, hidden, batch) -> RationalLoss:
         """Label-smoothed CE straight from decoder hidden states — logits
@@ -313,7 +335,7 @@ def create_model(options, src_vocab, trg_vocab,
 
 ARCH_KEY_PREFIXES = ("transformer", "enc-", "dec-", "dim-", "tied-",
                      "factors-", "lemma-", "input-types", "bert-", "char-",
-                     "ulr")
+                     "ulr", "plan-")
 ARCH_KEYS = ("type", "skip", "layer-normalization", "right-left",
              "max-length")
 

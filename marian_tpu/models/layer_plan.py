@@ -1,0 +1,420 @@
+"""A decoder-only stack driven by a per-layer PLAN.
+
+`--type transformer-lm --transformer-layer-plan kda:dense kda:experts
+mla:experts ...` names, for each layer in turn, its token-mixing kind and
+its feed-forward kind, as data:
+
+  mixing        kda      the delta rule with a per-channel decay
+                         (ops/kda.py, ops/pallas/kda_chunk.py)
+                mla      latent attention: keys and values expanded from
+                         one low-rank latent, plus key channels shared
+                         by all heads; causal softmax, no rotation
+  feed-forward  dense    gated MLP, W_d(SiLU(W_g x) * W_u x)
+                experts  a router over all experts, the held ones
+                         computed without dropping (ops/experts.py),
+                         plus shared experts on every token
+
+The block is pre-norm with RMSNorm (scale only) and a residual add; no
+positional signal anywhere; input and output tables untied; a final
+RMSNorm before the output projection. It is the family of Kimi Linear
+(https://huggingface.co/moonshotai/Kimi-Linear-48B-A3B-Instruct); the
+sizes come from flags, nothing here knows a model's name.
+
+The module is one more function family behind models/encoder_decoder.py
+(`init_params`, `encode`, `decode_train`, `output_logits`), next to
+transformer.py and s2s.py, and reuses transformer.py's embedding, output
+and fused-CE code through a config that extends TransformerConfig.
+Training only: there is no incremental `decode_step` for these layers
+yet.
+"""
+
+from __future__ import annotations
+
+import dataclasses
+import math
+from functools import partial
+from typing import Dict, Optional, Tuple
+
+import jax
+import jax.numpy as jnp
+
+from ..layers import initializers as inits
+from ..ops import experts as X
+from ..ops import kda as K
+from ..ops.attention import attention, causal_mask
+from ..ops.ops import rms_norm
+from . import transformer as T
+
+Params = Dict[str, jax.Array]
+
+MIXINGS = ("kda", "mla")
+FEED_FORWARDS = ("dense", "experts")
+# what the step carries out beside the loss, summed over the layers
+COUNTERS = X.COUNTERS
+# kept in the optimizer's float32 whatever the compute type: the router
+# decides WHICH experts run, and the decay's rate sits in an exponent
+_FLOAT32_SUFFIXES = ("_experts_router", "_kda_A_log", "_kda_dt_bias")
+
+
+@dataclasses.dataclass(frozen=True)
+class PlanConfig(T.TransformerConfig):
+    plan: Tuple[Tuple[str, str], ...] = ()
+    norm_eps: float = 1e-5
+    # kda
+    kda_dim_head: int = 128
+    kda_conv: int = 4
+    kda_low_rank: int = 128
+    kda_head_groups: int = 1          # heads mixed in this many turns
+    # mla
+    mla_dim_nope: int = 128           # per-head key channels from the latent
+    mla_dim_shared: int = 64          # key channels shared by all heads
+    mla_dim_v: int = 128
+    mla_latent: int = 512
+    # experts
+    experts: int = 0                  # the router's width
+    experts_top_k: int = 8
+    experts_dim_ffn: int = 1024
+    experts_shared: int = 1
+    experts_scale: float = 1.0
+    experts_first: int = 0            # the held set: first, count
+    experts_held: int = 0
+
+
+def parse_plan(spec) -> Tuple[Tuple[str, str], ...]:
+    plan = []
+    for item in spec:
+        mix, _, ffn = str(item).partition(":")
+        if mix not in MIXINGS or ffn not in FEED_FORWARDS:
+            raise ValueError(
+                f"--transformer-layer-plan entry {item!r}: want "
+                f"<mixing>:<feed-forward> with mixing one of {MIXINGS} "
+                f"and feed-forward one of {FEED_FORWARDS}")
+        plan.append((mix, ffn))
+    return tuple(plan)
+
+
+def config_from_options(options, src_vocab, trg_vocab, for_inference=False,
+                        **kw) -> PlanConfig:
+    g = options.get
+    base = T.config_from_options(options, src_vocab, trg_vocab,
+                                 for_inference, **kw)
+    plan = parse_plan(g("transformer-layer-plan", []) or [])
+    n_experts = int(g("plan-experts", 0) or 0)
+    held = [int(v) for v in (g("plan-experts-held", []) or [0, n_experts])]
+    if len(held) != 2 or (any(f == "experts" for _, f in plan) and not (
+            0 <= held[0] and held[1] >= 1 and sum(held) <= n_experts)):
+        raise ValueError(f"--plan-experts-held {held}: want FIRST COUNT, a "
+                         f"part of --plan-experts {n_experts}")
+    first, count = held
+    groups = int(g("plan-kda-head-groups", 1))
+    if groups < 1 or base.heads % groups:
+        raise ValueError(f"--plan-kda-head-groups {groups} does not divide "
+                         f"--transformer-heads {base.heads}")
+    fields = {f.name: getattr(base, f.name)
+              for f in dataclasses.fields(base)}
+    fields.update(
+        lm=True, dec_depth=len(plan), tied_embeddings=False,
+        tied_embeddings_all=False, tied_embeddings_src=False,
+        output_omit_bias=True)
+    return PlanConfig(
+        **fields, plan=plan,
+        norm_eps=float(g("plan-norm-eps", 1e-5)),
+        kda_dim_head=int(g("plan-kda-dim-head", 128)),
+        kda_conv=int(g("plan-kda-conv", 4)),
+        kda_low_rank=int(g("plan-kda-low-rank", 128)),
+        kda_head_groups=groups,
+        mla_dim_nope=int(g("plan-mla-dim-nope", 128)),
+        mla_dim_shared=int(g("plan-mla-dim-shared", 64)),
+        mla_dim_v=int(g("plan-mla-dim-v", 128)),
+        mla_latent=int(g("plan-mla-latent", 512)),
+        experts=n_experts,
+        experts_top_k=int(g("plan-experts-top-k", 8)),
+        experts_dim_ffn=int(g("plan-experts-dim-ffn", 1024)),
+        experts_shared=int(g("plan-experts-shared", 1)),
+        experts_scale=float(g("plan-experts-scale", 1.0)),
+        experts_first=first, experts_held=count)
+
+
+# ---------------------------------------------------------------------------
+# parameters
+# ---------------------------------------------------------------------------
+
+def init_params(cfg: PlanConfig, key: jax.Array) -> Params:
+    p: Params = {}
+    keys = iter(jax.random.split(key, 64 * max(len(cfg.plan), 1) + 8))
+    d, h = cfg.dim_emb, cfg.heads
+
+    def glorot(*shape, **kw):
+        return inits.glorot_uniform(next(keys), shape, **kw)
+
+    def ones(n):
+        return inits.ones((1, n))
+
+    p["decoder_Wemb"] = glorot(cfg.trg_vocab, d)
+    p["decoder_ff_logit_out_W"] = glorot(d, cfg.trg_vocab)
+    p["decoder_top_norm_scale"] = ones(d)
+    for l, (mix, ffn) in enumerate(cfg.plan, 1):
+        lp = f"decoder_l{l}"
+        p[f"{lp}_mix_norm_scale"] = ones(d)
+        p[f"{lp}_ffn_norm_scale"] = ones(d)
+        if mix == "kda":
+            dh, r = cfg.kda_dim_head, cfg.kda_low_rank
+            for n in "qkv":
+                p[f"{lp}_kda_W{n}"] = glorot(d, h * dh)
+                # a short filter that starts near the identity
+                p[f"{lp}_kda_conv_{n}"] = jax.random.uniform(
+                    next(keys), (cfg.kda_conv, h * dh), jnp.float32,
+                    -0.5, 0.5).at[-1].add(1.0)
+            p[f"{lp}_kda_Wf1"] = glorot(d, r)
+            p[f"{lp}_kda_Wf2"] = glorot(r, h * dh)
+            # decay rates exp(A_log) in [1, 16) and steps dt in
+            # [1e-3, 1e-1), dt_bias = softplus^-1(dt): a channel's
+            # memory starts between a few and a thousand positions
+            p[f"{lp}_kda_A_log"] = jnp.log(jax.random.uniform(
+                next(keys), (1, h), jnp.float32, 1.0, 16.0))
+            dt = jnp.exp(jax.random.uniform(
+                next(keys), (1, h * dh), jnp.float32,
+                math.log(1e-3), math.log(1e-1)))
+            p[f"{lp}_kda_dt_bias"] = dt + jnp.log(-jnp.expm1(-dt))
+            p[f"{lp}_kda_Wb"] = glorot(d, h)
+            p[f"{lp}_kda_Wg1"] = glorot(d, r)
+            p[f"{lp}_kda_Wg2"] = glorot(r, h * dh)
+            p[f"{lp}_kda_out_norm_scale"] = ones(dh)
+            p[f"{lp}_kda_Wo"] = glorot(h * dh, d)
+        else:
+            dq = cfg.mla_dim_nope + cfg.mla_dim_shared
+            p[f"{lp}_mla_Wq"] = glorot(d, h * dq)
+            p[f"{lp}_mla_Wkva"] = glorot(d, cfg.mla_latent
+                                         + cfg.mla_dim_shared)
+            p[f"{lp}_mla_kv_norm_scale"] = ones(cfg.mla_latent)
+            p[f"{lp}_mla_Wkvb"] = glorot(
+                cfg.mla_latent, h * (cfg.mla_dim_nope + cfg.mla_dim_v))
+            p[f"{lp}_mla_Wo"] = glorot(h * cfg.mla_dim_v, d)
+        if ffn == "dense":
+            f = cfg.dim_ffn
+            p[f"{lp}_ffn_Wg"] = glorot(d, f)
+            p[f"{lp}_ffn_Wu"] = glorot(d, f)
+            p[f"{lp}_ffn_Wd"] = glorot(f, d)
+        else:
+            f, n = cfg.experts_dim_ffn, cfg.experts_held
+            p[f"{lp}_experts_router"] = glorot(d, cfg.experts)
+            p[f"{lp}_experts_Wg"] = glorot(n, d, f, fan_in=d, fan_out=f)
+            p[f"{lp}_experts_Wu"] = glorot(n, d, f, fan_in=d, fan_out=f)
+            p[f"{lp}_experts_Wd"] = glorot(n, f, d, fan_in=f, fan_out=d)
+            if cfg.experts_shared:
+                fs = f * cfg.experts_shared
+                p[f"{lp}_shared_Wg"] = glorot(d, fs)
+                p[f"{lp}_shared_Wu"] = glorot(d, fs)
+                p[f"{lp}_shared_Wd"] = glorot(fs, d)
+    return p
+
+
+def cast_params(params: Params, dtype) -> Params:
+    """transformer.cast_params, less the few leaves that stay float32."""
+    keep = {k: v for k, v in params.items()
+            if k.endswith(_FLOAT32_SUFFIXES)}
+    return {**T.cast_params(params, dtype), **keep}
+
+
+# ---------------------------------------------------------------------------
+# layers
+# ---------------------------------------------------------------------------
+
+_heads = T._split_heads            # [B, T, h * d] -> [B, h, T, d]
+
+
+def _kda_carry():
+    """The state carry between chunks: the Pallas kernels on a TPU, the
+    jnp scan they are tested against elsewhere."""
+    if jax.default_backend() != "tpu":
+        return K.state_carry
+    from ..ops.pallas.kda_chunk import kda_state_carry
+    return kda_state_carry
+
+
+def _kda(cfg: PlanConfig, p: Params, lp: str, x):
+    """q, k, v = SiLU(conv(W x)), q and k L2-normalised per head; decay
+    g = -exp(A_log) softplus(W_f2 W_f1 x + dt_bias) per key channel,
+    b = sigmoid(W_b x) per head; the delta rule; RMSNorm per head gated
+    by sigmoid(W_g2 W_g1 x); W_o.
+
+    The heads are mixed in `kda_head_groups` groups, one after another
+    (a scan over the groups' slices of the weights, its body
+    rematerialised in the backward when there is more than one): every
+    intermediate is then [B, T, H / groups * dh] and only one group's
+    chunk terms, states and cotangents are alive at a time. The groups'
+    outputs meet in W_o's float32 accumulator."""
+    h, dh, n = cfg.heads, cfg.kda_dim_head, cfg.kda_head_groups
+    hg = h // n
+    f32 = jnp.float32
+
+    def by_group(name, per_head=dh, axis=-1):
+        w = p[f"{lp}_kda_{name}"]
+        axis %= w.ndim
+        shape = w.shape[:axis] + (n, hg * per_head) + w.shape[axis + 1:]
+        return jnp.moveaxis(w.reshape(shape), axis, 0)
+
+    weights = {name: by_group(name) for name in (
+        "Wq", "Wk", "Wv", "conv_q", "conv_k", "conv_v", "Wf2", "dt_bias",
+        "Wg2")}
+    weights.update(A_log=by_group("A_log", 1), Wb=by_group("Wb", 1),
+                   Wo=by_group("Wo", axis=0))
+    bsz, t, _ = x.shape
+    # everything between the projections and W_o is float32: the decay
+    # sits in an exponent that is summed over thousands of positions, and
+    # the short filter and the normalisations are cheap at a group's width
+    low_f = jnp.dot(x, p[f"{lp}_kda_Wf1"], preferred_element_type=f32)
+    low_g = jnp.dot(x, p[f"{lp}_kda_Wg1"], preferred_element_type=f32)
+
+    def wide(low, w):
+        return jnp.dot(low, w.astype(f32),
+                       precision=jax.lax.Precision.HIGHEST)
+
+    def group(acc, w):
+        def branch(n_):
+            y = K.short_conv(
+                jnp.dot(x, w[f"W{n_}"], preferred_element_type=f32),
+                w[f"conv_{n_}"].astype(f32))
+            return _heads(jax.nn.silu(y), hg)
+        q, k = K.l2_normalize(branch("q")), K.l2_normalize(branch("k"))
+        step = jax.nn.softplus(wide(low_f, w["Wf2"])
+                               + w["dt_bias"].astype(f32))
+        g = -jnp.exp(w["A_log"].astype(f32)).reshape(1, hg, 1, 1) \
+            * _heads(step, hg)
+        b = jax.nn.sigmoid(jnp.dot(
+            x, w["Wb"], preferred_element_type=f32)).transpose(0, 2, 1)
+        o = K.kda_chunked(q, k, branch("v"), g, b, dh ** -0.5,
+                          carry=_kda_carry())
+        o = rms_norm(o.transpose(0, 2, 1, 3),
+                     p[f"{lp}_kda_out_norm_scale"], eps=cfg.norm_eps)
+        gate = jax.nn.sigmoid(wide(low_g, w["Wg2"]))
+        o = (o.reshape(bsz, t, hg * dh) * gate).astype(x.dtype)
+        return acc + jnp.dot(o, w["Wo"], preferred_element_type=f32), None
+
+    with jax.named_scope("kda"):
+        out, _ = jax.lax.scan(jax.checkpoint(group) if n > 1 else group,
+                              jnp.zeros((bsz, t, cfg.dim_emb), f32),
+                              weights)
+        return out.astype(x.dtype)
+
+
+def _mla(cfg: PlanConfig, p: Params, lp: str, x, mask):
+    h, dn, dv = cfg.heads, cfg.mla_dim_nope, cfg.mla_dim_v
+    with jax.named_scope("mla"):
+        q = _heads(jnp.dot(x, p[f"{lp}_mla_Wq"]), h)
+        kva = jnp.dot(x, p[f"{lp}_mla_Wkva"])
+        latent = rms_norm(kva[..., :cfg.mla_latent],
+                          p[f"{lp}_mla_kv_norm_scale"], eps=cfg.norm_eps)
+        shared = kva[..., cfg.mla_latent:]
+        kv = _heads(jnp.dot(latent, p[f"{lp}_mla_Wkvb"]), h)
+        k = jnp.concatenate(
+            [kv[..., :dn], jnp.broadcast_to(
+                shared[:, None], (*kv.shape[:3], cfg.mla_dim_shared))],
+            axis=-1)
+        t = x.shape[1]
+        o, _ = attention(q, k, kv[..., dn:],
+                         mask=causal_mask(t) * mask[:, None, None, :],
+                         kv_mask=mask, causal=True,
+                         flash=cfg.flash_attention, packed="off")
+        o = o.transpose(0, 2, 1, 3).reshape(x.shape[0], t, h * dv)
+        return jnp.dot(o, p[f"{lp}_mla_Wo"])
+
+
+def _experts(cfg: PlanConfig, p: Params, lp: str, x, mask):
+    bsz, t, d = x.shape
+    flat = x.reshape(bsz * t, d)
+    with jax.named_scope("experts.route"):
+        idx, weights = X.route(flat, p[f"{lp}_experts_router"],
+                               cfg.experts_top_k, cfg.experts_scale)
+        if cfg.experts_held < cfg.experts:
+            # A share of the layer's output carries a share of the
+            # router's gradient, and that share alone teaches the router
+            # to send tokens elsewhere (fewer fresh experts in the sum is
+            # less noise). The whole gradient is the sum over all shares,
+            # which takes their exchange: until then the router of a
+            # share is not trained through its part.
+            weights = jax.lax.stop_gradient(weights)
+    with jax.named_scope("experts.compute"):
+        y, counters = X.held_experts(
+            flat, mask.reshape(-1), idx, weights, p[f"{lp}_experts_Wg"],
+            p[f"{lp}_experts_Wu"], p[f"{lp}_experts_Wd"],
+            cfg.experts_first,
+            pool=X.pool_rows(bsz * t, cfg.experts_top_k, cfg.experts_held,
+                             cfg.experts))
+    if cfg.experts_shared:
+        with jax.named_scope("experts.shared"):
+            y = y + X.gated_mlp(flat, p[f"{lp}_shared_Wg"],
+                                p[f"{lp}_shared_Wu"], p[f"{lp}_shared_Wd"])
+    return y.reshape(bsz, t, d), counters
+
+
+def _mix(cfg: PlanConfig, kind: str, lp: str, p: Params, x, mask):
+    pre = rms_norm(x, p[f"{lp}_mix_norm_scale"], eps=cfg.norm_eps)
+    return x + (_kda(cfg, p, lp, pre) if kind == "kda"
+                else _mla(cfg, p, lp, pre, mask))
+
+
+def _feed_forward(cfg: PlanConfig, kind: str, lp: str, p: Params, x, mask):
+    pre = rms_norm(x, p[f"{lp}_ffn_norm_scale"], eps=cfg.norm_eps)
+    if kind == "dense":
+        with jax.named_scope("ffn"):
+            out = X.gated_mlp(pre, p[f"{lp}_ffn_Wg"], p[f"{lp}_ffn_Wu"],
+                              p[f"{lp}_ffn_Wd"])
+        counters = jnp.zeros((len(COUNTERS),), jnp.float32)
+    else:
+        out, counters = _experts(cfg, p, lp, pre, mask)
+    return x + out, counters
+
+
+def _layer(cfg: PlanConfig, kinds, lp: str, p: Params, x, mask, remat):
+    """One block: x + mixing(norm(x)), then x + feed-forward(norm(x)).
+    With `remat` (--gradient-checkpointing, training) each half is
+    rematerialised in the backward on its own, so what stays alive
+    between the passes is a layer's input and its middle. KDA mixed in
+    head groups rematerialises itself group by group and is not wrapped
+    again: a second wrap would run its forward a third time."""
+    mix, ffn = kinds
+    f_mix = partial(_mix, cfg, mix, lp)
+    f_ffn = partial(_feed_forward, cfg, ffn, lp)
+    if remat:
+        if not (mix == "kda" and cfg.kda_head_groups > 1):
+            f_mix = jax.checkpoint(f_mix)
+        f_ffn = jax.checkpoint(f_ffn)
+    return f_ffn(p, f_mix(p, x, mask), mask)
+
+
+# ---------------------------------------------------------------------------
+# the function family models/encoder_decoder.py closes over
+# ---------------------------------------------------------------------------
+
+def encode(cfg, params, src_ids, src_mask, train=False, key=None):
+    return None
+
+
+def decode_train(cfg: PlanConfig, params: Params, enc_out, src_mask,
+                 trg_ids, trg_mask, train: bool = True,
+                 key: Optional[jax.Array] = None,
+                 return_alignment: bool = False,
+                 return_hidden: bool = False):
+    """Teacher-forced: [B, T] gold ids -> ([B, T, V] logits, or the
+    hidden states before the output projection when return_hidden;
+    counters [len(COUNTERS)]). The input is the gold embeddings shifted
+    right with a zero vector first, as transformer.decode_train's."""
+    if return_alignment:
+        raise ValueError("a layer plan has no cross attention to align")
+    with jax.named_scope("embed"):
+        x = T.shift_right_embeddings(
+            T._embed_words(cfg, params, trg_ids, "trg"))
+    mask = trg_mask.astype(jnp.float32)
+    counters = jnp.zeros((len(COUNTERS),), jnp.float32)
+    for l, kinds in enumerate(cfg.plan, 1):
+        x, c = _layer(cfg, kinds, f"decoder_l{l}", params, x, mask,
+                      remat=cfg.gradient_checkpointing and train)
+        counters = counters + c
+    x = rms_norm(x, params["decoder_top_norm_scale"], eps=cfg.norm_eps)
+    return (x if return_hidden else T.output_logits(cfg, params, x)), \
+        jax.lax.stop_gradient(counters)
+
+
+output_logits = T.output_logits
+_plain_output_table = T._plain_output_table

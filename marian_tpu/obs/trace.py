@@ -211,6 +211,10 @@ class Tracer:
         # name -> [calls, seconds, self seconds, thread]; allocated by
         # the first live span that ends, like the rings by enable()
         self._totals: Optional[Dict[str, List]] = None   # guarded-by: _lock
+        # step counters (count_lazy): the names with the device vectors
+        # not fetched yet, and the fetched sums by name
+        self._lazy: Optional[Tuple] = None               # guarded-by: _lock
+        self._counters: Optional[Dict[str, float]] = None  # guarded-by: _lock
         self._lock = lockdep.make_lock("Tracer._lock")
         self._seq = itertools.count(1)   # span ids; count() is GIL-atomic
 
@@ -246,6 +250,8 @@ class Tracer:
             self._ring = None
             self._events = None
             self._totals = None
+            self._lazy = None
+            self._counters = None
 
     def totals(self) -> Dict[str, Dict]:
         """Per span name, over every live span ended since reset():
@@ -257,6 +263,44 @@ class Tracer:
             return {name: {"calls": t[0], "seconds": t[1],
                            "self_seconds": t[2], "thread": t[3]}
                     for name, t in (self._totals or {}).items()}
+
+    # -- step counters -------------------------------------------------------
+    def count_lazy(self, names, values) -> None:
+        """Keep one step's counters: `values` is a LAZY device vector,
+        one entry per name, and stays on the device, untouched (no device
+        op, so nothing compiles; no sync). Live exactly when spans are;
+        otherwise two flag reads."""
+        if not self._enabled and not profiler_collecting():
+            return
+        with self._lock:
+            if self._lazy is None or self._lazy[0] != tuple(names):
+                self._lazy = (tuple(names), [])
+            self._lazy[1].append(values)
+
+    def fetch_counters(self) -> None:
+        """Bring what count_lazy kept to the host and add it to the sums.
+        Called where the caller syncs with the device anyway (the
+        Scheduler's display, after it fetched the cost: the counters of
+        the same steps are ready, so no wait of their own). With nothing
+        kept it is one attribute read: no lock, as while off."""
+        if self._lazy is None:        # mtlint: ok -- racy read by design; a value that lands now is fetched at the next display
+            return
+        with self._lock:
+            held, self._lazy = self._lazy, None
+        if held is None:
+            return
+        rows = [vec.tolist() for vec in held[1]]     # the fetch, unlocked
+        with self._lock:
+            if self._counters is None:
+                self._counters = {}
+            for name, column in zip(held[0], zip(*rows)):
+                self._counters[name] = self._counters.get(name, 0.0) \
+                    + float(sum(column))
+
+    def counters(self) -> Dict[str, float]:
+        """Fetched step counters by name, summed since reset()."""
+        with self._lock:
+            return dict(self._counters or {})
 
     # -- recording ----------------------------------------------------------
     def start_span(self, name: str, parent: Optional[Span] = None,

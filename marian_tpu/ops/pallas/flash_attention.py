@@ -15,8 +15,9 @@ Attention-weight dropout and returned weights are NOT supported here; the
 dispatcher (ops/attention.py :: attention) falls back to the dense path for
 those cases.
 
-Shapes: q [B, H, Tq, Dh], k/v [B, H, Tk, Dh] -> out [B, H, Tq, Dh].
-Compute is f32 on the MXU regardless of input dtype (bf16 in training).
+Shapes: q [B, H, Tq, Dh], k [B, H, Tk, Dh], v [B, H, Tk, Dv] -> out
+[B, H, Tq, Dv]: the value width may differ from the key width (latent
+attention: keys of 128 + 64, values of 128). Compute is f32 on the MXU regardless of input dtype (bf16 in training).
 """
 
 from __future__ import annotations
@@ -227,7 +228,7 @@ def _compiler_params(n_seq_dims: int = 1):
 
 def _fwd_call(q, k, v, kvm, scale, causal, block_q, block_k, interpret):
     b, h, tq, dh = q.shape
-    tk = k.shape[2]
+    tk, dv = k.shape[2], v.shape[3]
     n_q, n_k = tq // block_q, tk // block_k
     grid = (b, h, n_q, n_k)
     kernel = functools.partial(_fwd_kernel, scale=scale, causal=causal,
@@ -239,21 +240,21 @@ def _fwd_call(q, k, v, kvm, scale, causal, block_q, block_k, interpret):
         in_specs=[
             pl.BlockSpec((1, 1, block_q, dh), lambda b_, h_, i, j: (b_, h_, i, 0)),
             pl.BlockSpec((1, 1, block_k, dh), lambda b_, h_, i, j: (b_, h_, j, 0)),
-            pl.BlockSpec((1, 1, block_k, dh), lambda b_, h_, i, j: (b_, h_, j, 0)),
+            pl.BlockSpec((1, 1, block_k, dv), lambda b_, h_, i, j: (b_, h_, j, 0)),
             pl.BlockSpec((1, 1, block_k), lambda b_, h_, i, j: (b_, 0, j)),
         ],
         out_specs=[
-            pl.BlockSpec((1, 1, block_q, dh), lambda b_, h_, i, j: (b_, h_, i, 0)),
+            pl.BlockSpec((1, 1, block_q, dv), lambda b_, h_, i, j: (b_, h_, i, 0)),
             pl.BlockSpec((1, 1, block_q, 1), lambda b_, h_, i, j: (b_, h_, i, 0)),
         ],
         out_shape=[
-            jax.ShapeDtypeStruct((b, h, tq, dh), q.dtype),
+            jax.ShapeDtypeStruct((b, h, tq, dv), q.dtype),
             jax.ShapeDtypeStruct((b, h, tq, 1), jnp.float32),
         ],
         scratch_shapes=[
             pltpu.VMEM((block_q, _LANES), jnp.float32),
             pltpu.VMEM((block_q, _LANES), jnp.float32),
-            pltpu.VMEM((block_q, dh), jnp.float32),
+            pltpu.VMEM((block_q, dv), jnp.float32),
         ],
         interpret=interpret,
         compiler_params=None if interpret else _compiler_params(),
@@ -263,7 +264,7 @@ def _fwd_call(q, k, v, kvm, scale, causal, block_q, block_k, interpret):
 def _bwd_call(q, k, v, kvm, do, lse, delta, scale, causal, block_q, block_k,
               interpret):
     b, h, tq, dh = q.shape
-    tk = k.shape[2]
+    tk, dv = k.shape[2], v.shape[3]
     n_q, n_k = tq // block_q, tk // block_k
 
     dq_kernel = functools.partial(_dq_kernel, scale=scale, causal=causal,
@@ -275,9 +276,9 @@ def _bwd_call(q, k, v, kvm, do, lse, delta, scale, causal, block_q, block_k,
         in_specs=[
             pl.BlockSpec((1, 1, block_q, dh), lambda b_, h_, i, j: (b_, h_, i, 0)),
             pl.BlockSpec((1, 1, block_k, dh), lambda b_, h_, i, j: (b_, h_, j, 0)),
-            pl.BlockSpec((1, 1, block_k, dh), lambda b_, h_, i, j: (b_, h_, j, 0)),
+            pl.BlockSpec((1, 1, block_k, dv), lambda b_, h_, i, j: (b_, h_, j, 0)),
             pl.BlockSpec((1, 1, block_k), lambda b_, h_, i, j: (b_, 0, j)),
-            pl.BlockSpec((1, 1, block_q, dh), lambda b_, h_, i, j: (b_, h_, i, 0)),
+            pl.BlockSpec((1, 1, block_q, dv), lambda b_, h_, i, j: (b_, h_, i, 0)),
             pl.BlockSpec((1, 1, block_q, 1), lambda b_, h_, i, j: (b_, h_, i, 0)),
             pl.BlockSpec((1, 1, block_q, 1), lambda b_, h_, i, j: (b_, h_, i, 0)),
         ],
@@ -298,22 +299,22 @@ def _bwd_call(q, k, v, kvm, do, lse, delta, scale, causal, block_q, block_k,
         in_specs=[
             pl.BlockSpec((1, 1, block_q, dh), lambda b_, h_, j, i: (b_, h_, i, 0)),
             pl.BlockSpec((1, 1, block_k, dh), lambda b_, h_, j, i: (b_, h_, j, 0)),
-            pl.BlockSpec((1, 1, block_k, dh), lambda b_, h_, j, i: (b_, h_, j, 0)),
+            pl.BlockSpec((1, 1, block_k, dv), lambda b_, h_, j, i: (b_, h_, j, 0)),
             pl.BlockSpec((1, 1, block_k), lambda b_, h_, j, i: (b_, 0, j)),
-            pl.BlockSpec((1, 1, block_q, dh), lambda b_, h_, j, i: (b_, h_, i, 0)),
+            pl.BlockSpec((1, 1, block_q, dv), lambda b_, h_, j, i: (b_, h_, i, 0)),
             pl.BlockSpec((1, 1, block_q, 1), lambda b_, h_, j, i: (b_, h_, i, 0)),
             pl.BlockSpec((1, 1, block_q, 1), lambda b_, h_, j, i: (b_, h_, i, 0)),
         ],
         out_specs=[
             pl.BlockSpec((1, 1, block_k, dh), lambda b_, h_, j, i: (b_, h_, j, 0)),
-            pl.BlockSpec((1, 1, block_k, dh), lambda b_, h_, j, i: (b_, h_, j, 0)),
+            pl.BlockSpec((1, 1, block_k, dv), lambda b_, h_, j, i: (b_, h_, j, 0)),
         ],
         out_shape=[
             jax.ShapeDtypeStruct((b, h, tk, dh), k.dtype),
-            jax.ShapeDtypeStruct((b, h, tk, dh), v.dtype),
+            jax.ShapeDtypeStruct((b, h, tk, dv), v.dtype),
         ],
         scratch_shapes=[pltpu.VMEM((block_k, dh), jnp.float32),
-                        pltpu.VMEM((block_k, dh), jnp.float32)],
+                        pltpu.VMEM((block_k, dv), jnp.float32)],
         interpret=interpret,
         compiler_params=None if interpret else _compiler_params(),
     )(q, k, v, kvm, do, lse, delta)
@@ -358,7 +359,8 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array,
                     interpret: Optional[bool] = None) -> jax.Array:
     """softmax(scale * Q K^T + mask) V, never materializing the score matrix.
 
-    q [B,H,Tq,Dh], k/v [B,H,Tk,Dh], kv_mask [B,Tk] (1.0 = attend) or None.
+    q [B,H,Tq,Dh], k [B,H,Tk,Dh], v [B,H,Tk,Dv], kv_mask [B,Tk] (1.0 =
+    attend) or None; the default scale is 1/sqrt(Dh), the key width.
     Sequence dims are padded up to block multiples internally (padded keys
     are masked out; padded query rows are sliced off).
     """

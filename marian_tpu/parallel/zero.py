@@ -159,6 +159,10 @@ class _GradMachinery:
             for k, shape in self._shapes.items()}
         self.frozen_set = frozenset(frozen)
         self.model = model
+        # counts a model family takes inside the step (routing counts of
+        # an expert layer): one float32 vector beside the loss, summed
+        # over micro-batches and devices; length 0 for most models
+        self.n_counters = len(getattr(model, "step_counters", ()))
         gd = None if grad_dtype in (None, "float32") else jnp.dtype(grad_dtype)
         if gd is not None and gd == jnp.dtype(jnp.float32):
             gd = None
@@ -186,13 +190,17 @@ class _GradMachinery:
         self.grad_dtype = gd
 
     def grads(self, p, batch, rng):
-        """(grads, ce_sum, labels) — grads globally reduced and ZeRO-1
-        sharded (manual path) or logically global (GSPMD path, pinned to
-        the combined spec)."""
+        """(grads, ce_sum, labels, counters) — grads globally reduced and
+        ZeRO-1 sharded (manual path) or logically global (GSPMD path,
+        pinned to the combined spec); counters [n_counters] float32."""
         if self.manual_dp:
             return self._sharded_grads(p, batch, rng)
-        grads, ce_sum, labels = self._local_grads(p, batch, rng)
-        return self._constrain(grads), ce_sum, labels
+        grads, ce_sum, labels, counters = self._local_grads(p, batch, rng)
+        return self._constrain(grads), ce_sum, labels, counters
+
+    def _counters(self, aux):
+        return aux["counters"] if self.n_counters \
+            else jnp.zeros((0,), jnp.float32)
 
     def grad_shardings(self):
         """NamedSharding per gradient leaf (combined TP + ZeRO-1 spec) —
@@ -235,22 +243,25 @@ class _GradMachinery:
         delay paths are numerically interchangeable."""
         if self.delay > 1:
             def body(carry, sl):
-                acc, tot, lab = carry
+                acc, tot, lab, cnt = carry
                 micro, i = sl
                 g, aux = self._grads_of(p, micro,
                                         jax.random.fold_in(rng, i))
                 acc = jax.tree_util.tree_map(jnp.add, acc, g)
-                return (acc, tot + aux["ce_sum"], lab + aux["labels"]), None
+                return (acc, tot + aux["ce_sum"], lab + aux["labels"],
+                        cnt + self._counters(aux)), None
             zeros = jax.tree_util.tree_map(
                 lambda x: jnp.zeros(x.shape, jnp.float32), p)
-            (grads, ce_sum, labels), _ = jax.lax.scan(
+            (grads, ce_sum, labels, counters), _ = jax.lax.scan(
                 body, (zeros, jnp.zeros((), jnp.float32),
-                       jnp.zeros((), jnp.float32)),
+                       jnp.zeros((), jnp.float32),
+                       jnp.zeros((self.n_counters,), jnp.float32)),
                 (batch, jnp.arange(self.delay)))
         else:
             grads, aux = self._grads_of(p, batch, rng)
             ce_sum, labels = aux["ce_sum"], aux["labels"]
-        return grads, ce_sum, labels
+            counters = self._counters(aux)
+        return grads, ce_sum, labels, counters
 
     def _constrain(self, grads):
         """GSPMD path: pin each gradient leaf to its combined TP+ZeRO-1
@@ -301,26 +312,30 @@ class _GradMachinery:
 
         if self.delay > 1:
             def body(carry, sl):
-                acc, tot, lab = carry
+                acc, tot, lab, cnt = carry
                 micro, i = sl
                 g, aux = self._grads_of(p, micro, _k(rng, i))
                 with jax.named_scope("collectives"):
                     g = self._scatter(g)
                 acc = jax.tree_util.tree_map(jnp.add, acc, g)
-                return (acc, tot + aux["ce_sum"], lab + aux["labels"]), None
+                return (acc, tot + aux["ce_sum"], lab + aux["labels"],
+                        cnt + self._counters(aux)), None
             zeros = {k: jnp.zeros(self._shard_shape(k), jnp.float32)
                      for k in p}
-            (grads, ce_sum, labels), _ = jax.lax.scan(
+            (grads, ce_sum, labels, counters), _ = jax.lax.scan(
                 body, (zeros, jnp.zeros((), jnp.float32),
-                       jnp.zeros((), jnp.float32)),
+                       jnp.zeros((), jnp.float32),
+                       jnp.zeros((self.n_counters,), jnp.float32)),
                 (batch, jnp.arange(self.delay)))
         else:
             g, aux = self._grads_of(p, batch, _k(rng))
             with jax.named_scope("collectives"):
                 grads = self._scatter(g)
             ce_sum, labels = aux["ce_sum"], aux["labels"]
+            counters = self._counters(aux)
         return (grads, jax.lax.psum(ce_sum, "data"),
-                jax.lax.psum(labels, "data"))
+                jax.lax.psum(labels, "data"),
+                jax.lax.psum(counters, "data"))
 
     def _scatter(self, grads):
         """scatterReduceAndResetGrads on one gradient tree: per-leaf
@@ -358,7 +373,8 @@ class _GradMachinery:
         return jax.shard_map(
             self._scatter_reduce_body, mesh=self.mesh,
             in_specs=(P(), b_specs, P()),
-            out_specs=(g_out, P(), P()), check_vma=False)(p, batch, rng)
+            out_specs=(g_out, P(), P(), P()),
+            check_vma=False)(p, batch, rng)
 
 
 def build_grad_fn(model, mesh: Mesh, params: Params, frozen=(),
@@ -374,8 +390,11 @@ def build_grad_fn(model, mesh: Mesh, params: Params, frozen=(),
 
     def grad_step(p, batch, rng):
         batch = expand_compact_batch(batch)
-        grads, ce_sum, labels = m.grads(p, batch, rng)
-        return grads, {"ce_sum": ce_sum, "labels": labels}
+        grads, ce_sum, labels, counters = m.grads(p, batch, rng)
+        aux = {"ce_sum": ce_sum, "labels": labels}
+        if m.n_counters:
+            aux["counters"] = counters
+        return grads, aux
 
     return jax.jit(grad_step, out_shardings=(m.grad_shardings(), None))
 
@@ -439,7 +458,8 @@ def build_train_step(model, opt_cfg: OptimizerConfig, schedule, cost_type: str,
         # autodiff marks the forward ops jvp(..) and the backward ops
         # transpose(jvp(..)) inside this scope by itself
         with jax.named_scope("grads"):
-            grads, ce_sum, labels = machinery.grads(p, batch, rng)
+            grads, ce_sum, labels, counters = machinery.grads(p, batch,
+                                                              rng)
 
         # cost normalization → gradient scale (Marian's costScaleFactor)
         if cost_type in ("ce-mean-words", "perplexity"):
@@ -456,6 +476,8 @@ def build_train_step(model, opt_cfg: OptimizerConfig, schedule, cost_type: str,
                 opt_cfg, opt_state, p, grads, lr, labels, denom)
         metrics = {"ce_sum": ce_sum, "labels": labels, "gnorm": gnorm,
                    "lr": lr}
+        if machinery.n_counters:
+            metrics["counters"] = counters
         if opt_cfg.check_gradient_nan:
             metrics["skipped"] = skipped
             # a skipped batch must not poison the display window's cost
@@ -497,6 +519,8 @@ def build_train_step(model, opt_cfg: OptimizerConfig, schedule, cost_type: str,
     metrics_shardings = {"ce_sum": rep, "labels": rep, "gnorm": rep, "lr": rep}
     if opt_cfg.check_gradient_nan:
         metrics_shardings["skipped"] = rep
+    if machinery.n_counters:
+        metrics_shardings["counters"] = rep
 
     return jax.jit(  # mtlint: ok -- built once per training launch:
         # n_updates is a launch flag (--dispatch-window), not a

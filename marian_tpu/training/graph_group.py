@@ -26,6 +26,7 @@ Semantics carried over exactly:
 from __future__ import annotations
 
 import dataclasses
+from concurrent.futures import ThreadPoolExecutor
 from typing import Any, Dict, List, Optional, Tuple
 
 import jax
@@ -34,6 +35,7 @@ import numpy as np
 from jax.sharding import NamedSharding, PartitionSpec as P
 
 from ..common import logging as log
+from ..data.batch_generator import budget_shapes
 from ..models.encoder_decoder import EncoderDecoder
 from ..obs import trace as obs_trace
 from ..optimizers.optimizers import (OptimizerConfig, apply_update, init_state,
@@ -93,6 +95,11 @@ class GraphGroup:
         self._fix_src = bool(options.get("embedding-fix-src", False))
         self._fix_trg = bool(options.get("embedding-fix-trg", False))
         self._dump_hlo = options.get("dump-hlo", None)
+        # --precompile-buckets: shape key -> future of the step compiled
+        # ahead for it; None until the first update shows what a batch,
+        # its step number and its key look like
+        self._ahead_threads = int(options.get("precompile-buckets", 0) or 0)
+        self._ahead: Optional[Dict[tuple, Any]] = None
 
     def _frozen_names(self) -> frozenset:
         """Params excluded from updates: --embedding-fix-src/trg tables
@@ -224,6 +231,7 @@ class GraphGroup:
                                        shardings=(p_sh, o_sh), frozen=frozen,
                                        grad_dtype=grad_dtype)
         self._fused_delay = None
+        self._ahead = None              # executables of the step before
         # K updates per dispatch (build_train_step n_updates>1) — built
         # LAZILY on the first update_window call so paths that never fill
         # a window (the fused-CE A/B probe, short runs) skip its compile
@@ -281,6 +289,20 @@ class GraphGroup:
             donate_argnums=(0, 1, 2) if self._donate else ())
 
     # -- one (macro-)update --------------------------------------------------
+    def _output(self, metrics, i=None) -> TrainOutput:
+        """A step's metrics as a TrainOutput (entry i of a window's
+        stacked metrics). A model family's step counters go, still lazy,
+        to the tracer, which keeps them while spans are live and fetches
+        them where the Scheduler's display syncs anyway."""
+        def pick(key):
+            v = metrics.get(key)
+            return v if v is None or i is None else v[i]
+        if "counters" in metrics:
+            obs_trace.TRACER.count_lazy(self.model.step_counters,
+                                        pick("counters"))
+        return TrainOutput(pick("ce_sum"), pick("labels"), pick("gnorm"),
+                           pick("skipped"))
+
     @staticmethod
     def _dispatch(fn, step: int, *args):
         """The jitted call alone, as the span ``train.dispatch``:
@@ -290,10 +312,78 @@ class GraphGroup:
         with obs_trace.span("train.dispatch", step=int(step)) as sp:
             if not sp:
                 return fn(*args)
-            before = fn._cache_size()
+            # an executable compiled ahead has no cache to grow
+            size = getattr(fn, "_cache_size", lambda: 0)
+            before = size()
             out = fn(*args)
-            sp.set_attrs(retraced=int(fn._cache_size() > before))
+            sp.set_attrs(retraced=int(size() > before))
             return out
+
+    @staticmethod
+    def _shape_key(batch) -> tuple:
+        return tuple(sorted((k, tuple(v.shape), str(v.dtype))
+                            for k, v in batch.items()))
+
+    def _compile_ahead(self, batch, step, rng) -> Dict[tuple, Any]:
+        """Start compiling the fused step for every shape the loader's
+        bucket table can give (data/batch_generator.py::budget_shapes),
+        narrowest first, on --precompile-buckets threads. A step program
+        of a large model compiles for a minute on a few cores and every
+        bucket is a program of its own: compiled one after another as
+        their first batches arrive they are most of a start, side by
+        side they overlap each other and the updates already running.
+        `batch`, `step` and `rng` are the first update's own arguments:
+        what is lowered here is what `update` will pass."""
+        shapes = budget_shapes(self.options)
+        widths = {v.shape for v in batch.values() if v.ndim == 2}
+        if not shapes or len(widths) != 1:
+            return {}
+
+        def like(a, shape=None, sharding=None):
+            return jax.ShapeDtypeStruct(a.shape if shape is None else shape,
+                                        a.dtype, sharding=sharding)
+        # the arrays themselves are donated to the next update; their
+        # shapes and placements are what a later lowering needs
+        p, o = jax.tree_util.tree_map(lambda a: like(a, sharding=a.sharding),
+                                      (self.params, self.opt_state))
+        step, rng = like(step), like(rng)
+        fused = self._fused
+        pool = ThreadPoolExecutor(self._ahead_threads,
+                                  thread_name_prefix="precompile")
+        ahead = {}
+        for width, rows in shapes:
+            b = {k: like(v, (rows, width) if v.ndim == 2
+                         else (rows,) + v.shape[1:], v.sharding)
+                 for k, v in batch.items()}
+            # the futures outlive the pool's handle; its threads end with
+            # the last of them (shutdown below)
+            ahead[self._shape_key(b)] = pool.submit(  # mtlint: transfers
+                lambda b=b: fused.lower(p, o, b, step, rng).compile())
+        pool.shutdown(wait=False)       # the queued compiles still run
+        log.info("Compiling the train step ahead for {} shapes on {} "
+                 "threads", len(ahead), self._ahead_threads)
+        return ahead
+
+    def _step_for(self, batch, step, rng):
+        """The step to dispatch for this batch: the jitted step, or with
+        --precompile-buckets the executable compiled ahead for its
+        shape. A shape nobody foresaw, or whose compile is still queued
+        behind others, compiles here and now through the jitted step."""
+        if not self._ahead_threads:
+            return self._fused
+        if self._ahead is None:
+            self._ahead = self._compile_ahead(batch, step, rng)
+        key = self._shape_key(batch)
+        future = self._ahead.get(key)
+        if future is None or future.cancel():
+            self._ahead.pop(key, None)
+            return self._fused
+        try:
+            return future.result()
+        except Exception as e:  # noqa: BLE001 — the jitted step says it again
+            log.warn("Compiling ahead failed for {}: {}", key, e)
+            del self._ahead[key]
+            return self._fused
 
     def update(self, batches, step: int, rng) -> TrainOutput:
         """batches: one batch dict, or a list of `delay` micro-batch
@@ -317,10 +407,9 @@ class GraphGroup:
                     self.params, self.opt_state, b, step_f, rng))
                 self._dump_hlo = None
             self.params, self.opt_state, metrics = self._dispatch(
-                self._fused, step, self.params, self.opt_state, b, step_f,
-                rng)
-            return TrainOutput(metrics["ce_sum"], metrics["labels"],
-                               metrics["gnorm"], metrics.get("skipped"))
+                self._step_for(b, step_f, rng), step, self.params,
+                self.opt_state, b, step_f, rng)
+            return self._output(metrics)
         if (self._fused_delay is not None and len(batches) == self.delay
                 and all(b.keys() == batches[0].keys()
                         and all(v.shape == batches[0][k].shape
@@ -340,8 +429,7 @@ class GraphGroup:
             self.params, self.opt_state, metrics = self._dispatch(
                 self._fused_delay, step, self.params, self.opt_state,
                 stacked, step_f, rng)
-            return TrainOutput(metrics["ce_sum"], metrics["labels"],
-                               metrics["gnorm"], metrics.get("skipped"))
+            return self._output(metrics)
         total_loss = total_labels = 0.0
         n_sents = 0.0
         grads_acc = None
@@ -360,6 +448,9 @@ class GraphGroup:
             sharded = M.shard_batch(b, self.mesh)
             grads, aux = self._dispatch(self._grad_fn, step, self.params,
                                         sharded, r)
+            if "counters" in aux:
+                obs_trace.TRACER.count_lazy(self.model.step_counters,
+                                            aux["counters"])
             total_loss = total_loss + aux["ce_sum"]        # lazy device adds
             total_labels = total_labels + aux["labels"]
             # rows from whichever target form shipped (compact batches
@@ -411,11 +502,7 @@ class GraphGroup:
         self.params, self.opt_state, metrics = self._dispatch(
             self._fused_window, step, self.params, self.opt_state, stacked,
             np.int32(step), rng)
-        skipped = metrics.get("skipped")
-        return [TrainOutput(metrics["ce_sum"][i], metrics["labels"][i],
-                            metrics["gnorm"][i],
-                            None if skipped is None else skipped[i])
-                for i in range(self.window)]
+        return [self._output(metrics, i) for i in range(self.window)]
 
     # -- EMA access for validation/saving -----------------------------------
     def smoothed(self) -> Params:

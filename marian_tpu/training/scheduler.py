@@ -303,6 +303,9 @@ class Scheduler:
         # span `train.sync` (a child of `train.bookkeep`)
         with obs.span("train.sync", step=s.batches):
             self._cost_sum = float(self._cost_sum)
+            # the step counters of the same updates (routing counts of an
+            # expert layer), lazy until here: ready with the cost
+            obs.TRACER.fetch_counters()
         # clock read AFTER the cost sync (mtlint MT-SYNC-TIMER): forcing
         # the accumulated device scalar completes every update in the
         # display window, so words/s divides by real execution time.

@@ -1,4 +1,4 @@
-"""The five Pallas kernels compiled for the real chip, without the chip.
+"""The Pallas kernels compiled for the real chip, without the chip.
 
 The TPU compiler is installed beside the CPU backend and compiles for a
 described v5e (`jax.experimental.topologies`), so these cases run under
@@ -29,10 +29,12 @@ import pytest
 from jax.sharding import SingleDeviceSharding
 
 from marian_tpu.ops import auto_tuner
+from marian_tpu.ops.experts import held_experts, pool_rows
 from marian_tpu.ops.pallas import kv_pool
 from marian_tpu.ops.pallas.decode_attention import decode_attention
 from marian_tpu.ops.pallas.flash_attention import flash_attention
 from marian_tpu.ops.pallas.fused_ce import fused_softmax_xent
+from marian_tpu.ops.pallas.kda_chunk import kda_state_carry
 from marian_tpu.ops.pallas.packed_attention import packed_attention
 
 H, DH, EMB, VOCAB = 16, 64, 1024, 32000     # transformer-big
@@ -93,6 +95,47 @@ def _attn(kernel, b, tq, tk, causal, grad):
         return (jax.grad(loss, argnums=(0, 1, 2)), shapes,
                 [fwd_name] + bwd_names)
     return fwd, shapes, [fwd_name]
+
+
+def _latent_attn(b, t):
+    """The layer plan's latent attention at its published head widths:
+    32 heads, keys of 128 + 64 against values of 128, causal, with the
+    forward and both backward kernels."""
+    def loss(q, k, v, m):
+        return flash_attention(q, k, v, kv_mask=m, causal=True,
+                               interpret=False).astype(jnp.float32).sum()
+    shapes = [((b, 32, t, 192), DT), ((b, 32, t, 192), DT),
+              ((b, 32, t, 128), DT), ((b, t), jnp.float32)]
+    return jax.grad(loss, argnums=(0, 1, 2)), shapes, list(_FLASH[1:])
+
+
+def _kda_carry(b, heads, chunks):
+    """The delta rule's state carry (chunks of 64, 128 x 128 state a
+    head, float32), forward and backward."""
+    def loss(*terms):
+        return kda_state_carry(*terms, interpret=False).sum()
+    f32 = jnp.float32
+    wide = ((b, heads, chunks, 64, 128), f32)
+    shapes = [wide, wide, wide, wide, ((b, heads, chunks, 1, 128), f32),
+              ((b, heads, chunks, 64, 64), f32)]
+    return (jax.grad(loss, argnums=tuple(range(6))), shapes,
+            ["kda_chunk_fwd", "kda_chunk_bwd"])
+
+
+def _held_experts(tokens):
+    """The layer plan's held experts at the cell's sizes (8 of 256, top
+    8, 2304 -> 1024): the pool's grouped matmuls must stay the chip's
+    own ragged dot, not 8 dense matmuls under a mask."""
+    def loss(x, w, wg, wu, wd, idx):
+        y, _ = held_experts(x, jnp.ones((tokens,), jnp.float32), idx, w,
+                            wg, wu, wd, 0,
+                            pool=pool_rows(tokens, 8, 8, 256))
+        return y.astype(jnp.float32).sum()
+    shapes = [((tokens, 2304), DT), ((tokens, 8), jnp.float32),
+              ((8, 2304, 1024), DT), ((8, 2304, 1024), DT),
+              ((8, 1024, 2304), DT), ((tokens, 8), jnp.int32)]
+    return (jax.grad(loss, argnums=(0, 2, 3, 4)), shapes,
+            ["ragged-dot-none"])
 
 
 def _decode(rows, per_row_pos):
@@ -166,6 +209,13 @@ CASES = {
     "packed-fwd-3x20-mask": lambda: _attn(_PACKED, 3, 20, 20, False, False),
     "flash-fwd-t2048": lambda: _attn(_FLASH, 1, 2048, 2048, True, False),
     "flash-grad-t2048": lambda: _attn(_FLASH, 1, 2048, 2048, True, True),
+    # the layer plan's cell (kimi-linear.train-docs8k): 16384 tokens as 16
+    # rows of 1024 and as 2 of 8192; KDA's heads come 4 at a time
+    "flash-grad-192x128-16x1024": lambda: _latent_attn(16, 1024),
+    "flash-grad-192x128-2x8192": lambda: _latent_attn(2, 8192),
+    "kda-carry-grad-16x1024": lambda: _kda_carry(16, 4, 16),
+    "kda-carry-grad-2x8192": lambda: _kda_carry(2, 4, 128),
+    "held-experts-grad-16384": lambda: _held_experts(16384),
     # offline decoder: 64 sentences x beam 6, scalar and per-row positions
     "decode-r384-scalar-pos": lambda: _decode(64 * 6, False),
     "decode-r384-row-pos": lambda: _decode(64 * 6, True),
