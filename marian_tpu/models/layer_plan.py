@@ -223,13 +223,15 @@ def cast_params(params: Params, dtype) -> Params:
 _heads = T._split_heads            # [B, T, h * d] -> [B, h, T, d]
 
 
-def _kda_carry():
-    """The state carry between chunks: the Pallas kernels on a TPU, the
-    jnp scan they are tested against elsewhere."""
+def _kda_kernels():
+    """The preparation inside a chunk and the state carry between
+    chunks: the Pallas kernel pairs on a TPU, the jnp forms they are
+    tested against elsewhere."""
     if jax.default_backend() != "tpu":
-        return K.state_carry
+        return {"terms": K.chunk_terms, "carry": K.state_carry}
     from ..ops.pallas.kda_chunk import kda_state_carry
-    return kda_state_carry
+    from ..ops.pallas.kda_prep import kda_chunk_terms
+    return {"terms": kda_chunk_terms, "carry": kda_state_carry}
 
 
 def _kda(cfg: PlanConfig, p: Params, lp: str, x):
@@ -284,7 +286,7 @@ def _kda(cfg: PlanConfig, p: Params, lp: str, x):
         b = jax.nn.sigmoid(jnp.dot(
             x, w["Wb"], preferred_element_type=f32)).transpose(0, 2, 1)
         o = K.kda_chunked(q, k, branch("v"), g, b, dh ** -0.5,
-                          carry=_kda_carry())
+                          **_kda_kernels())
         o = rms_norm(o.transpose(0, 2, 1, 3),
                      p[f"{lp}_kda_out_norm_scale"], eps=cfg.norm_eps)
         gate = jax.nn.sigmoid(wide(low_g, w["Wg2"]))

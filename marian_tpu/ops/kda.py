@@ -10,11 +10,20 @@ with a_t in (0, 1]^dk given as its logarithm g_t <= 0 and b_t in [0, 1].
 Three forms of the same function, q/k/g [B, H, T, dk], v [B, H, T, dv],
 b [B, H, T] -> o [B, H, T, dv]:
 
-  kda_recurrent   the recurrence as written, token by token (the oracle)
+  kda_recurrent   the recurrence as written, token by token: the oracle,
+                  in tests only
   kda_chunked     chunks of 64: inside a chunk the WY / UT transform
-                  (a triangular solve), between chunks a state carry; the
-                  carry is a lax.scan in jnp here, and is what
-                  ops/pallas/kda_chunk.py runs on the chip
+                  (`chunk_terms`: pairwise decays, a triangular solve),
+                  between chunks a state carry (`state_carry`, a
+                  lax.scan); in jnp, what runs wherever there is no TPU
+                  (tier-1, the benchmark's rehearsals) and what the
+                  kernels are held against
+  kda_chunked with `terms=` and `carry=` from ops/pallas/   the same two
+                  steps as Pallas TPU kernel pairs, what
+                  models/layer_plan.py picks on a TPU: kda_prep.py
+                  (`kda_prep_fwd`, `kda_prep_bwd`) for the preparation,
+                  kda_chunk.py (`kda_chunk_fwd`, `kda_chunk_bwd`) for the
+                  carry; the six terms cross HBM in float32 between them
 
 Everything is float32 and the state accumulates in float32. No
 exponential of a positive number is ever taken: a decay between two
@@ -186,17 +195,20 @@ def state_carry(qg, wk, wv, kd, gc, p):
     return jnp.moveaxis(o, 0, 2)
 
 
-def kda_chunked(q, k, v, g, b, scale, chunk=CHUNK, carry=state_carry):
+def kda_chunked(q, k, v, g, b, scale, chunk=CHUNK, carry=state_carry,
+                terms=chunk_terms):
     """The chunked form. T is padded to a multiple of `chunk` with
-    positions that change nothing (g = 0, b = 0, zero q, k, v). `carry`
-    is the recurrence between chunks: `state_carry`, or the Pallas
-    kernels' (ops/pallas/kda_chunk.py :: kda_state_carry)."""
+    positions that change nothing (g = 0, b = 0, zero q, k, v). `terms`
+    is the preparation inside a chunk and `carry` the recurrence between
+    chunks: `chunk_terms` and `state_carry`, or the Pallas kernels'
+    (ops/pallas/kda_prep.py :: kda_chunk_terms, ops/pallas/kda_chunk.py
+    :: kda_state_carry)."""
     t = k.shape[2]
     pad = -t % chunk
     if pad:
         q, k, v, g = (jnp.pad(x, ((0, 0), (0, 0), (0, pad), (0, 0)))
                       for x in (q, k, v, g))
         b = jnp.pad(b, ((0, 0), (0, 0), (0, pad)))
-    o = carry(*chunk_terms(q, k, v, g, b, scale, chunk))
+    o = carry(*terms(q, k, v, g, b, scale, chunk))
     bsz, h = o.shape[:2]
     return o.reshape(bsz, h, t + pad, -1)[:, :, :t]

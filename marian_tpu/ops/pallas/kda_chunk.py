@@ -1,8 +1,10 @@
 """The state carry of the chunked delta rule (ops/kda.py) as Pallas TPU
 kernels: `kda_chunk_fwd` and `kda_chunk_bwd` under one custom VJP.
 
-ops/kda.py :: chunk_terms turns a chunk of 64 positions into six small
-matrices (the WY / UT transform, in XLA); what is left is a recurrence
+A chunk of 64 positions becomes six small matrices (the WY / UT
+transform: on a TPU kda_prep.py's `kda_prep_fwd` / `kda_prep_bwd` beside
+this file, elsewhere ops/kda.py :: chunk_terms in XLA; either way they
+reach this kernel through HBM in float32); what is left is a recurrence
 over the chunks of one (row, head), each step three dependent matmuls on
 a [dv, dk] float32 state:
 

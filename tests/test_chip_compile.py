@@ -35,6 +35,7 @@ from marian_tpu.ops.pallas.decode_attention import decode_attention
 from marian_tpu.ops.pallas.flash_attention import flash_attention
 from marian_tpu.ops.pallas.fused_ce import fused_softmax_xent
 from marian_tpu.ops.pallas.kda_chunk import kda_state_carry
+from marian_tpu.ops.pallas.kda_prep import kda_chunk_terms
 from marian_tpu.ops.pallas.packed_attention import packed_attention
 
 H, DH, EMB, VOCAB = 16, 64, 1024, 32000     # transformer-big
@@ -120,6 +121,21 @@ def _kda_carry(b, heads, chunks):
               ((b, heads, chunks, 64, 64), f32)]
     return (jax.grad(loss, argnums=tuple(range(6))), shapes,
             ["kda_chunk_fwd", "kda_chunk_bwd"])
+
+
+def _kda_prep(b, heads, t):
+    """The delta rule's chunk preparation (the WY transform and the
+    pairwise decays of 64 positions, 128 channels a head, float32),
+    forward and backward."""
+    def loss(*inputs):
+        # squares: a linear loss would leave the forward nothing to do
+        return sum((x * x).sum() for x in kda_chunk_terms(
+            *inputs, 128 ** -0.5, interpret=False))
+    f32 = jnp.float32
+    wide = ((b, heads, t, 128), f32)
+    return (jax.grad(loss, argnums=tuple(range(5))),
+            [wide, wide, wide, wide, ((b, heads, t), f32)],
+            ["kda_prep_fwd", "kda_prep_bwd"])
 
 
 def _held_experts(tokens):
@@ -215,6 +231,10 @@ CASES = {
     "flash-grad-192x128-2x8192": lambda: _latent_attn(2, 8192),
     "kda-carry-grad-16x1024": lambda: _kda_carry(16, 4, 16),
     "kda-carry-grad-2x8192": lambda: _kda_carry(2, 4, 128),
+    "kda-prep-grad-16x1024": lambda: _kda_prep(16, 4, 1024),
+    "kda-prep-grad-2x8192": lambda: _kda_prep(2, 4, 8192),
+    # an odd number of heads: one to a tile, [64, 64] matrices
+    "kda-prep-grad-3-heads": lambda: _kda_prep(2, 3, 256),
     "held-experts-grad-16384": lambda: _held_experts(16384),
     # offline decoder: 64 sentences x beam 6, scalar and per-row positions
     "decode-r384-scalar-pos": lambda: _decode(64 * 6, False),

@@ -3,7 +3,9 @@ its three mechanisms, on seeded random weights at small sizes, against
 straightforward forms of the same mathematics:
 
   the delta rule with a per-channel decay   chunked == token by token,
-      forward and gradient; the Pallas kernels == the chunked carry
+      forward and gradient; the Pallas kernels == the chunk's
+      preparation and the chunked carry, and both together == the
+      recurrence
   latent attention without rotary            == a dense masked softmax at
       key width 192 / value width 128; flash at unequal widths
   the expert layer told which experts it holds == a masked loop over the
@@ -31,7 +33,7 @@ from marian_tpu.models.encoder_decoder import create_model
 from marian_tpu.ops import experts as X
 from marian_tpu.ops import kda
 from marian_tpu.ops.attention import dense_attention
-from marian_tpu.ops.pallas import kda_chunk
+from marian_tpu.ops.pallas import kda_chunk, kda_prep
 from marian_tpu.ops.pallas.flash_attention import flash_attention
 
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
@@ -110,6 +112,100 @@ def test_kda_kernels_are_the_chunked_carry():
         np.testing.assert_allclose(g_got, g_want, atol=1e-4)
     assert kda_chunk.heads_a_step(32) == 4
     assert kda_chunk.heads_a_step(6, 4) == 3      # divisors only
+
+
+def _prep_case(case):
+    """Inputs at the kernels' widths (dk = dv = 128): T = 192, a T that
+    pads (100 -> 128, as kda_chunked pads it: positions that change
+    nothing), decays mild or forgetting everything within a sub-block,
+    and forty identical keys with b = 1 and no decay (the worst case of
+    the triangular solve)."""
+    t = 100 if "pads" in case else 192
+    q, k, v, g, beta = _kda_inputs(11, b=1, h=4, t=t, dk=128, dv=128,
+                                   strong="strong" in case)
+    if "identical" in case:
+        k = k.at[:, :, 20:60].set(k[:, :, 20:21])
+        beta = beta.at[:, :, 20:60].set(1.0)
+        g = g.at[:, :, 20:60].set(0.0)
+    return q, k, v, g, beta
+
+
+_PREP_CASES = ("mild-192", "strong-192", "mild-pads", "strong-pads",
+               "identical-keys-192", "identical-keys-pads")
+
+
+@pytest.mark.parametrize("case", _PREP_CASES + ("mild-192-single",
+                                                "strong-pads-single"))
+def test_kda_prep_kernels_are_the_chunk_terms(case):
+    """kda_prep_fwd / kda_prep_bwd (interpret mode) against ops/kda.py ::
+    chunk_terms: all six terms and, under one weighted sum of them, all
+    five cotangents, each within a share of its own largest value (the
+    kernels' small products take three bfloat16 passes, as chunk_terms'
+    do on a TPU; a forgetting decay's exponent is a difference of large
+    sums; where keys repeat it is chunk_terms that is 3e-4 off, through
+    the eighth power of a block of ones: the kernels' inverse goes by
+    halves and stays within 5e-6 of float64). A grid step's heads go two
+    to a tile, or singly where their number is odd."""
+    args = _prep_case(case)
+    pad = -args[0].shape[2] % kda.CHUNK
+    args = tuple(jnp.pad(x, ((0, 0), (0, 0), (0, pad)) + ((0, 0),)
+                         * (x.ndim - 3)) for x in args)
+    kernel = lambda *a: kda_prep.kda_chunk_terms(       # noqa: E731
+        *a, 0.1, heads=1 if "single" in case else 2, interpret=True)
+    want = kda.chunk_terms(*args, 0.1)
+    got = kernel(*args)
+    rtol = 5e-5 if "mild" in case else 1e-3
+    for name, x_got, x_want in zip("qg wk wv kd gc p".split(), got, want):
+        assert x_got.shape == x_want.shape and x_got.dtype == jnp.float32
+        assert bool(jnp.isfinite(x_got).all()), name
+        np.testing.assert_allclose(
+            x_got, x_want, rtol=0, err_msg=name,
+            atol=rtol * max(float(jnp.abs(x_want).max()), 1e-6))
+    ws = [jax.random.normal(jax.random.PRNGKey(9 + i), x.shape)
+          for i, x in enumerate(want)]
+
+    def grads(fn):
+        return jax.grad(lambda *a: sum(jnp.sum(x * w)
+                                       for x, w in zip(fn(*a), ws)),
+                        argnums=(0, 1, 2, 3, 4))(*args)
+    for name, g_got, g_want in zip(
+            "dq dk dv dg db".split(), grads(kernel),
+            grads(lambda *a: kda.chunk_terms(*a, 0.1))):
+        assert bool(jnp.isfinite(g_got).all()), name
+        if name == "dg" and "identical" in case:
+            # g = 0 there: chunk_terms' min(ref - G, 0) sits on its tie,
+            # where autodiff halves the gradient; the recurrence below
+            # holds this dg
+            continue
+        np.testing.assert_allclose(
+            g_got, g_want, rtol=0, err_msg=name,
+            atol=2 * rtol * float(jnp.abs(g_want).max()))
+
+
+@pytest.mark.parametrize("case", _PREP_CASES)
+def test_kda_chunked_on_both_kernel_pairs_is_the_recurrence(case):
+    """The layer as a TPU runs it (preparation and carry both Pallas, in
+    interpret mode) against the token-by-token oracle, forward and
+    jax.grad of every input."""
+    args = _prep_case(case)
+    kernels = dict(
+        terms=lambda *a: kda_prep.kda_chunk_terms(*a, heads=2,
+                                                  interpret=True),
+        carry=lambda *a: kda_chunk.kda_state_carry(*a, heads=2,
+                                                   interpret=True))
+    want = kda.kda_recurrent(*args, 0.1)
+    got = kda.kda_chunked(*args, 0.1, **kernels)
+    assert bool(jnp.isfinite(got).all())
+    np.testing.assert_allclose(got, want, atol=1e-4)
+
+    def grads(fn):
+        return jax.grad(lambda *a: jnp.sum(fn(*a, 0.1) ** 2),
+                        argnums=(0, 1, 2, 3, 4))(*args)
+    for g_got, g_want in zip(
+            grads(lambda *a: kda.kda_chunked(*a, **kernels)),
+            grads(kda.kda_recurrent)):
+        assert bool(jnp.isfinite(g_got).all())
+        np.testing.assert_allclose(g_got, g_want, atol=5e-4)
 
 
 def test_short_conv_is_causal():
