@@ -1,5 +1,6 @@
 """Decode benchmark: batched beam-6 translation throughput (sent/sec) —
-BASELINE.json's second driver metric (the train metric lives in bench.py).
+BASELINE.json's second driver metric (training is measured by the
+benchmark's `big.train` cell, BENCHMARK.json).
 
 Prints ONE JSON line {"metric", "value", "unit", "vs_baseline"}; the
 baseline field stays null (the empty reference mount ships no decode
@@ -203,6 +204,20 @@ def _warm_compile_s(window, armed: bool) -> "float | None":
     return round(sum(s for _site, s in window.compiles), 3)
 
 
+def tristate_env(name: str):
+    """Parse an on/off/auto A/B env knob; malformed values fall back to
+    None (= model default) with a warning."""
+    raw = os.environ.get(name)
+    if not raw:
+        return None
+    v = raw.strip().lower()
+    if v not in ("on", "off", "auto"):
+        print(f"bench: bad {name}={raw!r} (want on/off/auto) — using "
+              f"model default", file=sys.stderr, flush=True)
+        return None
+    return v
+
+
 def main():
     preset = os.environ.get("MARIAN_DECBENCH_PRESET", "big")
     n_sents = int(os.environ.get("MARIAN_DECBENCH_SENTS", 256))
@@ -259,7 +274,6 @@ def main():
     # per-step reorder+read traffic dominates the standard decode step —
     # is replaced by one [B*K, d] recurrent state per layer
     ssru = bool(os.environ.get("MARIAN_DECBENCH_SSRU"))
-    from bench import tristate_env
     fused_env = tristate_env("MARIAN_DECBENCH_FUSED") or ""
     opts = Options({
         "type": "transformer",

@@ -237,7 +237,6 @@ _TRAINING = [
     _f("optimizer", str, "adam", "adam, adagrad, sgd", "training"),
     _f("optimizer-params", float, [], "Optimizer hyperparameters (Adam: beta1 beta2 eps)", "training", "*"),
     _f("optimizer-delay", float, 1.0, "SGD update delay (gradient accumulation): N updates or fractional", "training"),
-    _f("dispatch-window", int, 1, "Run N full optimizer updates inside one jitted dispatch (lax.scan over same-shape batches; amortizes host dispatch latency — beyond the reference, whose host loop runs per update). Requires --optimizer-delay 1", "training"),
     _f("sync-sgd", bool, False, "Synchronous SGD (the only mode on TPU; async maps to it with a warning)", "training"),
     _f("learn-rate", float, 0.0001, "Learning rate", "training"),
     _f("lr-report", bool, False, "Report learning rate in progress lines", "training"),

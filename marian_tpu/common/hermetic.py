@@ -1,5 +1,5 @@
 """Virtual-CPU platform setup, shared by tests/conftest.py,
-__graft_entry__.dryrun_multichip and the benches' explicit-CPU smoke.
+__graft_entry__.dryrun_multichip and bench_decode.py's explicit-CPU smoke.
 
 Tests and dry runs never touch the chip: they run on N virtual CPU devices
 (Pallas kernels in interpret mode), so multi-device sharding logic is

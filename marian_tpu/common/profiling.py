@@ -12,15 +12,14 @@ obs/trace.py, live under either of them):
 - ``--dump-hlo path``: write the jaxpr and the optimized HLO of the jitted
   train step (the ExpressionGraph::graphviz debugging equivalent).
 
-``TraceWindow`` lives in ``marian_tpu/obs/profiling.py``; the name below
-is a re-export so existing call sites keep importing from here.
+``TraceWindow`` (the ``--profile`` window) lives in
+``marian_tpu/obs/profiling.py``.
 """
 
 from __future__ import annotations
 
 import os
 
-from ..obs.profiling import TraceWindow  # noqa: F401 — re-export
 from . import logging as log
 
 

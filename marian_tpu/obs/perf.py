@@ -619,7 +619,8 @@ class PerfMeter:
             trg_w = max(1, int(round(labels / sents)))
             # unpadded average widths: understates the attention terms a
             # padded batch really pays, so this MFU reads slightly HIGH —
-            # bench.py's padded-shape accounting stays the precise one
+            # the benchmark's padded-shape accounting (mfu.train) is the
+            # precise one
             flops = transformer_train_flops(
                 geo.emb, geo.ffn, geo.enc_depth, geo.dec_depth, geo.vocab,
                 src_tokens=float(src_words or labels),

@@ -403,7 +403,7 @@ def build_train_step(model, opt_cfg: OptimizerConfig, schedule, cost_type: str,
                      mesh: Mesh, params: Params, opt_state,
                      delay: int = 1, donate: bool = True, shardings=None,
                      frozen=(), force_gspmd: bool = False,
-                     n_updates: int = 1, grad_dtype=None):
+                     grad_dtype=None):
     """Returns a jitted fn(params, opt_state, batch, step) →
     (params, opt_state, metrics) with SyncGraphGroup semantics.
 
@@ -415,24 +415,7 @@ def build_train_step(model, opt_cfg: OptimizerConfig, schedule, cost_type: str,
     delay > 1 so the leading micro axis stays unsharded). Only the outputs
     are pinned here so donation layouts match. `shardings` optionally passes
     precomputed (param_shardings, opt_state_shardings) to avoid recomputing.
-
-    `n_updates` > 1 (--dispatch-window) runs K FULL update cycles —
-    fwd/bwd, reduce-scatter, clip, Adam, EMA, all-gather — inside ONE
-    jitted dispatch via lax.scan over a leading [K] window axis on the
-    batch leaves (shard_batch micro=True keeps it unsharded). `rng` must
-    be the RAW training stream key: scan iteration i folds it by the
-    absolute step number step+i-1 — the same derivation the sequential
-    path uses on the host — so trajectories are bit-identical no matter
-    how updates group into windows; metrics come back stacked [K]. The
-    point is amortizing host-bound dispatch latency over K real updates — the reference has no
-    equivalent lever because its per-update host loop is mandatory
-    (graph_group_sync.cpp :: SyncGraphGroup::update returns to the host
-    scheduler every update). Requires delay == 1.
     """
-    if n_updates > 1 and delay > 1:
-        raise ValueError("--dispatch-window composes with in-jit "
-                         "--optimizer-delay accumulation only via the "
-                         "host loop; use one or the other")
     machinery = _GradMachinery(model, mesh, params, delay=delay,
                                frozen=frozen, force_gspmd=force_gspmd,
                                grad_dtype=grad_dtype)
@@ -467,7 +450,7 @@ def build_train_step(model, opt_cfg: OptimizerConfig, schedule, cost_type: str,
         elif cost_type == "ce-mean":
             bsz = (batch["trg_ids"].shape[0] if delay == 1
                    else batch["trg_ids"].shape[0] * batch["trg_ids"].shape[1])
-            denom = jnp.asarray(float(bsz), jnp.float32)
+            denom = jnp.asarray(bsz, jnp.float32)
         else:
             denom = jnp.asarray(1.0, jnp.float32)
         with jax.named_scope("optimizer"):
@@ -486,28 +469,6 @@ def build_train_step(model, opt_cfg: OptimizerConfig, schedule, cost_type: str,
             metrics["labels"] = jnp.where(skipped > 0, 0.0, labels)
         return new_p, new_opt, metrics
 
-    if n_updates <= 1:
-        step_fn = one_update
-    else:
-        def step_fn(p, opt_state, batch, step, rng):
-            # rng is the RAW training stream key; one_update folds it by
-            # the absolute step number step+i-1 internally, so the
-            # windowed trajectory is bit-identical to sequential update()
-            # calls regardless of how updates group into windows. Int
-            # steps keep sub-step indices exact at any count.
-            step = jnp.asarray(step)
-            step_i = (step if jnp.issubdtype(step.dtype, jnp.integer)
-                      else step.astype(jnp.int32))
-
-            def body(carry, xs):
-                pp, oo = carry
-                b, i = xs
-                np_, no_, m = one_update(pp, oo, b, step_i + i, rng)
-                return (np_, no_), m
-            (p, opt_state), metrics = jax.lax.scan(
-                body, (p, opt_state), (batch, jnp.arange(n_updates)))
-            return p, opt_state, metrics
-
     rep = M.replicated(mesh)
     # TP (Megatron-style over 'model') via GSPMD param specs; replicated when
     # the model axis is 1. ZeRO-1 'data' sharding composes on the opt state.
@@ -523,9 +484,9 @@ def build_train_step(model, opt_cfg: OptimizerConfig, schedule, cost_type: str,
         metrics_shardings["counters"] = rep
 
     return jax.jit(  # mtlint: ok -- built once per training launch:
-        # n_updates is a launch flag (--dispatch-window), not a
-        # per-request key, so the domain is one value per process
-        step_fn,
+        # delay is a launch flag (--optimizer-delay), not a per-request
+        # key, so the domain is one value per process
+        one_update,
         out_shardings=(p_shardings, o_shardings, metrics_shardings),
         donate_argnums=(0, 1) if donate else ())
 

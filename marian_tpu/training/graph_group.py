@@ -75,11 +75,6 @@ class GraphGroup:
         self.opt_cfg = OptimizerConfig.from_options(options)
         self.schedule = LRSchedule.from_options(options)
         self.delay = max(1, int(float(options.get("optimizer-delay", 1))))
-        self.window = max(1, int(options.get("dispatch-window", 1)))
-        if self.window > 1 and self.delay > 1:
-            raise ValueError("--dispatch-window requires --optimizer-delay 1 "
-                             "(in-jit windowing and in-jit accumulation do "
-                             "not compose; pick one)")
         if options.has("sync-sgd") and options.get("sync-sgd") is False:
             log.warn("Asynchronous SGD has no SPMD equivalent; using sync-sgd")
         self.mesh = mesh if mesh is not None else M.make_mesh(options)
@@ -89,7 +84,6 @@ class GraphGroup:
         self._donate = donate
         self._fused = None
         self._fused_delay = None         # delay>1 in-jit micro-batch scan
-        self._fused_window = None        # dispatch-window>1 multi-update scan
         self._grad_fn = None
         self._update_fn = None
         self._fix_src = bool(options.get("embedding-fix-src", False))
@@ -232,15 +226,6 @@ class GraphGroup:
                                        grad_dtype=grad_dtype)
         self._fused_delay = None
         self._ahead = None              # executables of the step before
-        # K updates per dispatch (build_train_step n_updates>1) — built
-        # LAZILY on the first update_window call so paths that never fill
-        # a window (the fused-CE A/B probe, short runs) skip its compile
-        self._fused_window = None
-        self._window_build = lambda: build_train_step(
-            model, opt_cfg, schedule, self.cost_type, mesh,
-            self.params, self.opt_state, delay=1, donate=self._donate,
-            shardings=(p_sh, o_sh), frozen=frozen, n_updates=self.window,
-            grad_dtype=grad_dtype)
         if self.delay > 1:
             # in-jit micro-batch accumulation (one dispatch, one gradient
             # accumulator in HBM) for the common case of shape-uniform
@@ -289,19 +274,16 @@ class GraphGroup:
             donate_argnums=(0, 1, 2) if self._donate else ())
 
     # -- one (macro-)update --------------------------------------------------
-    def _output(self, metrics, i=None) -> TrainOutput:
-        """A step's metrics as a TrainOutput (entry i of a window's
-        stacked metrics). A model family's step counters go, still lazy,
-        to the tracer, which keeps them while spans are live and fetches
-        them where the Scheduler's display syncs anyway."""
-        def pick(key):
-            v = metrics.get(key)
-            return v if v is None or i is None else v[i]
+    def _output(self, metrics) -> TrainOutput:
+        """A step's metrics as a TrainOutput. A model family's step
+        counters go, still lazy, to the tracer, which keeps them while
+        spans are live and fetches them where the Scheduler's display
+        syncs anyway."""
         if "counters" in metrics:
             obs_trace.TRACER.count_lazy(self.model.step_counters,
-                                        pick("counters"))
-        return TrainOutput(pick("ce_sum"), pick("labels"), pick("gnorm"),
-                           pick("skipped"))
+                                        metrics["counters"])
+        return TrainOutput(metrics["ce_sum"], metrics["labels"],
+                           metrics["gnorm"], metrics.get("skipped"))
 
     @staticmethod
     def _dispatch(fn, step: int, *args):
@@ -475,34 +457,6 @@ class GraphGroup:
         return TrainOutput(
             total_loss, total_labels, gnorm,
             skipped if self.opt_cfg.check_gradient_nan else None)
-
-    def update_window(self, batches, step: int, rng) -> "list[TrainOutput]":
-        """K = --dispatch-window full updates in ONE jitted dispatch.
-
-        `batches`: list of exactly `self.window` batch dicts sharing one
-        padded shape (the train loop groups by bucket). `rng` is the RAW
-        training stream key — sub-update i folds it in-scan by the
-        absolute step number step+i-1, exactly matching sequential
-        update(b, s, rng) calls (update() folds the same raw key by s-1
-        internally), so the trajectory is bitwise independent of window
-        grouping. Returns one TrainOutput
-        per sub-update (lazy [K]-stacked device scalars — no host sync
-        here)."""
-        assert self.window > 1 and len(batches) == self.window
-        if self._fused_window is None:
-            self._fused_window = self._window_build()
-        stacked = {k: jnp.stack([b[k] for b in batches])
-                   for k in batches[0]}
-        stacked = M.shard_batch(stacked, self.mesh, micro=True)
-        if self._dump_hlo:
-            from ..common.profiling import dump_lowered
-            dump_lowered(self._dump_hlo, self._fused_window.lower(
-                self.params, self.opt_state, stacked, np.int32(step), rng))
-            self._dump_hlo = None
-        self.params, self.opt_state, metrics = self._dispatch(
-            self._fused_window, step, self.params, self.opt_state, stacked,
-            np.int32(step), rng)
-        return [self._output(metrics, i) for i in range(self.window)]
 
     # -- EMA access for validation/saving -----------------------------------
     def smoothed(self) -> Params:

@@ -55,20 +55,6 @@ class Scheduler:
         # display accumulators
         self._cost_sum = 0.0
         self._label_sum = 0.0
-        self._max_labels_update = 0   # largest single-update label count seen
-        # config-derived UPPER bound on per-update labels, for the
-        # --after Nt window cap: max observed alone is not conservative
-        # when bucket sizes vary (a later long-bucket update can carry
-        # far more labels than anything seen so far)
-        delay = max(1, int(options.get("optimizer-delay", 1) or 1))
-        mbw = int(options.get("mini-batch-words", 0) or 0)
-        if mbw:
-            self._labels_update_bound = mbw * delay
-        else:
-            mb = int(options.get("mini-batch", 0) or 0)
-            ml = int(options.get("max-length", 0) or 0)
-            self._labels_update_bound = (mb * (ml + 1) * delay
-                                         if mb and ml else 0)
         self._words_sum = 0.0
         self._sent_sum = 0
         self._timer = time.perf_counter()
@@ -183,7 +169,6 @@ class Scheduler:
             s.labels_total += int(labels)
             self._m_updates.inc()
             self._m_labels.inc(int(labels))
-            self._max_labels_update = max(self._max_labels_update, int(labels))
             if lr is not None:
                 s.eta = float(lr)
             if skipped is not None:
@@ -359,7 +344,7 @@ class Scheduler:
         self._cost_sum = self._label_sum = self._words_sum = 0.0
         self._sent_sum = 0
         self._disp_count = 0
-        self._timer = time.perf_counter()  # mtlint: ok -- float(cost_sum) above is this window's sync fence; a block_until_ready here would stall the dispatch-ahead hot loop
+        self._timer = time.perf_counter()  # mtlint: ok -- float(cost_sum) above is this window's sync fence; a block_until_ready here would stall the hot loop
 
     def _epoch_display(self):
         s = self.state
@@ -380,62 +365,6 @@ class Scheduler:
 
     def should_validate(self) -> bool:
         return bool(self.valid_freq) and self._hit(self.valid_freq)
-
-    def _hit_since(self, freq: SchedulingParameter, batches_before: int,
-                   labels_before: int) -> bool:
-        """Crossing test over a RANGE of updates: did any multiple of
-        `freq` land in (before, now]? --dispatch-window applies K updates
-        per dispatch, so the exact-multiple test in _hit would skip a
-        trigger that fell mid-window."""
-        if not freq:
-            return False
-        s = self.state
-        if freq.unit == SchedulingUnit.UPDATES:
-            return (s.batches // freq.n) > (batches_before // freq.n)
-        if freq.unit == SchedulingUnit.TRG_LABELS:
-            return (s.labels_total // freq.n) > (labels_before // freq.n)
-        return False
-
-    def should_save_since(self, batches_before: int,
-                          labels_before: int) -> bool:
-        return bool(self.save_freq) and self._hit_since(
-            self.save_freq, batches_before, labels_before)
-
-    def updates_remaining(self) -> Optional[int]:
-        """Updates left before an update-counted hard limit
-        (--after-batches / --after Nu), or None when no such limit is
-        set. --dispatch-window caps its fill with this so a window never
-        overshoots the limit by more than the final partial window."""
-        limits = []
-        if self.after_batches:
-            limits.append(self.after_batches)
-        if self.after and self.after.unit == SchedulingUnit.UPDATES:
-            limits.append(self.after.n)
-        if self.after and self.after.unit == SchedulingUnit.TRG_LABELS:
-            # labels-counted limit (--after Nt): conservative updates
-            # estimate, so the window cannot overshoot the labels stop
-            # by more than one update (the unwindowed loop's own
-            # guarantee). Divisor = the config-derived per-update label
-            # UPPER bound (token budget × delay, or mini-batch ×
-            # max-length) — max-observed alone under-estimates when a
-            # later long-bucket update carries more labels than any
-            # seen. No bound derivable (fresh start, sentence batching
-            # without max-length) → cap the fill at one update.
-            rem_labels = self.after.n - self.state.labels_total
-            bound = max(self._labels_update_bound, self._max_labels_update)
-            if bound <= 0:
-                est = 1
-            else:
-                est = -(-max(0, rem_labels) // bound)
-            limits.append(self.state.batches + est)
-        if not limits:
-            return None
-        return max(0, min(limits) - self.state.batches)
-
-    def should_validate_since(self, batches_before: int,
-                              labels_before: int) -> bool:
-        return bool(self.valid_freq) and self._hit_since(
-            self.valid_freq, batches_before, labels_before)
 
     def new_epoch(self) -> None:
         seen = self.state.samples_epoch

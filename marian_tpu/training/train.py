@@ -364,8 +364,8 @@ class Train:
                      "first {} updates", wu_n)
 
         # -- epoch loop ------------------------------------------------------
-        from ..common.profiling import (TraceWindow,
-                                        maybe_start_profile_server)
+        from ..common.profiling import maybe_start_profile_server
+        from ..obs.profiling import TraceWindow
         maybe_start_profile_server(opts)
         # observability: the loop's phases are spans opened by the objects
         # it calls (data.wait, train.h2d, train.dispatch, train.bookkeep,
@@ -453,9 +453,8 @@ class Train:
             return out
 
         def _check_stop():
-            """Signal / stopping-condition tail shared by both update
-            paths. Returns 'exit' (leave run() now), 'stop' (save done /
-            limits hit), or None."""
+            """Signal / stopping-condition tail of an update. Returns 'exit'
+            (leave run() now), 'stop' (save done / limits hit), or None."""
             if signal_handling.signal_flag():
                 if opts.get("sigterm", "save-and-exit") == \
                         "exit-immediately":
@@ -488,67 +487,6 @@ class Train:
                 do_save()
             return _check_stop()
 
-        # --dispatch-window: buffer same-shape batches and run K full
-        # updates per jitted dispatch (GraphGroup.update_window). Triggers
-        # (validate/save/sigterm) quantize to the window boundary — the
-        # same way --optimizer-delay quantizes them to macro-updates —
-        # with range-crossing detection (should_*_since) so a freq
-        # boundary that falls mid-window still fires at the drain.
-        # (GraphGroup refuses window>1 with delay>1, so no guard here.)
-        window = gg.window
-        win: List = []
-        win_key: List = []               # cached _shape_key of win[0]
-
-        def _shape_key(arrays):
-            return tuple(sorted((k, tuple(v.shape), str(v.dtype))
-                                for k, v in arrays.items()))
-
-        def _drain_window():
-            """Dispatch the buffered batches — a full window through the
-            scanned K-update step (ONE host dispatch), stragglers (bucket
-            change / epoch end) singly. ALL applied sub-updates are
-            accounted in the scheduler before any trigger runs, so a
-            save/validate at the boundary always sees a progress count
-            equal to the updates baked into the params."""
-            if not win:
-                return None
-            trace.tick(state.batches + 1)
-            # dispatch may block on a LEGITIMATE jit compile (first step,
-            # new bucket shape) — not a stall. Execution hangs are still
-            # caught: dispatch itself is async, and a wedged device
-            # surfaces at the scheduler's sync points, outside this pause.
-            if watchdog is not None:
-                watchdog.pause()
-            try:
-                if len(win) == window:
-                    outs = gg.update_window([a for a, _ in win],
-                                            state.batches + 1, train_key)
-                    pairs = [(o, b) for o, (_, b) in zip(outs, win)]
-                else:
-                    pairs = []
-                    for idx, (a, b) in enumerate(win):
-                        s0 = state.batches + 1 + idx
-                        pairs.append((gg.update(a, s0, train_key), b))
-            finally:
-                if watchdog is not None:
-                    watchdog.resume()
-            win.clear()
-            win_key.clear()
-            before_b, before_l = state.batches, state.labels_total
-            if pairs[-1][1].corpus_state is not None:
-                last_corpus_state[0] = pairs[-1][1].corpus_state
-            for out, b in pairs:
-                out = _maybe_poison_cost(out)
-                scheduler.update(out.loss_sum, b.words, b.size,
-                                 src_words=b.src_words,
-                                 lr=gg.schedule.host_lr(state.batches + 1),
-                                 skipped=out.skipped)
-            if scheduler.should_validate_since(before_b, before_l):
-                do_validate()
-            if scheduler.should_save_since(before_b, before_l):
-                do_save()
-            return _check_stop()
-
         def _epoch_loop() -> Optional[str]:
             nonlocal stop
             while scheduler.keep_going() and not stop:
@@ -556,7 +494,6 @@ class Train:
                     else BatchGenerator(corpus, opts,
                                         budget_scale=budget_scale)
                 micro: List = []
-                rc = None
                 for batch in bg:
                     if watchdog is not None:
                         watchdog.beat()
@@ -565,71 +502,32 @@ class Train:
                     # --train-stall-timeout watchdog; kill mode is the
                     # mid-step preemption drill
                     fp.fault_point("train.hang")
-                    if window > 1:
-                        # cheap host-side check per batch: a SIGTERM (or a
-                        # crossed stopping condition) must not wait for a
-                        # whole new window of batches to assemble
-                        if signal_handling.signal_flag() \
-                                or not scheduler.keep_going():
-                            if signal_handling.signal_flag() and \
-                                    opts.get("sigterm", "save-and-exit") \
-                                    == "exit-immediately":
-                                # drop the undispatched window: exit-
-                                # immediately must not do up to K more
-                                # updates of work the unwindowed path skips
-                                win.clear()
-                                win_key.clear()
-                            rc = _drain_window() or _check_stop()
-                            if rc == "exit":
-                                return "exit"
-                            stop = True
-                            break
-                        arrays = _arrays(batch)
-                        k_ = _shape_key(arrays)
-                        if win and k_ != win_key[0]:
-                            rc = _drain_window()      # bucket shape changed
-                        if rc is None:
-                            if not win:
-                                win_key[:] = [k_]
-                            win.append((arrays, batch))
-                            # fill to the window, but never past an update-
-                            # counted hard limit (--after-batches overshoot
-                            # bounded by the final PARTIAL window, not K)
-                            rem = scheduler.updates_remaining()
-                            if len(win) == window or \
-                                    (rem is not None and len(win) >= rem):
-                                rc = _drain_window()
-                    else:
-                        micro.append(batch)
-                        if len(micro) < delay:
-                            continue
-                        arrays = [_arrays(b) for b in micro]
-                        trace.tick(state.batches + 1)
-                        # same compile-is-not-a-stall pause as
-                        # _drain_window's dispatch
+                    micro.append(batch)
+                    if len(micro) < delay:
+                        continue
+                    arrays = [_arrays(b) for b in micro]
+                    trace.tick(state.batches + 1)
+                    # dispatch may block on a LEGITIMATE jit compile (first
+                    # step, new bucket shape) — not a stall. Execution hangs
+                    # are still caught: dispatch itself is async, and a
+                    # wedged device surfaces at the scheduler's sync points,
+                    # outside this pause.
+                    if watchdog is not None:
+                        watchdog.pause()
+                    try:
+                        out = gg.update(arrays, state.batches + 1, train_key)
+                    finally:
                         if watchdog is not None:
-                            watchdog.pause()
-                        try:
-                            out = gg.update(arrays, state.batches + 1,
-                                            train_key)
-                        finally:
-                            if watchdog is not None:
-                                watchdog.resume()
-                        rc = _after_update(out, micro)
-                        micro = []
+                            watchdog.resume()
+                    rc = _after_update(out, micro)
+                    micro = []
                     if rc == "exit":
                         return "exit"
                     if rc is not None:
                         stop = True
                         break
                 if not stop:
-                    rc = _drain_window()              # epoch-end stragglers
-                    if rc == "exit":
-                        return "exit"
-                    if rc is not None:
-                        stop = True
-                    else:
-                        scheduler.new_epoch()
+                    scheduler.new_epoch()
             # skip flags from the last ~2 updates may still be lazily
             # pending — resolve them so a divergence at the very end of
             # the run raises here (inside the rollback ladder) instead of
@@ -662,8 +560,6 @@ class Train:
                             extra={"retry": n, "update": state.batches})
             if saver is not None:
                 saver.wait()         # never reload under an in-flight save
-            win.clear()
-            win_key.clear()
             gg.opt_state = None      # drop poisoned moments before reload
             restored = TrainingState(seed=seed)
             reinit_params = None

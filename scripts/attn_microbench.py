@@ -42,7 +42,7 @@ def _timed(loop_fn, q, k, v, iters):
     """Time ONE jitted dispatch of `loop_fn` (which runs the candidate
     `iters` times inside a fori_loop) and return seconds per iteration.
     Sync is a scalar VALUE fetch — the only hard sync this backend
-    honors (bench.py's r4 finding)."""
+    honors."""
     float(loop_fn(q, k, v))                  # compile + warm
     t0 = time.perf_counter()
     float(loop_fn(q, k, v))

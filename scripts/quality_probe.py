@@ -98,7 +98,7 @@ def build_corpus(tmp: str, n_train: int, n_test: int, max_len: int,
         with open(sp, "w") as fs, open(tp, "w") as ft:
             if name == "train":
                 # line 0 mentions every vocab item so DefaultVocab covers
-                # all ids (same convention as bench.py's corpus)
+                # all ids
                 allw = [f"s{i}" for i in range(VOCAB_N)] + list(MARKERS)
                 fs.write(" ".join(allw) + "\n")
                 ft.write(" ".join(f"t{i}" for i in range(VOCAB_N)) + "\n")

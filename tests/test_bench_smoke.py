@@ -1,7 +1,6 @@
-"""Smoke tests for the driver-facing bench entry points (bench.py /
-bench_decode.py). These are the round's headline deliverable — a
-regression here would otherwise surface only when the driver runs the
-bench on scarce TPU time."""
+"""Smoke tests for bench_decode.py, the decode bench that stays until the
+benchmark holds a decode cell (ROADMAP B3): a regression here would
+otherwise surface only on scarce TPU time."""
 
 import json
 import os
@@ -27,17 +26,6 @@ def _run(script, env_extra, tmp_path, timeout=420):
     return json.loads(line)
 
 
-def test_train_bench_tiny_contract(tmp_path):
-    out = _run("bench.py", {"MARIAN_BENCH_PRESET": "tiny"}, tmp_path)
-    # metric/value/unit on ONE line; a CPU smoke never carries the device
-    # metric's name, a baseline ratio or an MFU
-    assert out["metric"] == "cpu_smoke_src_tokens_per_sec"
-    assert out["value"] > 0 and out["unit"] == "src-tokens/sec/chip"
-    assert out["vs_baseline"] is None and out["mfu"] is None
-    assert out["chip"] == "cpu" and out["platform"] == "cpu"
-    assert out["flops_per_src_token"] > 0
-
-
 def test_decode_bench_tiny_contract(tmp_path):
     out = _run("bench_decode.py", {"MARIAN_DECBENCH_PRESET": "tiny"},
                tmp_path)
@@ -47,7 +35,6 @@ def test_decode_bench_tiny_contract(tmp_path):
 
 
 @pytest.mark.parametrize("script,env", [
-    ("bench.py", {"MARIAN_BENCH_PRESET": "big"}),
     ("bench_decode.py", {"MARIAN_DECBENCH_PRESET": "big"}),
 ])
 def test_no_chip_fails_instead_of_falling_back(script, env):

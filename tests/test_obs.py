@@ -671,7 +671,7 @@ class TestTotalsAndProfilerSink:
         assert "trace-sync-phases" not in cp.ConfigParser("training").flags
         assert not hasattr(profiling, "StepTimer")
         assert not hasattr(obs_profiling, "StepTimer")
-        assert profiling.TraceWindow is obs_profiling.TraceWindow
+        assert hasattr(obs_profiling, "TraceWindow")
         with pytest.raises(SystemExit):
             cp.parse_options(["--trace-sync-phases"], mode="training")
 

@@ -80,9 +80,28 @@ def test_scope_of_a_name_stack(stack, want):
     assert ps.scope_of(stack) == want
 
 
+@pytest.fixture
+def empty_compile_cache(tmp_path, monkeypatch):
+    """On the CPU an executable loaded from the persistent compile cache
+    carries no HLO metadata, so every op of it reads `other`: whatever an
+    earlier test of this process enabled, the step traced below compiles
+    against a cache directory of its own, which is empty."""
+    from jax.experimental.compilation_cache import compilation_cache as cc
+    before = jax.config.jax_compilation_cache_dir
+    empty = str(tmp_path / "xla_cache")
+    os.makedirs(empty)
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", empty)
+    jax.config.update("jax_compilation_cache_dir", empty)
+    cc.reset_cache()
+    yield empty
+    jax.config.update("jax_compilation_cache_dir", before)
+    cc.reset_cache()
+
+
 @time_limit(240)
 def test_cpu_trace_of_the_trainer_prints_by_scope(tmp_corpus, tmp_path,
-                                                  capsys):
+                                                  capsys,
+                                                  empty_compile_cache):
     parts = build_loop(tmp_corpus, tmp_path)
     run_epoch(*parts)
     trace_dir = str(tmp_path / "prof")

@@ -250,7 +250,7 @@ def test_profile_window_holds_spans_and_no_python_calls(tmp_path):
     """marian-train --profile: the window's trace carries the program's
     spans; the Python tracer stays off (an event per call would nest in
     every span and eat its self time)."""
-    from marian_tpu.common.profiling import TraceWindow
+    from marian_tpu.obs.profiling import TraceWindow
     trace_dir = str(tmp_path / "prof")
     win = TraceWindow(Options({"profile": trace_dir, "profile-start": 3,
                                "profile-updates": 2}))

@@ -264,20 +264,26 @@ class TestDeviceDiffSafety:
         shed traffic the host path could serve: the round falls back to
         one single-step host-merge round (lazy claims at actual
         demand), and output stays bitwise the unpressured fused run's.
-        max_rows=K over a minimal pool reproduces the squeeze: k rows
-        at full divergence own the whole pool, so the boundary-round
-        preclaim cannot fit. The pool is pinned by pool_bytes to
-        max_rows full-cap rows with NO round-preclaim headroom (the
-        unsized default adds it since ISSUE 18 — exactly to make this
-        fallback rare — so the squeeze needs an explicit sizing, like
-        a production --kv-pool-bytes brownout would)."""
+        The squeeze: one sentence (max_rows=K) over a pool pinned by
+        pool_bytes to ONE PAGE UNDER what its K rows own at full
+        divergence and full cap. A two-step round at a page boundary
+        preclaims k + (k-1) fresh pages on top of what the beams
+        already hold, which that pool cannot give once the beams have
+        forked, while the pages the merge really forks into always
+        fit — so rounds fall back and nothing is evicted. (K whole
+        full-cap rows, the sizing this fixture had, leave the preclaim
+        room on this model: the beams share their trunk. The unsized
+        default adds round-preclaim headroom since ISSUE 18 — exactly
+        to make this fallback rare — so the squeeze needs an explicit
+        sizing, like a production --kv-pool-bytes brownout would.)"""
         ref = make_engine(tiny, merge="fused", steps_per_round=2)
         tight = make_engine(
             tiny, merge="fused", steps_per_round=2, max_rows=K,
-            pool_bytes=ref.page_bytes * K * ref.max_pages)
+            pool_bytes=ref.page_bytes * (K * ref.max_pages - 1))
         o, i = drive(tight, [TEXTS[2]])
         assert tight._counters.get("fused_fallback_rounds", 0) > 0, \
             "the squeeze never hit the fallback — tighten the fixture"
+        assert tight._counters.get("pool_evictions", 0) == 0
         ref_o, ref_i = drive(ref, [TEXTS[2]])
         assert o == ref_o
         assert_parity(i, ref_i)
