@@ -76,11 +76,13 @@ class AutoTuner:
 # ---------------------------------------------------------------------------
 
 # per-kernel base entries at dh<=64: the sequence-side capacity each
-# kernel holds per grid cell (packed: full padded Tq=Tk per (row, head-
-# group) TILE — a grid cell is a block of rows x all heads that the
-# kernel sizes itself from the shapes, packed_attention.py::cell_plan,
-# down to one row's single head group at the cap; decode: the whole
-# [L, dh] cache row per (row, head) cell)
+# kernel holds per grid cell (packed: full Tq=Tk per (row, head-group)
+# TILE, padded to multiples of 64 past 64 positions; up to 64 a tile is
+# 64 positions filled with 64 // T rows at their own width, rows_a_tile
+# — a grid cell is a block of rows x all heads that the kernel sizes
+# itself from the shapes, packed_attention.py::cell_plan, down to one
+# row's single head group at the cap; decode: the whole [L, dh] cache
+# row per (row, head) cell)
 KERNEL_BLOCKS = {
     # packed fwd tile peak ~ g*T x g*T f32 scores + operands; T=256 at
     # g=2/dh=64 is ~2.5 MB — comfortably under the kernel's VMEM budget,

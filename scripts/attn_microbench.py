@@ -12,6 +12,14 @@ Same in-jit timing discipline as gemm_microbench.py: the candidate runs
 inside a fori_loop with full-output liveness so XLA cannot DCE it and
 the host sync round-trip amortizes over ITERS real invocations.
 
+The last columns are the packed kernel alone: device time of
+`packed_attention_fwd` / `_bwd` in a profiler trace of a few calls of
+jit(grad), the tiles a call takes (64 // T rows share one, PR 31; "of"
+the tiles at one row each) and the us a tile costs — the law the
+kernel's time follows (PERF.md section 6, PRs 26 and 31). The default
+shapes are big.train's six batches (4096 words at widths 8 to 64), one
+shape past 64 positions and one at dh 32.
+
 Run it on the chip:
 
     python scripts/attn_microbench.py            # fwd table
@@ -26,8 +34,10 @@ share column reads n/a): interpret-mode Pallas is not a performance
 path, so CPU numbers say nothing about the kernel — run on silicon.
 """
 
+import glob
 import os
 import sys
+import tempfile
 import time
 
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
@@ -72,6 +82,30 @@ def _make_loop(fn, iters, grad):
     return jax.jit(loop)
 
 
+def _kernel_us(grad_fn, q, k, v, calls=5):
+    """Device us a call of packed_attention_fwd and _bwd: their ops on
+    the chip in a profiler trace of `calls` calls of the jitted gradient
+    (which runs one of each), read as `profile_summary` reads a trace."""
+    import jax
+
+    from marian_tpu.cli.profile_summary import device_ops, read_xspace
+
+    fn = jax.jit(grad_fn)
+    jax.block_until_ready(fn(q, k, v))       # compile outside the trace
+    with tempfile.TemporaryDirectory() as d:
+        with jax.profiler.trace(d):
+            for _ in range(calls):
+                jax.block_until_ready(fn(q, k, v))
+        path = glob.glob(os.path.join(d, "plugins", "profile", "*",
+                                      "*.xplane.pb"))[0]
+        ops = [op for device in device_ops(read_xspace(path))
+               for op in device]
+    return tuple(sum(end - start for start, end, (name, _) in ops
+                     if kernel in name) / 1e6 / calls
+                 for kernel in ("packed_attention_fwd",
+                                "packed_attention_bwd"))
+
+
 def main():
     import jax
     import jax.numpy as jnp
@@ -79,11 +113,13 @@ def main():
 
     from marian_tpu.ops.attention import dense_attention
     from marian_tpu.ops.pallas.packed_attention import (pack_group,
-                                                        packed_attention)
+                                                        packed_attention,
+                                                        rows_a_tile)
 
     bwd = bool(os.environ.get("MARIAN_ATTNBENCH_BWD"))
-    shapes = [(8, 16, 48, 64), (8, 16, 64, 64), (16, 16, 64, 64),
-              (8, 16, 128, 64), (8, 8, 64, 32)]
+    shapes = [(512, 16, 8, 64), (256, 16, 16, 64), (168, 16, 24, 64),
+              (128, 16, 32, 64), (80, 16, 48, 64), (64, 16, 64, 64),
+              (32, 16, 128, 64), (128, 8, 32, 32)]
     override = os.environ.get("MARIAN_ATTNBENCH_SHAPES")
     if override:
         try:
@@ -105,7 +141,8 @@ def main():
           f"{'' if on_tpu else '  [CPU: interpret mode, MXU share n/a]'}")
     print(f"{'shape (b,h,t,dh)':>20} {'g':>2} {'dense ms':>9} "
           f"{'packed ms':>10} {'speedup':>8} {'dense MXU%':>11} "
-          f"{'packed MXU%':>12}")
+          f"{'packed MXU%':>12} {'kernel fwd/bwd us':>18} "
+          f"{'tiles (of)':>12} {'us a tile':>12}")
 
     rng = np.random.RandomState(0)
     for (b, h, t, dh) in shapes:
@@ -149,9 +186,17 @@ def main():
                 return "n/a"
             return f"{100.0 * flops / dt / peak:.1f}"
 
+        tiles = b // rows_a_tile(b, t, t) * (h // g)
+        kernel = a_tile = "n/a"
+        if on_tpu:
+            fwd_us, bwd_us = _kernel_us(
+                jax.grad(loss_packed, argnums=(0, 1, 2)), q, k, v)
+            kernel = f"{fwd_us:.0f}/{bwd_us:.0f}"
+            a_tile = f"{fwd_us / tiles:.3f}/{bwd_us / tiles:.3f}"
         print(f"{str((b, h, t, dh)):>20} {g:>2} {td * 1e3:>9.3f} "
               f"{tp * 1e3:>10.3f} {td / tp:>8.2f} {share(td):>11} "
-              f"{share(tp):>12}")
+              f"{share(tp):>12} {kernel:>18} "
+              f"{f'{tiles} ({b * (h // g)})':>12} {a_tile:>12}")
 
 
 if __name__ == "__main__":

@@ -205,8 +205,11 @@ CASES = {
         lambda: _attn(_PACKED, 2, PACKED_CAP, PACKED_CAP, False, False),
     f"packed-grad-t{PACKED_CAP}-causal":
         lambda: _attn(_PACKED, 2, PACKED_CAP, PACKED_CAP, True, True),
-    # big.train's own batches (rows x width at 4096 words): a cell is a
-    # block of rows x all 16 heads, the rows a divisor of the batch's
+    # big.train's own batches (rows x width at 4096 words), at their own
+    # width: a cell is a block of rows x all 16 heads, the rows a divisor
+    # of the batch's; a tile takes 64 // width of them, their 8- to
+    # 48-row slabs set one under the other in VMEM and stored back in
+    # parts
     "packed-grad-512x8-mask": lambda: _attn(_PACKED, 512, 8, 8, False, True),
     "packed-grad-256x16-causal":
         lambda: _attn(_PACKED, 256, 16, 16, True, True),
@@ -217,6 +220,12 @@ CASES = {
     "packed-grad-80x48-mask": lambda: _attn(_PACKED, 80, 48, 48, False, True),
     "packed-grad-64x64-causal":
         lambda: _attn(_PACKED, 64, 64, 64, True, True),
+    # cross attention inside one tile, Tq != Tk: two rows of 24 queries
+    # against their 16 keys each; four of 16 against 8
+    "packed-grad-168x24x16-cross":
+        lambda: _attn(_PACKED, 168, 24, 16, False, True),
+    "packed-grad-256x16x8-cross":
+        lambda: _attn(_PACKED, 256, 16, 8, False, True),
     # cross attention past one pad (Tq 72 -> 128, Tk 40 -> 64), 45 rows:
     # no multiple of 8, cells of 5 or 3
     "packed-grad-45x72x40-cross":

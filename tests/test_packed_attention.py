@@ -21,7 +21,8 @@ from marian_tpu.ops.attention import (attention, causal_mask, combine_masks,
                                       dense_attention)
 from marian_tpu.ops.pallas.packed_attention import (cell_plan, cell_vmem,
                                                     pack_group,
-                                                    packed_attention)
+                                                    packed_attention,
+                                                    rows_a_tile)
 
 from tests.time_limit import time_limit
 
@@ -36,18 +37,25 @@ def _forget_plans():
     pa._bwd_call.clear_cache()
 
 
+def _kernel_t(t):
+    """The width a sequence of t positions reaches the kernel at: its
+    own, as a multiple of 8, up to 64; multiples of 64 past it."""
+    return -(-t // 8) * 8 if t <= 64 else -(-t // 64) * 64
+
+
 @pytest.fixture
 def rows_a_cell(monkeypatch):
     """Hold the kernel's cells to at most a given number of rows (all
-    heads) for a [b, h, t, dh] f32 call by shrinking the budget cell_plan
-    works to — the only way to several cells at test sizes."""
-    def hold(rows, h, t, dh, heads=None, backward=True):
+    heads) for a [b, h, t, dh] f32 call whose tiles hold r rows each, by
+    shrinking the budget cell_plan works to — the only way to several
+    cells at test sizes."""
+    def hold(rows, h, t, dh, heads=None, backward=True, r=1):
         g = pack_group(h, dh)
-        tp = -(-t // 64) * 64
+        tp = _kernel_t(t)
         monkeypatch.setattr(pa, "_CELL_BUDGET", cell_vmem(
-            rows, heads or h, g, tp, tp, dh, 4, backward))
+            rows, heads or h, g, tp, tp, dh, 4, backward, r))
         _forget_plans()
-        assert cell_plan(4 * rows, h, tp, tp, dh, 4, backward) == (
+        assert cell_plan(4 * rows, h, tp, tp, dh, 4, backward, r=r) == (
             rows, heads or h)
     yield hold
     _forget_plans()
@@ -202,8 +210,9 @@ def test_packed_gradients_with_padding(rng):
                                    rtol=1e-4, atol=1e-4)
 
 
-def test_bf16_inputs(rng):
-    b, h, t, dh = 2, 4, 64, 64
+@pytest.mark.parametrize("b,t", [(2, 64), (8, 16), (4, 24)])
+def test_bf16_inputs(rng, b, t):
+    h, dh = 4, 64
     q = jnp.asarray(rng.randn(b, h, t, dh), jnp.bfloat16)
     k = jnp.asarray(rng.randn(b, h, t, dh), jnp.bfloat16)
     v = jnp.asarray(rng.randn(b, h, t, dh), jnp.bfloat16)
@@ -230,9 +239,187 @@ def test_packed_under_jit(rng):
                                rtol=2e-5, atol=2e-5)
 
 
+# ---- what fills a tile (PR 31): 64 // T rows under a row mask ----
+
+def test_rows_a_tile():
+    """64 // max(Tq, Tk), reduced to a divisor of the batch; one row
+    past 64 positions and at a prime batch."""
+    assert [rows_a_tile(b, t, t) for b, t in (
+        (512, 8), (256, 16), (168, 24), (128, 32), (80, 48), (64, 64),
+        (45, 128))] == [8, 4, 2, 2, 1, 1, 1]
+    assert [rows_a_tile(b, 16, 16) for b in (6, 7, 9, 10, 12)] == [
+        3, 1, 3, 2, 4]
+    assert rows_a_tile(5, 8, 8) == 5 and rows_a_tile(11, 8, 8) == 1
+    assert rows_a_tile(8, 24, 16) == 2 and rows_a_tile(8, 16, 32) == 2
+    assert rows_a_tile(8, 8, 40) == 1 and rows_a_tile(8, 72, 8) == 1
+
+
+def _dense_and_packed(rng, b, h, tq, tk, dh, causal, m=None):
+    """Output and q/k/v gradients of (packed, dense) under one cotangent."""
+    q, k, v, do = (_rand(rng, b, h, t, dh) for t in (tq, tk, tk, tq))
+    m = _kv_mask(rng, b, tk) if m is None else m
+    dense_mask = combine_masks(causal_mask(tq) if causal else None,
+                               m[:, None, None, :])
+    out_p, vjp_p = jax.vjp(
+        lambda q, k, v: packed_attention(q, k, v, kv_mask=m, causal=causal),
+        q, k, v)
+    out_d, vjp_d = jax.vjp(
+        lambda q, k, v: dense_attention(q, k, v, mask=dense_mask), q, k, v)
+    return (out_p, *vjp_p(do)), (out_d, *vjp_d(do))
+
+
+@pytest.mark.parametrize("b,tq,tk,causal,h,dh,cell", [
+    # big.train's six batches, scaled down in rows: 8, 4, 2, 2, 1, 1 rows
+    # a tile; encoder (key mask) and decoder (causal) each
+    (16, 8, 8, False, 4, 64, None), (8, 8, 8, True, 4, 64, None),
+    (8, 16, 16, False, 4, 64, None), (8, 16, 16, True, 4, 64, None),
+    (4, 24, 24, False, 4, 64, None), (4, 24, 24, True, 4, 64, None),
+    (4, 32, 32, False, 4, 64, None), (4, 32, 32, True, 4, 64, None),
+    (2, 48, 48, False, 4, 64, None), (2, 48, 48, True, 4, 64, None),
+    (2, 64, 64, False, 4, 64, None), (2, 64, 64, True, 4, 64, None),
+    # cross attention, Tq != Tk, inside one tile
+    (4, 24, 16, False, 4, 64, None), (4, 16, 32, False, 4, 64, None),
+    (8, 8, 16, False, 4, 64, None),
+    # a width that is no multiple of 8 (20 -> 24, 2 rows a tile; 13 x 9)
+    (4, 20, 20, True, 4, 64, None), (4, 13, 9, False, 4, 64, None),
+    # g 4 and g 8
+    (8, 16, 16, True, 8, 32, None), (4, 32, 32, False, 8, 32, None),
+    (8, 16, 16, False, 8, 16, None), (8, 8, 8, True, 8, 16, None),
+    # a batch 64 // T does not divide: 6 -> 3 rows a tile, 7 and 5 -> 1
+    (6, 16, 16, True, 4, 64, None), (7, 16, 16, False, 4, 64, None),
+    (5, 8, 8, True, 4, 64, None),
+    # several cells of one tile group each, and of two
+    (16, 16, 16, True, 4, 64, 4), (16, 16, 16, False, 4, 64, 8),
+    (12, 24, 24, True, 4, 64, 2),
+])
+@time_limit(120)
+def test_folded_tiles_match_dense(rng, rows_a_cell, b, tq, tk, causal, h, dh,
+                                  cell):
+    """Forward and all three gradients against the dense path where a
+    tile holds several rows at their own width."""
+    if cell:
+        rows_a_cell(cell, h, max(tq, tk), dh,
+                    r=rows_a_tile(4 * cell, tq, tk))
+    got, want = _dense_and_packed(rng, b, h, tq, tk, dh, causal)
+    np.testing.assert_allclose(np.asarray(got[0]), np.asarray(want[0]),
+                               rtol=2e-5, atol=2e-5)
+    for a, d in zip(got[1:], want[1:]):
+        assert a.shape == d.shape
+        np.testing.assert_allclose(np.asarray(a), np.asarray(d),
+                                   rtol=1e-4, atol=1e-4)
+
+
+def _call_both(q, k, v, m, do, causal):
+    """The kernel pair itself on a folded batch: (out, dq, dk, dv)."""
+    b, h, t, dh = q.shape
+    g, kvm, scale = pack_group(h, dh), m[:, None, :], 1.0 / dh ** 0.5
+    out = pa._fwd_call(q, k, v, kvm, scale, causal, g, True)
+    return (out, *pa._bwd_call(q, k, v, kvm, do, out, scale, causal, g, True))
+
+
+@pytest.mark.parametrize("t,causal,dtype", [
+    (16, False, "float32"), (16, True, "bfloat16"), (8, True, "float32"),
+    (24, False, "bfloat16"), (32, True, "float32")])
+@time_limit(120)
+def test_no_leakage_between_the_rows_of_a_tile(rng, t, causal, dtype):
+    """Changing one row's k, v, key mask, q or cotangent leaves every
+    other row of its tile bit for bit the same: output, dq, dk and dv.
+    Cross-row probabilities are exact zeros, not small numbers."""
+    b, h, dh, dt = 64 // t * 2, 4, 64, jnp.dtype(dtype)
+    r = rows_a_tile(b, t, t)
+    assert r == 64 // t and r >= 2
+    q, k, v, do = (jnp.asarray(rng.randn(b, h, t, dh), dt) for _ in range(4))
+    m = _kv_mask(rng, b, t)
+    base = _call_both(q, k, v, m, do, causal)
+    j = 1                                   # inside the first tile
+    others = np.array([i for i in range(b) if i != j])
+    changed = {
+        "k": (q, k.at[j].set(7.0 * k[j] + 3.0), v, m, do),
+        "v": (q, k, v.at[j].set(-5.0 * v[j] + 100.0), m, do),
+        "mask": (q, k, v, m.at[j].set(1.0 - m[j]).at[j, 0].set(1.0), do),
+        "all masked": (q, k, v, m.at[j].set(0.0), do),
+        "q, do": (q.at[j].set(9.0 * q[j]), k, v, m, do.at[j].set(-do[j])),
+    }
+    for what, args in changed.items():
+        got = _call_both(*args, causal)
+        assert not np.array_equal(np.asarray(got[0][j], np.float32),
+                                  np.asarray(base[0][j], np.float32)), what
+        for a, ref in zip(got, base):
+            a, ref = np.asarray(a, np.float32), np.asarray(ref, np.float32)
+            assert np.isfinite(a).all(), what
+            np.testing.assert_array_equal(a[others], ref[others],
+                                          err_msg=what)
+
+
+@pytest.mark.parametrize("t,causal", [(16, False), (16, True), (24, False),
+                                      (8, True)])
+@time_limit(120)
+def test_a_row_with_every_key_masked_leaves_its_neighbours_exact(rng, t,
+                                                                 causal):
+    """A row whose keys are all masked (its own output is uniform
+    attention, which callers discard) does not move its tile's other
+    rows off the dense path, forward or backward, and is finite itself."""
+    b, h, dh = 64 // t * 2, 4, 64
+    m = _kv_mask(rng, b, t).at[1].set(0.0).at[b - 1].set(0.0)
+    got, want = _dense_and_packed(rng, b, h, t, t, dh, causal, m=m)
+    live = np.array([i for i in range(b) if i not in (1, b - 1)])
+    for a, d, tol in zip(got, want, (2e-5, 1e-4, 1e-4, 1e-4)):
+        assert np.isfinite(np.asarray(a)).all()
+        np.testing.assert_allclose(np.asarray(a)[live], np.asarray(d)[live],
+                                   rtol=tol, atol=tol)
+
+
+@pytest.mark.parametrize("b,t", [(8, 16), (4, 24), (6, 16), (2, 48)])
+@time_limit(120)
+def test_folded_equals_one_row_a_tile(rng, monkeypatch, b, t):
+    """The fold against the same kernel held to one row a tile (its own
+    width, zero rows up to 64): the same numbers to rounding."""
+    h, dh = 4, 64
+    q, k, v, do = (_rand(rng, b, h, t, dh) for _ in range(4))
+    m = _kv_mask(rng, b, t)
+    folded = _call_both(q, k, v, m, do, True)
+    monkeypatch.setattr(pa, "rows_a_tile", lambda b, tq, tk: 1)
+    _forget_plans()
+    alone = _call_both(q, k, v, m, do, True)
+    _forget_plans()
+    for a, ref in zip(folded, alone):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(ref),
+                                   rtol=1e-5, atol=1e-5)
+
+
+@time_limit(60)
+def test_the_plan_is_a_trace_event(rng):
+    """Once per distinct call, forward and backward: how many rows share
+    a tile and how many tiles that leaves."""
+    from marian_tpu import obs
+    b, h, t, dh = 8, 4, 16, 64
+    q, k, v = (_rand(rng, b, h, t, dh) for _ in range(3))
+    _forget_plans()
+    obs.TRACER.reset()
+    obs.TRACER.enable()
+    try:
+        for _ in range(2):                  # the second call is not traced
+            jax.grad(lambda q: packed_attention(q, k, v).sum())(q)
+        _, events = obs.TRACER.snapshot()
+    finally:
+        obs.TRACER.disable()
+        obs.TRACER.reset()
+        _forget_plans()
+    plans = [e["attrs"] for e in events
+             if e["name"] == "packed_attention.plan"]
+    assert plans == [
+        dict(b=8, tq=16, tk=16, backward=backward, rows_a_tile=4, tiles=4,
+             tiles_unfolded=16) for backward in (False, True)]
+
+
 # ---- the cell: cell_plan alone, and the kernel against one tile a step ----
 
 _PLAN_SHAPES = [  # (b, h, tq, tk, dh, itemsize)
+    # big.train's batches at their own width: 8, 4, 2, 2, 1 rows a tile
+    (512, 16, 8, 8, 64, 2), (256, 16, 16, 16, 64, 2),
+    (168, 16, 24, 24, 64, 2), (128, 16, 32, 32, 64, 2),
+    (80, 16, 48, 48, 64, 2), (168, 16, 24, 16, 64, 2),
+    (6, 8, 16, 16, 32, 4), (7, 8, 16, 16, 16, 4), (30, 16, 8, 8, 64, 2),
     (512, 16, 64, 64, 64, 2), (128, 16, 64, 64, 64, 2),
     (168, 16, 64, 64, 64, 2), (3, 16, 64, 64, 64, 2),
     (45, 16, 128, 64, 64, 2), (2, 16, 256, 256, 64, 2),
@@ -245,22 +432,47 @@ _PLAN_SHAPES = [  # (b, h, tq, tk, dh, itemsize)
 @time_limit(30)
 def test_cell_plan_fits_its_budget(shape, backward):
     """Never zero rows or heads, rows that divide the batch (no ragged
-    last cell), whole head groups that divide the heads, and within the
-    budget unless the cell is already the floor (1, g)."""
+    last cell) and are whole tiles (a multiple of the rows a tile),
+    whole head groups that divide the heads, and within the budget
+    unless the cell is already the floor (r, g)."""
     b, h, tq, tk, dh, itemsize = shape
-    g = pack_group(h, dh)
+    g, r = pack_group(h, dh), rows_a_tile(b, tq, tk)
     for budget in (0, 1 << 20, 4 << 20, 16 << 20, None, 100 << 20):
         rows, heads = cell_plan(b, h, tq, tk, dh, itemsize, backward,
-                                budget=budget)
-        assert 1 <= rows <= b and b % rows == 0
+                                budget=budget, r=r)
+        assert r <= rows <= b and b % rows == 0 and rows % r == 0
         assert g <= heads <= h and heads % g == 0 and h % heads == 0
-        assert rows == 1 or heads == h      # part of a row only alone
-        vmem = cell_vmem(rows, heads, g, tq, tk, dh, itemsize, backward)
+        assert rows == r or heads == h      # part of a row only alone
+        vmem = cell_vmem(rows, heads, g, tq, tk, dh, itemsize, backward, r)
         assert (vmem <= (pa._CELL_BUDGET if budget is None else budget)
-                or (rows, heads) == (1, g))
+                or (rows, heads) == (r, g))
     assert cell_plan(b, h, tq, tk, dh, itemsize, backward,
-                     budget=0) == (1, g)
+                     budget=0, r=r) == (r, g)
     assert pa._CELL_BUDGET < pa._VMEM_LIMIT
+
+
+@pytest.mark.parametrize("backward", [False, True])
+@pytest.mark.parametrize("h,dh,itemsize", [(16, 64, 2), (8, 64, 2),
+                                           (8, 32, 4), (8, 16, 4)])
+@time_limit(30)
+def test_cell_plan_holds_at_every_width(h, dh, itemsize, backward):
+    """Every width a caller can bring, 8 to the cap, at the trainer's
+    rows (4096 words, a multiple of 8) and at a prime batch: whole tiles,
+    a divisor of the batch, inside the budget, all heads up to 64."""
+    from marian_tpu.ops.auto_tuner import packed_attention_max_t
+    g = pack_group(h, dh)
+    for t in range(8, packed_attention_max_t(dh) + 1, 8):
+        tp = _kernel_t(t)
+        for b in (max(8, 4096 // t // 8 * 8), 13):
+            r = rows_a_tile(b, tp, tp)
+            assert r == (1 if b == 13 else max(
+                x for x in range(1, max(1, 64 // tp) + 1) if b % x == 0))
+            rows, heads = cell_plan(b, h, tp, tp, dh, itemsize, backward,
+                                    r=r)
+            assert rows % r == 0 and b % rows == 0 and h % heads == 0
+            assert tp > 64 or heads == h
+            assert cell_vmem(rows, heads, g, tp, tp, dh, itemsize, backward,
+                             r) <= pa._CELL_BUDGET
 
 
 @pytest.mark.parametrize("backward", [False, True])
@@ -299,27 +511,33 @@ def test_cell_plan_takes_divisors_of_the_batch():
 
 
 def _tile_a_step(q, k, v, kvm, do=None, out=None, *, causal):
-    """Today's geometry before PR 26, kept here and not in the package:
-    one grid step per (row, head group), blocks (1, g, T, dh), the
-    package's own tile functions called once a step."""
+    """The geometry before PR 26, kept here and not in the package: one
+    grid step per (row, head group), blocks (1, g, T, dh), one row a
+    tile, the package's own tile functions called once a step."""
     b, h, tq, dh = q.shape
     tk, g = k.shape[2], pack_group(h, dh)
-    kw = dict(scale=1.0 / dh ** 0.5, causal=causal, g=g, bq=tq, bk=tk, dh=dh)
+    kw = dict(scale=1.0 / dh ** 0.5, g=g, bk=tk, dh=dh)
     qspec = pl.BlockSpec((1, g, tq, dh), lambda r, hg: (r, hg, 0, 0))
     kspec = pl.BlockSpec((1, g, tk, dh), lambda r, hg: (r, hg, 0, 0))
     mspec = pl.BlockSpec((1, 1, tk), lambda r, hg: (r, 0, 0))
 
+    def heads(ref):
+        return pa._heads(ref, 0, 0, g, 1, ref.shape[2])
+
+    def bias(kvm_ref):
+        return pa._bias(kvm_ref[0],
+                        pa._live_pairs(1, tq, tk, tq, tk, causal), tk)
+
     def fwd(q_ref, k_ref, v_ref, kvm_ref, o_ref):
-        o = pa._fwd_tile(pa._heads(q_ref, 0, 0, g), pa._heads(k_ref, 0, 0, g),
-                         pa._heads(v_ref, 0, 0, g), kvm_ref[0], **kw)
+        o = pa._fwd_tile(heads(q_ref), heads(k_ref), heads(v_ref),
+                         bias(kvm_ref), **kw)
         for j in range(g):
             o_ref[0, j] = o[j].astype(o_ref.dtype)
 
     def bwd(q_ref, k_ref, v_ref, kvm_ref, do_ref, o_ref, *grads):
-        tiles = pa._bwd_tile(
-            pa._heads(q_ref, 0, 0, g), pa._heads(k_ref, 0, 0, g),
-            pa._heads(v_ref, 0, 0, g), kvm_ref[0],
-            pa._heads(do_ref, 0, 0, g), pa._heads(o_ref, 0, 0, g), **kw)
+        tiles = pa._bwd_tile(heads(q_ref), heads(k_ref), heads(v_ref),
+                             bias(kvm_ref), heads(do_ref), heads(o_ref),
+                             **kw)
         for ref, tile in zip(grads, tiles):
             for j in range(g):
                 ref[0, j] = tile[j].astype(ref.dtype)
@@ -374,7 +592,7 @@ def test_cells_equal_one_tile_a_step_bit_for_bit(rng, monkeypatch, b, h, tq,
             abs(rows), heads or h, g, tq, tk, dh, dt.itemsize, backward))
         _forget_plans()
         assert pa._plan(b, h, tq, tk, dh, dt.itemsize, backward) == (
-            min(abs(rows), b), heads or h)
+            min(abs(rows), b), heads or h, 1)
         if not backward:
             out = pa._fwd_call(q, k, v, kvm, scale, causal, g, True)
             ref = _tile_a_step(q, k, v, kvm, causal=causal)
