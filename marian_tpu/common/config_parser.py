@@ -151,9 +151,13 @@ _MODEL = [
     _f("plan-kda-low-rank", int, 128, "kda: rank of the decay and output-gate projections", "model"),
     _f("plan-kda-head-groups", int, 1, "kda: mix the heads in this many groups, one after another, each rematerialised in the backward: every intermediate is a group wide (memory for time; must divide --transformer-heads)", "model"),
     _f("plan-mla-dim-nope", int, 128, "mla: per-head key channels expanded from the latent", "model"),
-    _f("plan-mla-dim-shared", int, 64, "mla: key channels shared by all heads (not rotated: the plan has no positions)", "model"),
+    _f("plan-mla-dim-shared", int, 64, "mla: key channels shared by all heads (rotated by position where --plan-mla-rope-theta says so)", "model"),
     _f("plan-mla-dim-v", int, 128, "mla: value channels per head", "model"),
     _f("plan-mla-latent", int, 512, "mla: width of the key-value latent", "model"),
+    _f("plan-mla-q-rank", int, 0, "mla: rank of the query's projection, W_qb RMSNorm(W_qa x) (0: one full-rank W_q)", "model"),
+    _f("plan-mla-rope-theta", float, 0.0, "mla: base of the rotation by position of the shared key channels and of their query channels, pairs (2i, 2i+1), float32 angles (0: not rotated, the layer has no positional signal)", "model"),
+    _f("plan-mtp-modules", int, 0, "the last N entries of --transformer-layer-plan are prediction modules after the stack: each joins the hidden state with the next gold token's embedding, runs its block and predicts one token further through the shared output table", "model"),
+    _f("plan-mtp-weight", float, 0.3, "weight of the prediction modules' summed cost beside the main head's (the label count stays the main head's)", "model"),
     _f("plan-experts", int, 0, "experts: the router's width (all experts of the layer, held here or not)", "model"),
     _f("plan-experts-held", int, [], "experts: FIRST COUNT, the experts this process holds and computes (default: all); the rest are other chips' part of the result", "model", "*"),
     _f("plan-experts-top-k", int, 8, "experts: experts per token", "model"),

@@ -119,8 +119,19 @@ class EncoderDecoder:
     @property
     def step_counters(self) -> Tuple[str, ...]:
         """Names of the counts `loss` returns as aux["counters"] (one lazy
-        float32 vector, summed over the layers); () for most families."""
-        return tuple(getattr(self._mod, "COUNTERS", ()))
+        float32 vector): the family's own, summed over its layers, then
+        the summed cost and the labels of each extra head it trains; ()
+        for most families."""
+        return tuple(getattr(self._mod, "COUNTERS", ())) + tuple(
+            f"{name}.{what}" for name in self._head_names
+            for what in ("ce_sum", "labels"))
+
+    @property
+    def _head_names(self) -> Tuple[str, ...]:
+        """The extra weighted heads this model's family trains beside the
+        main one (`decode_train` then returns them last, as data)."""
+        names = getattr(self._mod, "head_names", None)
+        return tuple(names(self.cfg)) if names else ()
 
     @property
     def beam_carried_suffixes(self) -> Tuple[str, ...]:
@@ -172,21 +183,48 @@ class EncoderDecoder:
             moe_aux = moe_aux + parts.pop(0)
         # a family that counts inside the step (its COUNTERS names the
         # entries) hands the counts back as one lazy vector
-        counters = parts.pop(0) if self.step_counters else None
-        if table is not None and not (self.unlikelihood
-                                      and "data_weights" in batch):
-            # output projection and loss are ONE streaming kernel here
-            with jax.named_scope("loss"):
-                rl = self._fused_ce_loss(cparams, table, hidden, batch)
-        else:
-            if table is not None:      # fused path skipped for unlikelihood
+        counters = parts.pop(0) if hasattr(self._mod, "COUNTERS") else None
+        heads = parts.pop(0) if self._head_names else ()
+        fused = table is not None and not (self.unlikelihood
+                                           and "data_weights" in batch)
+        dw = batch.get("data_weights")
+
+        def cost(hidden, ids, mask, dw, was_projected=False):
+            """Summed cost and label count of one head: its hidden states
+            (or, `was_projected`, its logits) against ids under mask."""
+            if fused:
+                # output projection and loss are ONE streaming kernel here
+                with jax.named_scope("loss"):
+                    return self._fused_ce_loss(cparams, table, hidden, ids,
+                                               mask, dw)
+            if not was_projected:
                 hidden = self._mod.output_logits(self.cfg, cparams, hidden)
-            rl = cross_entropy_loss(hidden, batch["trg_ids"],
-                                    batch["trg_mask"], self.label_smoothing,
-                                    batch.get("data_weights"),
-                                    unlikelihood=self.unlikelihood)
+            return cross_entropy_loss(hidden, ids, mask,
+                                      self.label_smoothing, dw,
+                                      unlikelihood=self.unlikelihood)
+        rl = cost(hidden, batch["trg_ids"], batch["trg_mask"], dw,
+                  was_projected=table is None)
         total = rl.loss_sum
         aux = {"ce_sum": rl.loss_sum, "labels": rl.labels}
+        # a family's extra heads join as weighted sums over their own
+        # labels; the label count stays the main head's
+        sums = {name: jnp.zeros((2,), jnp.float32)
+                for name in self._head_names}
+        for head in heads:
+            # a token's weight follows it to where it is the label
+            hw = None if dw is None else jnp.roll(
+                jnp.broadcast_to(dw, batch["trg_mask"].shape), -head.shift,
+                axis=1)
+            with jax.named_scope(head.name), \
+                    jax.named_scope(f"{head.name}.loss"):
+                hl = cost(head.hidden, head.ids, head.mask, hw)
+            total = total + head.weight * hl.loss_sum
+            sums[head.name] = sums[head.name] + jnp.stack(
+                [hl.loss_sum, hl.labels])
+        if sums:
+            counters = jnp.concatenate(
+                [counters] + [jax.lax.stop_gradient(v)
+                              for v in sums.values()])
         if counters is not None:
             aux["counters"] = counters
         if moe and getattr(self.cfg, "moe_aux_weight", 0.0) > 0:
@@ -228,7 +266,8 @@ class EncoderDecoder:
             return None
         return self._mod._plain_output_table(cfg, cparams)
 
-    def _fused_ce_loss(self, cparams, table, hidden, batch) -> RationalLoss:
+    def _fused_ce_loss(self, cparams, table, hidden, ids, mask, dw
+                       ) -> RationalLoss:
         """Label-smoothed CE straight from decoder hidden states — logits
         blocks live only in VMEM (same numbers as cross_entropy_loss of
         output_logits; see fused_ce.py docstring for the algebra)."""
@@ -239,13 +278,11 @@ class EncoderDecoder:
                 else jnp.zeros((table.shape[0],), hidden.dtype))
         ce = fused_softmax_xent(
             hidden.reshape(b * t, e), table, bias,
-            batch["trg_ids"].reshape(-1), self.label_smoothing,
+            ids.reshape(-1), self.label_smoothing,
             interpret=None if self._fused_ce_mode == "auto" else
             (jax.default_backend() != "tpu"))
         ce = ce.reshape(b, t)
-        mask = batch["trg_mask"]
         w = mask.astype(jnp.float32)
-        dw = batch.get("data_weights")
         if dw is not None:
             w = w * jnp.broadcast_to(dw.astype(jnp.float32), w.shape)
         return RationalLoss(jnp.sum(ce * w),

@@ -8,17 +8,37 @@ its feed-forward kind, as data:
                          (ops/kda.py, ops/pallas/kda_chunk.py)
                 mla      latent attention: keys and values expanded from
                          one low-rank latent, plus key channels shared
-                         by all heads; causal softmax, no rotation
+                         by all heads; causal softmax. Two of its sizes
+                         are optional: a low-rank query with a norm of
+                         its own (--plan-mla-q-rank; 0 = one full-rank
+                         W_q) and a rotation of the shared key channels
+                         and their query channels by position
+                         (--plan-mla-rope-theta; 0 = not rotated)
   feed-forward  dense    gated MLP, W_d(SiLU(W_g x) * W_u x)
                 experts  a router over all experts, the held ones
                          computed without dropping (ops/experts.py),
                          plus shared experts on every token
 
-The block is pre-norm with RMSNorm (scale only) and a residual add; no
-positional signal anywhere; input and output tables untied; a final
-RMSNorm before the output projection. It is the family of Kimi Linear
-(https://huggingface.co/moonshotai/Kimi-Linear-48B-A3B-Instruct); the
-sizes come from flags, nothing here knows a model's name.
+The block is pre-norm with RMSNorm (scale only) and a residual add;
+input and output tables untied; a final RMSNorm before the output
+projection. The only positional signal is the rotation inside `mla`,
+where it is asked for: the delta rule has none, and a plan without a
+rotated layer has none anywhere.
+
+The last --plan-mtp-modules entries of the plan are not layers of the
+stack but PREDICTION MODULES that run after it (`_predict_ahead`):
+module k joins the normed hidden state of what came before it with the
+normed embedding of the gold token k places on, projects the pair back
+to the model's width, runs its one block and, through a norm of its own
+and the SHARED output table, predicts the token after that one. Each is
+one more weighted head of the cost (models/encoder_decoder.py), trained
+and counted beside the main head and never part of the label count.
+
+These are the families of Kimi Linear
+(https://huggingface.co/moonshotai/Kimi-Linear-48B-A3B-Instruct) and of
+DeepSeek-V3 (arXiv:2412.19437, 2.1.1 latent attention, 2.2 multi-token
+prediction); the sizes come from flags, nothing here knows a model's
+name.
 
 The module is one more function family behind models/encoder_decoder.py
 (`init_params`, `encode`, `decode_train`, `output_logits`), next to
@@ -33,7 +53,7 @@ from __future__ import annotations
 import dataclasses
 import math
 from functools import partial
-from typing import Dict, Optional, Tuple
+from typing import Dict, NamedTuple, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -70,6 +90,11 @@ class PlanConfig(T.TransformerConfig):
     mla_dim_shared: int = 64          # key channels shared by all heads
     mla_dim_v: int = 128
     mla_latent: int = 512
+    mla_q_rank: int = 0               # 0: one full-rank W_q
+    mla_rope_theta: float = 0.0       # 0: the shared channels not rotated
+    # prediction modules: the plan's last entries, after dec_depth layers
+    mtp_modules: int = 0
+    mtp_weight: float = 0.3
     # experts
     experts: int = 0                  # the router's width
     experts_top_k: int = 8
@@ -78,6 +103,17 @@ class PlanConfig(T.TransformerConfig):
     experts_scale: float = 1.0
     experts_first: int = 0            # the held set: first, count
     experts_held: int = 0
+
+
+def _blocks(cfg: PlanConfig):
+    """(parameter prefix, (mixing, feed-forward)) of every block of the
+    plan: the stack's dec_depth layers, then the prediction modules' one
+    block each."""
+    n = cfg.dec_depth
+    return [(f"decoder_l{l}", kinds)
+            for l, kinds in enumerate(cfg.plan[:n], 1)] \
+        + [(f"decoder_mtp{k}", kinds)
+           for k, kinds in enumerate(cfg.plan[n:], 1)]
 
 
 def parse_plan(spec) -> Tuple[Tuple[str, str], ...]:
@@ -110,10 +146,18 @@ def config_from_options(options, src_vocab, trg_vocab, for_inference=False,
     if groups < 1 or base.heads % groups:
         raise ValueError(f"--plan-kda-head-groups {groups} does not divide "
                          f"--transformer-heads {base.heads}")
+    ahead = int(g("plan-mtp-modules", 0) or 0)
+    if not 0 <= ahead < max(len(plan), 1):
+        raise ValueError(f"--plan-mtp-modules {ahead}: the last entries of "
+                         f"a plan of {len(plan)}, less than all of them")
+    theta = float(g("plan-mla-rope-theta", 0.0) or 0.0)
+    if theta and int(g("plan-mla-dim-shared", 64)) % 2:
+        raise ValueError("--plan-mla-rope-theta rotates channel pairs: "
+                         "--plan-mla-dim-shared must be even")
     fields = {f.name: getattr(base, f.name)
               for f in dataclasses.fields(base)}
     fields.update(
-        lm=True, dec_depth=len(plan), tied_embeddings=False,
+        lm=True, dec_depth=len(plan) - ahead, tied_embeddings=False,
         tied_embeddings_all=False, tied_embeddings_src=False,
         output_omit_bias=True)
     return PlanConfig(
@@ -127,6 +171,9 @@ def config_from_options(options, src_vocab, trg_vocab, for_inference=False,
         mla_dim_shared=int(g("plan-mla-dim-shared", 64)),
         mla_dim_v=int(g("plan-mla-dim-v", 128)),
         mla_latent=int(g("plan-mla-latent", 512)),
+        mla_q_rank=int(g("plan-mla-q-rank", 0) or 0),
+        mla_rope_theta=theta,
+        mtp_modules=ahead, mtp_weight=float(g("plan-mtp-weight", 0.3)),
         experts=n_experts,
         experts_top_k=int(g("plan-experts-top-k", 8)),
         experts_dim_ffn=int(g("plan-experts-dim-ffn", 1024)),
@@ -141,7 +188,8 @@ def config_from_options(options, src_vocab, trg_vocab, for_inference=False,
 
 def init_params(cfg: PlanConfig, key: jax.Array) -> Params:
     p: Params = {}
-    keys = iter(jax.random.split(key, 64 * max(len(cfg.plan), 1) + 8))
+    blocks = _blocks(cfg)
+    keys = iter(jax.random.split(key, 64 * max(len(blocks), 1) + 8))
     d, h = cfg.dim_emb, cfg.heads
 
     def glorot(*shape, **kw):
@@ -153,8 +201,7 @@ def init_params(cfg: PlanConfig, key: jax.Array) -> Params:
     p["decoder_Wemb"] = glorot(cfg.trg_vocab, d)
     p["decoder_ff_logit_out_W"] = glorot(d, cfg.trg_vocab)
     p["decoder_top_norm_scale"] = ones(d)
-    for l, (mix, ffn) in enumerate(cfg.plan, 1):
-        lp = f"decoder_l{l}"
+    for lp, (mix, ffn) in blocks:
         p[f"{lp}_mix_norm_scale"] = ones(d)
         p[f"{lp}_ffn_norm_scale"] = ones(d)
         if mix == "kda":
@@ -183,7 +230,12 @@ def init_params(cfg: PlanConfig, key: jax.Array) -> Params:
             p[f"{lp}_kda_Wo"] = glorot(h * dh, d)
         else:
             dq = cfg.mla_dim_nope + cfg.mla_dim_shared
-            p[f"{lp}_mla_Wq"] = glorot(d, h * dq)
+            if cfg.mla_q_rank:
+                p[f"{lp}_mla_Wqa"] = glorot(d, cfg.mla_q_rank)
+                p[f"{lp}_mla_q_norm_scale"] = ones(cfg.mla_q_rank)
+                p[f"{lp}_mla_Wqb"] = glorot(cfg.mla_q_rank, h * dq)
+            else:
+                p[f"{lp}_mla_Wq"] = glorot(d, h * dq)
             p[f"{lp}_mla_Wkva"] = glorot(d, cfg.mla_latent
                                          + cfg.mla_dim_shared)
             p[f"{lp}_mla_kv_norm_scale"] = ones(cfg.mla_latent)
@@ -206,6 +258,11 @@ def init_params(cfg: PlanConfig, key: jax.Array) -> Params:
                 p[f"{lp}_shared_Wg"] = glorot(d, fs)
                 p[f"{lp}_shared_Wu"] = glorot(d, fs)
                 p[f"{lp}_shared_Wd"] = glorot(fs, d)
+    for lp, _ in blocks[cfg.dec_depth:]:
+        p[f"{lp}_emb_norm_scale"] = ones(d)
+        p[f"{lp}_hidden_norm_scale"] = ones(d)
+        p[f"{lp}_Weh"] = glorot(2 * d, d)
+        p[f"{lp}_top_norm_scale"] = ones(d)
     return p
 
 
@@ -300,20 +357,54 @@ def _kda(cfg: PlanConfig, p: Params, lp: str, x):
         return out.astype(x.dtype)
 
 
+def rope_angles(length: int, dim: int, theta: float):
+    """[length, dim] float32: position t times theta^(-2i / dim) for the
+    channel pair i = (2i, 2i + 1), each pair's angle under both of its
+    channels. Integer positions times float32 rates: past 256 positions
+    a bfloat16 angle is no longer its position's."""
+    rate = theta ** (-jnp.arange(0, dim, 2, dtype=jnp.float32) / dim)
+    return jnp.repeat(jnp.arange(length, dtype=jnp.float32)[:, None]
+                      * rate[None, :], 2, axis=-1)
+
+
+def _rotate(x, angles):
+    """x [..., T, dim] with the pair (2i, 2i + 1) at position t turned by
+    angles[t, 2i], in float32: (a, b) -> (a cos - b sin, a sin + b cos).
+    The pair's other channel comes from a roll along the channels, so
+    nothing is reshaped to pairs."""
+    f = x.astype(jnp.float32)
+    even = jnp.arange(x.shape[-1]) % 2 == 0
+    other = jnp.where(even, -jnp.roll(f, -1, axis=-1),
+                      jnp.roll(f, 1, axis=-1))
+    return (f * jnp.cos(angles) + other * jnp.sin(angles)).astype(x.dtype)
+
+
 def _mla(cfg: PlanConfig, p: Params, lp: str, x, mask):
     h, dn, dv = cfg.heads, cfg.mla_dim_nope, cfg.mla_dim_v
     with jax.named_scope("mla"):
-        q = _heads(jnp.dot(x, p[f"{lp}_mla_Wq"]), h)
+        if cfg.mla_q_rank:
+            low = rms_norm(jnp.dot(x, p[f"{lp}_mla_Wqa"]),
+                           p[f"{lp}_mla_q_norm_scale"], eps=cfg.norm_eps)
+            q = _heads(jnp.dot(low, p[f"{lp}_mla_Wqb"]), h)
+        else:
+            q = _heads(jnp.dot(x, p[f"{lp}_mla_Wq"]), h)
         kva = jnp.dot(x, p[f"{lp}_mla_Wkva"])
         latent = rms_norm(kva[..., :cfg.mla_latent],
                           p[f"{lp}_mla_kv_norm_scale"], eps=cfg.norm_eps)
         shared = kva[..., cfg.mla_latent:]
+        t = x.shape[1]
+        if cfg.mla_rope_theta:
+            with jax.named_scope("mla.rope"):
+                angles = rope_angles(t, cfg.mla_dim_shared,
+                                     cfg.mla_rope_theta)
+                q = jnp.concatenate(
+                    [q[..., :dn], _rotate(q[..., dn:], angles)], axis=-1)
+                shared = _rotate(shared, angles)
         kv = _heads(jnp.dot(latent, p[f"{lp}_mla_Wkvb"]), h)
         k = jnp.concatenate(
             [kv[..., :dn], jnp.broadcast_to(
                 shared[:, None], (*kv.shape[:3], cfg.mla_dim_shared))],
             axis=-1)
-        t = x.shape[1]
         o, _ = attention(q, k, kv[..., dn:],
                          mask=causal_mask(t) * mask[:, None, None, :],
                          kv_mask=mask, causal=True,
@@ -393,6 +484,56 @@ def encode(cfg, params, src_ids, src_mask, train=False, key=None):
     return None
 
 
+class Head(NamedTuple):
+    """One more head of the cost, as data: `hidden` [B, T, d] goes
+    through the output table like the main head's, against `ids` under
+    `mask`; its summed cost joins the main head's times `weight` and is
+    counted as `<name>.ce_sum` / `<name>.labels`. `shift` says how far
+    its labels were rolled left (a per-token weight follows its label)."""
+    name: str
+    weight: float
+    hidden: jax.Array
+    ids: jax.Array
+    mask: jax.Array
+    shift: int
+
+
+def head_names(cfg: PlanConfig) -> Tuple[str, ...]:
+    return ("mtp",) if cfg.mtp_modules else ()
+
+
+def _predict_ahead(cfg: PlanConfig, params: Params, x, emb, trg_ids, mask,
+                   remat):
+    """The prediction modules, one after another: module k's position t
+    has seen y_<t+k-1 through `x` (the stack's output before its top
+    norm, or the module's before it) and is GIVEN the gold y_{t+k-1},
+    u = W_eh [RMSNorm(e(y_{t+k-1})) ; RMSNorm(x_t)]; one block over u; a
+    norm of its own; its label is y_{t+k}. Everything keeps the stack's
+    width T: the gold tokens are rolled left, and what rolled round the
+    end, or lies past a row's last token, is masked out of the module's
+    attention and of its labels. Returns ([Head], counters)."""
+    heads = []
+    counters = jnp.zeros((len(COUNTERS),), jnp.float32)
+    for k, (lp, kinds) in enumerate(_blocks(cfg)[cfg.dec_depth:], 1):
+        # position t is given y_{t+k-1} = emb[t+k-1] and keeps what can
+        # still have a label k places on
+        live = jnp.roll(mask, -k, axis=1).at[:, -k:].set(0.0)
+        given = jnp.roll(emb, 1 - k, axis=1)
+        u = jnp.concatenate(
+            [rms_norm(given, params[f"{lp}_emb_norm_scale"],
+                      eps=cfg.norm_eps),
+             rms_norm(x, params[f"{lp}_hidden_norm_scale"],
+                      eps=cfg.norm_eps)], axis=-1)
+        x, c = _layer(cfg, kinds, lp, params,
+                      jnp.dot(u, params[f"{lp}_Weh"]), live, remat)
+        counters = counters + c
+        heads.append(Head(
+            "mtp", cfg.mtp_weight,
+            rms_norm(x, params[f"{lp}_top_norm_scale"], eps=cfg.norm_eps),
+            jnp.roll(trg_ids, -k, axis=1), live, k))
+    return heads, counters
+
+
 def decode_train(cfg: PlanConfig, params: Params, enc_out, src_mask,
                  trg_ids, trg_mask, train: bool = True,
                  key: Optional[jax.Array] = None,
@@ -400,22 +541,31 @@ def decode_train(cfg: PlanConfig, params: Params, enc_out, src_mask,
                  return_hidden: bool = False):
     """Teacher-forced: [B, T] gold ids -> ([B, T, V] logits, or the
     hidden states before the output projection when return_hidden;
-    counters [len(COUNTERS)]). The input is the gold embeddings shifted
-    right with a zero vector first, as transformer.decode_train's."""
+    counters [len(COUNTERS)]; with prediction modules in the plan, the
+    list of their Heads last, hidden states whatever return_hidden
+    says). The input is the gold embeddings shifted right with a zero
+    vector first, as transformer.decode_train's."""
     if return_alignment:
         raise ValueError("a layer plan has no cross attention to align")
     with jax.named_scope("embed"):
-        x = T.shift_right_embeddings(
-            T._embed_words(cfg, params, trg_ids, "trg"))
+        emb = T._embed_words(cfg, params, trg_ids, "trg")
+        x = T.shift_right_embeddings(emb)
     mask = trg_mask.astype(jnp.float32)
+    remat = cfg.gradient_checkpointing and train
     counters = jnp.zeros((len(COUNTERS),), jnp.float32)
-    for l, kinds in enumerate(cfg.plan, 1):
-        x, c = _layer(cfg, kinds, f"decoder_l{l}", params, x, mask,
-                      remat=cfg.gradient_checkpointing and train)
+    for lp, kinds in _blocks(cfg)[:cfg.dec_depth]:
+        x, c = _layer(cfg, kinds, lp, params, x, mask, remat=remat)
+        counters = counters + c
+    heads = ()
+    if cfg.mtp_modules:
+        with jax.named_scope("mtp"):
+            heads, c = _predict_ahead(cfg, params, x, emb, trg_ids, mask,
+                                      remat)
         counters = counters + c
     x = rms_norm(x, params["decoder_top_norm_scale"], eps=cfg.norm_eps)
-    return (x if return_hidden else T.output_logits(cfg, params, x)), \
-        jax.lax.stop_gradient(counters)
+    out = (x if return_hidden else T.output_logits(cfg, params, x),
+           jax.lax.stop_gradient(counters))
+    return out + (heads,) if cfg.mtp_modules else out
 
 
 output_logits = T.output_logits

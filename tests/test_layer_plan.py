@@ -360,8 +360,11 @@ def test_held_experts_are_the_masked_loop(first, count, pool):
         np.testing.assert_allclose(g_got, g_want, atol=1e-4)
 
 
-def test_the_shares_add_up():
-    """16 experts, two chips' shares of 8: their outputs, with the shared
+@pytest.mark.parametrize("experts,held,width", [(16, 8, 32), (256, 16, 768)],
+                         ids=["2x8-of-16", "16x16-of-256-at-768"])
+def test_the_shares_add_up(experts, held, width):
+    """Every chip's share of the layer (8 of 16 experts; 16 of 256 at
+    expert width 768, one of 16 chips): their outputs, with the shared
     expert counted once, sum to the uncut layer's output; and the routing
     counters of the shares sum to the uncut layer's."""
     x, router, wg, wu, wd, mask, _ = _expert_inputs()
@@ -371,21 +374,31 @@ def test_the_shares_add_up():
     np.testing.assert_allclose(lo + hi, whole, atol=2e-5)
     assert float(c_all[1]) == float(c_all[0]) == 280 * K_
     assert float(c_lo[1] + c_hi[1]) == float(c_all[1])
-    # through the layer, shared expert and all: share + share - shared
-    models = [_plan_model(plan=("mla:experts",), held=h) for h in
-              ((0, 16), (0, 8), (8, 8))]
+    # through the layer, shared expert and all: the shares' sum, less the
+    # shared expert once for every share but one
+    size = ("--plan-experts-dim-ffn", str(width))
+    shares = [(first, held) for first in range(0, experts, held)]
+    models = [_plan_model(size, plan=("mla:experts",), held=h,
+                          experts=experts) for h in [(0, experts)] + shares]
     full = P.init_params(models[0].cfg, jax.random.PRNGKey(3))
+    assert full["decoder_l1_experts_Wg"].shape == (experts, 64, width)
     xs = jax.random.normal(jax.random.PRNGKey(4), (2, 24, 64))
     m2 = jnp.ones((2, 24))
-    outs = []
-    for m, sl in zip(models, (slice(0, 16), slice(0, 8), slice(8, 16))):
-        p = {k: (v[sl] if "_experts_W" in k else v) for k, v in full.items()}
-        outs.append(P._experts(m.cfg, p, "decoder_l1", xs, m2)[0])
+    outs, counts = [], []
+    for m, (first, n) in zip(models, [(0, experts)] + shares):
+        p = {k: (v[first:first + n] if "_experts_W" in k else v)
+             for k, v in full.items()}
+        y, c = P._experts(m.cfg, p, "decoder_l1", xs, m2)
+        outs.append(y)
+        counts.append(c)
     shared = X.gated_mlp(xs.reshape(-1, 64), full["decoder_l1_shared_Wg"],
                          full["decoder_l1_shared_Wu"],
                          full["decoder_l1_shared_Wd"]).reshape(xs.shape)
-    np.testing.assert_allclose(outs[1] + outs[2] - shared, outs[0],
-                               atol=2e-5)
+    np.testing.assert_allclose(
+        sum(outs[1:]) - (len(shares) - 1) * shared, outs[0],
+        atol=2e-5 * len(shares))
+    assert float(sum(c[1] for c in counts[1:])) == float(counts[0][1]) \
+        == 2 * 24 * 4
 
 
 @pytest.mark.parametrize("pool", [0, 64])
