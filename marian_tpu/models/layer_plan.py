@@ -58,11 +58,13 @@ from typing import Dict, NamedTuple, Optional, Tuple
 import jax
 import jax.numpy as jnp
 
+from .. import obs
 from ..layers import initializers as inits
 from ..ops import experts as X
 from ..ops import kda as K
 from ..ops.attention import attention, causal_mask
 from ..ops.ops import rms_norm
+from ..ops.pallas.flash_attention import RESIDUAL_LSE, RESIDUAL_OUT
 from . import transformer as T
 
 Params = Dict[str, jax.Array]
@@ -74,6 +76,9 @@ COUNTERS = X.COUNTERS
 # kept in the optimizer's float32 whatever the compute type: the router
 # decides WHICH experts run, and the decay's rate sits in an exponent
 _FLOAT32_SUFFIXES = ("_experts_router", "_kda_A_log", "_kda_dt_bias")
+# what a checkpointed `mla` half keeps across the backward beside its
+# input: the flash kernel's output and row statistics, by their names
+_MLA_KEEPS = (RESIDUAL_OUT, RESIDUAL_LSE)
 
 
 @dataclasses.dataclass(frozen=True)
@@ -459,18 +464,46 @@ def _feed_forward(cfg: PlanConfig, kind: str, lp: str, p: Params, x, mask):
     return x + out, counters
 
 
+def _named_bytes(f, names, *args) -> int:
+    """Bytes of the values that f's differentiated forward gives one of
+    `names` (jax.ad_checkpoint.checkpoint_name): what a checkpoint of f
+    under save_only_these_names(*names) holds across the backward, and 0
+    where the code that names them is not the path taken."""
+    forward = jax.make_jaxpr(lambda *a: jax.vjp(f, *a)[0])(*args)
+
+    def named(jaxpr):
+        for eqn in jaxpr.eqns:
+            if eqn.primitive.name == "name" and eqn.params["name"] in names:
+                yield from (v.aval for v in eqn.outvars)
+            for sub in jax.core.jaxprs_in_params(eqn.params):
+                yield from named(sub)
+    return sum(a.size * a.dtype.itemsize for a in named(forward.jaxpr))
+
+
 def _layer(cfg: PlanConfig, kinds, lp: str, p: Params, x, mask, remat):
     """One block: x + mixing(norm(x)), then x + feed-forward(norm(x)).
     With `remat` (--gradient-checkpointing, training) each half is
     rematerialised in the backward on its own, so what stays alive
-    between the passes is a layer's input and its middle. KDA mixed in
-    head groups rematerialises itself group by group and is not wrapped
-    again: a second wrap would run its forward a third time."""
+    between the passes is a layer's input and its middle, and of an
+    `mla` half also what only the flash kernel can produce
+    (_MLA_KEEPS): its projections, rotation and concatenates run again
+    in the backward, the kernel does not. Where the dense path runs
+    (short rows, the CPU) nothing bears those names and nothing more is
+    kept. KDA mixed in head groups rematerialises itself group by group
+    and is not wrapped again: a second wrap would run its forward a
+    third time."""
     mix, ffn = kinds
     f_mix = partial(_mix, cfg, mix, lp)
     f_ffn = partial(_feed_forward, cfg, ffn, lp)
     if remat:
-        if not (mix == "kda" and cfg.kda_head_groups > 1):
+        if mix == "mla":
+            if obs.enabled():
+                obs.event("plan.remat_keep", layer=lp, names=_MLA_KEEPS,
+                          bytes=_named_bytes(f_mix, _MLA_KEEPS, p, x, mask))
+            f_mix = jax.checkpoint(
+                f_mix, policy=jax.checkpoint_policies.save_only_these_names(
+                    *_MLA_KEEPS))
+        elif cfg.kda_head_groups == 1:
             f_mix = jax.checkpoint(f_mix)
         f_ffn = jax.checkpoint(f_ffn)
     return f_ffn(p, f_mix(p, x, mask), mask)

@@ -27,12 +27,20 @@ from typing import Optional
 
 import jax
 import jax.numpy as jnp
+from jax.ad_checkpoint import checkpoint_name
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 MASK_VALUE = -1e9       # additive bias for masked scores (matches ops.NEG_INF)
 STATS_INIT = -1e30      # running-max init; NOT -inf so exp() stays finite
 _LANES = 128            # TPU lane width; running stats are lane-replicated
+# The two residuals of the custom VJP that only the forward kernel can
+# produce, by the names a `jax.checkpoint` policy may keep them under
+# (jax.checkpoint_policies.save_only_these_names): a checkpoint that keeps
+# both runs the backward without running flash_attention_fwd again. Without
+# such a policy a name is the identity.
+RESIDUAL_OUT = "flash_attention_out"
+RESIDUAL_LSE = "flash_attention_lse"
 
 
 def _round_up(x: int, m: int) -> int:
@@ -335,6 +343,10 @@ def _flash(q, k, v, kvm, scale, causal, block_q, block_k, interpret):
 def _flash_fwd(q, k, v, kvm, scale, causal, block_q, block_k, interpret):
     out, lse = _fwd_call(q, k, v, kvm, scale, causal, block_q, block_k,
                          interpret)
+    out = checkpoint_name(out, RESIDUAL_OUT)
+    # the statistics are kept [B,H,Tq]: a trailing 1 held across layers
+    # would be tiled to 128 lanes on the chip, 128 times the bytes
+    lse = checkpoint_name(lse[..., 0], RESIDUAL_LSE)
     return out, (q, k, v, kvm, out, lse)
 
 
@@ -342,8 +354,8 @@ def _flash_bwd(scale, causal, block_q, block_k, interpret, res, do):
     q, k, v, kvm, out, lse = res
     delta = jnp.sum(do.astype(jnp.float32) * out.astype(jnp.float32),
                     axis=-1, keepdims=True)                   # [B,H,Tq,1]
-    dq, dk, dv = _bwd_call(q, k, v, kvm, do, lse, delta, scale, causal,
-                           block_q, block_k, interpret)
+    dq, dk, dv = _bwd_call(q, k, v, kvm, do, lse[..., None], delta, scale,
+                           causal, block_q, block_k, interpret)
     return dq, dk, dv, jnp.zeros_like(kvm)
 
 

@@ -80,6 +80,57 @@ def test_flash_gradients_match_dense(rng, causal):
                                    rtol=1e-4, atol=1e-4)
 
 
+def _equations(jaxpr):
+    """Every equation of a jaxpr and of the jaxprs under it."""
+    for eqn in jaxpr.eqns:
+        yield eqn
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            yield from _equations(sub)
+
+
+def test_the_vjp_keeps_lane_dense_statistics_under_two_names(rng):
+    """The custom VJP's residuals: `out` [B,H,Tq,Dv] and the row
+    statistics WITHOUT their trailing 1, [B,H,Tq] (a [..., 1] float32
+    array kept across layers is tiled to 128 lanes on the chip), each
+    under the name a checkpoint policy may keep it by. A checkpoint that
+    keeps both runs the forward kernel once where a plain one runs it
+    twice, and all three gradients are the same bit for bit: without a
+    policy a name is the identity."""
+    from marian_tpu.ops.pallas.flash_attention import (
+        RESIDUAL_LSE, RESIDUAL_OUT, _flash_fwd)
+    b, h, t, dh, dv = 2, 2, 128, 32, 16
+    q, k = _rand(rng, b, h, t, dh), _rand(rng, b, h, t, dh)
+    v, m = _rand(rng, b, h, t, dv), _kv_mask(rng, b, t)
+    out, res = _flash_fwd(q, k, v, m[:, None, :], dh ** -0.5, True,
+                          128, 128, True)
+    assert out.shape == res[4].shape == (b, h, t, dv)
+    assert res[5].shape == (b, h, t) and res[5].dtype == jnp.float32
+
+    def f(q, k, v):
+        return (flash_attention(q, k, v, kv_mask=m, causal=True) ** 2).sum()
+    keep = jax.checkpoint(
+        f, policy=jax.checkpoint_policies.save_only_these_names(
+            RESIDUAL_OUT, RESIDUAL_LSE))
+    fns = {"plain": f, "keep": keep, "again": jax.checkpoint(f)}
+    grads, forwards = {}, {}
+    for name, fn in fns.items():
+        grad = jax.grad(fn, argnums=(0, 1, 2))
+        eqns = list(_equations(jax.make_jaxpr(grad)(q, k, v).jaxpr))
+        forwards[name] = sum(
+            e.primitive.name == "pallas_call"
+            and e.params["name"] == "flash_attention_fwd" for e in eqns)
+        named = {e.params["name"]: e.outvars[0].aval.shape for e in eqns
+                 if e.primitive.name == "name"}
+        assert named == {RESIDUAL_OUT: (b, h, t, dv),
+                         RESIDUAL_LSE: (b, h, t)}
+        grads[name] = grad(q, k, v)
+    assert forwards == {"plain": 1, "keep": 1, "again": 2}
+    for kept, again, plain in zip(grads["keep"], grads["again"],
+                                  grads["plain"]):
+        np.testing.assert_array_equal(kept, plain)
+        np.testing.assert_array_equal(again, plain)
+
+
 def test_flash_under_jit_and_vmapless_batch(rng):
     b, h, t, dh = 2, 2, 128, 32
     q, k, v = _rand(rng, b, h, t, dh), _rand(rng, b, h, t, dh), _rand(rng, b, h, t, dh)

@@ -16,6 +16,7 @@ straightforward forms of the same mathematics:
       and a lower precision in the state or the router is caught
 """
 
+import collections
 import dataclasses
 import importlib
 import json
@@ -35,6 +36,7 @@ from marian_tpu.ops import kda
 from marian_tpu.ops.attention import dense_attention
 from marian_tpu.ops.pallas import kda_chunk, kda_prep
 from marian_tpu.ops.pallas.flash_attention import flash_attention
+from test_flash_attention import _equations
 
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
@@ -491,12 +493,18 @@ def test_the_cut_model_costs_what_the_reference_costs(tiny):
                                jnp.where(real, want, 0), atol=3e-5)
 
 
-@pytest.mark.parametrize("held", ["share", "whole"])
+@pytest.mark.parametrize("held", ["share", "whole", "share-flash"])
 def test_every_parameter_group_gets_the_reference_gradient(tiny, held):
-    """Every leaf's gradient is the reference's. A share of the layer (8
-    of 32 experts, as the benchmark's cut) passes nothing to its router,
-    in program and reference alike; holding the whole layer trains it."""
+    """Every leaf's gradient is the reference's, with the plan's halves
+    checkpointed (the file's `task_flags`). A share of the layer (8 of 32
+    experts, as the benchmark's cut) passes nothing to its router, in
+    program and reference alike; holding the whole layer trains it; with
+    the flash kernel forced on, the `mla` half's checkpoint keeps the
+    kernel's output and statistics and the gradient is the same."""
     model, dims, params, batch = tiny
+    assert model.cfg.gradient_checkpointing
+    if held == "share-flash":
+        model, _ = _tiny_model(extra=["--transformer-flash-attention", "on"])
     if held == "whole":
         n = dims["router_width"]
         model, _ = _tiny_model(extra=["--plan-experts-held", "0", str(n)])
@@ -513,7 +521,7 @@ def test_every_parameter_group_gets_the_reference_gradient(tiny, held):
     assert set(got) == set(want) == set(params)
     for name in sorted(params):
         scale = float(jnp.abs(want[name]).max())
-        if held == "share" and name.endswith("_experts_router"):
+        if held != "whole" and name.endswith("_experts_router"):
             assert scale == 0 == float(jnp.abs(got[name]).max()), name
             continue
         assert scale > 0, f"{name}: the reference's gradient is zero"
@@ -643,3 +651,120 @@ def test_head_groups_change_the_schedule_not_the_function(tiny):
     a = model.loss(params, batch, None, True)[0]
     b = other.loss(params, batch, None, True)[0]
     np.testing.assert_allclose(a, b, rtol=1e-6)
+
+
+# ---------------------------------------------------------------------------
+# what a checkpointed latent-attention half keeps across the backward
+# ---------------------------------------------------------------------------
+
+_KEEP_PLANS = {
+    "one-mla": (("mla:dense",), ()),
+    "rotated-low-rank-and-a-module": (
+        ("mla:dense", "mla:experts", "mla:experts"),
+        ("--plan-mla-q-rank", "24", "--plan-mla-rope-theta", "32e6",
+         "--plan-mtp-modules", "1")),
+}
+
+
+def _kernel_calls(jaxpr):
+    """{pallas_call name: equations} of a jaxpr and everything under it."""
+    return dict(collections.Counter(
+        eqn.params["name"] for eqn in _equations(jaxpr)
+        if eqn.primitive.name == "pallas_call"))
+
+
+@pytest.fixture(scope="module", params=list(_KEEP_PLANS))
+def checkpointed(request):
+    """A plan under --gradient-checkpointing with the flash kernel forced
+    on (interpret mode): (model, params, batch, its `mla` entries)."""
+    plan, extra = _KEEP_PLANS[request.param]
+    model = _plan_model(plan=plan, extra=(
+        "--gradient-checkpointing", "--transformer-flash-attention", "on",
+        *extra))
+    assert model.cfg.gradient_checkpointing
+    return (model, model.init(jax.random.PRNGKey(3)), _batch(96),
+            sum(1 for mix, _ in model.cfg.plan if mix == "mla"))
+
+
+def _loss_gradient(model, batch):
+    return jax.grad(lambda p: model.loss(p, batch, None, True)[0])
+
+
+def test_a_checkpointed_mla_half_runs_the_flash_forward_once(checkpointed,
+                                                             monkeypatch):
+    """The gradient's program holds flash_attention_fwd once for each
+    `mla` entry of the plan, the module's among them, like the two
+    backward kernels; with nothing kept (the checkpoint as it was) the
+    forward kernel is there twice. Fails if the policy stops engaging."""
+    model, params, batch, n = checkpointed
+    calls = _kernel_calls(jax.make_jaxpr(
+        _loss_gradient(model, batch))(params).jaxpr)
+    assert calls == {"flash_attention_fwd": n, "flash_attention_dq": n,
+                     "flash_attention_dkv": n}
+    monkeypatch.setattr(P, "_MLA_KEEPS", ())
+    calls = _kernel_calls(jax.make_jaxpr(
+        _loss_gradient(model, batch))(params).jaxpr)
+    assert calls == {"flash_attention_fwd": 2 * n, "flash_attention_dq": n,
+                     "flash_attention_dkv": n}
+
+
+def test_what_is_kept_changes_no_gradient(checkpointed, monkeypatch):
+    """Every parameter group's gradient with the kernel's output and
+    statistics kept is, bit for bit, the gradient with the forward
+    kernel run again: the kept arrays are what it would have written."""
+    model, params, batch, _ = checkpointed
+    kept = _loss_gradient(model, batch)(params)
+    monkeypatch.setattr(P, "_MLA_KEEPS", ())
+    again = _loss_gradient(model, batch)(params)
+    assert set(kept) == set(again) == set(params)
+    for name in sorted(params):
+        # (a share of the experts passes its router no gradient)
+        assert float(jnp.abs(kept[name]).max()) > 0 \
+            or name.endswith("_experts_router"), name
+        np.testing.assert_array_equal(kept[name], again[name], err_msg=name)
+
+
+def _keep_events(model, params, batch):
+    from marian_tpu.obs import TRACER
+    TRACER.reset()
+    TRACER.enable()
+    try:
+        jax.make_jaxpr(_loss_gradient(model, batch))(params)
+        _, events = TRACER.snapshot()
+    finally:
+        TRACER.disable()
+        TRACER.reset()
+    return [e["attrs"] for e in events if e["name"] == "plan.remat_keep"]
+
+
+def test_the_kept_bytes_are_said_as_an_mla_half_is_traced(checkpointed):
+    """`plan.remat_keep`, once a checkpointed `mla` half of a traced
+    step: the layer, the two names and the bytes kept under them, the
+    kernel's output [B, H, Tq padded, dv] in the compute type and its
+    statistics [B, H, Tq padded] in float32; 0 bytes where the dense
+    path runs and names nothing; nothing said without checkpointing, or
+    with the tracer off."""
+    model, params, batch, n = checkpointed
+    cfg = model.cfg
+    b, t = batch["trg_ids"].shape
+    rows = b * cfg.heads * 128                  # 80 positions pad to 128
+    said = _keep_events(model, params, batch)
+    assert [e["layer"] for e in said] == [
+        lp for lp, (mix, _) in P._blocks(cfg) if mix == "mla"]
+    assert len(said) == n and t == 80
+    for e in said:
+        assert e["names"] == P._MLA_KEEPS == (
+            "flash_attention_out", "flash_attention_lse")
+        assert e["bytes"] == rows * (cfg.mla_dim_v * 4 + 4)
+    kept = model.cfg
+    try:
+        model.cfg = dataclasses.replace(kept, flash_attention="off")
+        assert [e["bytes"] for e in _keep_events(model, params, batch)] \
+            == [0] * n
+        model.cfg = dataclasses.replace(kept, gradient_checkpointing=False)
+        assert _keep_events(model, params, batch) == []
+    finally:
+        model.cfg = kept
+    from marian_tpu.obs import TRACER
+    jax.make_jaxpr(_loss_gradient(model, batch))(params)    # tracer off
+    assert TRACER.snapshot()[1] == []
