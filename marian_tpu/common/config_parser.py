@@ -164,6 +164,11 @@ _MODEL = [
     _f("plan-experts-dim-ffn", int, 1024, "experts: hidden width of one expert's gated MLP", "model"),
     _f("plan-experts-shared", int, 1, "experts: shared experts added to every token", "model"),
     _f("plan-experts-scale", float, 1.0, "experts: factor on the renormalised routing weights", "model"),
+    _f("plan-experts-score", str, "sigmoid", "experts: the router's scores over all experts, before the top k and their renormalisation: sigmoid (of each logit) or softmax (over them)", "model"),
+    _f("plan-gqa-kv-heads", int, 0, "gqa: key/value heads, each read by --transformer-heads / N query heads (0: as many as query heads)", "model"),
+    _f("plan-gqa-dim-head", int, 128, "gqa: channels per head of queries, keys and values; queries and keys are RMS-normed per head with a learned scale", "model"),
+    _f("plan-gqa-rope-theta", float, 1e6, "gqa: base of the rotation by position of every query and key head, whole heads in half-split pairs (i, i + dim/2), float32 angles", "model"),
+    _f("plan-diffusion-block", int, 0, "train a plan of gqa layers by diffusion over blocks of N positions: the stack runs over [noised copy ; clean copy] of each row under the block rule and the cost is the masked positions' cross-entropy over the row's noise level (0: next-token training)", "model"),
 ]
 
 _TRAINING = [

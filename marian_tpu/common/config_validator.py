@@ -24,6 +24,14 @@ def _validate_common_model(opts: Options) -> None:
              "transformer-lm", "lm", "lm-transformer"}
     if t not in known:
         raise ValueError(f"Unknown model --type '{t}' (known: {sorted(known)})")
+    if int(opts.get("plan-diffusion-block", 0) or 0) and (
+            any(str(e).partition(":")[0] != "gqa"
+                for e in opts.get("transformer-layer-plan", []) or [])
+            or int(opts.get("plan-mtp-modules", 0) or 0)):
+        raise ValueError("--plan-diffusion-block trains gqa layers only, "
+                         "without prediction modules: the block rule is "
+                         "not written for kda or mla, nor for "
+                         "--plan-mtp-modules")
     if t == "transformer" and opts.get("dim-emb", 512) % opts.get("transformer-heads", 8) != 0:
         raise ValueError("--dim-emb must be divisible by --transformer-heads")
 

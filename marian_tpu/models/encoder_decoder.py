@@ -122,7 +122,9 @@ class EncoderDecoder:
         float32 vector): the family's own, summed over its layers, then
         the summed cost and the labels of each extra head it trains; ()
         for most families."""
-        return tuple(getattr(self._mod, "COUNTERS", ())) + tuple(
+        names = getattr(self._mod, "counter_names", None)
+        return tuple(names(self.cfg) if names
+                     else getattr(self._mod, "COUNTERS", ())) + tuple(
             f"{name}.{what}" for name in self._head_names
             for what in ("ce_sum", "labels"))
 
@@ -188,6 +190,13 @@ class EncoderDecoder:
         fused = table is not None and not (self.unlikelihood
                                            and "data_weights" in batch)
         dw = batch.get("data_weights")
+        if parts:
+            # what is left is the main head's per-token weights, where
+            # the family's objective weighs its labels (diffusion over
+            # blocks: masked / t); a batch's own weights act beside them
+            own = parts.pop(0)
+            dw = own if dw is None else own * jnp.broadcast_to(
+                dw.astype(own.dtype), own.shape)
 
         def cost(hidden, ids, mask, dw, was_projected=False):
             """Summed cost and label count of one head: its hidden states

@@ -14,16 +14,32 @@ its feed-forward kind, as data:
                          W_q) and a rotation of the shared key channels
                          and their query channels by position
                          (--plan-mla-rope-theta; 0 = not rotated)
+                gqa      grouped-query attention: --transformer-heads
+                         query heads on --plan-gqa-kv-heads key/value
+                         heads, queries and keys RMS-normed per head and
+                         rotated whole, in half-split pairs (i, i + d/2),
+                         at --plan-gqa-rope-theta; softmax
   feed-forward  dense    gated MLP, W_d(SiLU(W_g x) * W_u x)
-                experts  a router over all experts, the held ones
+                experts  a router over all experts (sigmoid or softmax
+                         scores, --plan-experts-score), the held ones
                          computed without dropping (ops/experts.py),
-                         plus shared experts on every token
+                         plus shared experts on every token, if any
 
 The block is pre-norm with RMSNorm (scale only) and a residual add;
 input and output tables untied; a final RMSNorm before the output
 projection. The only positional signal is the rotation inside `mla`,
-where it is asked for: the delta rule has none, and a plan without a
-rotated layer has none anywhere.
+where it is asked for, and inside `gqa`: the delta rule has none, and a
+plan without a rotated layer has none anywhere.
+
+The objective is next-token prediction (the input shifted right), or,
+with --plan-diffusion-block N and a plan of `gqa` layers, DIFFUSION OVER
+BLOCKS (arXiv:2503.09573): every real position of a row is replaced by
+the mask token with the row's own probability t (`diffusion_noise`), the
+stack runs over [noised copy ; clean copy], 2T positions, both halves at
+positions 0..T-1 and not shifted, under the rule
+ops/pallas/flash_attention.py::BlockDiffusion(T, N), and the cost of a
+row is its masked positions' cross-entropy, read off the noised half,
+over t. The label count stays the row's tokens.
 
 The last --plan-mtp-modules entries of the plan are not layers of the
 stack but PREDICTION MODULES that run after it (`_predict_ahead`):
@@ -35,9 +51,10 @@ one more weighted head of the cost (models/encoder_decoder.py), trained
 and counted beside the main head and never part of the label count.
 
 These are the families of Kimi Linear
-(https://huggingface.co/moonshotai/Kimi-Linear-48B-A3B-Instruct) and of
+(https://huggingface.co/moonshotai/Kimi-Linear-48B-A3B-Instruct), of
 DeepSeek-V3 (arXiv:2412.19437, 2.1.1 latent attention, 2.2 multi-token
-prediction); the sizes come from flags, nothing here knows a model's
+prediction) and of Qwen3's sparse models trained as block-diffusion
+models (arXiv:2505.09388; arXiv:2503.09573); the sizes come from flags, nothing here knows a model's
 name.
 
 The module is one more function family behind models/encoder_decoder.py
@@ -64,21 +81,28 @@ from ..ops import experts as X
 from ..ops import kda as K
 from ..ops.attention import attention, causal_mask
 from ..ops.ops import rms_norm
-from ..ops.pallas.flash_attention import RESIDUAL_LSE, RESIDUAL_OUT
+from ..ops.pallas.flash_attention import (RESIDUAL_LSE, RESIDUAL_OUT,
+                                          BlockDiffusion)
 from . import transformer as T
 
 Params = Dict[str, jax.Array]
 
-MIXINGS = ("kda", "mla")
+MIXINGS = ("kda", "mla", "gqa")
 FEED_FORWARDS = ("dense", "experts")
 # what the step carries out beside the loss, summed over the layers
 COUNTERS = X.COUNTERS
 # kept in the optimizer's float32 whatever the compute type: the router
 # decides WHICH experts run, and the decay's rate sits in an exponent
 _FLOAT32_SUFFIXES = ("_experts_router", "_kda_A_log", "_kda_dt_bias")
-# what a checkpointed `mla` half keeps across the backward beside its
-# input: the flash kernel's output and row statistics, by their names
-_MLA_KEEPS = (RESIDUAL_OUT, RESIDUAL_LSE)
+# what a checkpointed `mla` or `gqa` half keeps across the backward beside
+# its input: the flash kernel's output and row statistics, by their names
+_FLASH_KEEPS = (RESIDUAL_OUT, RESIDUAL_LSE)
+# diffusion over blocks: what the step counts beside COUNTERS (their
+# quotient is the realised noise level), the least noise level of a row,
+# and the token a masked position holds (the vocabulary's <unk>)
+DIFFUSION_COUNTERS = ("diffusion.masked", "diffusion.labels")
+DIFFUSION_EPS = 1e-3
+MASK_TOKEN = 1
 
 
 @dataclasses.dataclass(frozen=True)
@@ -100,12 +124,19 @@ class PlanConfig(T.TransformerConfig):
     # prediction modules: the plan's last entries, after dec_depth layers
     mtp_modules: int = 0
     mtp_weight: float = 0.3
+    # gqa
+    gqa_kv_heads: int = 0             # 0: as many as query heads
+    gqa_dim_head: int = 128
+    gqa_rope_theta: float = 1e6
+    # diffusion over blocks of this many positions; 0: next-token training
+    diffusion_block: int = 0
     # experts
     experts: int = 0                  # the router's width
     experts_top_k: int = 8
     experts_dim_ffn: int = 1024
     experts_shared: int = 1
     experts_scale: float = 1.0
+    experts_score: str = "sigmoid"
     experts_first: int = 0            # the held set: first, count
     experts_held: int = 0
 
@@ -159,6 +190,15 @@ def config_from_options(options, src_vocab, trg_vocab, for_inference=False,
     if theta and int(g("plan-mla-dim-shared", 64)) % 2:
         raise ValueError("--plan-mla-rope-theta rotates channel pairs: "
                          "--plan-mla-dim-shared must be even")
+    kv_heads = int(g("plan-gqa-kv-heads", 0) or 0) or base.heads
+    if base.heads % kv_heads or int(g("plan-gqa-dim-head", 128)) % 2:
+        raise ValueError(f"--plan-gqa-kv-heads {kv_heads} must divide "
+                         f"--transformer-heads {base.heads}, and "
+                         f"--plan-gqa-dim-head be even (rotated pairs)")
+    score = str(g("plan-experts-score", "sigmoid") or "sigmoid")
+    if score not in X.SCORES:
+        raise ValueError(f"--plan-experts-score {score!r}: one of "
+                         f"{X.SCORES}")
     fields = {f.name: getattr(base, f.name)
               for f in dataclasses.fields(base)}
     fields.update(
@@ -179,11 +219,16 @@ def config_from_options(options, src_vocab, trg_vocab, for_inference=False,
         mla_q_rank=int(g("plan-mla-q-rank", 0) or 0),
         mla_rope_theta=theta,
         mtp_modules=ahead, mtp_weight=float(g("plan-mtp-weight", 0.3)),
+        gqa_kv_heads=kv_heads,
+        gqa_dim_head=int(g("plan-gqa-dim-head", 128)),
+        gqa_rope_theta=float(g("plan-gqa-rope-theta", 1e6)),
+        diffusion_block=int(g("plan-diffusion-block", 0) or 0),
         experts=n_experts,
         experts_top_k=int(g("plan-experts-top-k", 8)),
         experts_dim_ffn=int(g("plan-experts-dim-ffn", 1024)),
         experts_shared=int(g("plan-experts-shared", 1)),
         experts_scale=float(g("plan-experts-scale", 1.0)),
+        experts_score=score,
         experts_first=first, experts_held=count)
 
 
@@ -233,6 +278,14 @@ def init_params(cfg: PlanConfig, key: jax.Array) -> Params:
             p[f"{lp}_kda_Wg2"] = glorot(r, h * dh)
             p[f"{lp}_kda_out_norm_scale"] = ones(dh)
             p[f"{lp}_kda_Wo"] = glorot(h * dh, d)
+        elif mix == "gqa":
+            dh, hk = cfg.gqa_dim_head, cfg.gqa_kv_heads
+            p[f"{lp}_gqa_Wq"] = glorot(d, h * dh)
+            p[f"{lp}_gqa_Wk"] = glorot(d, hk * dh)
+            p[f"{lp}_gqa_Wv"] = glorot(d, hk * dh)
+            p[f"{lp}_gqa_q_norm_scale"] = ones(dh)
+            p[f"{lp}_gqa_k_norm_scale"] = ones(dh)
+            p[f"{lp}_gqa_Wo"] = glorot(h * dh, d)
         else:
             dq = cfg.mla_dim_nope + cfg.mla_dim_shared
             if cfg.mla_q_rank:
@@ -362,25 +415,35 @@ def _kda(cfg: PlanConfig, p: Params, lp: str, x):
         return out.astype(x.dtype)
 
 
-def rope_angles(length: int, dim: int, theta: float):
+def rope_angles(length: int, dim: int, theta: float,
+                pairing: str = "interleaved"):
     """[length, dim] float32: position t times theta^(-2i / dim) for the
-    channel pair i = (2i, 2i + 1), each pair's angle under both of its
-    channels. Integer positions times float32 rates: past 256 positions
-    a bfloat16 angle is no longer its position's."""
+    channel pair i, each pair's angle under both of its channels: the
+    pair (2i, 2i + 1) where `pairing` is "interleaved", (i, i + dim / 2)
+    where it is "half". Integer positions times float32 rates: past 256
+    positions a bfloat16 angle is no longer its position's."""
     rate = theta ** (-jnp.arange(0, dim, 2, dtype=jnp.float32) / dim)
-    return jnp.repeat(jnp.arange(length, dtype=jnp.float32)[:, None]
-                      * rate[None, :], 2, axis=-1)
+    angles = jnp.arange(length, dtype=jnp.float32)[:, None] * rate[None, :]
+    if pairing == "half":
+        return jnp.concatenate([angles, angles], axis=-1)
+    return jnp.repeat(angles, 2, axis=-1)
 
 
-def _rotate(x, angles):
-    """x [..., T, dim] with the pair (2i, 2i + 1) at position t turned by
-    angles[t, 2i], in float32: (a, b) -> (a cos - b sin, a sin + b cos).
+def _rotate(x, angles, pairing: str = "interleaved"):
+    """x [..., T, dim] with each channel pair at position t turned by its
+    angle (angles[t], as rope_angles lays them out for the same
+    `pairing`), in float32: (a, b) -> (a cos - b sin, a sin + b cos).
     The pair's other channel comes from a roll along the channels, so
     nothing is reshaped to pairs."""
     f = x.astype(jnp.float32)
-    even = jnp.arange(x.shape[-1]) % 2 == 0
-    other = jnp.where(even, -jnp.roll(f, -1, axis=-1),
-                      jnp.roll(f, 1, axis=-1))
+    dim = x.shape[-1]
+    if pairing == "half":
+        first = jnp.arange(dim) < dim // 2
+        other = jnp.where(first, -1.0, 1.0) * jnp.roll(f, dim // 2, axis=-1)
+    else:
+        even = jnp.arange(dim) % 2 == 0
+        other = jnp.where(even, -jnp.roll(f, -1, axis=-1),
+                          jnp.roll(f, 1, axis=-1))
     return (f * jnp.cos(angles) + other * jnp.sin(angles)).astype(x.dtype)
 
 
@@ -418,12 +481,45 @@ def _mla(cfg: PlanConfig, p: Params, lp: str, x, mask):
         return jnp.dot(o, p[f"{lp}_mla_Wo"])
 
 
+def _gqa(cfg: PlanConfig, p: Params, lp: str, x, mask, rule=None):
+    """q, k, v = W x as heads of gqa_dim_head, q and k RMS-normed per head
+    and rotated whole at their position; query head h reads key/value
+    head h // (heads / kv heads); softmax; W_o. Next-token training: x
+    [B, T, d] under the causal rule. Under a BlockDiffusion `rule` x is
+    the doubled row [B, 2T, d], `mask` [B, 2T], and both halves stand at
+    positions 0..T-1."""
+    h, hk, dh = cfg.heads, cfg.gqa_kv_heads, cfg.gqa_dim_head
+    bsz, t, _ = x.shape
+    with jax.named_scope("gqa"):
+        q = rms_norm(_heads(jnp.dot(x, p[f"{lp}_gqa_Wq"]), h),
+                     p[f"{lp}_gqa_q_norm_scale"], eps=cfg.norm_eps)
+        k = rms_norm(_heads(jnp.dot(x, p[f"{lp}_gqa_Wk"]), hk),
+                     p[f"{lp}_gqa_k_norm_scale"], eps=cfg.norm_eps)
+        v = _heads(jnp.dot(x, p[f"{lp}_gqa_Wv"]), hk)
+        with jax.named_scope("gqa.rope"):
+            angles = rope_angles(t if rule is None else rule.length, dh,
+                                 cfg.gqa_rope_theta, "half")
+            if rule is not None:
+                angles = jnp.tile(angles, (2, 1))
+            q, k = _rotate(q, angles, "half"), _rotate(k, angles, "half")
+        # the dense path builds a rule's mask itself; the causal one is
+        # handed to it, as `_mla` does
+        o, _ = attention(
+            q, k, v, mask=causal_mask(t) * mask[:, None, None, :]
+            if rule is None else None, kv_mask=mask,
+            causal=True if rule is None else rule,
+            flash=cfg.flash_attention, packed="off")
+        o = o.transpose(0, 2, 1, 3).reshape(bsz, t, h * dh)
+        return jnp.dot(o, p[f"{lp}_gqa_Wo"])
+
+
 def _experts(cfg: PlanConfig, p: Params, lp: str, x, mask):
     bsz, t, d = x.shape
     flat = x.reshape(bsz * t, d)
     with jax.named_scope("experts.route"):
         idx, weights = X.route(flat, p[f"{lp}_experts_router"],
-                               cfg.experts_top_k, cfg.experts_scale)
+                               cfg.experts_top_k, cfg.experts_scale,
+                               cfg.experts_score)
         if cfg.experts_held < cfg.experts:
             # A share of the layer's output carries a share of the
             # router's gradient, and that share alone teaches the router
@@ -446,8 +542,11 @@ def _experts(cfg: PlanConfig, p: Params, lp: str, x, mask):
     return y.reshape(bsz, t, d), counters
 
 
-def _mix(cfg: PlanConfig, kind: str, lp: str, p: Params, x, mask):
+def _mix(cfg: PlanConfig, kind: str, lp: str, p: Params, x, mask,
+         rule=None):
     pre = rms_norm(x, p[f"{lp}_mix_norm_scale"], eps=cfg.norm_eps)
+    if kind == "gqa":
+        return x + _gqa(cfg, p, lp, pre, mask, rule)
     return x + (_kda(cfg, p, lp, pre) if kind == "kda"
                 else _mla(cfg, p, lp, pre, mask))
 
@@ -480,29 +579,34 @@ def _named_bytes(f, names, *args) -> int:
     return sum(a.size * a.dtype.itemsize for a in named(forward.jaxpr))
 
 
-def _layer(cfg: PlanConfig, kinds, lp: str, p: Params, x, mask, remat):
+def _layer(cfg: PlanConfig, kinds, lp: str, p: Params, x, mask, remat,
+           rule=None):
     """One block: x + mixing(norm(x)), then x + feed-forward(norm(x)).
     With `remat` (--gradient-checkpointing, training) each half is
     rematerialised in the backward on its own, so what stays alive
     between the passes is a layer's input and its middle, and of an
-    `mla` half also what only the flash kernel can produce
-    (_MLA_KEEPS): its projections, rotation and concatenates run again
+    `mla` or `gqa` half also what only the flash kernel can produce
+    (_FLASH_KEEPS): its projections, rotation and concatenates run again
     in the backward, the kernel does not. Where the dense path runs
     (short rows, the CPU) nothing bears those names and nothing more is
     kept. KDA mixed in head groups rematerialises itself group by group
     and is not wrapped again: a second wrap would run its forward a
-    third time."""
+    third time. `rule`: the BlockDiffusion rule of a `gqa` half over a
+    doubled row, None under next-token training."""
     mix, ffn = kinds
     f_mix = partial(_mix, cfg, mix, lp)
+    if rule is not None:
+        f_mix = partial(f_mix, rule=rule)
     f_ffn = partial(_feed_forward, cfg, ffn, lp)
     if remat:
-        if mix == "mla":
+        if mix in ("mla", "gqa"):
             if obs.enabled():
-                obs.event("plan.remat_keep", layer=lp, names=_MLA_KEEPS,
-                          bytes=_named_bytes(f_mix, _MLA_KEEPS, p, x, mask))
+                obs.event("plan.remat_keep", layer=lp, names=_FLASH_KEEPS,
+                          bytes=_named_bytes(f_mix, _FLASH_KEEPS, p, x,
+                                             mask))
             f_mix = jax.checkpoint(
                 f_mix, policy=jax.checkpoint_policies.save_only_these_names(
-                    *_MLA_KEEPS))
+                    *_FLASH_KEEPS))
         elif cfg.kda_head_groups == 1:
             f_mix = jax.checkpoint(f_mix)
         f_ffn = jax.checkpoint(f_ffn)
@@ -533,6 +637,32 @@ class Head(NamedTuple):
 
 def head_names(cfg: PlanConfig) -> Tuple[str, ...]:
     return ("mtp",) if cfg.mtp_modules else ()
+
+
+def counter_names(cfg: PlanConfig) -> Tuple[str, ...]:
+    """What the step's one lazy vector counts, in its order."""
+    return COUNTERS + (DIFFUSION_COUNTERS if cfg.diffusion_block else ())
+
+
+def diffusion_noise(key: Optional[jax.Array], mask):
+    """(masked [B, T] float32, t [B] float32) for a batch's mask [B, T]
+    (1 = a real token): a noise level t = eps + (1 - eps) u, u uniform on
+    [0, 1), per ROW, and each real position masked independently with
+    probability t. Without a key (validation; a check that runs rows in
+    chunks) nothing may depend on the batch but its width: t = 1/2 for
+    every row, and position p is masked iff
+    jax.random.uniform(jax.random.key(0), (T,))[p] < 1/2."""
+    rows, width = mask.shape
+    if key is None:
+        t = jnp.full((rows,), 0.5, jnp.float32)
+        draw = jnp.broadcast_to(
+            jax.random.uniform(jax.random.key(0), (width,)), (rows, width))
+    else:
+        k_level, k_draw = jax.random.split(key)
+        t = DIFFUSION_EPS + (1.0 - DIFFUSION_EPS) * jax.random.uniform(
+            k_level, (rows,))
+        draw = jax.random.uniform(k_draw, (rows, width))
+    return (draw < t[:, None]).astype(jnp.float32) * mask, t
 
 
 def _predict_ahead(cfg: PlanConfig, params: Params, x, emb, trg_ids, mask,
@@ -567,19 +697,60 @@ def _predict_ahead(cfg: PlanConfig, params: Params, x, emb, trg_ids, mask,
     return heads, counters
 
 
+def _decode_diffusion(cfg: PlanConfig, params: Params, trg_ids, trg_mask,
+                      train: bool, key, return_hidden: bool, noise):
+    """decode_train under --plan-diffusion-block: the stack over [noised ;
+    clean], 2T indices under BlockDiffusion(T, block), not shifted. Returns
+    (the NOISED half's [B, T, ...] logits or hidden states, counters
+    [len(counter_names(cfg))], the main head's per-token weights [B, T],
+    masked / t)."""
+    mask = trg_mask.astype(jnp.float32)
+    remat = cfg.gradient_checkpointing and train
+    width = trg_ids.shape[1]
+    with jax.named_scope("diffusion.noise"):
+        masked, level = noise if noise is not None \
+            else diffusion_noise(key, mask)
+        noised = jnp.where(masked > 0, MASK_TOKEN, trg_ids)
+        weights = masked / level[:, None]
+    with jax.named_scope("embed"):
+        x = T._embed_words(cfg, params, jnp.concatenate(
+            [noised, trg_ids], axis=1), "trg")
+    both = jnp.concatenate([mask, mask], axis=1)
+    rule = BlockDiffusion(width, cfg.diffusion_block)
+    counters = jnp.zeros((len(COUNTERS),), jnp.float32)
+    for lp, kinds in _blocks(cfg):
+        x, c = _layer(cfg, kinds, lp, params, x, both, remat, rule)
+        counters = counters + c
+    x = rms_norm(x[:, :width], params["decoder_top_norm_scale"],
+                 eps=cfg.norm_eps)
+    counters = jnp.concatenate(
+        [counters, jnp.stack([jnp.sum(masked), jnp.sum(mask)])])
+    return (x if return_hidden else T.output_logits(cfg, params, x),
+            jax.lax.stop_gradient(counters),
+            jax.lax.stop_gradient(weights))
+
+
 def decode_train(cfg: PlanConfig, params: Params, enc_out, src_mask,
                  trg_ids, trg_mask, train: bool = True,
                  key: Optional[jax.Array] = None,
                  return_alignment: bool = False,
-                 return_hidden: bool = False):
+                 return_hidden: bool = False, noise=None):
     """Teacher-forced: [B, T] gold ids -> ([B, T, V] logits, or the
     hidden states before the output projection when return_hidden;
-    counters [len(COUNTERS)]; with prediction modules in the plan, the
-    list of their Heads last, hidden states whatever return_hidden
-    says). The input is the gold embeddings shifted right with a zero
-    vector first, as transformer.decode_train's."""
+    counters [len(counter_names(cfg))]; with prediction modules in the
+    plan, the list of their Heads last, hidden states whatever
+    return_hidden says). The input is the gold embeddings shifted right
+    with a zero vector first, as transformer.decode_train's.
+
+    Under --plan-diffusion-block the row is not shifted and the result is
+    `_decode_diffusion`'s, the main head's per-token weights last; `noise`
+    = (masked, t) then replaces what diffusion_noise(key, mask) draws
+    (tests hand the reference the same)."""
     if return_alignment:
         raise ValueError("a layer plan has no cross attention to align")
+    if cfg.diffusion_block:
+        return _decode_diffusion(cfg, params, trg_ids, trg_mask, train, key,
+                                 return_hidden, noise)
     with jax.named_scope("embed"):
         emb = T._embed_words(cfg, params, trg_ids, "trg")
         x = T.shift_right_embeddings(emb)

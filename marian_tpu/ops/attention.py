@@ -7,8 +7,8 @@ src/models/transformer.h :: MultiHead). Here the dense path is einsum-based
 flash-attention kernel (ops/pallas/flash_attention.py) takes over for long
 sequences where the O(L²) score tensor would blow HBM bandwidth.
 
-Shapes are batch-major: q [B, H, Tq, Dh], k/v [B, H, Tk, Dh],
-mask [B, 1, Tq, Tk] (1 = attend).
+Shapes are batch-major: q [B, H, Tq, Dh], k/v [B, Hkv, Tk, Dh] with H a
+multiple of Hkv (grouped-query heads), mask [B, 1, Tq, Tk] (1 = attend).
 """
 
 from __future__ import annotations
@@ -54,7 +54,7 @@ def dense_attention_with_weights(q, k, v, mask=None, dropout_rate=0.0,
 def attention(q: jax.Array, k: jax.Array, v: jax.Array,
               mask: Optional[jax.Array] = None,
               kv_mask: Optional[jax.Array] = None,
-              causal: bool = False,
+              causal=False,
               dropout_rate: float = 0.0,
               dropout_key: Optional[jax.Array] = None,
               deterministic: bool = True,
@@ -68,7 +68,12 @@ def attention(q: jax.Array, k: jax.Array, v: jax.Array,
 
     `mask` is the general [B,1,Tq,Tk] dense mask; `kv_mask` [B,Tk] + `causal`
     is the structured form both Pallas kernels understand. Callers that can,
-    pass both. A kernel is picked when it is (a) allowed (its gate = auto|on),
+    pass both. `causal` may also be a flash_attention.BlockDiffusion rule
+    (block-diffusion training over a doubled row): the flash kernels take
+    the rule, the dense path builds its mask here (`block_diffusion_mask`),
+    and `mask` may then be left out. Key/value heads shared by a group of
+    query heads are read in place by flash and repeated for the dense path.
+    A kernel is picked when it is (a) allowed (its gate = auto|on),
     (b) applicable (no returned weights, no active attention dropout, a
     structured mask describing the dense one, multi-query step), and (c) for
     "auto", worth it on its regime: flash when the sequence is long enough
@@ -92,6 +97,16 @@ def attention(q: jax.Array, k: jax.Array, v: jax.Array,
             flash == "on" or max(q.shape[-2], k.shape[-2]) >= flash_min_len):
         from .pallas.flash_attention import flash_attention
         return flash_attention(q, k, v, kv_mask=kv_mask, causal=causal), None
+    from .pallas.flash_attention import BlockDiffusion
+    if isinstance(causal, BlockDiffusion):
+        packed = "off"
+        see = block_diffusion_mask(causal.length, causal.block)
+        if kv_mask is not None:
+            see = see * kv_mask[:, None, None, :].astype(see.dtype)
+        mask = combine_masks(mask, see)
+    group = q.shape[1] // k.shape[1]
+    if group > 1:
+        k, v = (jnp.repeat(a, group, axis=1) for a in (k, v))
     if applicable and packed != "off":
         from .auto_tuner import packed_attention_max_t
         from .pallas.packed_attention import pack_group
@@ -114,6 +129,17 @@ def causal_mask(length: int, dtype=jnp.float32) -> jax.Array:
     """[1, 1, T, T] future mask (reference: transformer.h triangle mask)."""
     m = jnp.tril(jnp.ones((length, length), dtype=dtype))
     return m[None, None, :, :]
+
+
+def block_diffusion_mask(length: int, block: int, dtype=jnp.float32
+                         ) -> jax.Array:
+    """[1, 1, 2T, 2T]: flash_attention.BlockDiffusion(length, block) as an
+    array, for the dense path and for tests (1 = the query sees the key)."""
+    from .pallas.flash_attention import BlockDiffusion, rule_mask
+    pos = jnp.arange(2 * length, dtype=jnp.int32)
+    see = rule_mask(BlockDiffusion(length, block), pos[:, None],
+                    pos[None, :])
+    return see.astype(dtype)[None, None, :, :]
 
 
 def combine_masks(*masks: Optional[jax.Array]) -> Optional[jax.Array]:

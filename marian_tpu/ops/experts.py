@@ -47,13 +47,18 @@ COUNTERS = ("moe.assignments", "moe.assignments_held", "moe.load_max",
             "moe.load_mean", "moe.dropped")
 
 
-def route(x, w_router, top_k: int, scale: float):
-    """Sigmoid scores over all experts in float32, the top k of them,
-    renormalised to sum 1 and scaled: x [T, d], w_router [d, E] ->
-    (idx [T, k] int32, weights [T, k] float32)."""
-    s = jax.nn.sigmoid(jnp.dot(x.astype(jnp.float32),
-                               w_router.astype(jnp.float32),
-                               precision=jax.lax.Precision.HIGHEST))
+SCORES = ("sigmoid", "softmax")
+
+
+def route(x, w_router, top_k: int, scale: float, score: str = "sigmoid"):
+    """Scores over all experts in float32 (`score`: a sigmoid of each
+    logit, or a softmax over them), the top k of them, renormalised to
+    sum 1 and scaled: x [T, d], w_router [d, E] -> (idx [T, k] int32,
+    weights [T, k] float32)."""
+    logits = jnp.dot(x.astype(jnp.float32), w_router.astype(jnp.float32),
+                     precision=jax.lax.Precision.HIGHEST)
+    s = jax.nn.sigmoid(logits) if score == "sigmoid" \
+        else jax.nn.softmax(logits, axis=-1)
     vals, idx = jax.lax.top_k(s, top_k)
     return idx, vals / jnp.sum(vals, axis=-1, keepdims=True) * scale
 

@@ -8,28 +8,44 @@ This module computes the same masked softmax(QK^T)V with the online-softmax
 recurrence, streaming K/V blocks through VMEM so the score matrix never
 touches HBM, with a matching blockwise backward (custom VJP).
 
-Supported masking covers every attention pattern in the model zoo:
+Masks the kernels take (any other pattern, e.g. an arbitrary dense
+[Tq, Tk] mask, goes through the dense path):
   - kv_mask [B, Tk]: key padding mask (1.0 = attend), and/or
-  - causal: future mask (query position >= key position).
+  - a RULE over (query index, key index), the `causal` argument:
+      True               the future mask, key index <= query index
+      BlockDiffusion(T, block)   block-diffusion training over a doubled
+                         row [noised ; clean] of 2T positions (see the
+                         class)
+    From a rule the three kernels take which tiles are live and the mask
+    inside a live tile. Under `causal=True` a dead tile is fetched and
+    not computed; under BlockDiffusion it is neither: a dead step's block
+    index is clamped to a resident live one, so the pipeline fetches
+    nothing new for it.
 Attention-weight dropout and returned weights are NOT supported here; the
 dispatcher (ops/attention.py :: attention) falls back to the dense path for
 those cases.
 
-Shapes: q [B, H, Tq, Dh], k [B, H, Tk, Dh], v [B, H, Tk, Dv] -> out
+Shapes: q [B, H, Tq, Dh], k [B, Hkv, Tk, Dh], v [B, Hkv, Tk, Dv] -> out
 [B, H, Tq, Dv]: the value width may differ from the key width (latent
-attention: keys of 128 + 64, values of 128). Compute is f32 on the MXU regardless of input dtype (bf16 in training).
+attention: keys of 128 + 64, values of 128), and H may be a multiple of
+Hkv (grouped-query heads: query head h reads key/value head
+h // (H / Hkv); the dkv kernel sums a group's query heads inside the
+kernel). Compute is f32 on the MXU regardless of input dtype (bf16 in
+training).
 """
 
 from __future__ import annotations
 
 import functools
-from typing import Optional
+from typing import NamedTuple, Optional, Union
 
 import jax
 import jax.numpy as jnp
 from jax.ad_checkpoint import checkpoint_name
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
+
+from ... import obs
 
 MASK_VALUE = -1e9       # additive bias for masked scores (matches ops.NEG_INF)
 STATS_INIT = -1e30      # running-max init; NOT -inf so exp() stays finite
@@ -67,6 +83,164 @@ def _env_block(name: str, default: int) -> int:
 
 
 # ---------------------------------------------------------------------------
+# The rule over (query index, key index): which pairs see each other, which
+# tiles hold such a pair, and which tile stays resident over a dead step.
+# ---------------------------------------------------------------------------
+
+class BlockDiffusion(NamedTuple):
+    """Block-diffusion training (arXiv:2503.09573) over a doubled row of
+    2 * `length` positions: index p < length is the NOISED copy of position
+    p, index length + p its CLEAN copy; a position's block is p // block.
+    A query sees a key iff
+      both are noised and in the same block, or
+      the query is noised, the key clean and of an EARLIER block, or
+      both are clean and the key's block is the query's or earlier;
+    a clean query sees no noised key. Queries and keys are the same 2 *
+    length indices (self-attention)."""
+    length: int
+    block: int
+
+
+Rule = Union[bool, BlockDiffusion]
+
+
+def _block_of(pos, block: int):
+    shift = block.bit_length() - 1
+    return pos >> shift if block == 1 << shift else pos // block
+
+
+def rule_mask(rule: BlockDiffusion, qpos, kpos):
+    """Boolean: may the query at index qpos see the key at index kpos.
+    int32 arrays that broadcast against each other: a column of queries
+    against a row of keys keeps all but two compares and an `or` off the
+    square. (Written as compares of per-index codes: Mosaic has no select
+    between vectors of booleans.)"""
+    t, b = rule
+    q_noised, k_noised = qpos < t, kpos < t
+    qb = _block_of(jnp.where(q_noised, qpos, qpos - t), b)
+    kb = _block_of(jnp.where(k_noised, kpos, kpos - t), b)
+    # a noised key is seen by the noised queries of its block ...
+    same = jnp.where(k_noised, kb, -1) == jnp.where(q_noised, qb, -2)
+    # ... a clean one from an earlier block by a noised query, from the
+    # query's own block too by a clean one
+    earlier = jnp.where(k_noised, 2 * t, kb) \
+        < jnp.where(q_noised, qb, qb + 1)
+    return same | earlier
+
+
+def _kv_spans(rule: BlockDiffusion, i, block_q: int, block_k: int):
+    """The key tiles that hold a key some query of query tile i sees:
+    two runs of tile indices (lo, hi inclusive; lo > hi = none), one
+    among the noised keys and one among the clean. Python ints, numpy
+    arrays or traced scalars."""
+    t, b = rule
+    q0 = i * block_q
+    q1 = jnp.minimum(q0 + block_q, 2 * t) - 1        # its last real row
+    qn1 = jnp.minimum(q1, t - 1)                     # ... last noised one
+    has_noised, has_clean = q0 < t, q1 >= t
+    a_lo = (q0 // b * b) // block_k
+    a_hi = jnp.where(
+        has_noised,
+        jnp.minimum(qn1 // b * b + b - 1, t - 1) // block_k, a_lo - 1)
+    # the last clean position seen: before its block by a noised row, to
+    # the end of its block by a clean one
+    last = jnp.maximum(
+        jnp.where(has_clean, jnp.minimum(
+            jnp.maximum(q1 - t, 0) // b * b + b - 1, t - 1), -1),
+        jnp.where(has_noised, qn1 // b * b - 1, -1))
+    b_lo = t // block_k
+    b_hi = jnp.where(last >= 0, (t + jnp.maximum(last, 0)) // block_k,
+                     b_lo - 1)
+    return a_lo, a_hi, b_lo, b_hi
+
+
+def _q_spans(rule: BlockDiffusion, j, block_q: int, block_k: int):
+    """The query tiles that hold a query which sees some key of key tile
+    j: a run among the noised queries and one among the clean."""
+    t, b = rule
+    k0 = j * block_k
+    k1 = jnp.minimum(k0 + block_k, 2 * t) - 1
+    kn1 = jnp.minimum(k1, t - 1)
+    has_noised, has_clean = k0 < t, k1 >= t
+    none_lo, none_hi = 2 * t // block_q + 1, -1
+    # noised keys: the noised queries of their blocks
+    n_lo = jnp.where(has_noised, (k0 // b * b) // block_q, none_lo)
+    n_hi = jnp.where(
+        has_noised,
+        jnp.minimum(kn1 // b * b + b - 1, t - 1) // block_q, none_hi)
+    # clean keys: the noised queries of every LATER block, and the clean
+    # queries from the first key's block on
+    kc0 = jnp.maximum(k0, t) - t
+    later = (kc0 // b + 1) * b
+    seen_later = has_clean & (later <= t - 1)
+    c_lo = jnp.where(seen_later, later // block_q, none_lo)
+    c_hi = jnp.where(seen_later, (t - 1) // block_q, none_hi)
+    b_lo = (t + kc0 // b * b) // block_q
+    b_hi = jnp.where(has_clean, (2 * t - 1) // block_q, b_lo - 1)
+    return (jnp.minimum(n_lo, c_lo), jnp.maximum(n_hi, c_hi), b_lo, b_hi)
+
+
+def _in_spans(x, spans):
+    a_lo, a_hi, b_lo, b_hi = spans
+    return ((x >= a_lo) & (x <= a_hi)) | ((x >= b_lo) & (x <= b_hi))
+
+
+def _resident(x, spans, n: int):
+    """x where tile x is live; else the live tile before it, which the
+    pipeline still holds (the first live one before any): a dead step
+    asks for no new block."""
+    a_lo, a_hi, b_lo, b_hi = spans
+    r = jnp.where(a_lo <= a_hi, a_lo, b_lo)
+    r = jnp.where((a_lo <= a_hi) & (x >= a_lo), jnp.minimum(x, a_hi), r)
+    r = jnp.where((b_lo <= b_hi) & (x >= b_lo), jnp.minimum(x, b_hi), r)
+    return jnp.clip(r, 0, n - 1)
+
+
+def _live(rule: Rule, i, j, block_q: int, block_k: int):
+    """Does tile (query tile i, key tile j) hold a pair that sees?"""
+    if rule is True:
+        # skip k-blocks that are entirely in the future of this q-block
+        return j * block_k <= i * block_q + block_q - 1
+    if rule:
+        return _in_spans(j, _kv_spans(rule, i, block_q, block_k))
+    return True
+
+
+def _masked(rule: Rule, s, i, j, block_q: int, block_k: int):
+    """The scores of tile (i, j) with the pairs that do not see set to
+    MASK_VALUE."""
+    if rule is True:
+        qpos = i * block_q + jax.lax.broadcasted_iota(
+            jnp.int32, (block_q, block_k), 0)
+        kpos = j * block_k + jax.lax.broadcasted_iota(
+            jnp.int32, (block_q, block_k), 1)
+        return jnp.where(qpos >= kpos, s, MASK_VALUE)
+    if rule:
+        qpos = i * block_q + jax.lax.broadcasted_iota(
+            jnp.int32, (block_q, 1), 0)
+        kpos = j * block_k + jax.lax.broadcasted_iota(
+            jnp.int32, (1, block_k), 1)
+        return jnp.where(rule_mask(rule, qpos, kpos), s, MASK_VALUE)
+    return s
+
+
+def _rule_name(rule: Rule) -> str:
+    if isinstance(rule, BlockDiffusion):
+        return f"block_diffusion({rule.length},{rule.block})"
+    return "causal" if rule else "none"
+
+
+def live_tiles(rule: Rule, n_q: int, n_k: int, block_q: int,
+               block_k: int) -> int:
+    """How many of the n_q x n_k tiles the kernels compute."""
+    with jax.ensure_compile_time_eval():
+        i = jnp.arange(n_q, dtype=jnp.int32)[:, None]
+        j = jnp.arange(n_k, dtype=jnp.int32)[None, :]
+        return int(jnp.sum(jnp.broadcast_to(
+            _live(rule, i, j, block_q, block_k), (n_q, n_k))))
+
+
+# ---------------------------------------------------------------------------
 # Forward kernel: grid (B, H, nq, nk); the k-block axis is innermost and
 # sequential on TPU, so running stats live in VMEM scratch across k-blocks.
 # ---------------------------------------------------------------------------
@@ -82,10 +256,7 @@ def _fwd_kernel(q_ref, k_ref, v_ref, kvm_ref, o_ref, lse_ref,
         l_scr[:] = jnp.zeros_like(l_scr)
         acc_scr[:] = jnp.zeros_like(acc_scr)
 
-    # Causal: skip k-blocks that are entirely in the future of this q-block.
-    live = (j * block_k <= i * block_q + block_q - 1) if causal else True
-
-    @pl.when(live)
+    @pl.when(_live(causal, i, j, block_q, block_k))
     def _compute():
         q = q_ref[0, 0].astype(jnp.float32)          # [bq, dh]
         k = k_ref[0, 0].astype(jnp.float32)          # [bk, dh]
@@ -94,12 +265,7 @@ def _fwd_kernel(q_ref, k_ref, v_ref, kvm_ref, o_ref, lse_ref,
             preferred_element_type=jnp.float32) * scale       # [bq, bk]
         kvm = kvm_ref[0, 0].astype(jnp.float32)      # [bk]
         s = s + (1.0 - kvm)[None, :] * MASK_VALUE
-        if causal:
-            qpos = i * block_q + jax.lax.broadcasted_iota(
-                jnp.int32, (block_q, block_k), 0)
-            kpos = j * block_k + jax.lax.broadcasted_iota(
-                jnp.int32, (block_q, block_k), 1)
-            s = jnp.where(qpos >= kpos, s, MASK_VALUE)
+        s = _masked(causal, s, i, j, block_q, block_k)
 
         m_prev = m_scr[:, :1]                        # [bq, 1]
         l_prev = l_scr[:, :1]
@@ -135,12 +301,7 @@ def _recompute_p(q, k, kvm, lse, scale, causal, i, j, block_q, block_k):
         q, k, (((1,), (1,)), ((), ())),
         preferred_element_type=jnp.float32) * scale           # [bq, bk]
     s = s + (1.0 - kvm)[None, :] * MASK_VALUE
-    if causal:
-        qpos = i * block_q + jax.lax.broadcasted_iota(
-            jnp.int32, (block_q, block_k), 0)
-        kpos = j * block_k + jax.lax.broadcasted_iota(
-            jnp.int32, (block_q, block_k), 1)
-        s = jnp.where(qpos >= kpos, s, MASK_VALUE)
+    s = _masked(causal, s, i, j, block_q, block_k)
     return jnp.exp(s - lse[:, None])                          # [bq, bk]
 
 
@@ -152,9 +313,7 @@ def _dq_kernel(q_ref, k_ref, v_ref, kvm_ref, do_ref, lse_ref, delta_ref,
     def _init():
         dq_scr[:] = jnp.zeros_like(dq_scr)
 
-    live = (j * block_k <= i * block_q + block_q - 1) if causal else True
-
-    @pl.when(live)
+    @pl.when(_live(causal, i, j, block_q, block_k))
     def _compute():
         q = q_ref[0, 0].astype(jnp.float32)
         k = k_ref[0, 0].astype(jnp.float32)
@@ -180,18 +339,19 @@ def _dq_kernel(q_ref, k_ref, v_ref, kvm_ref, do_ref, lse_ref, delta_ref,
 
 def _dkv_kernel(q_ref, k_ref, v_ref, kvm_ref, do_ref, lse_ref, delta_ref,
                 dk_ref, dv_ref, dk_scr, dv_scr, *, scale, causal, block_q,
-                block_k, n_q):
-    # grid = (B, H, nk, nq): program_id(2) is the k-block, (3) the q-block.
-    j, i = pl.program_id(2), pl.program_id(3)
+                block_k, n_q, group):
+    # grid = (B, Hkv, nk, group * nq): program_id(2) is the k-block, (3)
+    # runs over the q-blocks of each query head that reads this key/value
+    # head, one head after another.
+    j, step = pl.program_id(2), pl.program_id(3)
+    i = step if group == 1 else step % n_q
 
-    @pl.when(i == 0)
+    @pl.when(step == 0)
     def _init():
         dk_scr[:] = jnp.zeros_like(dk_scr)
         dv_scr[:] = jnp.zeros_like(dv_scr)
 
-    live = (j * block_k <= i * block_q + block_q - 1) if causal else True
-
-    @pl.when(live)
+    @pl.when(_live(causal, i, j, block_q, block_k))
     def _compute():
         q = q_ref[0, 0].astype(jnp.float32)
         k = k_ref[0, 0].astype(jnp.float32)
@@ -213,7 +373,7 @@ def _dkv_kernel(q_ref, k_ref, v_ref, kvm_ref, do_ref, lse_ref, delta_ref,
             ds, q, (((0,), (0,)), ((), ())),
             preferred_element_type=jnp.float32)
 
-    @pl.when(i == n_q - 1)
+    @pl.when(step == group * n_q - 1)
     def _finalize():
         dk_ref[0, 0] = dk_scr[:].astype(dk_ref.dtype)
         dv_ref[0, 0] = dv_scr[:].astype(dv_ref.dtype)
@@ -234,6 +394,23 @@ def _compiler_params(n_seq_dims: int = 1):
         dimension_semantics=("parallel", "parallel", "parallel", "arbitrary"))
 
 
+def _index_maps(causal, group, block_q, block_k, n_q, n_k):
+    """The block index maps of the grids (b, h, i, j) of the forward and
+    dq kernels: (queries' side, keys' side, key mask). A query head reads
+    key/value head h // group; under a BlockDiffusion rule a dead step
+    keeps the key tile of the live step before it."""
+    def kv_head(h_):
+        return h_ if group == 1 else h_ // group
+
+    def k_tile(i, j):
+        if isinstance(causal, BlockDiffusion):
+            return _resident(j, _kv_spans(causal, i, block_q, block_k), n_k)
+        return j
+    return (lambda b_, h_, i, j: (b_, h_, i, 0),
+            lambda b_, h_, i, j: (b_, kv_head(h_), k_tile(i, j), 0),
+            lambda b_, h_, i, j: (b_, 0, k_tile(i, j)))
+
+
 def _fwd_call(q, k, v, kvm, scale, causal, block_q, block_k, interpret):
     b, h, tq, dh = q.shape
     tk, dv = k.shape[2], v.shape[3]
@@ -241,19 +418,21 @@ def _fwd_call(q, k, v, kvm, scale, causal, block_q, block_k, interpret):
     grid = (b, h, n_q, n_k)
     kernel = functools.partial(_fwd_kernel, scale=scale, causal=causal,
                                block_q=block_q, block_k=block_k, n_k=n_k)
+    at_q, at_k, at_mask = _index_maps(causal, h // k.shape[1], block_q,
+                                      block_k, n_q, n_k)
     return pl.pallas_call(
         kernel,
         name="flash_attention_fwd",
         grid=grid,
         in_specs=[
-            pl.BlockSpec((1, 1, block_q, dh), lambda b_, h_, i, j: (b_, h_, i, 0)),
-            pl.BlockSpec((1, 1, block_k, dh), lambda b_, h_, i, j: (b_, h_, j, 0)),
-            pl.BlockSpec((1, 1, block_k, dv), lambda b_, h_, i, j: (b_, h_, j, 0)),
-            pl.BlockSpec((1, 1, block_k), lambda b_, h_, i, j: (b_, 0, j)),
+            pl.BlockSpec((1, 1, block_q, dh), at_q),
+            pl.BlockSpec((1, 1, block_k, dh), at_k),
+            pl.BlockSpec((1, 1, block_k, dv), at_k),
+            pl.BlockSpec((1, 1, block_k), at_mask),
         ],
         out_specs=[
-            pl.BlockSpec((1, 1, block_q, dv), lambda b_, h_, i, j: (b_, h_, i, 0)),
-            pl.BlockSpec((1, 1, block_q, 1), lambda b_, h_, i, j: (b_, h_, i, 0)),
+            pl.BlockSpec((1, 1, block_q, dv), at_q),
+            pl.BlockSpec((1, 1, block_q, 1), at_q),
         ],
         out_shape=[
             jax.ShapeDtypeStruct((b, h, tq, dv), q.dtype),
@@ -272,54 +451,71 @@ def _fwd_call(q, k, v, kvm, scale, causal, block_q, block_k, interpret):
 def _bwd_call(q, k, v, kvm, do, lse, delta, scale, causal, block_q, block_k,
               interpret):
     b, h, tq, dh = q.shape
-    tk, dv = k.shape[2], v.shape[3]
+    h_kv, tk, dv = k.shape[1], k.shape[2], v.shape[3]
+    group = h // h_kv
     n_q, n_k = tq // block_q, tk // block_k
 
     dq_kernel = functools.partial(_dq_kernel, scale=scale, causal=causal,
                                   block_q=block_q, block_k=block_k, n_k=n_k)
+    at_q, at_k, at_mask = _index_maps(causal, group, block_q, block_k,
+                                      n_q, n_k)
     dq = pl.pallas_call(
         dq_kernel,
         name="flash_attention_dq",
         grid=(b, h, n_q, n_k),
         in_specs=[
-            pl.BlockSpec((1, 1, block_q, dh), lambda b_, h_, i, j: (b_, h_, i, 0)),
-            pl.BlockSpec((1, 1, block_k, dh), lambda b_, h_, i, j: (b_, h_, j, 0)),
-            pl.BlockSpec((1, 1, block_k, dv), lambda b_, h_, i, j: (b_, h_, j, 0)),
-            pl.BlockSpec((1, 1, block_k), lambda b_, h_, i, j: (b_, 0, j)),
-            pl.BlockSpec((1, 1, block_q, dv), lambda b_, h_, i, j: (b_, h_, i, 0)),
-            pl.BlockSpec((1, 1, block_q, 1), lambda b_, h_, i, j: (b_, h_, i, 0)),
-            pl.BlockSpec((1, 1, block_q, 1), lambda b_, h_, i, j: (b_, h_, i, 0)),
+            pl.BlockSpec((1, 1, block_q, dh), at_q),
+            pl.BlockSpec((1, 1, block_k, dh), at_k),
+            pl.BlockSpec((1, 1, block_k, dv), at_k),
+            pl.BlockSpec((1, 1, block_k), at_mask),
+            pl.BlockSpec((1, 1, block_q, dv), at_q),
+            pl.BlockSpec((1, 1, block_q, 1), at_q),
+            pl.BlockSpec((1, 1, block_q, 1), at_q),
         ],
-        out_specs=pl.BlockSpec((1, 1, block_q, dh),
-                               lambda b_, h_, i, j: (b_, h_, i, 0)),
+        out_specs=pl.BlockSpec((1, 1, block_q, dh), at_q),
         out_shape=jax.ShapeDtypeStruct((b, h, tq, dh), q.dtype),
         scratch_shapes=[pltpu.VMEM((block_q, dh), jnp.float32)],
         interpret=interpret,
         compiler_params=None if interpret else _compiler_params(),
     )(q, k, v, kvm, do, lse, delta)
 
+    # grid (b, key/value head, k tile, the group's query heads x q tiles):
+    # the last axis is sequential, so one key tile's dk and dv gather every
+    # query head of the group in VMEM
+    def q_side(b_, h_, j, step):
+        if group == 1:
+            head, i = h_, step
+        else:
+            head, i = h_ * group + step // n_q, step % n_q
+        if isinstance(causal, BlockDiffusion):
+            i = _resident(i, _q_spans(causal, j, block_q, block_k), n_q)
+        return b_, head, i, 0
+
+    def k_side(b_, h_, j, step):
+        return b_, h_, j, 0
     dkv_kernel = functools.partial(_dkv_kernel, scale=scale, causal=causal,
-                                   block_q=block_q, block_k=block_k, n_q=n_q)
+                                   block_q=block_q, block_k=block_k, n_q=n_q,
+                                   group=group)
     dk, dv = pl.pallas_call(
         dkv_kernel,
         name="flash_attention_dkv",
-        grid=(b, h, n_k, n_q),
+        grid=(b, h_kv, n_k, group * n_q),
         in_specs=[
-            pl.BlockSpec((1, 1, block_q, dh), lambda b_, h_, j, i: (b_, h_, i, 0)),
-            pl.BlockSpec((1, 1, block_k, dh), lambda b_, h_, j, i: (b_, h_, j, 0)),
-            pl.BlockSpec((1, 1, block_k, dv), lambda b_, h_, j, i: (b_, h_, j, 0)),
-            pl.BlockSpec((1, 1, block_k), lambda b_, h_, j, i: (b_, 0, j)),
-            pl.BlockSpec((1, 1, block_q, dv), lambda b_, h_, j, i: (b_, h_, i, 0)),
-            pl.BlockSpec((1, 1, block_q, 1), lambda b_, h_, j, i: (b_, h_, i, 0)),
-            pl.BlockSpec((1, 1, block_q, 1), lambda b_, h_, j, i: (b_, h_, i, 0)),
+            pl.BlockSpec((1, 1, block_q, dh), q_side),
+            pl.BlockSpec((1, 1, block_k, dh), k_side),
+            pl.BlockSpec((1, 1, block_k, dv), k_side),
+            pl.BlockSpec((1, 1, block_k), lambda b_, h_, j, step: (b_, 0, j)),
+            pl.BlockSpec((1, 1, block_q, dv), q_side),
+            pl.BlockSpec((1, 1, block_q, 1), q_side),
+            pl.BlockSpec((1, 1, block_q, 1), q_side),
         ],
         out_specs=[
-            pl.BlockSpec((1, 1, block_k, dh), lambda b_, h_, j, i: (b_, h_, j, 0)),
-            pl.BlockSpec((1, 1, block_k, dv), lambda b_, h_, j, i: (b_, h_, j, 0)),
+            pl.BlockSpec((1, 1, block_k, dh), k_side),
+            pl.BlockSpec((1, 1, block_k, dv), k_side),
         ],
         out_shape=[
-            jax.ShapeDtypeStruct((b, h, tk, dh), k.dtype),
-            jax.ShapeDtypeStruct((b, h, tk, dv), v.dtype),
+            jax.ShapeDtypeStruct((b, h_kv, tk, dh), k.dtype),
+            jax.ShapeDtypeStruct((b, h_kv, tk, dv), v.dtype),
         ],
         scratch_shapes=[pltpu.VMEM((block_k, dh), jnp.float32),
                         pltpu.VMEM((block_k, dv), jnp.float32)],
@@ -364,20 +560,31 @@ _flash.defvjp(_flash_fwd, _flash_bwd)
 
 def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array,
                     kv_mask: Optional[jax.Array] = None,
-                    causal: bool = False,
+                    causal: Rule = False,
                     scale: Optional[float] = None,
                     block_q: Optional[int] = None,
                     block_k: Optional[int] = None,
                     interpret: Optional[bool] = None) -> jax.Array:
     """softmax(scale * Q K^T + mask) V, never materializing the score matrix.
 
-    q [B,H,Tq,Dh], k [B,H,Tk,Dh], v [B,H,Tk,Dv], kv_mask [B,Tk] (1.0 =
-    attend) or None; the default scale is 1/sqrt(Dh), the key width.
-    Sequence dims are padded up to block multiples internally (padded keys
-    are masked out; padded query rows are sliced off).
+    q [B,H,Tq,Dh], k [B,Hkv,Tk,Dh], v [B,Hkv,Tk,Dv] with H a multiple of
+    Hkv, kv_mask [B,Tk] (1.0 = attend) or None, `causal` a rule (False,
+    True, or a BlockDiffusion over Tq = Tk = 2 * its length); the default
+    scale is 1/sqrt(Dh), the key width. Sequence dims are padded up to
+    block multiples internally (padded keys are masked out; padded query
+    rows are sliced off).
     """
     b, h, tq, dh = q.shape
     tk = k.shape[2]
+    if h % k.shape[1] or k.shape[1] != v.shape[1]:
+        raise ValueError(f"{h} query heads on {k.shape[1]} key and "
+                         f"{v.shape[1]} value heads")
+    if isinstance(causal, BlockDiffusion):
+        if not (tq == tk == 2 * causal.length and causal.block >= 1):
+            raise ValueError(f"{causal} over {tq} queries and {tk} keys: "
+                             f"want twice its length of both")
+    else:
+        causal = bool(causal)
     if scale is None:
         scale = 1.0 / (dh ** 0.5)
     if interpret is None:
@@ -408,9 +615,9 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array,
         # biggest block <= limit whose grid padding wastes <= 25% of t:
         # big blocks cut online-softmax rescale passes (the r5 sweep
         # win), but a 2048 block on t=2176 would pad to 4096 and run
-        # the fully-masked blocks through every kernel — padded k/q
-        # blocks are NOT skipped (the causal `live` test is
-        # position-only)
+        # the fully-masked blocks through every kernel — a tile of
+        # padded keys is skipped only where the rule kills it (a rule's
+        # `_live` test reads indices, not the key mask)
         b = _round_up(min(limit, _round_up(t, _LANES)), _LANES)
         while b > _LANES:
             if _round_up(t, b) - t <= max(t // 4, _LANES):
@@ -433,7 +640,13 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array,
     if tq_p != tq:
         q = jnp.pad(q, ((0, 0), (0, 0), (0, tq_p - tq), (0, 0)))
 
-    out = _flash(q, k, v, kvm, float(scale), bool(causal), bq, bk,
+    if obs.enabled():
+        n_q, n_k = tq_p // bq, tk_p // bk
+        obs.event("flash_attention.plan", tq=tq, tk=tk, block_q=bq,
+                  block_k=bk, rule=_rule_name(causal),
+                  tiles_live=live_tiles(causal, n_q, n_k, bq, bk),
+                  tiles=n_q * n_k, kv_group=h // k.shape[1])
+    out = _flash(q, k, v, kvm, float(scale), causal, bq, bk,
                  bool(interpret))
     if tq_p != tq:
         out = out[:, :, :tq, :]

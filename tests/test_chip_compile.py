@@ -110,6 +110,22 @@ def _latent_attn(b, t):
     return jax.grad(loss, argnums=(0, 1, 2)), shapes, list(_FLASH[1:])
 
 
+def _block_diffusion_attn(b, t):
+    """The layer plan's grouped-query attention under the block rule at
+    its published head widths: 32 query heads on 4 key/value heads of
+    128, the doubled row of 2t indices, blocks of 4, with the forward and
+    both backward kernels."""
+    from marian_tpu.ops.pallas.flash_attention import BlockDiffusion
+
+    def loss(q, k, v, m):
+        return flash_attention(q, k, v, kv_mask=m,
+                               causal=BlockDiffusion(t, 4),
+                               interpret=False).astype(jnp.float32).sum()
+    shapes = [((b, 32, 2 * t, 128), DT), ((b, 4, 2 * t, 128), DT),
+              ((b, 4, 2 * t, 128), DT), ((b, 2 * t), jnp.float32)]
+    return jax.grad(loss, argnums=(0, 1, 2)), shapes, list(_FLASH[1:])
+
+
 def _kda_carry(b, heads, chunks):
     """The delta rule's state carry (chunks of 64, 128 x 128 state a
     head, float32), forward and backward."""
@@ -238,6 +254,11 @@ CASES = {
     # rows of 1024 and as 2 of 8192; KDA's heads come 4 at a time
     "flash-grad-192x128-16x1024": lambda: _latent_attn(16, 1024),
     "flash-grad-192x128-2x8192": lambda: _latent_attn(2, 8192),
+    # block-diffusion training (sdar.train-docs8k): the same rows, doubled
+    "flash-grad-block-diffusion-16x1024":
+        lambda: _block_diffusion_attn(16, 1024),
+    "flash-grad-block-diffusion-2x8192":
+        lambda: _block_diffusion_attn(2, 8192),
     "kda-carry-grad-16x1024": lambda: _kda_carry(16, 4, 16),
     "kda-carry-grad-2x8192": lambda: _kda_carry(2, 4, 128),
     "kda-prep-grad-16x1024": lambda: _kda_prep(16, 4, 1024),

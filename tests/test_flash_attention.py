@@ -6,6 +6,8 @@ test tier (src/tests/units/attention_tests.cpp): small-tensor agreement
 between two independent implementations, plus autodiff agreement.
 """
 
+import importlib
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -220,3 +222,207 @@ class TestBlockEnvOverrides:
         ref = dense_attention(q, k, v)
         np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
                                    atol=2e-5)
+
+
+# ---------------------------------------------------------------------------
+# A rule in place of `causal`: block-diffusion training over a doubled row,
+# grouped-query heads, and `causal=True` as it was
+# ---------------------------------------------------------------------------
+
+from time_limit import time_limit  # noqa: E402
+
+from marian_tpu.ops.attention import block_diffusion_mask  # noqa: E402
+# (the package re-exports the FUNCTION under the module's name)
+F = importlib.import_module("marian_tpu.ops.pallas.flash_attention")
+
+
+def _written_out(length, block):
+    """The rule's three sentences, index by index, in plain Python."""
+    see = np.zeros((2 * length, 2 * length), bool)
+    for q in range(2 * length):
+        for k in range(2 * length):
+            q_noised, k_noised = q < length, k < length
+            qb, kb = (q % length) // block, (k % length) // block
+            see[q, k] = (q_noised and k_noised and qb == kb) \
+                or (q_noised and not k_noised and kb < qb) \
+                or (not q_noised and not k_noised and kb <= qb)
+    return see
+
+
+@pytest.mark.parametrize("length,block", [(10, 4), (37, 4), (24, 3),
+                                          (16, 1), (9, 16)])
+@time_limit(60)
+def test_the_rule_is_its_three_sentences(length, block):
+    got = np.asarray(block_diffusion_mask(length, block))[0, 0] > 0
+    np.testing.assert_array_equal(got, _written_out(length, block))
+
+
+@pytest.mark.parametrize("length,block,bq,bk", [
+    (10, 4, 8, 8), (37, 4, 16, 8), (64, 4, 16, 32), (50, 3, 8, 16),
+    (33, 5, 16, 16), (1024, 4, 512, 1024), (8192, 4, 512, 1024)])
+@time_limit(120)
+def test_a_tile_is_live_iff_it_holds_a_pair_that_sees(length, block, bq, bk):
+    """Both tile tests (the key tiles of a query tile: forward and dq; the
+    query tiles of a key tile: dkv) against the mask itself, and the tile
+    a dead step stays on: a live one, already fetched."""
+    rule = F.BlockDiffusion(length, block)
+    n = 2 * length
+    n_q, n_k = -(-n // bq), -(-n // bk)
+    see = np.array(F.rule_mask(rule, np.arange(n_q * bq)[:, None],
+                               np.arange(n_k * bk)[None, :]))
+    see[n:], see[:, n:] = False, False
+    want = see.reshape(n_q, bq, n_k, bk).any(axis=(1, 3))
+    i, j = np.arange(n_q)[:, None], np.arange(n_k)[None, :]
+    with jax.ensure_compile_time_eval():
+        by_q = np.broadcast_to(np.asarray(F._live(rule, i, j, bq, bk)),
+                               want.shape)
+        by_k = np.broadcast_to(np.asarray(F._in_spans(
+            i, F._q_spans(rule, j, bq, bk))), want.shape)
+        stay_k = np.broadcast_to(np.asarray(F._resident(
+            j, F._kv_spans(rule, i, bq, bk), n_k)), want.shape)
+        stay_q = np.broadcast_to(np.asarray(F._resident(
+            i, F._q_spans(rule, j, bq, bk), n_q)), want.shape)
+    np.testing.assert_array_equal(by_q, want)
+    np.testing.assert_array_equal(by_k, want)
+    assert F.live_tiles(rule, n_q, n_k, bq, bk) == want.sum()
+    # a live step asks for its own tile, a dead one for a live tile at or
+    # before it (or the row's first live one)
+    np.testing.assert_array_equal(stay_k[want], np.broadcast_to(j, want.shape)[want])
+    np.testing.assert_array_equal(stay_q[want], np.broadcast_to(i, want.shape)[want])
+    assert want[np.broadcast_to(i, want.shape), stay_k].all()
+    assert want[stay_q, np.broadcast_to(j, want.shape)].all()
+    if length == 8192:
+        assert want.sum() / want.size < 0.35
+
+
+def _doubled_case(rng, length, heads, kv_heads, dh=16, rows=2):
+    q = _rand(rng, rows, heads, 2 * length, dh)
+    k = _rand(rng, rows, kv_heads, 2 * length, dh)
+    v = _rand(rng, rows, kv_heads, 2 * length, dh)
+    real = np.arange(length)[None] < np.array([length, length - 7])[:, None]
+    mask = jnp.asarray(np.concatenate([real, real], 1), jnp.float32)
+    return q, k, v, mask, _rand(rng, rows, heads, 2 * length, dh)
+
+
+@pytest.mark.parametrize("length,block,group,bq,bk", [
+    (37, 4, 1, 16, 8),        # T no multiple of the block or of a tile
+    (50, 3, 4, 8, 16),
+    (40, 4, 8, None, None),   # one tile
+    (300, 4, 8, 128, 128),
+    (160, 4, 4, 128, 256)])
+@time_limit(240)
+def test_block_diffusion_kernels_match_dense(rng, length, block, group, bq,
+                                             bk):
+    """Forward and the three gradients of the kernels (interpret mode)
+    against the dense path under the same rule: padded keys, T no
+    multiple of block or tile, 1, 4 and 8 query heads a key/value head."""
+    q, k, v, mask, w = _doubled_case(rng, length, group * (2 if group < 8
+                                                           else 1),
+                                     2 if group < 8 else 1)
+    rule = F.BlockDiffusion(length, block)
+    real = mask[:, None, :, None]
+
+    def dense(q, k, v):
+        out, _ = attention(q, k, v, kv_mask=mask, causal=rule, flash="off",
+                           packed="off")
+        return jnp.sum(out * w * real), out
+
+    def flash(q, k, v):
+        out = flash_attention(q, k, v, kv_mask=mask, causal=rule,
+                              block_q=bq, block_k=bk)
+        return jnp.sum(out * w * real), out
+    (_, want), want_g = jax.value_and_grad(dense, (0, 1, 2), True)(q, k, v)
+    (_, got), got_g = jax.value_and_grad(flash, (0, 1, 2), True)(q, k, v)
+    np.testing.assert_allclose(got * real, want * real, atol=2e-5)
+    for a, b in zip(got_g, want_g):
+        assert a.shape == b.shape
+        np.testing.assert_allclose(a, b, atol=5e-5)
+
+
+@time_limit(120)
+def test_the_dispatcher_hands_flash_the_rule_and_the_shared_heads(rng):
+    q, k, v, mask, _ = _doubled_case(rng, 72, 4, 2)
+    rule = F.BlockDiffusion(72, 4)
+    got, _ = attention(q, k, v, kv_mask=mask, causal=rule, flash="on")
+    want, _ = attention(q, k, v, kv_mask=mask, causal=rule, flash="off")
+    np.testing.assert_allclose(got, want, atol=2e-5)
+    with pytest.raises(ValueError):
+        flash_attention(q[:, :, :100], k, v, causal=rule)
+    with pytest.raises(ValueError):
+        flash_attention(q[:, :3], k, v, causal=True)
+
+
+@time_limit(120)
+def test_grouped_heads_are_repeated_heads_under_causal(rng):
+    """8 query heads on 2 key/value heads, causal with a padding mask: the
+    kernels reading a shared head in place against the same kernels on
+    the heads repeated; dk and dv are the group's sum."""
+    b, t, dh = 2, 200, 16
+    q, k, v = _rand(rng, b, 8, t, dh), _rand(rng, b, 2, t, dh), \
+        _rand(rng, b, 2, t, dh)
+    m = _kv_mask(rng, b, t)
+
+    def shared(q, k, v):
+        return (flash_attention(q, k, v, kv_mask=m, causal=True,
+                                block_q=128, block_k=128) ** 2).sum()
+
+    def repeated(q, k, v):
+        return shared(q, jnp.repeat(k, 4, axis=1), jnp.repeat(v, 4, axis=1))
+    got = jax.grad(shared, (0, 1, 2))(q, k, v)
+    want = jax.grad(repeated, (0, 1, 2))(q, k, v)
+    for a, b_ in zip(got, want):
+        np.testing.assert_allclose(a, b_, atol=1e-4, rtol=1e-5)
+
+
+# sha256 over the bytes of (out, dq, dk, dv) of `_causal_as_it_was` through
+# the kernels of the commit before they took a rule or shared heads (PR 33,
+# 590c63a), on this machine's CPU in interpret mode
+_CAUSAL_SHA256 = \
+    "d51e34cc0b7d41673bae681c48fefa05f617ae3b8b184eb5cf848614294b1ec5"
+
+
+def _causal_as_it_was():
+    rng = np.random.RandomState(1234)
+    b, h, t, dh, dv = 2, 3, 300, 24, 16
+    q, k = _rand(rng, b, h, t, dh), _rand(rng, b, h, t, dh)
+    v, m = _rand(rng, b, h, t, dv), _kv_mask(rng, b, t)
+
+    def f(q, k, v):
+        out = flash_attention(q, k, v, kv_mask=m, causal=True, block_q=128,
+                              block_k=128)
+        return (out ** 2).sum(), out
+    (_, out), grads = jax.value_and_grad(f, (0, 1, 2), True)(q, k, v)
+    import hashlib
+    h = hashlib.sha256()
+    for a in (out,) + grads:
+        h.update(np.asarray(a).tobytes())
+    return h.hexdigest()
+
+
+@time_limit(120)
+def test_causal_through_the_edited_kernels_is_bitwise_what_it_was():
+    assert _causal_as_it_was() == _CAUSAL_SHA256
+
+
+@time_limit(60)
+def test_the_plan_event_says_how_many_tiles_are_live(rng):
+    from marian_tpu.obs import TRACER
+    q, k, v, mask, _ = _doubled_case(rng, 300, 8, 1)
+    TRACER.reset()
+    TRACER.enable()
+    try:
+        flash_attention(q, k, v, kv_mask=mask,
+                        causal=F.BlockDiffusion(300, 4), block_q=128,
+                        block_k=128)
+        flash_attention(q, q, q, causal=True, block_q=128, block_k=128)
+        events = [e for e in TRACER.snapshot()[1]
+                  if e["name"] == "flash_attention.plan"]
+    finally:
+        TRACER.disable()
+        TRACER.reset()
+    rule, causal = (e["attrs"] for e in events)
+    assert rule == {"tq": 600, "tk": 600, "block_q": 128, "block_k": 128,
+                    "rule": "block_diffusion(300,4)", "tiles_live": 15,
+                    "tiles": 25, "kv_group": 8}
+    assert (causal["rule"], causal["tiles_live"], causal["tiles"],
+            causal["kv_group"]) == ("causal", 15, 25, 1)

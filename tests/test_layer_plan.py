@@ -566,7 +566,7 @@ def _bf16_state(qg, wk, wv, kd, gc, p):
     return jnp.stack(out, 2)
 
 
-def _bf16_route(x, w_router, top_k, scale):
+def _bf16_route(x, w_router, top_k, scale, score="sigmoid"):
     s = jax.nn.sigmoid(jnp.dot(x.astype(jnp.bfloat16),
                                w_router.astype(jnp.bfloat16)))
     vals, idx = jax.lax.top_k(s, top_k)
@@ -701,7 +701,7 @@ def test_a_checkpointed_mla_half_runs_the_flash_forward_once(checkpointed,
         _loss_gradient(model, batch))(params).jaxpr)
     assert calls == {"flash_attention_fwd": n, "flash_attention_dq": n,
                      "flash_attention_dkv": n}
-    monkeypatch.setattr(P, "_MLA_KEEPS", ())
+    monkeypatch.setattr(P, "_FLASH_KEEPS", ())
     calls = _kernel_calls(jax.make_jaxpr(
         _loss_gradient(model, batch))(params).jaxpr)
     assert calls == {"flash_attention_fwd": 2 * n, "flash_attention_dq": n,
@@ -714,7 +714,7 @@ def test_what_is_kept_changes_no_gradient(checkpointed, monkeypatch):
     kernel run again: the kept arrays are what it would have written."""
     model, params, batch, _ = checkpointed
     kept = _loss_gradient(model, batch)(params)
-    monkeypatch.setattr(P, "_MLA_KEEPS", ())
+    monkeypatch.setattr(P, "_FLASH_KEEPS", ())
     again = _loss_gradient(model, batch)(params)
     assert set(kept) == set(again) == set(params)
     for name in sorted(params):
@@ -753,7 +753,7 @@ def test_the_kept_bytes_are_said_as_an_mla_half_is_traced(checkpointed):
         lp for lp, (mix, _) in P._blocks(cfg) if mix == "mla"]
     assert len(said) == n and t == 80
     for e in said:
-        assert e["names"] == P._MLA_KEEPS == (
+        assert e["names"] == P._FLASH_KEEPS == (
             "flash_attention_out", "flash_attention_lse")
         assert e["bytes"] == rows * (cfg.mla_dim_v * 4 + 4)
     kept = model.cfg
