@@ -17,10 +17,9 @@ Masks the kernels take (any other pattern, e.g. an arbitrary dense
                          row [noised ; clean] of 2T positions (see the
                          class)
     From a rule the three kernels take which tiles are live and the mask
-    inside a live tile. Under `causal=True` a dead tile is fetched and
-    not computed; under BlockDiffusion it is neither: a dead step's block
-    index is clamped to a resident live one, so the pipeline fetches
-    nothing new for it.
+    inside a live tile. A dead tile is neither computed nor fetched: a
+    dead step's block index is clamped to a resident live tile, so the
+    pipeline fetches nothing new for it.
 Attention-weight dropout and returned weights are NOT supported here; the
 dispatcher (ops/attention.py :: attention) falls back to the dense path for
 those cases.
@@ -196,6 +195,33 @@ def _resident(x, spans, n: int):
     return jnp.clip(r, 0, n - 1)
 
 
+def _key_tile(rule: Rule, i, j, block_q: int, block_k: int, n_k: int):
+    """The key tile that step (i, j) of the forward and dq grids asks
+    for: j where tile (i, j) is live, else a live tile of query tile i
+    that the pipeline still holds, so a dead step fetches nothing.
+    (`lax` and not `jnp` under `causal`: an index map is traced and
+    lowered for every block of every kernel call, and a `jnp` function
+    there is a function of its own each time: half again the lowering
+    time of an attention layer.)"""
+    if rule is True:
+        return jax.lax.min(j, jax.lax.div(i * block_q + (block_q - 1),
+                                          block_k))
+    if rule:
+        return _resident(j, _kv_spans(rule, i, block_q, block_k), n_k)
+    return j
+
+
+def _query_tile(rule: Rule, i, j, block_q: int, block_k: int, n_q: int):
+    """Its twin for the dkv grid: the query tile that step (j, i) asks
+    for."""
+    if rule is True:
+        first = jax.lax.div(j * block_k, block_q)
+        return jax.lax.min(jax.lax.max(i, first), n_q - 1)
+    if rule:
+        return _resident(i, _q_spans(rule, j, block_q, block_k), n_q)
+    return i
+
+
 def _live(rule: Rule, i, j, block_q: int, block_k: int):
     """Does tile (query tile i, key tile j) hold a pair that sees?"""
     if rule is True:
@@ -206,22 +232,39 @@ def _live(rule: Rule, i, j, block_q: int, block_k: int):
     return True
 
 
-def _masked(rule: Rule, s, i, j, block_q: int, block_k: int):
-    """The scores of tile (i, j) with the pairs that do not see set to
-    MASK_VALUE."""
+def _whole(rule: Rule, i, j, block_q: int, block_k: int):
+    """Does every pair of tile (i, j) see, by the rule alone (the key
+    padding mask is no part of it)? Sufficient, not necessary. Counted
+    for the plan's event only: the kernels mask a whole tile like a cut
+    one, because on the chip the mask's passes hide behind the MXU and
+    a second, unmasked body cost set-up time for nothing (PERF.md 6, PR
+    36)."""
     if rule is True:
-        qpos = i * block_q + jax.lax.broadcasted_iota(
-            jnp.int32, (block_q, block_k), 0)
-        kpos = j * block_k + jax.lax.broadcasted_iota(
-            jnp.int32, (block_q, block_k), 1)
-        return jnp.where(qpos >= kpos, s, MASK_VALUE)
+        return j * block_k + block_k - 1 <= i * block_q
     if rule:
-        qpos = i * block_q + jax.lax.broadcasted_iota(
-            jnp.int32, (block_q, 1), 0)
-        kpos = j * block_k + jax.lax.broadcasted_iota(
-            jnp.int32, (1, block_k), 1)
-        return jnp.where(rule_mask(rule, qpos, kpos), s, MASK_VALUE)
-    return s
+        # clean keys only, before queries of one kind: a clean query sees
+        # up to its own block, a noised one up to the block before
+        t, b = rule
+        q0, k0 = i * block_q, j * block_k
+        last = _block_of(k0 + block_k - 1 - t, b)
+        clean = (q0 >= t) & (last <= _block_of(q0 - t, b))
+        noised = (q0 + block_q <= t) & (last < _block_of(q0, b))
+        return (k0 >= t) & (clean | noised)
+    return True
+
+
+def _masked(rule: Rule, s, i, j, block_q: int, block_k: int):
+    """The scores of live tile (i, j) with the pairs that do not see set
+    to MASK_VALUE: a column of query indices against a row of key
+    indices, so one compare and one select run on the square."""
+    if not rule:
+        return s
+    qpos = i * block_q + jax.lax.broadcasted_iota(
+        jnp.int32, (block_q, 1), 0)
+    kpos = j * block_k + jax.lax.broadcasted_iota(
+        jnp.int32, (1, block_k), 1)
+    sees = qpos >= kpos if rule is True else rule_mask(rule, qpos, kpos)
+    return jnp.where(sees, s, MASK_VALUE)
 
 
 def _rule_name(rule: Rule) -> str:
@@ -230,14 +273,26 @@ def _rule_name(rule: Rule) -> str:
     return "causal" if rule else "none"
 
 
-def live_tiles(rule: Rule, n_q: int, n_k: int, block_q: int,
-               block_k: int) -> int:
-    """How many of the n_q x n_k tiles the kernels compute."""
+def _count_tiles(test, rule: Rule, n_q: int, n_k: int, block_q: int,
+                 block_k: int) -> int:
     with jax.ensure_compile_time_eval():
         i = jnp.arange(n_q, dtype=jnp.int32)[:, None]
         j = jnp.arange(n_k, dtype=jnp.int32)[None, :]
         return int(jnp.sum(jnp.broadcast_to(
-            _live(rule, i, j, block_q, block_k), (n_q, n_k))))
+            test(rule, i, j, block_q, block_k), (n_q, n_k))))
+
+
+def live_tiles(rule: Rule, n_q: int, n_k: int, block_q: int,
+               block_k: int) -> int:
+    """How many of the n_q x n_k tiles the kernels compute."""
+    return _count_tiles(_live, rule, n_q, n_k, block_q, block_k)
+
+
+def whole_tiles(rule: Rule, n_q: int, n_k: int, block_q: int,
+                block_k: int) -> int:
+    """How many of them no edge of the rule cuts; of the others' pairs
+    about half are computed and masked."""
+    return _count_tiles(_whole, rule, n_q, n_k, block_q, block_k)
 
 
 # ---------------------------------------------------------------------------
@@ -397,15 +452,13 @@ def _compiler_params(n_seq_dims: int = 1):
 def _index_maps(causal, group, block_q, block_k, n_q, n_k):
     """The block index maps of the grids (b, h, i, j) of the forward and
     dq kernels: (queries' side, keys' side, key mask). A query head reads
-    key/value head h // group; under a BlockDiffusion rule a dead step
-    keeps the key tile of the live step before it."""
+    key/value head h // group; under a rule a dead step keeps the key
+    tile of a live one."""
     def kv_head(h_):
         return h_ if group == 1 else h_ // group
 
     def k_tile(i, j):
-        if isinstance(causal, BlockDiffusion):
-            return _resident(j, _kv_spans(causal, i, block_q, block_k), n_k)
-        return j
+        return _key_tile(causal, i, j, block_q, block_k, n_k)
     return (lambda b_, h_, i, j: (b_, h_, i, 0),
             lambda b_, h_, i, j: (b_, kv_head(h_), k_tile(i, j), 0),
             lambda b_, h_, i, j: (b_, 0, k_tile(i, j)))
@@ -487,9 +540,7 @@ def _bwd_call(q, k, v, kvm, do, lse, delta, scale, causal, block_q, block_k,
             head, i = h_, step
         else:
             head, i = h_ * group + step // n_q, step % n_q
-        if isinstance(causal, BlockDiffusion):
-            i = _resident(i, _q_spans(causal, j, block_q, block_k), n_q)
-        return b_, head, i, 0
+        return b_, head, _query_tile(causal, i, j, block_q, block_k, n_q), 0
 
     def k_side(b_, h_, j, step):
         return b_, h_, j, 0
@@ -645,6 +696,7 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array,
         obs.event("flash_attention.plan", tq=tq, tk=tk, block_q=bq,
                   block_k=bk, rule=_rule_name(causal),
                   tiles_live=live_tiles(causal, n_q, n_k, bq, bk),
+                  tiles_whole=whole_tiles(causal, n_q, n_k, bq, bk),
                   tiles=n_q * n_k, kv_group=h // k.shape[1])
     out = _flash(q, k, v, kvm, float(scale), causal, bq, bk,
                  bool(interpret))

@@ -257,9 +257,11 @@ def test_the_rule_is_its_three_sentences(length, block):
     np.testing.assert_array_equal(got, _written_out(length, block))
 
 
-@pytest.mark.parametrize("length,block,bq,bk", [
-    (10, 4, 8, 8), (37, 4, 16, 8), (64, 4, 16, 32), (50, 3, 8, 16),
-    (33, 5, 16, 16), (1024, 4, 512, 1024), (8192, 4, 512, 1024)])
+_TILINGS = [(10, 4, 8, 8), (37, 4, 16, 8), (64, 4, 16, 32), (50, 3, 8, 16),
+            (33, 5, 16, 16), (1024, 4, 512, 1024), (8192, 4, 512, 1024)]
+
+
+@pytest.mark.parametrize("length,block,bq,bk", _TILINGS)
 @time_limit(120)
 def test_a_tile_is_live_iff_it_holds_a_pair_that_sees(length, block, bq, bk):
     """Both tile tests (the key tiles of a query tile: forward and dq; the
@@ -293,6 +295,54 @@ def test_a_tile_is_live_iff_it_holds_a_pair_that_sees(length, block, bq, bk):
     assert want[stay_q, np.broadcast_to(j, want.shape)].all()
     if length == 8192:
         assert want.sum() / want.size < 0.35
+
+
+# (whole, live) tiles a row and head on the benchmark cells' shapes: the
+# widest and the narrowest rows of their documents at blocks 512 / 1024
+_CELL_TILES = {("causal", 8192): (56, 72), ("causal", 1024): (0, 2),
+               ("block_diffusion(8192,4)", 16384): (112, 160),
+               ("block_diffusion(1024,4)", 2048): (0, 6)}
+
+
+@pytest.mark.parametrize("rule,n,bq,bk", [
+    (F.BlockDiffusion(length, block), 2 * length, bq, bk)
+    for length, block, bq, bk in _TILINGS] + [
+    (True, 20, 8, 8), (True, 100, 16, 8), (True, 90, 8, 32),
+    (True, 300, 128, 128), (True, 1024, 512, 1024),
+    (True, 8192, 512, 1024)],
+    ids=lambda x: F._rule_name(x) if isinstance(x, (bool, tuple)) else None)
+@time_limit(120)
+def test_a_whole_tile_holds_no_pair_that_does_not_see(rule, n, bq, bk):
+    """`_whole` (the plan event's count) against the mask itself (numpy,
+    off the kernels): every real pair of a whole tile sees, a whole tile
+    is live, and on the cells' shapes the test finds every tile the rule
+    leaves whole. And the tile a dead step asks for under either rule: a
+    live one."""
+    n_q, n_k = -(-n // bq), -(-n // bk)
+    qpos, kpos = np.arange(n_q * bq)[:, None], np.arange(n_k * bk)[None, :]
+    see = qpos >= kpos if rule is True else np.array(
+        F.rule_mask(rule, qpos, kpos))
+    tiles = see.reshape(n_q, bq, n_k, bk)
+    real = np.broadcast_to((qpos < n) & (kpos < n), see.shape).reshape(
+        tiles.shape)
+    all_see, live = (tiles | ~real).all(axis=(1, 3)), \
+        (tiles & real).any(axis=(1, 3))
+    ii, jj = (np.ascontiguousarray(a, np.int32) for a in np.broadcast_arrays(
+        np.arange(n_q)[:, None], np.arange(n_k)[None, :]))
+    with jax.ensure_compile_time_eval():
+        whole = np.asarray(F._whole(rule, ii, jj, bq, bk))
+        stay_k = np.asarray(F._key_tile(rule, ii, jj, bq, bk, n_k))
+        stay_q = np.asarray(F._query_tile(rule, ii, jj, bq, bk, n_q))
+        np.testing.assert_array_equal(F._live(rule, ii, jj, bq, bk), live)
+    assert not (whole & ~all_see).any() and not (whole & ~live).any()
+    assert F.whole_tiles(rule, n_q, n_k, bq, bk) == whole.sum()
+    cell = _CELL_TILES.get((F._rule_name(rule), n))
+    if cell:
+        np.testing.assert_array_equal(whole, all_see)
+        assert (whole.sum(), live.sum()) == cell
+    np.testing.assert_array_equal(stay_k[live], jj[live])
+    np.testing.assert_array_equal(stay_q[live], ii[live])
+    assert live[ii, stay_k].all() and live[stay_q, jj].all()
 
 
 def _doubled_case(rng, length, heads, kv_heads, dh=16, rows=2):
@@ -374,6 +424,73 @@ def test_grouped_heads_are_repeated_heads_under_causal(rng):
         np.testing.assert_allclose(a, b_, atol=1e-4, rtol=1e-5)
 
 
+def _grouped_causal(rng):
+    """8 query heads on 2 key/value heads over 300 positions, padded keys:
+    3 x 3 tiles of 128, three of them dead."""
+    q, k, v = _rand(rng, 2, 8, 300, 16), _rand(rng, 2, 2, 300, 16), \
+        _rand(rng, 2, 2, 300, 16)
+    return q, k, v, _kv_mask(rng, 2, 300), True, _rand(rng, 2, 8, 300, 16)
+
+
+def _grouped_doubled(rng):
+    """8 query heads on one key/value head over [noised ; clean] of 384
+    each: 6 x 6 tiles of 128, 21 of them dead."""
+    q, k, v, mask, w = _doubled_case(rng, 384, 8, 1)
+    return q, k, v, mask, F.BlockDiffusion(384, 4), w
+
+
+@pytest.mark.parametrize("case", [_grouped_causal, _grouped_doubled])
+@time_limit(600)
+def test_a_dead_step_that_fetches_nothing_changes_no_bit(rng, monkeypatch,
+                                                         case):
+    """out, dq, dk and dv are the same BITS whether a dead step's block
+    index is clamped to a live tile or every step asks for its own: an
+    unfetched dead tile only leaves work out."""
+    q, k, v, mask, rule, w = case(rng)
+    n = -(-q.shape[2] // 128)
+    assert F.live_tiles(rule, n, n, 128, 128) < n * n
+
+    def run():
+        def f(q, k, v):
+            out = flash_attention(q, k, v, kv_mask=mask, causal=rule,
+                                  block_q=128, block_k=128)
+            return jnp.sum(out * w), out
+        (_, out), grads = jax.value_and_grad(f, (0, 1, 2), True)(q, k, v)
+        return (out,) + grads
+    got = run()
+    monkeypatch.setattr(F, "_key_tile", lambda rule, i, j, bq, bk, n_k: j)
+    monkeypatch.setattr(F, "_query_tile", lambda rule, i, j, bq, bk, n_q: i)
+    for a, b in zip(got, run()):
+        np.testing.assert_array_equal(a, b)
+
+
+def _bodies(kernel_jaxpr):
+    return sum(e.primitive.name == "cond" for e in kernel_jaxpr.eqns)
+
+
+@pytest.mark.parametrize("rule,bodies", [
+    (False, 2), (True, 3), (F.BlockDiffusion(128, 4), 3)],
+    ids=F._rule_name)
+@time_limit(120)
+def test_a_kernel_holds_one_tile_body(rng, rule, bodies):
+    """A kernel's conditional bodies: set-up and the write-back, and
+    under a rule ONE more, the live tile's. A second, unmasked body for
+    the tiles a rule leaves whole was built and measured (PERF.md 6, PR
+    36): the kernels were no faster and an attention's compiled code
+    half again as large, so it went. Without a rule the tile's body
+    stands under no condition, as it did."""
+    q = _rand(rng, 1, 2, 256, 16)
+
+    def f(q):
+        return flash_attention(q, q, q, causal=rule, block_q=128,
+                               block_k=128).sum()
+    kernels = {e.params["name"]: _bodies(e.params["jaxpr"])
+               for e in _equations(jax.make_jaxpr(jax.grad(f))(q).jaxpr)
+               if e.primitive.name == "pallas_call"}
+    assert kernels == {f"flash_attention_{k}": bodies
+                       for k in ("fwd", "dq", "dkv")}
+
+
 # sha256 over the bytes of (out, dq, dk, dv) of `_causal_as_it_was` through
 # the kernels of the commit before they took a rule or shared heads (PR 33,
 # 590c63a), on this machine's CPU in interpret mode
@@ -423,6 +540,6 @@ def test_the_plan_event_says_how_many_tiles_are_live(rng):
     rule, causal = (e["attrs"] for e in events)
     assert rule == {"tq": 600, "tk": 600, "block_q": 128, "block_k": 128,
                     "rule": "block_diffusion(300,4)", "tiles_live": 15,
-                    "tiles": 25, "kv_group": 8}
-    assert (causal["rule"], causal["tiles_live"], causal["tiles"],
-            causal["kv_group"]) == ("causal", 15, 25, 1)
+                    "tiles_whole": 1, "tiles": 25, "kv_group": 8}
+    assert (causal["rule"], causal["tiles_live"], causal["tiles_whole"],
+            causal["tiles"], causal["kv_group"]) == ("causal", 15, 10, 25, 1)
