@@ -31,6 +31,10 @@ Design constraints (docs/OBSERVABILITY.md):
   no lock is ever acquired, no dict is built. The tier-1 overhead-guard
   tests assert exactly this on the scheduler's per-batch hot path and
   on the trainer's loop objects.
+- **Gauges** beside the totals and the step counters (which only sum):
+  :meth:`Tracer.gauge` keeps a sampled level's last, least and largest
+  value. The trainer samples the device allocator's free bytes with
+  them (training/hbm.py).
 - **Lock-free-ish when enabled**: spans are recorded once, at END time,
   with a single bounded-deque append under a lockdep-named lock
   (``Tracer._lock``) held for nanoseconds; exports snapshot under the
@@ -215,6 +219,8 @@ class Tracer:
         # not fetched yet, and the fetched sums by name
         self._lazy: Optional[Tuple] = None               # guarded-by: _lock
         self._counters: Optional[Dict[str, float]] = None  # guarded-by: _lock
+        # gauges: name -> [last, min, max, n]
+        self._gauges: Optional[Dict[str, List]] = None   # guarded-by: _lock
         self._lock = lockdep.make_lock("Tracer._lock")
         self._seq = itertools.count(1)   # span ids; count() is GIL-atomic
 
@@ -241,8 +247,11 @@ class Tracer:
 
     def disable(self) -> None:
         """Stop recording; the rings keep their contents (a flight dump
-        after disable still has the history). reset() frees them."""
+        after disable still has the history). reset() frees them. The
+        gauges go: a minimum is over one stretch of recording."""
         self._enabled = False
+        with self._lock:
+            self._gauges = None
 
     def reset(self) -> None:
         self._enabled = False
@@ -252,6 +261,7 @@ class Tracer:
             self._totals = None
             self._lazy = None
             self._counters = None
+            self._gauges = None
 
     def totals(self) -> Dict[str, Dict]:
         """Per span name, over every live span ended since reset():
@@ -301,6 +311,37 @@ class Tracer:
         """Fetched step counters by name, summed since reset()."""
         with self._lock:
             return dict(self._counters or {})
+
+    # -- gauges --------------------------------------------------------------
+    def gauge(self, name: str, value) -> Optional[int]:
+        """Keep a sampled level (free device memory before a dispatch):
+        per name the last value, the least, the largest and how many.
+        totals() and counters() only sum; a minimum cannot be rebuilt
+        from a sum. Live exactly when spans are; otherwise two flag
+        reads. Returns how many samples the name holds now (1: the first
+        since reset() or disable(), where a caller writes what does not
+        change once), None when off."""
+        if not self._enabled and not profiler_collecting():
+            return None
+        with self._lock:
+            if self._gauges is None:
+                self._gauges = {}
+            g = self._gauges.get(name)
+            if g is None:
+                g = self._gauges[name] = [value, value, value, 1]
+            else:
+                g[0] = value
+                g[1] = min(g[1], value)
+                g[2] = max(g[2], value)
+                g[3] += 1
+            return g[3]
+
+    def gauges(self) -> Dict[str, Dict]:
+        """``last``, ``min``, ``max`` and ``n`` by gauge name, over the
+        samples since reset() or disable()."""
+        with self._lock:
+            return {name: {"last": g[0], "min": g[1], "max": g[2], "n": g[3]}
+                    for name, g in (self._gauges or {}).items()}
 
     # -- recording ----------------------------------------------------------
     def start_span(self, name: str, parent: Optional[Span] = None,

@@ -26,6 +26,8 @@ Semantics carried over exactly:
 from __future__ import annotations
 
 import dataclasses
+import itertools
+import math
 from concurrent.futures import ThreadPoolExecutor
 from typing import Any, Dict, List, Optional, Tuple
 
@@ -44,8 +46,20 @@ from ..optimizers.schedule import LRSchedule
 from ..ops.ops import clip_by_global_norm, global_norm
 from ..parallel import mesh as M
 from ..parallel.zero import build_train_step, place
+from . import hbm
 
 Params = Dict[str, jax.Array]
+
+# a step executable's memory_analysis() under the ledger's names
+_PROGRAM_COSTS = (("code", "generated_code_size_in_bytes"),
+                  ("temp", "temp_size_in_bytes"),
+                  ("args", "argument_size_in_bytes"),
+                  ("out", "output_size_in_bytes"),
+                  ("alias", "alias_size_in_bytes"))
+
+
+def _mb(n: Optional[int]) -> str:
+    return "?" if n is None else f"{n / 1e6:.1f} MB"
 
 
 @dataclasses.dataclass
@@ -94,6 +108,15 @@ class GraphGroup:
         # its step number and its key look like
         self._ahead_threads = int(options.get("precompile-buckets", 0) or 0)
         self._ahead: Optional[Dict[tuple, Any]] = None
+        # the memory ledger, host integers: what `params` and `opt_state`
+        # hold on one device, and by shape key what the compiler says of
+        # each step executable compiled ahead (`name`, `code`, `temp`,
+        # `args`, `out`, `alias`). The jitted step without
+        # --precompile-buckets reaches no executable without a second
+        # lowering: its ledger holds the state alone.
+        self._state_bytes = 0
+        self._programs: Dict[tuple, Dict[str, Any]] = {}
+        self._ledger_gauged: Optional[Dict[str, int]] = None
 
     def _frozen_names(self) -> frozenset:
         """Params excluded from updates: --embedding-fix-src/trg tables
@@ -203,6 +226,11 @@ class GraphGroup:
         self.params, self.opt_state = place(
             self.params, self.opt_state, self.mesh,
             dim_emb=int(getattr(self.model.cfg, "dim_emb", 0) or 0))
+        # as placed: a sharded leaf costs a device its shard
+        self._state_bytes = sum(
+            math.prod(a.sharding.shard_shape(a.shape)) * a.dtype.itemsize
+            for a in jax.tree_util.tree_leaves((self.params,
+                                                self.opt_state)))
         self._build()
 
     def _build(self) -> None:
@@ -226,6 +254,7 @@ class GraphGroup:
                                        grad_dtype=grad_dtype)
         self._fused_delay = None
         self._ahead = None              # executables of the step before
+        self._programs = {}
         if self.delay > 1:
             # in-jit micro-batch accumulation (one dispatch, one gradient
             # accumulator in HBM) for the common case of shape-uniform
@@ -285,21 +314,120 @@ class GraphGroup:
         return TrainOutput(metrics["ce_sum"], metrics["labels"],
                            metrics["gnorm"], metrics.get("skipped"))
 
-    @staticmethod
-    def _dispatch(fn, step: int, *args):
+    def _dispatch(self, fn, step: int, *args, batch=None):
         """The jitted call alone, as the span ``train.dispatch``:
         ``step`` is the update number the spans of one update share,
         ``retraced`` whether the step function's cache grew in this call
-        (a trace and a compile or cache load hid in the dispatch)."""
-        with obs_trace.span("train.dispatch", step=int(step)) as sp:
-            if not sp:
-                return fn(*args)
-            # an executable compiled ahead has no cache to grow
-            size = getattr(fn, "_cache_size", lambda: 0)
-            before = size()
-            out = fn(*args)
-            sp.set_attrs(retraced=int(size() > before))
-            return out
+        (a trace and a compile or cache load hid in the dispatch). Where
+        the device's allocator keeps statistics the span also says which
+        step ``program`` the call runs (`batch`'s shape) and what was
+        ``free_before`` it went in, and the ``hbm.*`` gauges take the
+        same sample beside the ledger's sums. A call the device's memory
+        refuses is reported with the ledger, and raised as it was."""
+        try:
+            with obs_trace.span("train.dispatch", step=int(step)) as sp:
+                if not sp:
+                    return fn(*args)
+                mem = hbm.device_memory()
+                if mem is not None:
+                    self._sample_memory(sp, mem, batch)
+                # an executable compiled ahead has no cache to grow
+                size = getattr(fn, "_cache_size", lambda: 0)
+                before = size()
+                out = fn(*args)
+                sp.set_attrs(retraced=int(size() > before))
+                return out
+        except Exception as e:
+            if "RESOURCE_EXHAUSTED" in str(e):
+                self._report_exhausted(step, batch)
+            raise
+
+    # -- the memory ledger ----------------------------------------------------
+    def _report_exhausted(self, step: int, batch) -> None:
+        """ONE error line for a dispatch the device's memory refused. Best
+        effort: it asks a client that has just failed an allocation for
+        its statistics, and nothing raised here may take the place of the
+        exception being reported."""
+        try:
+            held = list(self._programs.values())
+            log.error(
+                "Update {}: step program {} does not fit the device. {}; "
+                "step programs held: {}", int(step),
+                self._program_name(batch),
+                self._memory_line(hbm.device_memory()),
+                ", ".join(f"{p['name']} {_mb(p['code'])}"
+                          for p in held) or "none compiled ahead")
+        except Exception as e:  # noqa: BLE001 — the caller raises its own
+            log.error("Update {}: a step program does not fit the device "
+                      "(no memory report: {!r})", int(step), e)
+
+    @staticmethod
+    def _program_name(batch) -> str:
+        """Which step program a batch runs: rows x width of its 2-D
+        leaves ("10x1536"; both, where two streams differ)."""
+        if batch is None:
+            return "update"         # the split delay path's optimizer tail
+        return "+".join(dict.fromkeys(
+            "x".join(map(str, v.shape))
+            for _, v in sorted(batch.items()) if v.ndim >= 2)) or "-"
+
+    def _sample_memory(self, sp, mem: Dict[str, int], batch) -> None:
+        """One live dispatch's sample, taken BEFORE the call."""
+        held = list(self._programs.values())    # compile threads add to it
+        sp.set_attrs(program=self._program_name(batch))
+        # what does not change from one dispatch to the next
+        ledger = {"hbm.state": self._state_bytes}
+        if "limit" in mem:
+            ledger["hbm.limit"] = mem["limit"]
+        if held:
+            ledger["hbm.programs_code"] = sum(p["code"] for p in held)
+            ledger["hbm.step_temp_max"] = max(p["temp"] for p in held)
+        samples = {}
+        if "limit" in mem and "in_use" in mem:
+            free = samples["hbm.free"] = mem["limit"] - mem["in_use"]
+            sp.set_attrs(free_before=free)
+            if held:
+                # `in_use` holds the state and the programs loaded, never
+                # a step's temporaries: what a program load can count on
+                # is what is free LESS the widest step's, whichever step
+                # is running when it comes
+                samples["hbm.headroom"] = free - ledger["hbm.step_temp_max"]
+        for key in ("largest_free", "reserved"):
+            if key in mem:
+                samples["hbm." + key] = mem[key]
+        # by name in a loop: mtlint reads a literal `.gauge("...")` as a
+        # Prometheus registration (MT-METRIC-UNUSED)
+        gauge = obs_trace.TRACER.gauge
+        counts = [gauge(name, value) for name, value in samples.items()]
+        # the ledger once a stretch of recording (a gauge's first sample
+        # opens one), and again when a compile thread has added to it
+        if 1 in counts or ledger != self._ledger_gauged:
+            self._ledger_gauged = ledger
+            for name, value in ledger.items():
+                gauge(name, value)
+
+    def _memory_line(self, mem: Optional[Dict[str, int]]) -> str:
+        """The ledger and the allocator's word in one line."""
+        mem = mem or {}
+        held = list(self._programs.values())
+        line = f"HBM {_mb(mem.get('limit'))}: state {_mb(self._state_bytes)}, "
+        if held:
+            code = max(held, key=lambda p: p["code"])
+            temp = max(held, key=lambda p: p["temp"])
+            line += (f"{len(held)} step programs "
+                     f"{_mb(sum(p['code'] for p in held))} (largest "
+                     f"{_mb(code['code'])}, {code['name']}), widest step's "
+                     f"temporaries {_mb(temp['temp'])} ({temp['name']}), ")
+        else:
+            line += "no step program compiled ahead, "
+        line += (f"in use now {_mb(mem.get('in_use'))}, reserved "
+                 f"{_mb(mem.get('reserved'))}, largest free block "
+                 f"{_mb(mem.get('largest_free'))}")
+        if held and "limit" in mem and "in_use" in mem:
+            # the gauge `hbm.headroom` (_sample_memory), as of now
+            line += (", headroom (free less those temporaries) "
+                     + _mb(mem["limit"] - mem["in_use"] - temp["temp"]))
+        return line
 
     @staticmethod
     def _shape_key(batch) -> tuple:
@@ -330,6 +458,18 @@ class GraphGroup:
                                       (self.params, self.opt_state))
         step, rng = like(step), like(rng)
         fused = self._fused
+        programs = self._programs = {}
+
+        def compile_one(key, b):
+            exe = fused.lower(p, o, b, step, rng).compile()
+            # what the compiler says the executable costs the device
+            cost = exe.memory_analysis()
+            if cost is not None:
+                programs[key] = {"name": self._program_name(b), **{
+                    field: int(getattr(cost, attr, 0)) for field, attr in
+                    _PROGRAM_COSTS}}
+            return exe
+
         pool = ThreadPoolExecutor(self._ahead_threads,
                                   thread_name_prefix="precompile")
         ahead = {}
@@ -339,9 +479,20 @@ class GraphGroup:
                  for k, v in batch.items()}
             # the futures outlive the pool's handle; its threads end with
             # the last of them (shutdown below)
-            ahead[self._shape_key(b)] = pool.submit(  # mtlint: transfers
-                lambda b=b: fused.lower(p, o, b, step, rng).compile())
+            key = self._shape_key(b)
+            ahead[key] = pool.submit(  # mtlint: transfers
+                compile_one, key, b)
         pool.shutdown(wait=False)       # the queued compiles still run
+        # the ledger's line when the last of them is done, on its compile
+        # thread. A future `_step_for` cancels is done on the update path:
+        # it is counted, and should it be the last the line is left out
+        done, last = itertools.count(1), len(ahead)  # next(): GIL-atomic
+
+        def one_done(future):
+            if next(done) == last and not future.cancelled():
+                log.info("{}", self._memory_line(hbm.device_memory()))
+        for future in list(ahead.values()):
+            future.add_done_callback(one_done)
         log.info("Compiling the train step ahead for {} shapes on {} "
                  "threads", len(ahead), self._ahead_threads)
         return ahead
@@ -390,7 +541,7 @@ class GraphGroup:
                 self._dump_hlo = None
             self.params, self.opt_state, metrics = self._dispatch(
                 self._step_for(b, step_f, rng), step, self.params,
-                self.opt_state, b, step_f, rng)
+                self.opt_state, b, step_f, rng, batch=b)
             return self._output(metrics)
         if (self._fused_delay is not None and len(batches) == self.delay
                 and all(b.keys() == batches[0].keys()
@@ -410,7 +561,7 @@ class GraphGroup:
                 self._dump_hlo = None
             self.params, self.opt_state, metrics = self._dispatch(
                 self._fused_delay, step, self.params, self.opt_state,
-                stacked, step_f, rng)
+                stacked, step_f, rng, batch=stacked)
             return self._output(metrics)
         total_loss = total_labels = 0.0
         n_sents = 0.0
@@ -429,7 +580,7 @@ class GraphGroup:
                 self._dump_hlo = None
             sharded = M.shard_batch(b, self.mesh)
             grads, aux = self._dispatch(self._grad_fn, step, self.params,
-                                        sharded, r)
+                                        sharded, r, batch=sharded)
             if "counters" in aux:
                 obs_trace.TRACER.count_lazy(self.model.step_counters,
                                             aux["counters"])

@@ -16,6 +16,7 @@ from typing import Dict, List, Optional
 from .. import obs
 from ..common import logging as log
 from ..common.scheduling_parameter import SchedulingParameter, SchedulingUnit
+from . import hbm
 from .training_state import TrainingState
 
 
@@ -286,11 +287,18 @@ class Scheduler:
         cost_type = self.options.get("cost-type", "ce-sum")
         # the one deferred sync: the host blocked on the device, as the
         # span `train.sync` (a child of `train.bookkeep`)
-        with obs.span("train.sync", step=s.batches):
+        with obs.span("train.sync", step=s.batches) as sp:
             self._cost_sum = float(self._cost_sum)
             # the step counters of the same updates (routing counts of an
             # expert layer), lazy until here: ready with the cost
             obs.TRACER.fetch_counters()
+            # the device has drained: what its allocator holds now is the
+            # resident set, no step's temporaries (a host call, no sync)
+            mem = (hbm.device_memory() if sp else None) or {}
+            for name, key in (("hbm.in_use_drained", "in_use"),
+                              ("hbm.peak", "peak"), ("hbm.limit", "limit")):
+                if key in mem:
+                    obs.TRACER.gauge(name, mem[key])
         # clock read AFTER the cost sync (mtlint MT-SYNC-TIMER): forcing
         # the accumulated device scalar completes every update in the
         # display window, so words/s divides by real execution time.

@@ -90,7 +90,9 @@ class TestTracerCore:
         with t.span("y") as sp2:
             assert sp2 is NOOP_SPAN
             t.set_attrs(z=1)         # no-op, no allocation
+        t.gauge("g", 1)
         assert t._ring is None and t._events is None
+        assert t._gauges is None
 
     def test_enable_records_parent_child_tree(self):
         t = Tracer()
@@ -674,6 +676,106 @@ class TestTotalsAndProfilerSink:
         assert hasattr(obs_profiling, "TraceWindow")
         with pytest.raises(SystemExit):
             cp.parse_options(["--trace-sync-phases"], mode="training")
+
+
+# ---------------------------------------------------------------------------
+# gauges and the allocator's word (ISSUE 38)
+# ---------------------------------------------------------------------------
+
+class _FakeDevice:
+    def __init__(self, stats):
+        self._stats = stats
+
+    def memory_stats(self):
+        return self._stats
+
+
+class TestGauges:
+    def test_off_keeps_nothing(self):
+        t = Tracer()
+        t._lock = _RaisingLock()
+        for v in (3, 1, 2):
+            t.gauge("hbm.free", v)
+        assert t._gauges is None
+        t._lock = threading.Lock()
+        assert t.gauges() == {}
+
+    def test_last_min_max_n_over_a_sequence(self):
+        t = Tracer()
+        t.enable()
+        for v in (5, 2, 9, 4):
+            t.gauge("hbm.free", v)
+        t.gauge("hbm.limit", 16)
+        assert t.gauges() == {
+            "hbm.free": {"last": 4, "min": 2, "max": 9, "n": 4},
+            "hbm.limit": {"last": 16, "min": 16, "max": 16, "n": 1}}
+        assert t.totals() == {} and t.counters() == {}
+
+    def test_gauge_returns_the_count_of_its_samples(self):
+        """1 on a name's first sample of a stretch (a caller writes what
+        does not change then), None when off."""
+        t = Tracer()
+        assert t.gauge("g", 1) is None
+        t.enable()
+        assert [t.gauge("g", v) for v in (4, 5, 6)] == [1, 2, 3]
+        assert t.gauge("h", 0) == 1
+        t.disable()
+        assert t.gauge("g", 7) is None
+        t.enable()
+        assert t.gauge("g", 7) == 1
+
+    def test_live_under_a_collecting_profiler(self, monkeypatch):
+        """The guard count_lazy uses: the tracer's own switch OR the
+        profiler's flag; the ring stays unallocated."""
+        from marian_tpu.obs import trace as tr
+        t = Tracer()
+        t.gauge("g", 1)
+        assert t.gauges() == {}
+        monkeypatch.setattr(tr, "profiler_collecting", lambda: True)
+        t.gauge("g", 7)
+        monkeypatch.setattr(tr, "profiler_collecting", lambda: False)
+        t.gauge("g", 8)                         # off again: not kept
+        assert t.gauges() == {"g": {"last": 7, "min": 7, "max": 7, "n": 1}}
+        assert t._ring is None and not t.enabled
+
+    @pytest.mark.parametrize("clear", ["reset", "disable"])
+    def test_reset_and_disable_clear(self, clear):
+        t = Tracer()
+        t.enable()
+        t.gauge("g", 1)
+        getattr(t, clear)()
+        assert t._gauges is None and t.gauges() == {}
+        t.enable()
+        t.gauge("g", 5)                 # a new stretch, a new minimum
+        assert t.gauges()["g"] == {"last": 5, "min": 5, "max": 5, "n": 1}
+
+    def test_device_memory_is_none_on_the_cpu(self):
+        import jax
+        from marian_tpu.training import hbm as tr
+        assert jax.local_devices()[0].memory_stats() is None
+        assert tr.device_memory() is None
+
+    def test_device_memory_reads_the_fullest_device(self, monkeypatch):
+        """Host integers under the ledger's names, whichever keys the
+        runtime gives, of the device with the most in use."""
+        import jax
+        from marian_tpu.training import hbm as tr
+        devs = [_FakeDevice(None),
+                _FakeDevice({"bytes_limit": 100, "bytes_in_use": 10,
+                             "peak_bytes_in_use": 20}),
+                _FakeDevice({"bytes_limit": 100, "bytes_in_use": 60,
+                             "peak_bytes_in_use": 70,
+                             "largest_free_block_bytes": 30,
+                             "bytes_reserved": 25, "num_allocs": 5})]
+        monkeypatch.setattr(jax, "local_devices", lambda: devs)
+        assert tr.device_memory() == {"limit": 100, "in_use": 60,
+                                      "peak": 70, "reserved": 25,
+                                      "largest_free": 30}
+        monkeypatch.setattr(jax, "local_devices", lambda: devs[:2])
+        assert tr.device_memory() == {"limit": 100, "in_use": 10,
+                                      "peak": 20}
+        monkeypatch.setattr(jax, "local_devices", lambda: devs[:1])
+        assert tr.device_memory() is None
 
 
 # ---------------------------------------------------------------------------
