@@ -9,43 +9,64 @@ layer. On one chip the layer runs without its exchange.
 
 No [tokens, experts, capacity] one-hots and nothing dropped: the
 assignments that landed here are bucketed by expert, bucket after
-bucket, and that list is computed in two parts.
+bucket, and that list is computed in three parts, whichever experts
+its rows name.
 
-  its first `pool` rows, whichever experts they name: one gather, three
-      grouped matmuls (`jax.lax.ragged_dot`: consecutive groups of rows,
-      each against its own expert's matrix) and one scatter-add, all of a
-      fixed shape; rows past the end of the list weigh zero. `pool` is a
-      few times the share of the assignments that even routing would
-      send here (`pool_rows`), and it is shared: one crowded expert uses
-      what the others leave. While the list fits, this part is the whole
-      layer, at the matmuls' own speed and at a cost that does not
+  the FIRST POOL, its first `FIRST_SHARES` even shares (`pool_rows`):
+      one gather, three grouped matmuls (`jax.lax.ragged_dot`:
+      consecutive groups of rows, each against its own expert's matrix)
+      and one scatter-add, all of a fixed shape and ALWAYS run; rows
+      past the end of the list weigh zero. An even share is what even
+      routing would send here, and the pool is shared: one crowded
+      expert uses what the others leave. Every list the records hold
+      fits (the evidence is over FIRST_SHARES), so this part is the
+      whole layer, at the matmuls' own speed and at a cost that does not
       follow the routing.
-  what arrived beyond the pool, expert by expert: blocks of `block`
-      rows in a loop with a DYNAMIC trip count — the number of blocks
+  the SECOND POOL, the next rows of the list up to `POOL_SHARES` shares
+      in all, run only when the list reaches them: the SAME batch once
+      more, one step further down the list. Both are one loop of one or
+      two trips, so the program holds the batch's code once, a list that
+      ends in the first pool runs nothing for the second, forward or
+      backward, and no branch hands back zero gradients to add.
+  what arrived beyond both pools, expert by expert: blocks of `block`
+      rows in a loop with a DYNAMIC trip count, the number of blocks
       that arrived. Work follows what arrived, at any imbalance: every
-      token on one expert is more blocks, never a dropped token. The
-      loop's backward is written out (a while loop has no reverse mode)
-      and recomputes a block's activations instead of keeping them.
+      token on one expert is more blocks, never a dropped token.
+
+All three are `_held`, whose backward is written out (a while loop has
+no reverse mode): it runs a pool's batch again for its gradients, and
+recomputes a block's activations, instead of keeping either. Under a
+checkpoint that costs nothing (the forward run again there feeds
+nothing and is dropped); without one it is one more forward of the
+layer for activations never held.
 """
 
 from __future__ import annotations
 
 import functools
+import math
+from typing import Tuple
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 
 BLOCK = 256
-# the pool, in shares of the assignments that even routing sends to the
-# held experts: a fresh, unbalanced router sent 0.45 to 1.2 shares here,
-# by seed (PERF.md 6, PR 28), and a block of the loop costs ~4x its
-# matmuls, so the loop is for what no deployment should see
+# both pools, in shares of the assignments that even routing sends to
+# the held experts; a block of the loop costs ~4x its matmuls, so the
+# loop is for what no deployment should see
 POOL_SHARES = 3
-_POOL_ROWS = 512          # the pool is whole tiles of the grouped matmul
+# the first pool, which always runs. Sized from the counters: over every
+# line the records hold, a span's mean list (`moe.assignments_held` over
+# `moe.assignments`, in even shares) was 0.4 to 1.42 shares: 0.45-1.2 by
+# seed on a fresh router (PERF.md 6, PR 28), 1.42 at most (ledger, PR
+# 32), 0.4-1.35 where the pool is the whole feed-forward half (PRs
+# 34-38). What lies beyond is the second pool's, and conditional.
+FIRST_SHARES = 1.5
+_POOL_ROWS = 512          # a pool is whole tiles of the grouped matmul
 COUNTERS = ("moe.assignments", "moe.assignments_held", "moe.load_max",
-            "moe.load_mean", "moe.dropped")
-
+            "moe.load_mean", "moe.dropped", "moe.pool_calls",
+            "moe.second_pool", "moe.loop_rows")
 
 SCORES = ("sigmoid", "softmax")
 
@@ -85,11 +106,16 @@ def _arrivals(idx, mask, first: int, count: int):
     return order, sizes, starts
 
 
-def pool_rows(tokens: int, top_k: int, held: int, experts: int) -> int:
-    """`POOL_SHARES` times the `tokens * top_k * held / experts`
-    assignments of even routing, up to whole tiles."""
+def pool_rows(tokens: int, top_k: int, held: int,
+              experts: int) -> Tuple[int, int]:
+    """(first, second): the rows of the pool that always runs,
+    `FIRST_SHARES` times the `tokens * top_k * held / experts`
+    assignments of even routing, and of the one that runs when the list
+    reaches it, the rest of `POOL_SHARES` shares; both in whole tiles."""
     share = -(-tokens * top_k * held // experts)
-    return -(-POOL_SHARES * share // _POOL_ROWS) * _POOL_ROWS
+    first, both = (math.ceil(shares * share / _POOL_ROWS) * _POOL_ROWS
+                   for shares in (FIRST_SHARES, POOL_SHARES))
+    return first, both - first
 
 
 def _block_rows(e, j, order, sizes, starts, k, block, pooled):
@@ -105,25 +131,37 @@ def _blocks(sizes, e, block, pooled):
     return (sizes[e] - pooled[e] + block - 1) // block
 
 
-def _pool_part(x, w, wg, wu, wd, order, sizes, starts, k, rows):
-    """The first `rows` assignments of the bucketed list in one batch of
-    a fixed shape: (y [T, d] float32, per expert the rows of its bucket
-    computed here). Plain autodiff."""
+def _pooled(sizes, starts, lo, hi):
+    """Per expert, the rows of its bucket among rows [lo, hi) of the
+    list."""
+    return jnp.clip(starts + sizes, lo, hi) - jnp.clip(starts, lo, hi)
+
+
+def _batch_groups(sizes, starts, rows, off, end):
+    """The group sizes of the batch over rows [off, off + rows) of a list
+    that is computed up to row `end`: per expert its rows in the batch,
+    the last group with the rows past the end besides, whose weight is
+    zero. Every row of the batch is in some group: they sum to `rows`."""
+    pooled = _pooled(sizes, starts, off, jnp.clip(end, off, off + rows))
+    return pooled.at[-1].add(rows - jnp.sum(pooled))
+
+
+def _pool_part(y0, x, w, wg, wu, wd, order, sizes, starts, k, rows, off, end):
+    """Rows [off, off + rows) of the bucketed list, as far as they lie
+    before row `end`, in one batch of a fixed shape, added to y0 [T, d]
+    float32. Plain autodiff."""
     f32 = jnp.float32
-    pos = jnp.arange(rows, dtype=jnp.int32)
+    pos = off + jnp.arange(rows, dtype=jnp.int32)
     flat = order[jnp.minimum(pos, order.shape[0] - 1)]
     tok, slot = flat // k, flat % k
-    pooled = jnp.minimum(starts + sizes, rows) - jnp.minimum(starts, rows)
-    # every row of the pool is in some group: the last takes the rows
-    # past the end of the list, whose weight is zero
-    groups = pooled.at[-1].add(rows - jnp.sum(pooled))
-    dot = functools.partial(jax.lax.ragged_dot, group_sizes=groups,
-                            preferred_element_type=f32)
+    end = jnp.minimum(end, jnp.sum(sizes))
+    dot = functools.partial(
+        jax.lax.ragged_dot, preferred_element_type=f32,
+        group_sizes=_batch_groups(sizes, starts, rows, off, end))
     xb = x[tok]                                            # [rows, d]
     h = (jax.nn.silu(dot(xb, wg)) * dot(xb, wu)).astype(x.dtype)
-    wt = jnp.where(pos < jnp.sum(sizes), w[tok, slot], 0.0)
-    y = jnp.zeros(x.shape, f32).at[tok].add(wt[:, None] * dot(h, wd))
-    return y, pooled
+    wt = jnp.where(pos < end, w[tok, slot], 0.0)
+    return y0.at[tok].add(wt[:, None] * dot(h, wd))
 
 
 def _expert_block(xb, wg, wu, wd):
@@ -134,15 +172,32 @@ def _expert_block(xb, wg, wu, wd):
     return a, u, sg, h, jnp.dot(h, wd, preferred_element_type=jnp.float32)
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(10, 11))
-def _held(y0, x, w, wg, wu, wd, order, sizes, starts, pooled, k, block):
-    return _held_fwd(y0, x, w, wg, wu, wd, order, sizes, starts, pooled, k,
-                     block)[0]
+@functools.partial(jax.custom_vjp, nondiff_argnums=(9, 10, 11))
+def _held(x, w, wg, wu, wd, order, sizes, starts, pools, k, block, pool):
+    return _held_fwd(x, w, wg, wu, wd, order, sizes, starts, pools, k, block,
+                     pool)[0]
 
 
-def _held_fwd(y0, x, w, wg, wu, wd, order, sizes, starts, pooled, k, block):
-    """What the buckets hold beyond their `pooled` rows, added to y0
-    [T, d] float32; with it the rows computed, the pool's and these."""
+# jitted for its trace cache alone: the primal, the forward rule and every
+# layer of a plan with the same shapes share ONE trace of it (set-up of a
+# plan's thirteen step programs is mostly tracing: PERF.md 7)
+@functools.partial(jax.jit, static_argnums=(9, 10, 11))
+def _held_fwd(x, w, wg, wu, wd, order, sizes, starts, pools, k, block, pool):
+    """The held experts' part of the output, [T, d]: `pools` (an int32
+    scalar, 1 or 2) batches of the first pool's shape over the first
+    rows of the list, the second as far as the second pool goes, and
+    what the buckets hold beyond both in the loop; with it the rows
+    computed."""
+    first, both = pool[0], sum(pool)
+    y = jnp.zeros(x.shape, jnp.float32)
+    if first:
+        y = jax.lax.fori_loop(
+            0, pools, lambda i, y: _pool_part(
+                y, x, w, wg, wu, wd, order, sizes, starts, k, first,
+                i * first, both), y)
+    # both pools are prefixes of the list, so of every bucket
+    pooled = _pooled(sizes, starts, 0, both)
+
     def expert(e, carry):
         def body(j, carry):
             y, done = carry
@@ -156,20 +211,37 @@ def _held_fwd(y0, x, w, wg, wu, wd, order, sizes, starts, pooled, k, block):
                                  carry)
 
     y, done = jax.lax.fori_loop(0, wg.shape[0], expert,
-                                (y0, jnp.sum(pooled, dtype=jnp.int32)))
+                                (y, jnp.sum(pooled, dtype=jnp.int32)))
     return (y.astype(x.dtype), done), (x, w, wg, wu, wd, order, sizes,
-                                       starts, pooled)
+                                       starts, pools)
 
 
-def _held_bwd(k, block, res, cts):
-    x, w, wg, wu, wd, order, sizes, starts, pooled = res
+@functools.partial(jax.jit, static_argnums=(0, 1, 2))
+def _held_bwd(k, block, pool, res, cts):
+    x, w, wg, wu, wd, order, sizes, starts, pools = res
+    first, both = pool[0], sum(pool)
     dy = cts[0].astype(jnp.float32)
     f32 = jnp.float32
-    zeros = lambda a: jnp.zeros(a.shape, f32)      # noqa: E731
+    pooled = _pooled(sizes, starts, 0, both)
+    # dx and dw gather in float32; the stacks of weight gradients are the
+    # weights' own type, as autodiff's sum of the parts' gradients is
+    acc = (jnp.zeros(x.shape, f32), jnp.zeros(w.shape, f32),
+           jnp.zeros_like(wg), jnp.zeros_like(wu), jnp.zeros_like(wd))
+    if first:
+        # a pool's batch again, and its gradients into the accumulators
+        # that the loop below goes on with
+        def batch(i, acc):
+            grads = jax.vjp(
+                lambda *a: _pool_part(jnp.zeros(x.shape, f32), *a, order,
+                                      sizes, starts, k, first, i * first,
+                                      both),
+                x, w, wg, wu, wd)[1](dy)
+            return tuple(a + g.astype(a.dtype) for a, g in zip(acc, grads))
+        acc = jax.lax.fori_loop(0, pools, batch, acc)
 
     def expert(e, carry):
         # an expert's weight gradients gather in accumulators of their
-        # own and land in the stacks once, after its last block
+        # own and join the stacks once, after its last block
         def body(j, carry):
             dx, dw, dwg, dwu, dwd = carry
             tok, slot, valid = _block_rows(e, j, order, sizes, starts, k,
@@ -192,51 +264,52 @@ def _held_bwd(k, block, res, cts):
                     dwd + jnp.dot(h.T, dyb, preferred_element_type=f32))
 
         dx, dw, dwg, dwu, dwd = carry
+        zeros = lambda a: jnp.zeros(a.shape, f32)      # noqa: E731
         dx, dw, *own = jax.lax.fori_loop(
             0, _blocks(sizes, e, block, pooled), body,
             (dx, dw, zeros(wg[0]), zeros(wu[0]), zeros(wd[0])))
         return (dx, dw) + tuple(
-            stack.at[e].set(g) for stack, g in zip((dwg, dwu, dwd), own))
+            stack.at[e].add(g.astype(stack.dtype))
+            for stack, g in zip((dwg, dwu, dwd), own))
 
-    dx, dw, dwg, dwu, dwd = jax.lax.fori_loop(
-        0, wg.shape[0], expert,
-        (zeros(x), zeros(w), zeros(wg), zeros(wu), zeros(wd)))
-    ints = tuple(np.zeros(a.shape, jax.dtypes.float0)
-                 for a in (order, sizes, starts, pooled))
-    return (dy, dx.astype(x.dtype), dw.astype(w.dtype),
-            dwg.astype(wg.dtype), dwu.astype(wu.dtype),
-            dwd.astype(wd.dtype)) + ints
+    dx, dw, dwg, dwu, dwd = jax.lax.fori_loop(0, wg.shape[0], expert, acc)
+    ints = tuple(np.zeros(a.shape, jax.dtypes.float0)  # mtlint: ok -- an integer input's cotangent IS a host float0 array; jnp has none
+                 for a in (order, sizes, starts, pools))
+    return (dx.astype(x.dtype), dw.astype(w.dtype), dwg, dwu, dwd) + ints
 
 
 _held.defvjp(_held_fwd, _held_bwd)
 
 
 def held_experts(x, mask, idx, weights, wg, wu, wd, first: int,
-                 block: int = BLOCK, pool: int = 0):
-    """The held experts' part of the layer's output: the first `pool`
-    assignments that arrived in one fixed-shape batch, the rest in the
-    loop (pool 0: all of it in the loop).
+                 block: int = BLOCK, pool: Tuple[int, int] = (0, 0)):
+    """The held experts' part of the layer's output: the first `pool[0]`
+    assignments that arrived in one fixed-shape batch, the next
+    `pool[1]` in another where the list reaches them, the rest in the
+    loop (pool (0, 0): all of it in the loop).
 
     x [T, d]; mask [T] (0 = padding, routed nowhere); idx, weights [T, k]
     from `route`; wg, wu [count, d, f], wd [count, f, d]: the gated MLPs
     W_d(SiLU(W_g x) * W_u x) of experts first .. first + count - 1.
-    Returns (y [T, d], counters [5] float32 in the order of COUNTERS:
+    Returns (y [T, d], counters [8] float32 in the order of COUNTERS:
     assignments of real tokens, those that named a held expert, the
-    largest and the mean group of a held expert, and assignments that
-    named a held expert and were not computed — always 0)."""
+    largest and the mean group of a held expert, assignments that named
+    a held expert and were not computed — always 0 —, this call, whether
+    its second pool ran, and the assignments its loop computed)."""
+    if pool[1] > pool[0]:
+        raise ValueError(f"the second pool runs as one more batch of the "
+                         f"first's shape and cannot be larger: {pool}")
     count = wg.shape[0]
     k = idx.shape[1]
     order, sizes, starts = _arrivals(idx, mask, first, count)
-    y0, pooled = jnp.zeros(x.shape, jnp.float32), jnp.zeros_like(sizes)
-    if pool:
-        y0, pooled = _pool_part(x, weights, wg, wu, wd, order, sizes, starts,
-                                k, pool)
-    y, done = _held(y0, x, weights, wg, wu, wd, order, sizes, starts, pooled,
-                    k, block)
     arrived = jnp.sum(sizes)
+    second = (arrived > pool[0]) & (pool[1] > 0)
+    y, done = _held(x, weights, wg, wu, wd, order, sizes, starts,
+                    1 + second.astype(jnp.int32), k, block, tuple(pool))
     counters = jnp.stack([
-        jnp.sum(mask > 0) * k, arrived, jnp.max(sizes),
-        arrived / count, arrived - done]).astype(jnp.float32)
+        jnp.sum(mask > 0) * k, arrived, jnp.max(sizes), arrived / count,
+        arrived - done, 1, second,
+        done - jnp.minimum(arrived, sum(pool))]).astype(jnp.float32)
     return y, counters
 
 

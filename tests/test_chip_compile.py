@@ -156,8 +156,9 @@ def _kda_prep(b, heads, t):
 
 def _held_experts(tokens):
     """The layer plan's held experts at the cell's sizes (8 of 256, top
-    8, 2304 -> 1024): the pool's grouped matmuls must stay the chip's
-    own ragged dot, not 8 dense matmuls under a mask."""
+    8, 2304 -> 1024), both pools of `pool_rows`' pair, the second under
+    its cond: the pools' grouped matmuls must stay the chip's own ragged
+    dot, not 8 dense matmuls under a mask."""
     def loss(x, w, wg, wu, wd, idx):
         y, _ = held_experts(x, jnp.ones((tokens,), jnp.float32), idx, w,
                             wg, wu, wd, 0,

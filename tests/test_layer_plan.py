@@ -335,24 +335,44 @@ def _held_loop(x, router, wg, wu, wd, mask, first):
     return y
 
 
-def _held_layer(x, router, wg, wu, wd, mask, first, block=32, pool=0):
+def _held_layer(x, router, wg, wu, wd, mask, first, block=32, pool=(0, 0)):
     idx, w = X.route(x, router, K_, 2.5)
     return X.held_experts(x, mask, idx, w, wg, wu, wd, first, block, pool)
 
 
-# 280 tokens x 4 picks over 16 experts are 70 rows an expert: all of
-# them in the loop; a pool that ends inside the second or third bucket;
-# and a pool that holds them all
-@pytest.mark.parametrize("pool", [0, 136, 1536])
+# 280 tokens x 4 picks over 16 experts are 70 rows an expert, so the
+# lists here hold ~210 (3 held) to 1120 (all 16) rows. (first pool,
+# second pool), whole or as shares of the list n, and what must run: all
+# of the list in the loop; a first pool that ends inside the second or
+# third bucket, no second; a first pool that holds them all; a list
+# shorter than the first pool (the second skipped); one that ends inside
+# the second; one that passes both, so that the loop runs too
+@pytest.mark.parametrize("pool,second,loop", [
+    ((0, 0), False, True), ((136, 0), False, True),
+    ((1536, 0), False, False), ((1536, 512), False, False),
+    ((0.6, 0.5), True, False), ((0.3, 0.25), True, True)],
+    ids=["loop", "first-loop", "first", "first-skip", "first-second",
+         "first-second-loop"])
 @pytest.mark.parametrize("first,count", [(0, 8), (8, 8), (0, 16), (5, 3)])
-def test_held_experts_are_the_masked_loop(first, count, pool):
+def test_held_experts_are_the_masked_loop(first, count, pool, second, loop):
     x, router, wg, wu, wd, mask, kw = _expert_inputs()
     sl = slice(first, first + count)
+    idx = X.route(x, router, K_, 2.5)[0]
+    n = int(jnp.sum((idx >= first) & (idx < first + count)
+                    & (mask[:, None] > 0)))
+    pool = tuple(int(p * n) if isinstance(p, float) else p for p in pool)
     want = _held_loop(x, router, wg[sl], wu[sl], wd[sl], mask, first)
     got, counters = _held_layer(x, router, wg[sl], wu[sl], wd[sl], mask,
                                 first, pool=pool)
     np.testing.assert_allclose(got, want, atol=2e-5)
-    assert float(counters[0]) == 280 * K_ and float(counters[4]) == 0
+    counts = dict(zip(X.COUNTERS, np.asarray(counters).tolist()))
+    assert counts["moe.assignments"] == 280 * K_ and counts["moe.dropped"] == 0
+    assert counts["moe.assignments_held"] == n
+    assert counts["moe.pool_calls"] == 1.0
+    assert counts["moe.second_pool"] == float(second) \
+        == float(n > pool[0] and pool[1] > 0)
+    assert counts["moe.loop_rows"] == max(n - sum(pool), 0.0)
+    assert (counts["moe.loop_rows"] > 0) == loop
     w = jax.random.normal(kw, want.shape)
     grads = [jax.grad(lambda *a: jnp.sum(f(*a, mask, first) * w),
                       argnums=(0, 1, 2, 3, 4))(x, router, wg[sl], wu[sl],
@@ -360,6 +380,52 @@ def test_held_experts_are_the_masked_loop(first, count, pool):
              for f in (lambda *a: _held_layer(*a, pool=pool)[0], _held_loop)]
     for g_got, g_want in zip(*grads):
         np.testing.assert_allclose(g_got, g_want, atol=1e-4)
+
+
+@pytest.mark.parametrize("end", [0, 5, 12, 13, 29, 30, 100])
+def test_a_batch_s_groups_hold_every_row_of_it(end):
+    """Whatever part of the list a batch of 10 rows covers (buckets of 5,
+    0, 8, 17 rows: 30 in all), its group sizes are its rows of each
+    bucket before `end`, none negative, and they sum to 10: the chip's
+    grouped matmul reads past its operand otherwise."""
+    sizes = jnp.array([5, 0, 8, 17])
+    starts = jnp.cumsum(sizes) - sizes
+    for off in (0, 10, 20):
+        groups = np.asarray(X._batch_groups(sizes, starts, 10, off, end))
+        rows = np.arange(off, off + 10)
+        want = [int(np.sum((rows >= s) & (rows < s + n) & (rows < end)))
+                for s, n in zip(np.asarray(starts), np.asarray(sizes))]
+        want[-1] += 10 - sum(want)
+        assert groups.tolist() == want and groups.min() >= 0
+
+
+def _ragged_dot_rows(jaxpr, inside=False, found=None):
+    """[(rows of a grouped matmul's left operand, whether it sits inside
+    a while loop's body)] over a jaxpr and everything it calls."""
+    found = [] if found is None else found
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name.startswith("ragged_dot"):
+            found.append((eqn.invars[0].aval.shape[0], inside))
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            _ragged_dot_rows(sub, inside or eqn.primitive.name == "while",
+                             found)
+    return found
+
+
+def test_a_skipped_second_pool_runs_nothing():
+    """Both pools are ONE batch of the first's shape in a loop that runs
+    once or twice, forward (3 grouped matmuls) and backward (the 3 again
+    and their 6 transposes): a list that ends in the first pool runs the
+    batch once and nothing stands outside the loop for the second — no
+    second copy of the code, no zero gradients to add."""
+    x, router, wg, wu, wd, mask, _ = _expert_inputs()
+    pool = (1536, 512)             # 1120 rows at most: the second skipped
+    grad = jax.grad(lambda *a: jnp.sum(_held_layer(*a, mask, 0, pool=pool)[0]),
+                    argnums=(0, 2, 3, 4))
+    dots = _ragged_dot_rows(jax.make_jaxpr(grad)(x, router, wg, wu, wd).jaxpr)
+    assert dots == [(1536, True)] * 12
+    with pytest.raises(ValueError, match="cannot be larger"):
+        _held_layer(x, router, wg, wu, wd, mask, 0, pool=(512, 1024))
 
 
 @pytest.mark.parametrize("experts,held,width", [(16, 8, 32), (256, 16, 768)],
@@ -403,7 +469,7 @@ def test_the_shares_add_up(experts, held, width):
         == 2 * 24 * 4
 
 
-@pytest.mark.parametrize("pool", [0, 64])
+@pytest.mark.parametrize("pool", [(0, 0), (64, 0)])
 def test_no_token_is_dropped_when_all_pick_one_expert(pool):
     x, _, wg, wu, wd, mask, _ = _expert_inputs()
     idx = jnp.tile(jnp.array([[3, 17, 18, 19]]), (T_, 1))   # 3 is held
@@ -413,7 +479,10 @@ def test_no_token_is_dropped_when_all_pick_one_expert(pool):
     want = 0.25 * mask[:, None] * (
         (jax.nn.silu(x @ wg[3]) * (x @ wu[3])) @ wd[3])
     np.testing.assert_allclose(y, want, atol=2e-5)
-    assert [float(c) for c in counters] == [1120.0, 280.0, 280.0, 35.0, 0.0]
+    # all 280 on one expert: 64 in the first pool and 216 in the loop,
+    # or all of them in the loop; no second pool either way
+    assert [float(c) for c in counters] == [
+        1120.0, 280.0, 280.0, 35.0, 0.0, 1.0, 0.0, 280.0 - pool[0]]
     # an expert nobody picked costs no block: nothing arrives, nothing runs
     none, c0 = X.held_experts(x, mask, idx + 20, w, wg[:8], wu[:8], wd[:8],
                               0, 32, pool)
@@ -422,9 +491,15 @@ def test_no_token_is_dropped_when_all_pick_one_expert(pool):
 
 def test_the_pool_is_a_few_even_shares_in_whole_tiles():
     # the cell: 16384 tokens x top 8, 8 of 256 experts held: 4096 a share
-    assert X.pool_rows(16384, 8, 8, 256) == 3 * 4096
-    assert X.pool_rows(11264, 8, 8, 256) == 8704          # 8448 and up
-    assert X.pool_rows(48, 4, 8, 32) == 512 == X.pool_rows(1, 1, 1, 64)
+    # (first, second): 1.5 shares that always run, then up to 3 in all
+    assert X.pool_rows(16384, 8, 8, 256) == (6144, 6144)
+    assert X.pool_rows(11264, 8, 8, 256) == (4608, 4096)  # 8448 and up
+    assert X.pool_rows(48, 4, 8, 32) == (512, 0) == X.pool_rows(1, 1, 1, 64)
+    # 16 of 256 held at 16384 tokens; 16 of 128 at the doubled row
+    assert X.pool_rows(16384, 8, 16, 256) == (12288, 12288)
+    assert X.pool_rows(32768, 8, 16, 128) == (49152, 49152)
+    for sizes in ((16384, 8, 8, 256), (11264, 8, 8, 256), (48, 4, 8, 32)):
+        assert all(rows % 512 == 0 for rows in X.pool_rows(*sizes))
 
 
 # ---------------------------------------------------------------------------
@@ -607,6 +682,10 @@ def test_counters_leave_the_step_lazily_and_reach_the_tracer(tiny):
     assert 0 < counts["moe.assignments_held"] < counts["moe.assignments"]
     assert counts["moe.dropped"] == 0.0
     assert counts["moe.load_max"] >= counts["moe.load_mean"]
+    # every expert layer's call is counted; at this size the first pool
+    # is one tile and there is no second: what passes it is the loop's
+    assert counts["moe.pool_calls"] == layers
+    assert counts["moe.second_pool"] == 0.0 <= counts["moe.loop_rows"]
     TRACER.reset()
     TRACER.count_lazy(names, aux["counters"])          # off: not kept
     TRACER.fetch_counters()
