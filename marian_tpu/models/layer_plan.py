@@ -74,6 +74,7 @@ from typing import Dict, NamedTuple, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from .. import obs
 from ..layers import initializers as inits
@@ -429,25 +430,74 @@ def rope_angles(length: int, dim: int, theta: float,
     return jnp.repeat(angles, 2, axis=-1)
 
 
-def _rotate(x, angles, pairing: str = "interleaved"):
-    """x [..., T, dim] with each channel pair at position t turned by its
-    angle (angles[t], as rope_angles lays them out for the same
-    `pairing`), in float32: (a, b) -> (a cos - b sin, a sin + b cos).
-    The pair's other channel comes from a roll along the channels, so
-    nothing is reshaped to pairs."""
-    f = x.astype(jnp.float32)
-    dim = x.shape[-1]
+def _pair_matrix(dim: int, pairing: str, start: int) -> np.ndarray:
+    """[dim, dim] float32 with one +-1 a column from `start` on and zero
+    columns under it: x @ M holds, under each turned channel, its pair's
+    other channel with the sign the turn wants (-b under a, +a under b)."""
+    j = np.arange(dim - start)
     if pairing == "half":
-        first = jnp.arange(dim) < dim // 2
-        other = jnp.where(first, -1.0, 1.0) * jnp.roll(f, dim // 2, axis=-1)
+        half = (dim - start) // 2
+        other, first = (j + half) % (2 * half), j < half
     else:
-        even = jnp.arange(dim) % 2 == 0
-        other = jnp.where(even, -jnp.roll(f, -1, axis=-1),
-                          jnp.roll(f, 1, axis=-1))
-    return (f * jnp.cos(angles) + other * jnp.sin(angles)).astype(x.dtype)
+        other, first = j ^ 1, j % 2 == 0
+    m = np.zeros((dim, dim), np.float32)
+    m[start + other, start + j] = np.where(first, -1.0, 1.0)
+    return m
+
+
+@partial(jax.custom_vjp, nondiff_argnums=(2, 3))
+def _rotate(x, angles, pairing: str = "interleaved", start: int = 0):
+    """x [..., T, dim] with each channel pair from channel `start` on, at
+    position t, turned by its angle (angles[t] [dim - start], as
+    rope_angles lays them out for the same `pairing`), in float32:
+    (a, b) -> (a cos - b sin, a sin + b cos), one rounding to x's type;
+    the channels under `start` come back as they are (cos 1, sin 0), so
+    a tensor whose last channels turn is turned IN PLACE, at its full
+    width, with no slice and no concatenate.
+
+    The pair's other channel is x @ M, M a constant signed permutation
+    (`_pair_matrix`), accumulated in float32: exact, on the MXU, and one
+    fusion with the turn, where a roll along the channels was slices that
+    wrote float32 pieces of the whole tensor to HBM (PERF.md 6, PR 42).
+    The backward is the same pass at the negated angles."""
+    # each output is ONE input times +-1, so the product is exact in x's
+    # type (float32 operands would be rounded to bfloat16 on the chip
+    # under the default precision)
+    other = jnp.dot(
+        x, jnp.asarray(_pair_matrix(x.shape[-1], pairing, start), x.dtype),
+        precision=jax.lax.Precision.HIGHEST
+        if x.dtype == jnp.float32 else None,
+        preferred_element_type=jnp.float32)
+    cos, sin = jnp.cos(angles), jnp.sin(angles)
+    if start:
+        still = ((0, 0),) * (angles.ndim - 1) + ((start, 0),)
+        cos = jnp.pad(cos, still, constant_values=1.0)
+        sin = jnp.pad(sin, still)
+    return (x.astype(jnp.float32) * cos + other * sin).astype(x.dtype)
+
+
+def _rotate_fwd(x, angles, pairing, start):
+    return _rotate(x, angles, pairing, start), angles
+
+
+def _rotate_bwd(pairing, start, angles, g):
+    # the turn is orthogonal: its transpose is the turn back, the same
+    # pass (float32 inside, one rounding); the angles take no cotangent
+    return _rotate(g, -angles, pairing, start), jnp.zeros_like(angles)
+
+
+_rotate.defvjp(_rotate_fwd, _rotate_bwd)
 
 
 def _mla(cfg: PlanConfig, p: Params, lp: str, x, mask):
+    """Latent attention: per-head keys and values expanded from one
+    normed low-rank latent, `mla_dim_shared` more key channels shared by
+    all heads, a full-rank or low-rank query (`mla_q_rank`); causal
+    softmax; W_o. Where `mla_rope_theta` is set, the shared key channels
+    and each head's last `mla_dim_shared` query channels turn by their
+    position: the query IN PLACE at its full width (`_rotate` from
+    channel `mla_dim_nope` on; nothing is sliced off and joined again),
+    the shared key before it is broadcast to the heads."""
     h, dn, dv = cfg.heads, cfg.mla_dim_nope, cfg.mla_dim_v
     with jax.named_scope("mla"):
         if cfg.mla_q_rank:
@@ -465,8 +515,7 @@ def _mla(cfg: PlanConfig, p: Params, lp: str, x, mask):
             with jax.named_scope("mla.rope"):
                 angles = rope_angles(t, cfg.mla_dim_shared,
                                      cfg.mla_rope_theta)
-                q = jnp.concatenate(
-                    [q[..., :dn], _rotate(q[..., dn:], angles)], axis=-1)
+                q = _rotate(q, angles, start=dn)
                 shared = _rotate(shared, angles)
         kv = _heads(jnp.dot(latent, p[f"{lp}_mla_Wkvb"]), h)
         k = jnp.concatenate(

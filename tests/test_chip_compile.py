@@ -13,11 +13,16 @@ a cell falls to part of a row's heads) and the benchmark cell's six
 batches at 4096 words, flash at 2048, dense beam decode at 64 sentences x beam 6, the paged
 engine at its smallest and largest row bucket and at its SMEM row bound.
 
+One case is no kernel: the layer plan's rotation, XLA's own, compiled as
+its two call sites use it, for what the compiler makes of it (one fusion
+a tensor, no float32 piece of the tensor in HBM).
+
 Nothing runs: a passing compile is not a chip run (chip_smoke.py is).
 The file sorts early on purpose — tier-1 runs into its wall-clock box
 and sheds whatever sorts late.
 """
 
+import math
 import os
 import re
 
@@ -28,6 +33,7 @@ import jax.numpy as jnp
 import pytest
 from jax.sharding import SingleDeviceSharding
 
+from marian_tpu.models.layer_plan import _rotate, rope_angles
 from marian_tpu.ops import auto_tuner
 from marian_tpu.ops.experts import held_experts, pool_rows
 from marian_tpu.ops.pallas import kv_pool
@@ -297,3 +303,53 @@ def test_kernel_compiles_for_v5e(chip, case):
     assert not missing, (
         f"{missing} not among the compiled program's Pallas kernels — "
         f"something gave way to a reference")
+
+
+def _rotated_gqa(q, k):
+    """`_gqa`'s call of the turn: 32 + 4 whole heads of 128 in half-split
+    pairs, the angles tiled over the doubled row."""
+    angles = jnp.tile(rope_angles(q.shape[2] // 2, 128, 1e6, "half"), (2, 1))
+    return _rotate(q, angles, "half"), _rotate(k, angles, "half")
+
+
+def _rotated_mla(q, shared):
+    """`_mla`'s: the 192-wide query turned in place from channel 128 on,
+    the 64 shared key channels whole, interleaved pairs."""
+    angles = rope_angles(q.shape[2], 64, 32e6)
+    return _rotate(q, angles, start=128), _rotate(shared, angles)
+
+
+ROTATIONS = {
+    "gqa.rope-2x2048": (_rotated_gqa, ((2, 32, 2048, 128), (2, 4, 2048, 128))),
+    "mla.rope-2x1024": (_rotated_mla, ((2, 32, 1024, 192), (2, 1024, 64))),
+}
+
+
+@pytest.mark.parametrize("backward", [False, True], ids=["forward", "vjp"])
+@pytest.mark.parametrize("site", ROTATIONS)
+def test_the_rotation_is_one_pass_for_v5e(chip, site, backward):
+    """The turn and its VJP, as the layer plan's two call sites use them,
+    read and write their tensor once: the compiled program's temporaries
+    stay under the query's own bytes (a roll along the channels was slices
+    XLA did not fuse, 3.5 x them: PERF.md 6, PR 42) and no instruction of
+    its entry computation yields a float32 array of a quarter of the
+    query's elements or more (the roll's were float32 halves, or 63 + 1
+    of 64 channels, of the whole tensor, in HBM)."""
+    fn, shapes = ROTATIONS[site]
+    if backward:
+        turn = fn
+
+        def fn(a, b, ga, gb):
+            return jax.vjp(turn, a, b)[1]((ga, gb))
+    args = [jax.ShapeDtypeStruct(s, DT, sharding=chip)
+            for s in (shapes * 2 if backward else shapes)]
+    compiled = jax.jit(fn).lower(*args).compile()
+    query = math.prod(shapes[0])
+    assert compiled.memory_analysis().temp_size_in_bytes < query * 2
+    entry = compiled.as_text().split("\nENTRY ", 1)[1].split("\n}", 1)[0]
+    # "%name = <type or tuple of types> op(operands...)": the types alone
+    yields = re.findall(r"^\s*(?:ROOT )?\S+ = (.*?) [\w-]+\(", entry, re.M)
+    wide = [held for held in yields
+            for dims in re.findall(r"\bf32\[([\d,]+)\]", held)
+            if math.prod(map(int, dims.split(","))) * 4 >= query]
+    assert not wide, wide

@@ -4,7 +4,9 @@ low-rank query, a rotation by position) and the plan's prediction modules
 
   rank 0 and theta 0              == the layer as it stood before it had
       either, bit for bit; the rotated low-rank layer == its equations
-      written out; rotation is relative
+      written out; rotation is relative; the turn whose other channel
+      is a signed-permutation matmul == the roll form it replaced, value
+      and cotangent, in place from a start channel on
   the cut model with its module   == the plain float32 reference beside the
       benchmark's configuration (benchmark/configs/
       joyai_llm_flash_reference.py): per-token costs and every parameter
@@ -142,6 +144,53 @@ def test_rotation_is_relative(monkeypatch):
         t, dim, theta))
     still = P._mla(cfg, p, "decoder_l1", x, mask)
     assert float(jnp.abs(still - here).max()) > 1e-3  # and it does turn
+
+
+def _roll_form(x, angles, pairing):
+    """`_rotate` as it stood before the pair's other channel came from a
+    matmul (PR 42): a roll along the channels and a select, kept here word
+    for word."""
+    f = x.astype(jnp.float32)
+    dim = x.shape[-1]
+    if pairing == "half":
+        first = jnp.arange(dim) < dim // 2
+        other = jnp.where(first, -1.0, 1.0) * jnp.roll(f, dim // 2, axis=-1)
+    else:
+        even = jnp.arange(dim) % 2 == 0
+        other = jnp.where(even, -jnp.roll(f, -1, axis=-1),
+                          jnp.roll(f, 1, axis=-1))
+    return (f * jnp.cos(angles) + other * jnp.sin(angles)).astype(x.dtype)
+
+
+@pytest.mark.parametrize("start, dim", [(0, 64), (128, 192)])
+@pytest.mark.parametrize("dtype", ["bfloat16", "float32"])
+@pytest.mark.parametrize("pairing", ["interleaved", "half"])
+def test_the_matmul_turn_equals_the_roll_form(pairing, dtype, start, dim):
+    """Value and cotangent equal (==, every element) the roll form around
+    a slice and a concatenate, op by op (under jit the CPU's compiler
+    contracts the two forms' multiply-adds differently, a unit in the last
+    place); the channels under `start` come back untouched, and so does
+    their cotangent. Past position 256, where a bfloat16 angle is no
+    longer its position's."""
+    t = 300
+    kx, kg = jax.random.split(jax.random.PRNGKey(5))
+    x = jax.random.normal(kx, (2, 3, t, dim)).astype(dtype)
+    g = jax.random.normal(kg, (2, 3, t, dim)).astype(dtype)
+    angles = P.rope_angles(t, dim - start, 1e4, pairing)
+
+    def before(x):
+        return jnp.concatenate(
+            [x[..., :start], _roll_form(x[..., start:], angles, pairing)],
+            axis=-1)
+    want, back_before = jax.vjp(before, x)
+    got, back = jax.vjp(lambda x: P._rotate(x, angles, pairing, start), x)
+    assert got.dtype == x.dtype and back(g)[0].dtype == x.dtype
+    np.testing.assert_array_equal(got, want)
+    np.testing.assert_array_equal(back(g)[0], back_before(g)[0])
+    np.testing.assert_array_equal(got[..., :start], x[..., :start])
+    np.testing.assert_array_equal(back(g)[0][..., :start], g[..., :start])
+    assert float(jnp.abs(got.astype(jnp.float32)
+                         - x.astype(jnp.float32))[..., start:].max()) > 0.5
 
 
 def _joyai_reference():
