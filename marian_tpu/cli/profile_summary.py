@@ -38,7 +38,8 @@ COLLECTIVE_OPS = ("all-gather", "all-reduce", "reduce-scatter", "all-to-all",
 LEVEL2 = ("encoder", "decoder", "embed", "self_attn", "cross_attn", "ffn",
           "pre_post", "output", "loss", "cast", "clip", "adam", "ema",
           # a layer plan's scopes (models/layer_plan.py)
-          "kda", "mla", "mla.rope", "gqa", "gqa.rope", "diffusion.noise",
+          "kda", "mla", "mla.rope", "gqa", "gqa.rope", "swa", "swa.rope",
+          "attn.gate", "diffusion.noise",
           "experts.route", "experts.compute", "experts.shared", "mtp")
 
 

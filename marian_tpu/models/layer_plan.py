@@ -18,18 +18,34 @@ its feed-forward kind, as data:
                          query heads on --plan-gqa-kv-heads key/value
                          heads, queries and keys RMS-normed per head and
                          rotated whole, in half-split pairs (i, i + d/2),
-                         at --plan-gqa-rope-theta; softmax
+                         at --plan-gqa-rope-theta (0 = not rotated);
+                         causal softmax
+                swa      `gqa` (its sizes, its parameters' names) under a
+                         SLIDING WINDOW: a query sees the last
+                         --plan-swa-window keys up to its own
+                         (ops/pallas/flash_attention.py::Window), rotated
+                         at a theta of its own, --plan-swa-rope-theta
+                         (0 = not rotated). A plan may hold both kinds:
+                         window layers that are rotated beside global
+                         `gqa` layers that are not
+                         With --plan-gqa-gate both kinds multiply the
+                         heads' output by sigmoid(W_gate x), channel by
+                         channel, before W_o
   feed-forward  dense    gated MLP, W_d(SiLU(W_g x) * W_u x)
                 experts  a router over all experts (sigmoid or softmax
                          scores, --plan-experts-score), the held ones
                          computed without dropping (ops/experts.py),
                          plus shared experts on every token, if any
 
-The block is pre-norm with RMSNorm (scale only) and a residual add;
-input and output tables untied; a final RMSNorm before the output
-projection. The only positional signal is the rotation inside `mla`,
-where it is asked for, and inside `gqa`: the delta rule has none, and a
-plan without a rotated layer has none anywhere.
+The block is pre-norm with RMSNorm (scale only) and a residual add,
+x + mixing(norm(x)) then x + feed-forward(norm(x)); with --plan-post-norms
+each branch's OUTPUT is normed too before it is added (four norms a
+block: x + norm(mixing(norm(x)))). Input and output tables untied; a
+final RMSNorm before the output projection. The only positional signal
+is the rotation inside `mla`, `gqa` and `swa`, each where its theta asks
+for it (and, under `swa`, how far back a query sees): the delta rule has
+none, a `gqa` layer at theta 0 has none, and a plan without a rotated
+layer has none anywhere.
 
 The objective is next-token prediction (the input shifted right), or,
 with --plan-diffusion-block N and a plan of `gqa` layers, DIFFUSION OVER
@@ -53,9 +69,10 @@ and counted beside the main head and never part of the label count.
 These are the families of Kimi Linear
 (https://huggingface.co/moonshotai/Kimi-Linear-48B-A3B-Instruct), of
 DeepSeek-V3 (arXiv:2412.19437, 2.1.1 latent attention, 2.2 multi-token
-prediction) and of Qwen3's sparse models trained as block-diffusion
-models (arXiv:2505.09388; arXiv:2503.09573); the sizes come from flags, nothing here knows a model's
-name.
+prediction), of Qwen3's sparse models trained as block-diffusion
+models (arXiv:2505.09388; arXiv:2503.09573) and of decoders that mix
+window and global attention layers behind an output gate and output
+norms; the sizes come from flags, nothing here knows a model's name.
 
 The module is one more function family behind models/encoder_decoder.py
 (`init_params`, `encode`, `decode_train`, `output_logits`), next to
@@ -83,25 +100,33 @@ from ..ops import kda as K
 from ..ops.attention import attention, causal_mask
 from ..ops.ops import rms_norm
 from ..ops.pallas.flash_attention import (RESIDUAL_LSE, RESIDUAL_OUT,
-                                          BlockDiffusion)
+                                          BlockDiffusion, Window, tile_plan)
 from . import transformer as T
 
 Params = Dict[str, jax.Array]
 
-MIXINGS = ("kda", "mla", "gqa")
+MIXINGS = ("kda", "mla", "gqa", "swa")
+# grouped-query attention, causal or under a window: one set of parameters
+# (`_gqa_*`), one function; with `mla` the mixings that are softmax
+# attention through ops/attention.py
+_GROUPED = ("gqa", "swa")
+_ATTENTION = ("mla",) + _GROUPED
 FEED_FORWARDS = ("dense", "experts")
 # what the step carries out beside the loss, summed over the layers
 COUNTERS = X.COUNTERS
 # kept in the optimizer's float32 whatever the compute type: the router
 # decides WHICH experts run, and the decay's rate sits in an exponent
 _FLOAT32_SUFFIXES = ("_experts_router", "_kda_A_log", "_kda_dt_bias")
-# what a checkpointed `mla` or `gqa` half keeps across the backward beside
+# what a checkpointed `mla`, `gqa` or `swa` half keeps across the backward beside
 # its input: the flash kernel's output and row statistics, by their names
 _FLASH_KEEPS = (RESIDUAL_OUT, RESIDUAL_LSE)
 # diffusion over blocks: what the step counts beside COUNTERS (their
 # quotient is the realised noise level), the least noise level of a row,
 # and the token a masked position holds (the vocabulary's <unk>)
 DIFFUSION_COUNTERS = ("diffusion.masked", "diffusion.labels")
+# a plan with a window layer: what the step counts of its attention, from
+# shapes alone (`_attention_pairs`)
+ATTENTION_COUNTERS = ("attn.pairs_seen", "attn.pairs_tiled")
 DIFFUSION_EPS = 1e-3
 MASK_TOKEN = 1
 
@@ -128,7 +153,12 @@ class PlanConfig(T.TransformerConfig):
     # gqa
     gqa_kv_heads: int = 0             # 0: as many as query heads
     gqa_dim_head: int = 128
-    gqa_rope_theta: float = 1e6
+    gqa_rope_theta: float = 1e6       # 0: `gqa` layers not rotated
+    gqa_gate: bool = False            # o * sigmoid(W_gate x) before W_o
+    # swa: `gqa` under a window, at a theta of its own
+    swa_window: int = 0
+    swa_rope_theta: float = 1e4       # 0: not rotated
+    post_norms: bool = False          # a norm on each branch's output
     # diffusion over blocks of this many positions; 0: next-token training
     diffusion_block: int = 0
     # experts
@@ -196,6 +226,10 @@ def config_from_options(options, src_vocab, trg_vocab, for_inference=False,
         raise ValueError(f"--plan-gqa-kv-heads {kv_heads} must divide "
                          f"--transformer-heads {base.heads}, and "
                          f"--plan-gqa-dim-head be even (rotated pairs)")
+    window = int(g("plan-swa-window", 0) or 0)
+    if window < 1 and any(m == "swa" for m, _ in plan):
+        raise ValueError(f"--plan-swa-window {window}: a `swa` layer sees "
+                         f"1 key or more")
     score = str(g("plan-experts-score", "sigmoid") or "sigmoid")
     if score not in X.SCORES:
         raise ValueError(f"--plan-experts-score {score!r}: one of "
@@ -222,7 +256,11 @@ def config_from_options(options, src_vocab, trg_vocab, for_inference=False,
         mtp_modules=ahead, mtp_weight=float(g("plan-mtp-weight", 0.3)),
         gqa_kv_heads=kv_heads,
         gqa_dim_head=int(g("plan-gqa-dim-head", 128)),
-        gqa_rope_theta=float(g("plan-gqa-rope-theta", 1e6)),
+        gqa_rope_theta=float(g("plan-gqa-rope-theta", 1e6) or 0.0),
+        gqa_gate=bool(g("plan-gqa-gate", False)),
+        swa_window=window,
+        swa_rope_theta=float(g("plan-swa-rope-theta", 1e4) or 0.0),
+        post_norms=bool(g("plan-post-norms", False)),
         diffusion_block=int(g("plan-diffusion-block", 0) or 0),
         experts=n_experts,
         experts_top_k=int(g("plan-experts-top-k", 8)),
@@ -255,6 +293,9 @@ def init_params(cfg: PlanConfig, key: jax.Array) -> Params:
     for lp, (mix, ffn) in blocks:
         p[f"{lp}_mix_norm_scale"] = ones(d)
         p[f"{lp}_ffn_norm_scale"] = ones(d)
+        if cfg.post_norms:
+            p[f"{lp}_mix_post_norm_scale"] = ones(d)
+            p[f"{lp}_ffn_post_norm_scale"] = ones(d)
         if mix == "kda":
             dh, r = cfg.kda_dim_head, cfg.kda_low_rank
             for n in "qkv":
@@ -279,7 +320,7 @@ def init_params(cfg: PlanConfig, key: jax.Array) -> Params:
             p[f"{lp}_kda_Wg2"] = glorot(r, h * dh)
             p[f"{lp}_kda_out_norm_scale"] = ones(dh)
             p[f"{lp}_kda_Wo"] = glorot(h * dh, d)
-        elif mix == "gqa":
+        elif mix in _GROUPED:
             dh, hk = cfg.gqa_dim_head, cfg.gqa_kv_heads
             p[f"{lp}_gqa_Wq"] = glorot(d, h * dh)
             p[f"{lp}_gqa_Wk"] = glorot(d, hk * dh)
@@ -287,6 +328,8 @@ def init_params(cfg: PlanConfig, key: jax.Array) -> Params:
             p[f"{lp}_gqa_q_norm_scale"] = ones(dh)
             p[f"{lp}_gqa_k_norm_scale"] = ones(dh)
             p[f"{lp}_gqa_Wo"] = glorot(h * dh, d)
+            if cfg.gqa_gate:
+                p[f"{lp}_gqa_Wgate"] = glorot(d, h * dh)
         else:
             dq = cfg.mla_dim_nope + cfg.mla_dim_shared
             if cfg.mla_q_rank:
@@ -530,27 +573,44 @@ def _mla(cfg: PlanConfig, p: Params, lp: str, x, mask):
         return jnp.dot(o, p[f"{lp}_mla_Wo"])
 
 
-def _gqa(cfg: PlanConfig, p: Params, lp: str, x, mask, rule=None):
+def _gate(cfg: PlanConfig, p: Params, lp: str, x, o):
+    """o * sigmoid(W_gate x), channel by channel over [B, T, heads x
+    dim_head]: the gate's matmul in the compute type, its sigmoid and the
+    product in float32, one rounding."""
+    with jax.named_scope("attn.gate"):
+        g = jax.nn.sigmoid(jnp.dot(x, p[f"{lp}_gqa_Wgate"])
+                           .astype(jnp.float32))
+        return (o.astype(jnp.float32) * g).astype(o.dtype)
+
+
+def _gqa(cfg: PlanConfig, p: Params, lp: str, x, mask, rule=None,
+         kind: str = "gqa"):
     """q, k, v = W x as heads of gqa_dim_head, q and k RMS-normed per head
-    and rotated whole at their position; query head h reads key/value
-    head h // (heads / kv heads); softmax; W_o. Next-token training: x
-    [B, T, d] under the causal rule. Under a BlockDiffusion `rule` x is
-    the doubled row [B, 2T, d], `mask` [B, 2T], and both halves stand at
-    positions 0..T-1."""
+    and, where the kind's theta is not 0, rotated whole at their position;
+    query head h reads key/value head h // (heads / kv heads); softmax;
+    the gate, where asked for; W_o. Next-token training: x [B, T, d]
+    under the causal rule (`gqa`) or under Window(swa_window) (`swa`).
+    Under a BlockDiffusion `rule` x is the doubled row [B, 2T, d], `mask`
+    [B, 2T], and both halves stand at positions 0..T-1."""
     h, hk, dh = cfg.heads, cfg.gqa_kv_heads, cfg.gqa_dim_head
     bsz, t, _ = x.shape
-    with jax.named_scope("gqa"):
+    theta = cfg.swa_rope_theta if kind == "swa" else cfg.gqa_rope_theta
+    if kind == "swa":
+        rule = Window(cfg.swa_window)
+    with jax.named_scope(kind):
         q = rms_norm(_heads(jnp.dot(x, p[f"{lp}_gqa_Wq"]), h),
                      p[f"{lp}_gqa_q_norm_scale"], eps=cfg.norm_eps)
         k = rms_norm(_heads(jnp.dot(x, p[f"{lp}_gqa_Wk"]), hk),
                      p[f"{lp}_gqa_k_norm_scale"], eps=cfg.norm_eps)
         v = _heads(jnp.dot(x, p[f"{lp}_gqa_Wv"]), hk)
-        with jax.named_scope("gqa.rope"):
-            angles = rope_angles(t if rule is None else rule.length, dh,
-                                 cfg.gqa_rope_theta, "half")
-            if rule is not None:
-                angles = jnp.tile(angles, (2, 1))
-            q, k = _rotate(q, angles, "half"), _rotate(k, angles, "half")
+        if theta:
+            with jax.named_scope(f"{kind}.rope"):
+                doubled = isinstance(rule, BlockDiffusion)
+                angles = rope_angles(rule.length if doubled else t, dh,
+                                     theta, "half")
+                if doubled:
+                    angles = jnp.tile(angles, (2, 1))
+                q, k = _rotate(q, angles, "half"), _rotate(k, angles, "half")
         # the dense path builds a rule's mask itself; the causal one is
         # handed to it, as `_mla` does
         o, _ = attention(
@@ -559,6 +619,8 @@ def _gqa(cfg: PlanConfig, p: Params, lp: str, x, mask, rule=None):
             causal=True if rule is None else rule,
             flash=cfg.flash_attention, packed="off")
         o = o.transpose(0, 2, 1, 3).reshape(bsz, t, h * dh)
+        if cfg.gqa_gate:
+            o = _gate(cfg, p, lp, x, o)
         return jnp.dot(o, p[f"{lp}_gqa_Wo"])
 
 
@@ -594,10 +656,15 @@ def _experts(cfg: PlanConfig, p: Params, lp: str, x, mask):
 def _mix(cfg: PlanConfig, kind: str, lp: str, p: Params, x, mask,
          rule=None):
     pre = rms_norm(x, p[f"{lp}_mix_norm_scale"], eps=cfg.norm_eps)
-    if kind == "gqa":
-        return x + _gqa(cfg, p, lp, pre, mask, rule)
-    return x + (_kda(cfg, p, lp, pre) if kind == "kda"
-                else _mla(cfg, p, lp, pre, mask))
+    if kind in _GROUPED:
+        out = _gqa(cfg, p, lp, pre, mask, rule, kind)
+    else:
+        out = _kda(cfg, p, lp, pre) if kind == "kda" \
+            else _mla(cfg, p, lp, pre, mask)
+    if cfg.post_norms:
+        out = rms_norm(out, p[f"{lp}_mix_post_norm_scale"],
+                       eps=cfg.norm_eps)
+    return x + out
 
 
 def _feed_forward(cfg: PlanConfig, kind: str, lp: str, p: Params, x, mask):
@@ -609,6 +676,9 @@ def _feed_forward(cfg: PlanConfig, kind: str, lp: str, p: Params, x, mask):
         counters = jnp.zeros((len(COUNTERS),), jnp.float32)
     else:
         out, counters = _experts(cfg, p, lp, pre, mask)
+    if cfg.post_norms:
+        out = rms_norm(out, p[f"{lp}_ffn_post_norm_scale"],
+                       eps=cfg.norm_eps)
     return x + out, counters
 
 
@@ -630,13 +700,14 @@ def _named_bytes(f, names, *args) -> int:
 
 def _layer(cfg: PlanConfig, kinds, lp: str, p: Params, x, mask, remat,
            rule=None):
-    """One block: x + mixing(norm(x)), then x + feed-forward(norm(x)).
+    """One block: x + mixing(norm(x)), then x + feed-forward(norm(x)),
+    each branch's output normed first under `post_norms`.
     With `remat` (--gradient-checkpointing, training) each half is
     rematerialised in the backward on its own, so what stays alive
     between the passes is a layer's input and its middle, and of an
-    `mla` or `gqa` half also what only the flash kernel can produce
-    (_FLASH_KEEPS): its projections, rotation and concatenates run again
-    in the backward, the kernel does not. Where the dense path runs
+    `mla`, `gqa` or `swa` half also what only the flash kernel can produce
+    (_FLASH_KEEPS): its projections, rotation, concatenates, gate and
+    output norm run again in the backward, the kernel does not. Where the dense path runs
     (short rows, the CPU) nothing bears those names and nothing more is
     kept. KDA mixed in head groups rematerialises itself group by group
     and is not wrapped again: a second wrap would run its forward a
@@ -648,7 +719,7 @@ def _layer(cfg: PlanConfig, kinds, lp: str, p: Params, x, mask, remat,
         f_mix = partial(f_mix, rule=rule)
     f_ffn = partial(_feed_forward, cfg, ffn, lp)
     if remat:
-        if mix in ("mla", "gqa"):
+        if mix in _ATTENTION:
             if obs.enabled():
                 obs.event("plan.remat_keep", layer=lp, names=_FLASH_KEEPS,
                           bytes=_named_bytes(f_mix, _FLASH_KEEPS, p, x,
@@ -690,7 +761,35 @@ def head_names(cfg: PlanConfig) -> Tuple[str, ...]:
 
 def counter_names(cfg: PlanConfig) -> Tuple[str, ...]:
     """What the step's one lazy vector counts, in its order."""
-    return COUNTERS + (DIFFUSION_COUNTERS if cfg.diffusion_block else ())
+    return COUNTERS + (DIFFUSION_COUNTERS if cfg.diffusion_block else ()) \
+        + (ATTENTION_COUNTERS if _has_window(cfg) else ())
+
+
+def _has_window(cfg: PlanConfig) -> bool:
+    return any(mix == "swa" for mix, _ in cfg.plan)
+
+
+def _attention_pairs(cfg: PlanConfig, rows: int, width: int):
+    """[2] float32, ATTENTION_COUNTERS of one step over [rows, width],
+    from shapes alone: the (query, key) pairs that the layers' rules admit
+    in the padded rows, over all `gqa` and `swa` layers and query heads,
+    and the pairs of the tiles that the flash kernels compute for them
+    (`tile_plan`: live tiles x block_q x block_k, whichever path runs).
+    Their quotient is the share of the tiles' work that the rules admit;
+    the rest is what the diagonal and the window's trailing edge cut off
+    inside a tile, and the rows' padding to whole tiles."""
+    seen = tiled = 0
+    for kind, rule, w in (("swa", Window(cfg.swa_window),
+                           min(cfg.swa_window, width)),
+                          ("gqa", True, width)):
+        layers = sum(mix == kind for mix, _ in cfg.plan)
+        if layers:
+            plan = tile_plan(rule, width, width, cfg.gqa_dim_head)
+            seen += layers * (width * w - w * (w - 1) // 2)
+            tiled += layers * plan["tiles_live"] * plan["block_q"] \
+                * plan["block_k"]
+    return jnp.asarray([rows * cfg.heads * seen, rows * cfg.heads * tiled],
+                       jnp.float32)
 
 
 def diffusion_noise(key: Optional[jax.Array], mask):
@@ -815,6 +914,9 @@ def decode_train(cfg: PlanConfig, params: Params, enc_out, src_mask,
             heads, c = _predict_ahead(cfg, params, x, emb, trg_ids, mask,
                                       remat)
         counters = counters + c
+    if _has_window(cfg):
+        counters = jnp.concatenate(
+            [counters, _attention_pairs(cfg, *trg_ids.shape)])
     x = rms_norm(x, params["decoder_top_norm_scale"], eps=cfg.norm_eps)
     out = (x if return_hidden else T.output_logits(cfg, params, x),
            jax.lax.stop_gradient(counters))

@@ -68,10 +68,13 @@ def attention(q: jax.Array, k: jax.Array, v: jax.Array,
 
     `mask` is the general [B,1,Tq,Tk] dense mask; `kv_mask` [B,Tk] + `causal`
     is the structured form both Pallas kernels understand. Callers that can,
-    pass both. `causal` may also be a flash_attention.BlockDiffusion rule
-    (block-diffusion training over a doubled row): the flash kernels take
-    the rule, the dense path builds its mask here (`block_diffusion_mask`),
-    and `mask` may then be left out. Key/value heads shared by a group of
+    pass both. `causal` is a RULE over (query index, key index), as
+    flash_attention.py lists them: False, True (the future mask), a
+    flash_attention.BlockDiffusion (block-diffusion training over a
+    doubled row) or a flash_attention.Window (the last W keys up to the
+    query's own). The flash kernels take the rule; for the last two the
+    dense path builds the rule's mask here (`block_diffusion_mask`,
+    `window_mask`), and `mask` may then be left out. Key/value heads shared by a group of
     query heads are read in place by flash and repeated for the dense path.
     A kernel is picked when it is (a) allowed (its gate = auto|on),
     (b) applicable (no returned weights, no active attention dropout, a
@@ -97,10 +100,10 @@ def attention(q: jax.Array, k: jax.Array, v: jax.Array,
             flash == "on" or max(q.shape[-2], k.shape[-2]) >= flash_min_len):
         from .pallas.flash_attention import flash_attention
         return flash_attention(q, k, v, kv_mask=kv_mask, causal=causal), None
-    from .pallas.flash_attention import BlockDiffusion
-    if isinstance(causal, BlockDiffusion):
+    from .pallas.flash_attention import BlockDiffusion, Window
+    if isinstance(causal, (BlockDiffusion, Window)):
         packed = "off"
-        see = block_diffusion_mask(causal.length, causal.block)
+        see = _rule_as_mask(causal, q.shape[-2])
         if kv_mask is not None:
             see = see * kv_mask[:, None, None, :].astype(see.dtype)
         mask = combine_masks(mask, see)
@@ -135,11 +138,24 @@ def block_diffusion_mask(length: int, block: int, dtype=jnp.float32
                          ) -> jax.Array:
     """[1, 1, 2T, 2T]: flash_attention.BlockDiffusion(length, block) as an
     array, for the dense path and for tests (1 = the query sees the key)."""
-    from .pallas.flash_attention import BlockDiffusion, rule_mask
-    pos = jnp.arange(2 * length, dtype=jnp.int32)
-    see = rule_mask(BlockDiffusion(length, block), pos[:, None],
-                    pos[None, :])
-    return see.astype(dtype)[None, None, :, :]
+    from .pallas.flash_attention import BlockDiffusion
+    return _rule_as_mask(BlockDiffusion(length, block), 2 * length, dtype)
+
+
+def window_mask(length: int, window: int, dtype=jnp.float32) -> jax.Array:
+    """[1, 1, T, T]: flash_attention.Window(window) as an array, for the
+    dense path and for tests (1 = the query sees the key: the `window`
+    keys that end at its own)."""
+    from .pallas.flash_attention import Window
+    return _rule_as_mask(Window(window), length, dtype)
+
+
+def _rule_as_mask(rule, n: int, dtype=jnp.float32) -> jax.Array:
+    """[1, 1, n, n]: a flash_attention rule over n indices as an array."""
+    from .pallas.flash_attention import rule_mask
+    pos = jnp.arange(n, dtype=jnp.int32)
+    return rule_mask(rule, pos[:, None], pos[None, :]).astype(dtype)[
+        None, None, :, :]
 
 
 def combine_masks(*masks: Optional[jax.Array]) -> Optional[jax.Array]:

@@ -16,6 +16,9 @@ Masks the kernels take (any other pattern, e.g. an arbitrary dense
       BlockDiffusion(T, block)   block-diffusion training over a doubled
                          row [noised ; clean] of 2T positions (see the
                          class)
+      Window(W)          a sliding window: the last W keys up to the
+                         query's own, i - W < key index <= i (see the
+                         class)
     From a rule the three kernels take which tiles are live and the mask
     inside a live tile. A dead tile is neither computed nor fetched: a
     dead step's block index is clamped to a resident live tile, so the
@@ -36,7 +39,7 @@ training).
 from __future__ import annotations
 
 import functools
-from typing import NamedTuple, Optional, Union
+from typing import Dict, NamedTuple, Optional, Tuple, Union
 
 import jax
 import jax.numpy as jnp
@@ -100,7 +103,18 @@ class BlockDiffusion(NamedTuple):
     block: int
 
 
-Rule = Union[bool, BlockDiffusion]
+class Window(NamedTuple):
+    """A sliding window over a row (self-attention, Tq = Tk): the query at
+    index i sees the key at index j iff i - window < j <= i, the `window`
+    keys that end at its own. Two edges cut tiles, the diagonal and the
+    trailing edge `window` behind it; the live key tiles of a query tile
+    are ONE run that starts behind the tile, not at 0, with dead tiles on
+    both sides. A row no longer than the window is a causal row, and
+    `flash_attention` runs it as one."""
+    window: int
+
+
+Rule = Union[bool, BlockDiffusion, Window]
 
 
 def _block_of(pos, block: int):
@@ -108,12 +122,14 @@ def _block_of(pos, block: int):
     return pos >> shift if block == 1 << shift else pos // block
 
 
-def rule_mask(rule: BlockDiffusion, qpos, kpos):
+def rule_mask(rule: Union[BlockDiffusion, Window], qpos, kpos):
     """Boolean: may the query at index qpos see the key at index kpos.
     int32 arrays that broadcast against each other: a column of queries
-    against a row of keys keeps all but two compares and an `or` off the
-    square. (Written as compares of per-index codes: Mosaic has no select
-    between vectors of booleans.)"""
+    against a row of keys keeps all but two compares and an `or` (an
+    `and` under a window) off the square. (Written as compares of
+    per-index codes: Mosaic has no select between vectors of booleans.)"""
+    if isinstance(rule, Window):
+        return (kpos <= qpos) & (kpos > qpos - rule.window)
     t, b = rule
     q_noised, k_noised = qpos < t, kpos < t
     qb = _block_of(jnp.where(q_noised, qpos, qpos - t), b)
@@ -179,6 +195,27 @@ def _q_spans(rule: BlockDiffusion, j, block_q: int, block_k: int):
     return (jnp.minimum(n_lo, c_lo), jnp.maximum(n_hi, c_hi), b_lo, b_hi)
 
 
+def _window_keys(rule: Window, i, block_q: int, block_k: int, n_k: int):
+    """The key tiles that hold a key some query of query tile i sees under
+    a window: one run (lo, hi inclusive), from the tile of the first
+    query's oldest key to the tile of the last query, clipped at 0 and at
+    the row's end. `lax`, as under `causal` in `_key_tile`."""
+    q0 = i * block_q
+    lo = jax.lax.div(jax.lax.max(q0 - (rule.window - 1), 0), block_k)
+    hi = jax.lax.div(q0 + (block_q - 1), block_k)
+    return jax.lax.min(lo, n_k - 1), jax.lax.min(hi, n_k - 1)
+
+
+def _window_queries(rule: Window, j, block_q: int, block_k: int, n_q: int):
+    """Its twin: the query tiles that hold a query which sees some key of
+    key tile j, from the tile of the first key's own query to the tile of
+    the last query that still reaches the last key."""
+    k0 = j * block_k
+    lo = jax.lax.div(k0, block_q)
+    hi = jax.lax.div(k0 + (block_k - 1 + rule.window - 1), block_q)
+    return jax.lax.min(lo, n_q - 1), jax.lax.min(hi, n_q - 1)
+
+
 def _in_spans(x, spans):
     a_lo, a_hi, b_lo, b_hi = spans
     return ((x >= a_lo) & (x <= a_hi)) | ((x >= b_lo) & (x <= b_hi))
@@ -206,6 +243,11 @@ def _key_tile(rule: Rule, i, j, block_q: int, block_k: int, n_k: int):
     if rule is True:
         return jax.lax.min(j, jax.lax.div(i * block_q + (block_q - 1),
                                           block_k))
+    if isinstance(rule, Window):
+        # before the run its first tile, fetched at step 0 and held;
+        # after it its last
+        lo, hi = _window_keys(rule, i, block_q, block_k, n_k)
+        return jax.lax.min(jax.lax.max(j, lo), hi)
     if rule:
         return _resident(j, _kv_spans(rule, i, block_q, block_k), n_k)
     return j
@@ -217,6 +259,9 @@ def _query_tile(rule: Rule, i, j, block_q: int, block_k: int, n_q: int):
     if rule is True:
         first = jax.lax.div(j * block_k, block_q)
         return jax.lax.min(jax.lax.max(i, first), n_q - 1)
+    if isinstance(rule, Window):
+        lo, hi = _window_queries(rule, j, block_q, block_k, n_q)
+        return jax.lax.min(jax.lax.max(i, lo), hi)
     if rule:
         return _resident(i, _q_spans(rule, j, block_q, block_k), n_q)
     return i
@@ -227,6 +272,11 @@ def _live(rule: Rule, i, j, block_q: int, block_k: int):
     if rule is True:
         # skip k-blocks that are entirely in the future of this q-block
         return j * block_k <= i * block_q + block_q - 1
+    if isinstance(rule, Window):
+        # not wholly in the future, and its last key still inside the
+        # first query's window
+        return (j * block_k <= i * block_q + block_q - 1) \
+            & (j * block_k + block_k - 1 > i * block_q - rule.window)
     if rule:
         return _in_spans(j, _kv_spans(rule, i, block_q, block_k))
     return True
@@ -241,6 +291,11 @@ def _whole(rule: Rule, i, j, block_q: int, block_k: int):
     36)."""
     if rule is True:
         return j * block_k + block_k - 1 <= i * block_q
+    if isinstance(rule, Window):
+        # neither edge cuts it: its last key is no later than the first
+        # query, its first key inside the last query's window
+        return (j * block_k + block_k - 1 <= i * block_q) \
+            & (j * block_k > i * block_q + block_q - 1 - rule.window)
     if rule:
         # clean keys only, before queries of one kind: a clean query sees
         # up to its own block, a noised one up to the block before
@@ -270,6 +325,8 @@ def _masked(rule: Rule, s, i, j, block_q: int, block_k: int):
 def _rule_name(rule: Rule) -> str:
     if isinstance(rule, BlockDiffusion):
         return f"block_diffusion({rule.length},{rule.block})"
+    if isinstance(rule, Window):
+        return f"window({rule.window})"
     return "causal" if rule else "none"
 
 
@@ -609,37 +666,26 @@ def _flash_bwd(scale, causal, block_q, block_k, interpret, res, do):
 _flash.defvjp(_flash_fwd, _flash_bwd)
 
 
-def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array,
-                    kv_mask: Optional[jax.Array] = None,
-                    causal: Rule = False,
-                    scale: Optional[float] = None,
-                    block_q: Optional[int] = None,
-                    block_k: Optional[int] = None,
-                    interpret: Optional[bool] = None) -> jax.Array:
-    """softmax(scale * Q K^T + mask) V, never materializing the score matrix.
+def _pick_block(limit: int, t: int) -> int:
+    # biggest block <= limit whose grid padding wastes <= 25% of t:
+    # big blocks cut online-softmax rescale passes (the r5 sweep
+    # win), but a 2048 block on t=2176 would pad to 4096 and run
+    # the fully-masked blocks through every kernel — a tile of
+    # padded keys is skipped only where the rule kills it (a rule's
+    # `_live` test reads indices, not the key mask)
+    b = _round_up(min(limit, _round_up(t, _LANES)), _LANES)
+    while b > _LANES:
+        if _round_up(t, b) - t <= max(t // 4, _LANES):
+            return b
+        b = (b // 2 // _LANES) * _LANES
+    return _LANES
 
-    q [B,H,Tq,Dh], k [B,Hkv,Tk,Dh], v [B,Hkv,Tk,Dv] with H a multiple of
-    Hkv, kv_mask [B,Tk] (1.0 = attend) or None, `causal` a rule (False,
-    True, or a BlockDiffusion over Tq = Tk = 2 * its length); the default
-    scale is 1/sqrt(Dh), the key width. Sequence dims are padded up to
-    block multiples internally (padded keys are masked out; padded query
-    rows are sliced off).
-    """
-    b, h, tq, dh = q.shape
-    tk = k.shape[2]
-    if h % k.shape[1] or k.shape[1] != v.shape[1]:
-        raise ValueError(f"{h} query heads on {k.shape[1]} key and "
-                         f"{v.shape[1]} value heads")
-    if isinstance(causal, BlockDiffusion):
-        if not (tq == tk == 2 * causal.length and causal.block >= 1):
-            raise ValueError(f"{causal} over {tq} queries and {tk} keys: "
-                             f"want twice its length of both")
-    else:
-        causal = bool(causal)
-    if scale is None:
-        scale = 1.0 / (dh ** 0.5)
-    if interpret is None:
-        interpret = _interpret_default()
+
+def pick_blocks(tq: int, tk: int, dh: int, block_q: Optional[int] = None,
+                block_k: Optional[int] = None) -> Tuple[int, int]:
+    """The (query, key) block sizes `flash_attention` runs Tq queries on
+    Tk keys of width dh at; the sequence dims are padded up to their
+    multiples."""
     # Default blocks 512/2048, from the r5 silicon sweep at seq 2048
     # (tok/s: 128/128 5,441 · 256/512 13,625 · 512/512 15,373 ·
     # 256/1024 15,929 · **512/2048 18,039** · 1024/2048 VMEM-OOM in the
@@ -661,23 +707,69 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array,
         # size must be the block size that actually ran.
         default_k = 2048 if dh <= 64 else 1024
         block_k = _env_block("MARIAN_FLASH_BLOCK_K", default_k)
+    return _pick_block(block_q, tq), _pick_block(block_k, tk)
 
-    def _pick_block(limit: int, t: int) -> int:
-        # biggest block <= limit whose grid padding wastes <= 25% of t:
-        # big blocks cut online-softmax rescale passes (the r5 sweep
-        # win), but a 2048 block on t=2176 would pad to 4096 and run
-        # the fully-masked blocks through every kernel — a tile of
-        # padded keys is skipped only where the rule kills it (a rule's
-        # `_live` test reads indices, not the key mask)
-        b = _round_up(min(limit, _round_up(t, _LANES)), _LANES)
-        while b > _LANES:
-            if _round_up(t, b) - t <= max(t // 4, _LANES):
-                return b
-            b = (b // 2 // _LANES) * _LANES
-        return _LANES
 
-    bq = _pick_block(block_q, tq)
-    bk = _pick_block(block_k, tk)
+def _checked(rule: Rule, tq: int, tk: int) -> Rule:
+    """The rule as the kernels take it over Tq queries and Tk keys, or a
+    ValueError where it is not written for them."""
+    if isinstance(rule, BlockDiffusion):
+        if not (tq == tk == 2 * rule.length and rule.block >= 1):
+            raise ValueError(f"{rule} over {tq} queries and {tk} keys: "
+                             f"want twice its length of both")
+        return rule
+    if isinstance(rule, Window):
+        if not (tq == tk and rule.window >= 1):
+            raise ValueError(f"{rule} over {tq} queries and {tk} keys: "
+                             f"want a window of 1 or more over one row")
+        # no key is behind the window: a causal row
+        return True if rule.window >= tk else rule
+    return bool(rule)
+
+
+def tile_plan(rule: Rule, tq: int, tk: int, dh: int,
+              block_q: Optional[int] = None,
+              block_k: Optional[int] = None) -> Dict[str, int]:
+    """What the kernels do with a rule over Tq x Tk, from shapes alone:
+    the blocks, the tiles of the padded grid, those computed (`tiles_live`)
+    and those no edge cuts (`tiles_whole`). The `flash_attention.plan`
+    event's numbers; whoever counts a step's pairs reads the same."""
+    rule = _checked(rule, tq, tk)
+    bq, bk = pick_blocks(tq, tk, dh, block_q, block_k)
+    n_q, n_k = _round_up(tq, bq) // bq, _round_up(tk, bk) // bk
+    return dict(block_q=bq, block_k=bk, rule=_rule_name(rule),
+                tiles_live=live_tiles(rule, n_q, n_k, bq, bk),
+                tiles_whole=whole_tiles(rule, n_q, n_k, bq, bk),
+                tiles=n_q * n_k)
+
+
+def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array,
+                    kv_mask: Optional[jax.Array] = None,
+                    causal: Rule = False,
+                    scale: Optional[float] = None,
+                    block_q: Optional[int] = None,
+                    block_k: Optional[int] = None,
+                    interpret: Optional[bool] = None) -> jax.Array:
+    """softmax(scale * Q K^T + mask) V, never materializing the score matrix.
+
+    q [B,H,Tq,Dh], k [B,Hkv,Tk,Dh], v [B,Hkv,Tk,Dv] with H a multiple of
+    Hkv, kv_mask [B,Tk] (1.0 = attend) or None, `causal` a rule (False,
+    True, a BlockDiffusion over Tq = Tk = 2 * its length, or a Window over
+    Tq = Tk); the default scale is 1/sqrt(Dh), the key width. Sequence
+    dims are padded up to block multiples internally (padded keys are
+    masked out; padded query rows are sliced off).
+    """
+    b, h, tq, dh = q.shape
+    tk = k.shape[2]
+    if h % k.shape[1] or k.shape[1] != v.shape[1]:
+        raise ValueError(f"{h} query heads on {k.shape[1]} key and "
+                         f"{v.shape[1]} value heads")
+    causal = _checked(causal, tq, tk)
+    if scale is None:
+        scale = 1.0 / (dh ** 0.5)
+    if interpret is None:
+        interpret = _interpret_default()
+    bq, bk = pick_blocks(tq, tk, dh, block_q, block_k)
     tq_p, tk_p = _round_up(tq, bq), _round_up(tk, bk)
 
     if kv_mask is None:
@@ -692,12 +784,9 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array,
         q = jnp.pad(q, ((0, 0), (0, 0), (0, tq_p - tq), (0, 0)))
 
     if obs.enabled():
-        n_q, n_k = tq_p // bq, tk_p // bk
-        obs.event("flash_attention.plan", tq=tq, tk=tk, block_q=bq,
-                  block_k=bk, rule=_rule_name(causal),
-                  tiles_live=live_tiles(causal, n_q, n_k, bq, bk),
-                  tiles_whole=whole_tiles(causal, n_q, n_k, bq, bk),
-                  tiles=n_q * n_k, kv_group=h // k.shape[1])
+        obs.event("flash_attention.plan", tq=tq, tk=tk,
+                  kv_group=h // k.shape[1],
+                  **tile_plan(causal, tq, tk, dh, bq, bk))
     out = _flash(q, k, v, kvm, float(scale), causal, bq, bk,
                  bool(interpret))
     if tq_p != tq:

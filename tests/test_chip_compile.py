@@ -132,6 +132,21 @@ def _block_diffusion_attn(b, t):
     return jax.grad(loss, argnums=(0, 1, 2)), shapes, list(_FLASH[1:])
 
 
+def _window_attn(b, t, window=2048):
+    """The layer plan's grouped-query attention under a sliding window at
+    its published head widths: 32 query heads on 4 key/value heads of
+    128, the last 2048 keys, with the forward and both backward
+    kernels."""
+    from marian_tpu.ops.pallas.flash_attention import Window
+
+    def loss(q, k, v, m):
+        return flash_attention(q, k, v, kv_mask=m, causal=Window(window),
+                               interpret=False).astype(jnp.float32).sum()
+    shapes = [((b, 32, t, 128), DT), ((b, 4, t, 128), DT),
+              ((b, 4, t, 128), DT), ((b, t), jnp.float32)]
+    return jax.grad(loss, argnums=(0, 1, 2)), shapes, list(_FLASH[1:])
+
+
 def _kda_carry(b, heads, chunks):
     """The delta rule's state carry (chunks of 64, 128 x 128 state a
     head, float32), forward and backward."""
@@ -266,6 +281,10 @@ CASES = {
         lambda: _block_diffusion_attn(16, 1024),
     "flash-grad-block-diffusion-2x8192":
         lambda: _block_diffusion_attn(2, 8192),
+    # window layers over long documents (trinity-mini.train-docs16k): the
+    # narrowest and the widest batch of 16384 tokens
+    "flash-grad-window-4x4096": lambda: _window_attn(4, 4096),
+    "flash-grad-window-1x16384": lambda: _window_attn(1, 16384),
     "kda-carry-grad-16x1024": lambda: _kda_carry(16, 4, 16),
     "kda-carry-grad-2x8192": lambda: _kda_carry(2, 4, 128),
     "kda-prep-grad-16x1024": lambda: _kda_prep(16, 4, 1024),

@@ -439,7 +439,18 @@ def _grouped_doubled(rng):
     return q, k, v, mask, F.BlockDiffusion(384, 4), w
 
 
-@pytest.mark.parametrize("case", [_grouped_causal, _grouped_doubled])
+def _grouped_window(rng):
+    """4 query heads on one key/value head over 400 positions under a
+    window of 130, padded keys: 4 x 4 tiles of 128, seven of them dead
+    (six in the future, one behind the window)."""
+    q, k, v = _rand(rng, 1, 4, 400, 16), _rand(rng, 1, 1, 400, 16), \
+        _rand(rng, 1, 1, 400, 16)
+    return q, k, v, _kv_mask(rng, 1, 400), F.Window(130), \
+        _rand(rng, 1, 4, 400, 16)
+
+
+@pytest.mark.parametrize("case", [_grouped_causal, _grouped_doubled,
+                                  _grouped_window])
 @time_limit(600)
 def test_a_dead_step_that_fetches_nothing_changes_no_bit(rng, monkeypatch,
                                                          case):
@@ -469,7 +480,8 @@ def _bodies(kernel_jaxpr):
 
 
 @pytest.mark.parametrize("rule,bodies", [
-    (False, 2), (True, 3), (F.BlockDiffusion(128, 4), 3)],
+    (False, 2), (True, 3), (F.BlockDiffusion(128, 4), 3),
+    (F.Window(100), 3)],
     ids=F._rule_name)
 @time_limit(120)
 def test_a_kernel_holds_one_tile_body(rng, rule, bodies):
@@ -543,3 +555,202 @@ def test_the_plan_event_says_how_many_tiles_are_live(rng):
                     "tiles_whole": 1, "tiles": 25, "kv_group": 8}
     assert (causal["rule"], causal["tiles_live"], causal["tiles_whole"],
             causal["tiles"], causal["kv_group"]) == ("causal", 15, 10, 25, 1)
+
+
+# ---------------------------------------------------------------------------
+# A third rule: a sliding window, the last W keys up to the query's own
+# ---------------------------------------------------------------------------
+
+from marian_tpu.ops.attention import window_mask  # noqa: E402
+
+
+def _window_written_out(n, window):
+    """query i sees key j iff i - window < j <= i, index by index."""
+    see = np.zeros((n, n), bool)
+    for q in range(n):
+        for k in range(max(0, q - window + 1), q + 1):
+            see[q, k] = True
+    return see
+
+
+@pytest.mark.parametrize("n,window", [(10, 4), (37, 1), (24, 24), (9, 16)])
+@time_limit(60)
+def test_the_window_is_its_sentence(n, window):
+    got = np.asarray(window_mask(n, window))[0, 0] > 0
+    np.testing.assert_array_equal(got, _window_written_out(n, window))
+    assert got.sum() == n * min(n, window) \
+        - min(n, window) * (min(n, window) - 1) // 2
+
+
+# (whole, live) tiles a row and head on the benchmark cell's shapes under
+# a window of 2048 at blocks 512 / 1024: the narrowest and the widest row
+_WINDOW_CELL_TILES = {4096: (6, 18), 16384: (30, 90)}
+
+
+@pytest.mark.parametrize("n,window,bq,bk", [
+    (20, 5, 8, 8), (100, 17, 16, 8), (90, 40, 8, 32), (64, 64, 16, 16),
+    (50, 1, 8, 16), (300, 130, 128, 128), (400, 128, 128, 256),
+    (4096, 2048, 512, 1024), (16384, 2048, 512, 1024)])
+@time_limit(120)
+def test_window_tiles_against_a_brute_force_count(n, window, bq, bk):
+    """`_live` and `_whole` (both edges), `live_tiles` / `whole_tiles`, and
+    the tile a dead step of either grid asks for, against the mask itself
+    (numpy, off the kernels): a live step asks for its own tile, a dead
+    one for a LIVE tile of its row (its column under dkv), so it fetches
+    nothing new; each row's and column's live tiles are ONE run."""
+    rule = F.Window(window)
+    n_q, n_k = -(-n // bq), -(-n // bk)
+    live, all_see, some_real, all_real = (
+        np.zeros((n_q, n_k), bool) for _ in range(4))
+    for i in range(n_q):                 # a tile at a time, from the sentence
+        q = np.arange(i * bq, (i + 1) * bq)[:, None]
+        for j in range(n_k):
+            k = np.arange(j * bk, (j + 1) * bk)[None, :]
+            real = (q < n) & (k < n)
+            see = (k <= q) & (k > q - window) & real
+            np.testing.assert_array_equal(
+                see, np.asarray(F.rule_mask(rule, q, k)) & real)
+            live[i, j], some_real[i, j] = see.any(), real.any()
+            all_real[i, j] = real.all()
+            all_see[i, j] = see.any() and (see | ~real).all()
+    ii, jj = (np.ascontiguousarray(a, np.int32) for a in np.broadcast_arrays(
+        np.arange(n_q)[:, None], np.arange(n_k)[None, :]))
+    with jax.ensure_compile_time_eval():
+        got_live = np.asarray(F._live(rule, ii, jj, bq, bk))
+        whole = np.asarray(F._whole(rule, ii, jj, bq, bk))
+        stay_k = np.asarray(F._key_tile(rule, ii, jj, bq, bk, n_k))
+        stay_q = np.asarray(F._query_tile(rule, ii, jj, bq, bk, n_q))
+    # a tile of padding alone may be computed (the test reads indices, not
+    # the row's end); no tile with a pair that sees is left out
+    assert not (live & ~got_live).any()
+    assert not (got_live & ~live & some_real).any()
+    assert not (whole & ~all_see & all_real).any()
+    assert not (whole & ~got_live).any()
+    assert F.live_tiles(rule, n_q, n_k, bq, bk) == got_live.sum()
+    assert F.whole_tiles(rule, n_q, n_k, bq, bk) == whole.sum()
+    if n in _WINDOW_CELL_TILES and window == 2048:
+        np.testing.assert_array_equal(whole, all_see)
+        np.testing.assert_array_equal(got_live, live)
+        assert (whole.sum(), live.sum()) == _WINDOW_CELL_TILES[n]
+        assert F.tile_plan(rule, n, n, 128) == {
+            "block_q": bq, "block_k": bk, "rule": "window(2048)",
+            "tiles_live": live.sum(), "tiles_whole": whole.sum(),
+            "tiles": n_q * n_k}
+    np.testing.assert_array_equal(stay_k[got_live], jj[got_live])
+    np.testing.assert_array_equal(stay_q[got_live], ii[got_live])
+    assert got_live[ii, stay_k].all() and got_live[stay_q, jj].all()
+    for runs in (got_live, got_live.T):            # one run a row, a column
+        assert (np.abs(np.diff(np.pad(runs.astype(int), ((0, 0), (1, 1))),
+                               axis=1)).sum(axis=1) == 2).all()
+
+
+@pytest.mark.parametrize("t,window,group,bq,bk", [
+    (100, 200, 2, None, None),    # T < W: one tile
+    (256, 256, 1, 128, 128),      # T = W
+    (300, 130, 4, 128, 128),      # T > W, T no multiple of the blocks
+    (260, 100, 8, 128, 128),      # 8 query heads a key/value head
+    (400, 128, 4, 128, 256),      # W a whole tile, blocks that differ
+    (300, 1, 2, 128, 128)])       # a query sees itself alone
+@time_limit(240)
+def test_window_kernels_match_dense(rng, t, window, group, bq, bk):
+    """Forward and the three gradients of the kernels (interpret mode)
+    against dense attention under the mask written out index by index:
+    T under, at and over the window, T no multiple of the blocks, padded
+    keys, 1 to 8 query heads a key/value head (dk and dv the group's
+    sum)."""
+    kv_heads = 2 if group < 8 else 1
+    q = _rand(rng, 2, group * kv_heads, t, 16)
+    k, v = _rand(rng, 2, kv_heads, t, 16), _rand(rng, 2, kv_heads, t, 16)
+    w = _rand(rng, 2, group * kv_heads, t, 16)
+    mask = jnp.asarray(np.arange(t)[None] < np.array([[t], [t - 9]]),
+                       jnp.float32)
+    see = jnp.asarray(_window_written_out(t, window), jnp.float32)[
+        None, None] * mask[:, None, None, :]
+    real = mask[:, None, :, None]
+
+    def dense(q, k, v):
+        out = dense_attention(q, jnp.repeat(k, group, 1),
+                              jnp.repeat(v, group, 1), see)
+        return jnp.sum(out * w * real), out
+
+    def flash(q, k, v):
+        out = flash_attention(q, k, v, kv_mask=mask, causal=F.Window(window),
+                              block_q=bq, block_k=bk)
+        return jnp.sum(out * w * real), out
+    (_, want), want_g = jax.value_and_grad(dense, (0, 1, 2), True)(q, k, v)
+    (_, got), got_g = jax.value_and_grad(flash, (0, 1, 2), True)(q, k, v)
+    np.testing.assert_allclose(got * real, want * real, atol=2e-5)
+    for a, b in zip(got_g, want_g):
+        assert a.shape == b.shape
+        np.testing.assert_allclose(a, b, atol=5e-5)
+
+
+@pytest.mark.parametrize("t,window", [(200, 200), (300, 1000)])
+@time_limit(240)
+def test_a_row_inside_the_window_is_a_causal_row_to_the_bit(rng, t, window):
+    """T <= W: out, dq, dk and dv are `causal=True`'s, bit for bit, and
+    the plan event says so."""
+    from marian_tpu.obs import TRACER
+    q, k, v = _rand(rng, 2, 4, t, 16), _rand(rng, 2, 2, t, 16), \
+        _rand(rng, 2, 2, t, 16)
+    m, w = _kv_mask(rng, 2, t), _rand(rng, 2, 4, t, 16)
+
+    def run(rule):
+        def f(q, k, v):
+            out = flash_attention(q, k, v, kv_mask=m, causal=rule,
+                                  block_q=128, block_k=128)
+            return jnp.sum(out * w), out
+        (_, out), grads = jax.value_and_grad(f, (0, 1, 2), True)(q, k, v)
+        return (out,) + grads
+    TRACER.reset()
+    TRACER.enable()
+    try:
+        got = run(F.Window(window))
+        events = [e["attrs"]["rule"] for e in TRACER.snapshot()[1]
+                  if e["name"] == "flash_attention.plan"]
+    finally:
+        TRACER.disable()
+        TRACER.reset()
+    assert events == ["causal"]
+    for a, b in zip(got, run(True)):
+        np.testing.assert_array_equal(a, b)
+
+
+@time_limit(120)
+def test_the_dispatcher_hands_flash_the_window(rng):
+    q, k, v = _rand(rng, 2, 4, 200, 16), _rand(rng, 2, 2, 200, 16), \
+        _rand(rng, 2, 2, 200, 16)
+    mask = jnp.asarray(np.arange(200)[None] < np.array([[200], [150]]),
+                       jnp.float32)
+    rule = F.Window(70)
+    got, _ = attention(q, k, v, kv_mask=mask, causal=rule, flash="on")
+    want, _ = attention(q, k, v, kv_mask=mask, causal=rule, flash="off")
+    real = mask[:, None, :, None]
+    np.testing.assert_allclose(got * real, want * real, atol=2e-5)
+    causal, _ = attention(q, k, v, kv_mask=mask, causal=True, flash="off")
+    assert float(jnp.abs((want - causal) * real).max()) > 1e-3
+    with pytest.raises(ValueError):
+        flash_attention(q[:, :, :100], k, v, causal=rule)
+    with pytest.raises(ValueError):
+        flash_attention(q, k, v, causal=F.Window(0))
+
+
+@time_limit(60)
+def test_the_plan_event_names_the_window(rng):
+    from marian_tpu.obs import TRACER
+    q, k = _rand(rng, 1, 8, 600, 16), _rand(rng, 1, 2, 600, 16)
+    TRACER.reset()
+    TRACER.enable()
+    try:
+        flash_attention(q, k, k, causal=F.Window(200), block_q=128,
+                        block_k=128)
+        events = [e["attrs"] for e in TRACER.snapshot()[1]
+                  if e["name"] == "flash_attention.plan"]
+    finally:
+        TRACER.disable()
+        TRACER.reset()
+    # 5 x 5 tiles of 128: the diagonal, one behind it everywhere and two
+    # where a tile's first query still reaches (200 > 128 + 1)
+    assert events == [{"tq": 600, "tk": 600, "block_q": 128, "block_k": 128,
+                       "rule": "window(200)", "tiles_live": 12,
+                       "tiles_whole": 0, "tiles": 25, "kv_group": 4}]

@@ -428,11 +428,15 @@ def test_a_skipped_second_pool_runs_nothing():
         _held_layer(x, router, wg, wu, wd, mask, 0, pool=(512, 1024))
 
 
-@pytest.mark.parametrize("experts,held,width", [(16, 8, 32), (256, 16, 768)],
-                         ids=["2x8-of-16", "16x16-of-256-at-768"])
-def test_the_shares_add_up(experts, held, width):
+@pytest.mark.parametrize("experts,held,width,extra", [
+    (16, 8, 32, ()), (256, 16, 768, ()),
+    (128, 8, 32, ("--plan-experts-top-k", "8", "--plan-experts-scale",
+                  "2.826"))],
+    ids=["2x8-of-16", "16x16-of-256-at-768", "16x8-of-128-top-8-scaled"])
+def test_the_shares_add_up(experts, held, width, extra):
     """Every chip's share of the layer (8 of 16 experts; 16 of 256 at
-    expert width 768, one of 16 chips): their outputs, with the shared
+    expert width 768, one of 16 chips; 8 of 128 under a top 8 and a route
+    scale of 2.826, one of 16 chips): their outputs, with the shared
     expert counted once, sum to the uncut layer's output; and the routing
     counters of the shares sum to the uncut layer's."""
     x, router, wg, wu, wd, mask, _ = _expert_inputs()
@@ -444,7 +448,7 @@ def test_the_shares_add_up(experts, held, width):
     assert float(c_lo[1] + c_hi[1]) == float(c_all[1])
     # through the layer, shared expert and all: the shares' sum, less the
     # shared expert once for every share but one
-    size = ("--plan-experts-dim-ffn", str(width))
+    size = ("--plan-experts-dim-ffn", str(width), *extra)
     shares = [(first, held) for first in range(0, experts, held)]
     models = [_plan_model(size, plan=("mla:experts",), held=h,
                           experts=experts) for h in [(0, experts)] + shares]
@@ -466,7 +470,8 @@ def test_the_shares_add_up(experts, held, width):
         sum(outs[1:]) - (len(shares) - 1) * shared, outs[0],
         atol=2e-5 * len(shares))
     assert float(sum(c[1] for c in counts[1:])) == float(counts[0][1]) \
-        == 2 * 24 * 4
+        == 2 * 24 * models[0].cfg.experts_top_k
+    assert models[0].cfg.experts_scale == (2.826 if extra else 2.446)
 
 
 @pytest.mark.parametrize("pool", [(0, 0), (64, 0)])

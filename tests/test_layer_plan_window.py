@@ -1,0 +1,319 @@
+"""The layer plan's fourth mixing, `swa` (`gqa` under a sliding window, at a
+theta of its own), beside global `gqa` layers that are NOT rotated, the
+sigmoid gate on the heads' output and the two output norms of a block
+(models/layer_plan.py), beside tests/test_layer_plan.py and on its helpers:
+
+  the cut model   == the plain float32 reference beside the benchmark's
+      configuration (benchmark/configs/trinity_mini_reference.py) at the
+      rehearsal's widths, three layers deep (a dense window layer, a window
+      layer and a global layer with experts), rows LONGER than the window:
+      the cost, every parameter's gradient (dense and through the flash
+      kernels in more than one tile, checkpointed halves and not), the
+      per-token costs as the benchmark's check reads them
+  one mechanism lower is caught   window -> causal, the global layer
+      rotated, the gate off, the output norms off, the rotation's pairs
+      interleaved
+  one precision lower is caught   bfloat16 angles, a bfloat16 router, a
+      bfloat16 gate sigmoid
+  the pair counters against a brute-force count; what a checkpointed half
+      keeps; the validator's refusal
+"""
+
+import copy
+import dataclasses
+import importlib
+import json
+import os
+import subprocess
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from marian_tpu.common.config_parser import parse_options
+from marian_tpu.models import layer_plan as P
+from marian_tpu.models.encoder_decoder import create_model
+from marian_tpu.ops import experts as X
+from test_layer_plan import F32_LIMIT, ROOT, _batch, _token_costs
+from time_limit import time_limit
+
+REF = importlib.import_module("benchmark.configs.trinity_mini_reference")
+F = importlib.import_module("marian_tpu.ops.pallas.flash_attention")
+# the source's layers 1 (window, dense), 6 (window, experts), 7 (global)
+BUILT = [1, 6, 7]
+PLAN = ["swa:dense", "swa:experts", "gqa:experts"]
+WIDTH = 150            # rows of 150, 100 and 50 tokens under a window of 8
+
+
+def _dims():
+    with open(os.path.join(ROOT, "benchmark", "configs",
+                           "trinity-mini.json")) as fh:
+        config = json.load(fh)
+    return dict(config, **config["rehearse"]["dims"], num_hidden_layers=3,
+                layers_built=BUILT, layer_plan=PLAN), config
+
+
+def _model(precision="float32", extra=(), drop=()):
+    """The benchmark's configuration at its rehearsal's widths, three
+    layers deep: its `task_flags` (checkpointed halves and all) under the
+    rehearsal's."""
+    dims, config = _dims()
+    flags = [f for f in config["task_flags"] if f not in drop]
+    i = flags.index("--transformer-layer-plan")
+    flags[i + 1:i + 6] = PLAN
+    tiny = list(config["rehearse"]["flags"])
+    tiny[tiny.index("--precision") + 1] = precision
+    argv = flags + tiny + ["--train-sets", "x", "--vocabs", "v", *extra]
+    return create_model(parse_options(argv, mode="training"),
+                        dims["vocab"], dims["vocab"]), dims
+
+
+def _with(model, **changes):
+    other = copy.copy(model)
+    other.cfg = dataclasses.replace(model.cfg, **changes)
+    return other
+
+
+@pytest.fixture(scope="module")
+def tiny():
+    model, dims = _model()
+    params = model.init(jax.random.PRNGKey(7))
+    return model, dims, params, _batch(dims["vocab"], rows=3, width=WIDTH)
+
+
+@pytest.fixture(scope="module")
+def reference(tiny):
+    """The reference's summed cost and its gradient of every parameter."""
+    _, dims, params, batch = tiny
+    return jax.value_and_grad(lambda p: jnp.sum(REF.token_costs(
+        p, dims, None, None, batch["trg_ids"], batch["trg_mask"])
+        * batch["trg_mask"]))(params)
+
+
+@time_limit(120)
+def test_the_plan_is_the_configurations(tiny):
+    model, dims, params, _ = tiny
+    cfg = model.cfg
+    assert cfg.plan == tuple(tuple(e.split(":")) for e in PLAN)
+    assert REF.layer_kinds(dims) == [("sliding_attention", True),
+                                     ("sliding_attention", False),
+                                     ("full_attention", False)]
+    assert (cfg.heads, cfg.gqa_kv_heads, cfg.gqa_dim_head) == (8, 2, 16)
+    assert (cfg.swa_window, cfg.swa_rope_theta, cfg.gqa_rope_theta,
+            cfg.gqa_gate, cfg.post_norms, cfg.norm_eps) \
+        == (8, 1e4, 0.0, True, True, 1e-5)
+    assert (cfg.experts_score, cfg.experts_shared, cfg.experts_scale,
+            cfg.experts_held, cfg.experts) == ("sigmoid", 1, 2.826, 8, 32)
+    assert cfg.gradient_checkpointing
+    assert model.step_counters == X.COUNTERS + P.ATTENTION_COUNTERS
+    assert params["decoder_l1_gqa_Wgate"].shape == (64, 8 * 16)
+    assert sum(k.endswith("_norm_scale") and "_gqa_" not in k
+               for k in params if k.startswith("decoder_l3_")) == 4
+    # nothing under marian_tpu/ names the model the plan was sized for
+    hits = subprocess.run(
+        ["grep", "-rliE", "trinity|afmoe|arcee",
+         os.path.join(ROOT, "marian_tpu")],
+        capture_output=True, text=True).stdout
+    assert hits == ""
+    with pytest.raises(ValueError, match="plan-swa-window"):
+        _model(extra=["--plan-swa-window", "0"])
+
+
+@pytest.mark.parametrize("flash,remat", [("off", True), ("on", True),
+                                         ("off", False)])
+@time_limit(600)
+def test_cost_and_gradients_are_the_references(tiny, reference, monkeypatch,
+                                               flash, remat):
+    """The summed cost and every leaf's gradient against jax.grad of the
+    reference; dense and through the flash kernels (interpret mode, tiles
+    of 128: 2 x 2 of them, the window's trailing edge inside one); the
+    halves checkpointed and not."""
+    model, dims, params, batch = tiny
+    if flash == "on":
+        monkeypatch.setenv("MARIAN_FLASH_BLOCK_Q", "128")
+        monkeypatch.setenv("MARIAN_FLASH_BLOCK_K", "128")
+        model, _ = _model(extra=["--transformer-flash-attention", "on"])
+    if not remat:
+        model, _ = _model(drop=("--gradient-checkpointing",))
+    assert model.cfg.gradient_checkpointing == remat
+    want, want_g = reference
+    (got, aux), got_g = jax.value_and_grad(
+        lambda p: model.loss(p, batch, jax.random.PRNGKey(11), True),
+        has_aux=True)(params)
+    np.testing.assert_allclose(got, want, rtol=2e-6)
+    assert float(aux["labels"]) == float(batch["trg_mask"].sum())
+    assert set(got_g) == set(want_g) == set(params)
+    for name in sorted(params):
+        scale = float(jnp.abs(want_g[name]).max())
+        if name.endswith("_experts_router"):       # a share trains none
+            assert scale == 0 == float(jnp.abs(got_g[name]).max()), name
+            continue
+        assert scale > 0, f"{name}: the reference's gradient is zero"
+        np.testing.assert_allclose(got_g[name], want_g[name],
+                                   atol=2e-4 * scale, err_msg=name)
+
+
+def _token_error(model, dims, params, batch):
+    """RMS error of a token's cost over the spread of the reference's
+    costs: what the benchmark's `token_rtol` bounds."""
+    want = REF.token_costs(params, dims, None, None, batch["trg_ids"],
+                           batch["trg_mask"])
+    got = _token_costs(model, params, batch)
+    real = np.asarray(batch["trg_mask"]) > 0
+    want, got = np.asarray(want)[real], np.asarray(got)[real]
+    return float(np.sqrt(np.mean((got - want) ** 2)) / want.std())
+
+
+# Readings at these widths on the CPU (PR 44): float32 8.7e-7; bfloat16
+# compute 8.5e-2, as the first plan's at these widths (test_layer_plan.py:
+# top-k picks that flip on bfloat16-rounded activations, a quarter of which
+# land on a held expert here against a sixteenth at the published router
+# width). The benchmark's own limit, at the published widths on the chip,
+# is in benchmark/traffic/train-docs16k.json.
+BF16_LIMIT = 1.65e-1
+
+
+@time_limit(300)
+def test_the_per_token_costs_are_the_references(tiny):
+    model, dims, params, batch = tiny
+    assert _token_error(model, dims, params, batch) < F32_LIMIT
+    low, _ = _model("bfloat16")
+    assert F32_LIMIT < _token_error(low, dims, params, batch) < BF16_LIMIT
+    # a row alone costs what it costs in its batch (the benchmark's check
+    # runs the reference in chunks of rows), the attention a head at a time
+    # what it costs at once
+    one = {k: v[1:2] for k, v in batch.items()}
+    np.testing.assert_allclose(
+        _token_costs(model, params, one)[0] * one["trg_mask"][0],
+        _token_costs(model, params, batch)[1] * batch["trg_mask"][1],
+        atol=3e-5)
+    x = jax.random.normal(jax.random.PRNGKey(2), (2, 40, 64))
+    mask = batch["trg_mask"][:2, :40]
+    for kind in ("sliding_attention", "full_attention"):
+        np.testing.assert_allclose(
+            REF._attention_by_head(params, "decoder_l2", dims, x, mask, kind),
+            REF._attention(params, "decoder_l2", dims, x, mask, kind),
+            atol=2e-5)
+
+
+def _interleaved(monkeypatch):
+    angles, rotate = P.rope_angles, P._rotate
+    monkeypatch.setattr(P, "rope_angles", lambda n, dim, theta, pairing=None:
+                        angles(n, dim, theta, "interleaved"))
+    monkeypatch.setattr(P, "_rotate", lambda x, a, pairing=None, start=0:
+                        rotate(x, a, "interleaved", start))
+
+
+@pytest.mark.parametrize("lower", [
+    "window -> causal", "the global layer rotated", "the gate off",
+    "the output norms off", "the rotation's pairs interleaved"])
+@time_limit(300)
+def test_one_mechanism_lower_is_caught(tiny, monkeypatch, lower):
+    """Each alone, in a float32 model on the reference's parameters,
+    moves a token's cost past the float32 limit."""
+    model, dims, params, batch = tiny
+    if lower == "window -> causal":
+        monkeypatch.setattr(P, "Window", lambda window: F.Window(10 ** 6))
+    elif lower == "the global layer rotated":
+        model = _with(model, gqa_rope_theta=model.cfg.swa_rope_theta)
+    elif lower == "the gate off":
+        model = _with(model, gqa_gate=False)
+    elif lower == "the output norms off":
+        model = _with(model, post_norms=False)
+    else:
+        _interleaved(monkeypatch)
+    assert _token_error(model, dims, params, batch) > 10 * F32_LIMIT
+
+
+def _bf16_router(x, w_router, top_k, scale, score="sigmoid"):
+    s = jax.nn.sigmoid(jnp.dot(x.astype(jnp.bfloat16),
+                               w_router.astype(jnp.bfloat16)
+                               ).astype(jnp.float32))
+    vals, idx = jax.lax.top_k(s, top_k)
+    return idx, vals / jnp.sum(vals, -1, keepdims=True) * scale
+
+
+def _bf16_gate(cfg, p, lp, x, o):
+    g = jax.nn.sigmoid(jnp.dot(x, p[f"{lp}_gqa_Wgate"]).astype(jnp.bfloat16))
+    return o * g.astype(o.dtype)
+
+
+@pytest.mark.parametrize("lower", ["bfloat16 angles", "a bfloat16 router",
+                                   "a bfloat16 gate sigmoid"])
+@time_limit(300)
+def test_one_precision_lower_is_caught(tiny, monkeypatch, lower):
+    """Each alone in a float32 model exceeds the float32 limit."""
+    model, dims, params, batch = tiny
+    if lower == "bfloat16 angles":
+        exact = P.rope_angles
+        monkeypatch.setattr(P, "rope_angles", lambda *a: exact(*a).astype(
+            jnp.bfloat16).astype(jnp.float32))
+    elif lower == "a bfloat16 router":
+        monkeypatch.setattr(X, "route", _bf16_router)
+    else:
+        monkeypatch.setattr(P, "_gate", _bf16_gate)
+    assert _token_error(model, dims, params, batch) > F32_LIMIT
+
+
+@pytest.mark.parametrize("rows,width", [(3, WIDTH), (2, 5), (1, 300)])
+@time_limit(120)
+def test_the_pair_counters_are_a_brute_force_count(tiny, rows, width):
+    """`attn.pairs_seen`: the pairs of the reference's own masks over the
+    padded rows, all layers and query heads; `attn.pairs_tiled`: the live
+    tiles of each layer's rule at the blocks the kernels pick."""
+    model, dims, _, _ = tiny
+    seen = tiled = 0
+    for layer_type, _ in REF.layer_kinds(dims):
+        see = REF.visibility(width, layer_type, dims["sliding_window"])
+        seen += int(see.sum())
+        bq, bk = F.pick_blocks(width, width, model.cfg.gqa_dim_head)
+        n_q, n_k = -(-width // bq), -(-width // bk)
+        padded = np.zeros((n_q * bq, n_k * bk), bool)
+        padded[:width, :width] = see
+        tiled += int(padded.reshape(n_q, bq, n_k, bk).any(axis=(1, 3)).sum()
+                     ) * bq * bk
+    got = P._attention_pairs(model.cfg, rows, width)
+    assert got.tolist() == [rows * 8 * seen, rows * 8 * tiled]
+    assert 0 < seen <= tiled
+
+
+@time_limit(300)
+def test_the_counters_and_the_kept_halves_reach_the_tracer(tiny, monkeypatch):
+    """The step's lazy vector ends with the two pair counters, fetched
+    with the routing counters; every checkpointed attention half, window
+    or global, keeps the flash kernel's output and statistics by name,
+    and the plan event names the window."""
+    from marian_tpu.obs import TRACER
+    _, dims, params, batch = tiny
+    monkeypatch.setenv("MARIAN_FLASH_BLOCK_Q", "128")
+    monkeypatch.setenv("MARIAN_FLASH_BLOCK_K", "128")
+    model, _ = _model(extra=["--transformer-flash-attention", "on"])
+    TRACER.reset()
+    TRACER.enable()
+    try:
+        _, aux = jax.jit(lambda p: model.loss(p, batch, None, True))(params)
+        TRACER.count_lazy(model.step_counters, aux["counters"])
+        TRACER.fetch_counters()
+        counters = TRACER.counters()
+        events = TRACER.snapshot()[1]
+    finally:
+        TRACER.disable()
+        TRACER.reset()
+    want = P._attention_pairs(model.cfg, 3, WIDTH).tolist()
+    assert [counters["attn.pairs_seen"], counters["attn.pairs_tiled"]] == want
+    assert counters["moe.assignments"] == 2 * 4 * float(
+        batch["trg_mask"].sum())
+    kept = [e["attrs"] for e in events if e["name"] == "plan.remat_keep"]
+    assert [k["layer"] for k in kept] == ["decoder_l1", "decoder_l2",
+                                          "decoder_l3"]
+    # out [3, 8, 256, 16] and its row statistics [3, 8, 256], float32, at
+    # the kernels' padded width
+    assert all(k["bytes"] == 3 * 8 * 256 * 17 * 4 for k in kept)
+    # (a half is traced once more to count what it keeps, so a layer's
+    # event comes more than once): two window layers, then the global one
+    plans = [(a["rule"], a["tiles_live"], a["tiles_whole"], a["tiles"])
+             for a in (e["attrs"] for e in events
+                       if e["name"] == "flash_attention.plan")]
+    assert set(plans) == {("window(8)", 3, 0, 4), ("causal", 3, 1, 4)}
+    assert plans[0][0] == "window(8)" and plans[-1][0] == "causal"

@@ -75,6 +75,13 @@ def test_tpu_fixture_metadata_carries_the_name_stack():
      ("grads bwd", "decoder")),
     ("jit(step)/dot_general:", ("other", "-")),
     ("", ("other", "-")),
+    # a layer plan's window and global attention, and the gate in either
+    ("jit(one_update)/grads/jvp(checkpoint)/swa/flash_attention_fwd/"
+     "pallas_call", ("grads fwd", "swa")),
+    ("jit(one_update)/grads/transpose(jvp(checkpoint))/swa/swa.rope/mul",
+     ("grads bwd", "swa.rope")),
+    ("jit(one_update)/grads/jvp(checkpoint)/gqa/attn.gate/dot_general",
+     ("grads fwd", "attn.gate")),
 ])
 def test_scope_of_a_name_stack(stack, want):
     assert ps.scope_of(stack) == want
