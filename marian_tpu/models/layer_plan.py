@@ -47,6 +47,19 @@ for it (and, under `swa`, how far back a query sees): the delta rule has
 none, a `gqa` layer at theta 0 has none, and a plan without a rotated
 layer has none anywhere.
 
+Under --gradient-checkpointing each half of a block is rematerialised in
+the backward on its own, and keeps by name what that would run again on
+the MXU for nothing (`_layer`, `_keeps`; bytes a token in the compute
+type): an `mla`, `gqa` or `swa` half the flash kernel's output and row
+statistics; a `gqa` or `swa` half the outputs of its k, v and gate
+projections too (2 x (heads + 2 kv heads) x dim_head; q's, the widest
+where there is no gate, is run again: holding it cost a plan of doubled
+rows the memory its step programs need); either half of a block with
+output norms the branch's output (2 x dim_emb), which the norm's
+backward reads. A `kda` or `mla` half keeps no projection (low rank:
+cheap to run again, as dear to hold). What is kept follows the layer's
+kind and `post_norms`, which the plan states; there is no knob.
+
 The objective is next-token prediction (the input shifted right), or,
 with --plan-diffusion-block N and a plan of `gqa` layers, DIFFUSION OVER
 BLOCKS (arXiv:2503.09573): every real position of a row is replaced by
@@ -92,6 +105,7 @@ from typing import Dict, NamedTuple, Optional, Tuple
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.ad_checkpoint import checkpoint_name
 
 from .. import obs
 from ..layers import initializers as inits
@@ -117,9 +131,16 @@ COUNTERS = X.COUNTERS
 # kept in the optimizer's float32 whatever the compute type: the router
 # decides WHICH experts run, and the decay's rate sits in an exponent
 _FLOAT32_SUFFIXES = ("_experts_router", "_kda_A_log", "_kda_dt_bias")
-# what a checkpointed `mla`, `gqa` or `swa` half keeps across the backward beside
-# its input: the flash kernel's output and row statistics, by their names
+# What a checkpointed half keeps across the backward beside its input, by
+# NAME (`_keeps`). An `mla`, `gqa` or `swa` half: the flash kernel's output
+# and row statistics
 _FLASH_KEEPS = (RESIDUAL_OUT, RESIDUAL_LSE)
+# a `gqa` or `swa` half besides: three of its four projections' outputs, k
+# before its norm, v, and the gate's before its sigmoid (not q's: `_layer`)
+_PROJECTION_KEEPS = ("gqa_k", "gqa_v", "gqa_gate")
+# either half under `post_norms`: the branch's output, which the output
+# norm's backward reads
+BRANCH_OUT = "plan_branch_out"
 # diffusion over blocks: what the step counts beside COUNTERS (their
 # quotient is the realised noise level), the least noise level of a row,
 # and the token a masked position holds (the vocabulary's <unk>)
@@ -578,8 +599,8 @@ def _gate(cfg: PlanConfig, p: Params, lp: str, x, o):
     dim_head]: the gate's matmul in the compute type, its sigmoid and the
     product in float32, one rounding."""
     with jax.named_scope("attn.gate"):
-        g = jax.nn.sigmoid(jnp.dot(x, p[f"{lp}_gqa_Wgate"])
-                           .astype(jnp.float32))
+        g = jax.nn.sigmoid(checkpoint_name(
+            jnp.dot(x, p[f"{lp}_gqa_Wgate"]), "gqa_gate").astype(jnp.float32))
         return (o.astype(jnp.float32) * g).astype(o.dtype)
 
 
@@ -600,9 +621,11 @@ def _gqa(cfg: PlanConfig, p: Params, lp: str, x, mask, rule=None,
     with jax.named_scope(kind):
         q = rms_norm(_heads(jnp.dot(x, p[f"{lp}_gqa_Wq"]), h),
                      p[f"{lp}_gqa_q_norm_scale"], eps=cfg.norm_eps)
-        k = rms_norm(_heads(jnp.dot(x, p[f"{lp}_gqa_Wk"]), hk),
-                     p[f"{lp}_gqa_k_norm_scale"], eps=cfg.norm_eps)
-        v = _heads(jnp.dot(x, p[f"{lp}_gqa_Wv"]), hk)
+        k = rms_norm(_heads(checkpoint_name(
+            jnp.dot(x, p[f"{lp}_gqa_Wk"]), "gqa_k"), hk),
+            p[f"{lp}_gqa_k_norm_scale"], eps=cfg.norm_eps)
+        v = _heads(checkpoint_name(jnp.dot(x, p[f"{lp}_gqa_Wv"]), "gqa_v"),
+                   hk)
         if theta:
             with jax.named_scope(f"{kind}.rope"):
                 doubled = isinstance(rule, BlockDiffusion)
@@ -662,8 +685,8 @@ def _mix(cfg: PlanConfig, kind: str, lp: str, p: Params, x, mask,
         out = _kda(cfg, p, lp, pre) if kind == "kda" \
             else _mla(cfg, p, lp, pre, mask)
     if cfg.post_norms:
-        out = rms_norm(out, p[f"{lp}_mix_post_norm_scale"],
-                       eps=cfg.norm_eps)
+        out = rms_norm(checkpoint_name(out, BRANCH_OUT),
+                       p[f"{lp}_mix_post_norm_scale"], eps=cfg.norm_eps)
     return x + out
 
 
@@ -677,8 +700,8 @@ def _feed_forward(cfg: PlanConfig, kind: str, lp: str, p: Params, x, mask):
     else:
         out, counters = _experts(cfg, p, lp, pre, mask)
     if cfg.post_norms:
-        out = rms_norm(out, p[f"{lp}_ffn_post_norm_scale"],
-                       eps=cfg.norm_eps)
+        out = rms_norm(checkpoint_name(out, BRANCH_OUT),
+                       p[f"{lp}_ffn_post_norm_scale"], eps=cfg.norm_eps)
     return x + out, counters
 
 
@@ -698,39 +721,79 @@ def _named_bytes(f, names, *args) -> int:
     return sum(a.size * a.dtype.itemsize for a in named(forward.jaxpr))
 
 
+def _keeps(cfg: PlanConfig, kind: str) -> Tuple[str, ...]:
+    """The names a checkpointed half of this kind keeps (see `_layer`)."""
+    return (_FLASH_KEEPS if kind in _ATTENTION else ()) \
+        + (_PROJECTION_KEEPS if kind in _GROUPED else ()) \
+        + ((BRANCH_OUT,) if cfg.post_norms else ())
+
+
+def _checkpointed(f, lp: str, half: str, names, *args):
+    """jax.checkpoint(f), keeping across the backward what f's forward
+    gives one of `names`; a half that keeps a name says so once a traced
+    call (`plan.remat_keep`), with the tracer on."""
+    if not names:
+        return jax.checkpoint(f)
+    if obs.enabled():
+        obs.event("plan.remat_keep", layer=lp, half=half, names=names,
+                  bytes=_named_bytes(f, names, *args))
+    return jax.checkpoint(
+        f, policy=jax.checkpoint_policies.save_only_these_names(*names))
+
+
 def _layer(cfg: PlanConfig, kinds, lp: str, p: Params, x, mask, remat,
            rule=None):
     """One block: x + mixing(norm(x)), then x + feed-forward(norm(x)),
     each branch's output normed first under `post_norms`.
     With `remat` (--gradient-checkpointing, training) each half is
     rematerialised in the backward on its own, so what stays alive
-    between the passes is a layer's input and its middle, and of an
-    `mla`, `gqa` or `swa` half also what only the flash kernel can produce
-    (_FLASH_KEEPS): its projections, rotation, concatenates, gate and
-    output norm run again in the backward, the kernel does not. Where the dense path runs
-    (short rows, the CPU) nothing bears those names and nothing more is
-    kept. KDA mixed in head groups rematerialises itself group by group
-    and is not wrapped again: a second wrap would run its forward a
-    third time. `rule`: the BlockDiffusion rule of a `gqa` half over a
-    doubled row, None under next-token training."""
+    between the passes is a layer's input and its middle, and what a half
+    keeps BY NAME (`_keeps`; bytes a token in the compute type, d the
+    model's width):
+
+      an `mla`, `gqa` or `swa` half   what only the flash kernel can
+          produce (_FLASH_KEEPS: heads x (dim_v x 2 + 4)): the kernel
+          does not run again;
+      a `gqa` or `swa` half besides   the outputs of its k, v and gate
+          projections (_PROJECTION_KEEPS: (heads + 2 kv heads) x dim_head
+          x 2 with the gate, 10 KB at 32 on 4 heads of 128; 2 KB without
+          one): their matmuls do not run again, while q's, the per-head
+          norms, the rotation (its own float32 dot is NOT kept: by name,
+          never `dots_saveable`), the transposes, the sigmoid and the
+          product do. q's output is NOT kept: as dear a FLOP as the
+          others', it is four fifths of the bytes where there is no gate,
+          and held over a plan of doubled rows it left the widest step
+          under a gigabyte of headroom (PERF.md 6, PR 47). An `mla` or
+          `kda` half keeps none of its projections: they are low-rank,
+          cheap to run again and as dear to hold;
+      either half under `post_norms`  the branch's output (BRANCH_OUT:
+          d x 2), which the output norm's backward reads: without it the
+          backward regenerates the whole branch to get it (W_o, the dense
+          and the shared W_d, and the held experts' forward, which
+          ops/experts.py's own backward then runs once more for its
+          gradients). The name exists only where a backward reads it:
+          x + out needs no `out`, and without `post_norms` nothing is
+          named and the feed-forward half is the plain checkpoint.
+
+    Where the dense path runs (short rows, the CPU) nothing bears the
+    kernel's names and they keep nothing. KDA mixed in head groups
+    rematerialises itself group by group and is not wrapped again: a
+    second wrap would run its forward a third time. `rule`: the
+    BlockDiffusion rule of a `gqa` half over a doubled row, None under
+    next-token training."""
     mix, ffn = kinds
     f_mix = partial(_mix, cfg, mix, lp)
     if rule is not None:
         f_mix = partial(f_mix, rule=rule)
     f_ffn = partial(_feed_forward, cfg, ffn, lp)
+    if remat and (mix != "kda" or cfg.kda_head_groups == 1):
+        f_mix = _checkpointed(f_mix, lp, "mixing", _keeps(cfg, mix),
+                              p, x, mask)
+    x = f_mix(p, x, mask)
     if remat:
-        if mix in _ATTENTION:
-            if obs.enabled():
-                obs.event("plan.remat_keep", layer=lp, names=_FLASH_KEEPS,
-                          bytes=_named_bytes(f_mix, _FLASH_KEEPS, p, x,
-                                             mask))
-            f_mix = jax.checkpoint(
-                f_mix, policy=jax.checkpoint_policies.save_only_these_names(
-                    *_FLASH_KEEPS))
-        elif cfg.kda_head_groups == 1:
-            f_mix = jax.checkpoint(f_mix)
-        f_ffn = jax.checkpoint(f_ffn)
-    return f_ffn(p, f_mix(p, x, mask), mask)
+        f_ffn = _checkpointed(f_ffn, lp, "feed-forward", _keeps(cfg, ffn),
+                              p, x, mask)
+    return f_ffn(p, x, mask)
 
 
 # ---------------------------------------------------------------------------

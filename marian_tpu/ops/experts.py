@@ -37,8 +37,12 @@ All three are `_held`, whose backward is written out (a while loop has
 no reverse mode): it runs a pool's batch again for its gradients, and
 recomputes a block's activations, instead of keeping either. Under a
 checkpoint that costs nothing (the forward run again there feeds
-nothing and is dropped); without one it is one more forward of the
-layer for activations never held.
+nothing and is dropped) UNLESS the caller's checkpoint must give back
+the layer's OUTPUT, which a norm on that output asks for: the forward
+then runs a third time to make it. models/layer_plan.py::_layer keeps
+that output by name across the backward, so the pool runs twice there
+too. Without a checkpoint it is one more forward of the layer for
+activations never held.
 """
 
 from __future__ import annotations

@@ -821,6 +821,27 @@ def _keep_events(model, params, batch):
     return [e["attrs"] for e in events if e["name"] == "plan.remat_keep"]
 
 
+@pytest.mark.parametrize("kind,post_norms,names", [
+    ("kda", False, ()),
+    ("dense", False, ()),
+    ("experts", False, ()),
+    ("mla", False, P._FLASH_KEEPS),
+    ("gqa", False, P._FLASH_KEEPS + P._PROJECTION_KEEPS),
+    ("swa", False, P._FLASH_KEEPS + P._PROJECTION_KEEPS),
+    ("kda", True, (P.BRANCH_OUT,)),
+    ("experts", True, (P.BRANCH_OUT,)),
+    ("mla", True, P._FLASH_KEEPS + (P.BRANCH_OUT,)),
+    ("swa", True, P._FLASH_KEEPS + P._PROJECTION_KEEPS + (P.BRANCH_OUT,))])
+def test_what_a_half_keeps_follows_its_kind_and_the_output_norms(
+        kind, post_norms, names):
+    """The names a checkpointed half keeps are decided by what the plan
+    states, the half's kind and whether a norm reads its output: a `kda`
+    or `mla` half holds no projection, a half without an output norm no
+    branch output, and a half that keeps no name is the plain checkpoint."""
+    cfg = P.PlanConfig(src_vocab=96, trg_vocab=96, post_norms=post_norms)
+    assert P._keeps(cfg, kind) == names
+
+
 def test_the_kept_bytes_are_said_as_an_mla_half_is_traced(checkpointed):
     """`plan.remat_keep`, once a checkpointed `mla` half of a traced
     step: the layer, the two names and the bytes kept under them, the
@@ -837,7 +858,8 @@ def test_the_kept_bytes_are_said_as_an_mla_half_is_traced(checkpointed):
         lp for lp, (mix, _) in P._blocks(cfg) if mix == "mla"]
     assert len(said) == n and t == 80
     for e in said:
-        assert e["names"] == P._FLASH_KEEPS == (
+        assert e["half"] == "mixing"
+        assert e["names"] == P._keeps(cfg, "mla") == P._FLASH_KEEPS == (
             "flash_attention_out", "flash_attention_lse")
         assert e["bytes"] == rows * (cfg.mla_dim_v * 4 + 4)
     kept = model.cfg

@@ -25,6 +25,7 @@ import hashlib
 import importlib
 import json
 import os
+import re
 import subprocess
 
 import jax
@@ -427,9 +428,12 @@ def _kernel_calls(jaxpr):
 @time_limit(600)
 def test_a_checkpointed_gqa_half_keeps_the_flash_output(tiny):
     """Under --gradient-checkpointing the `gqa` half keeps what the `mla`
-    half keeps: flash_attention_fwd runs once a layer, as often as _dq
-    and _dkv; `plan.remat_keep` says the bytes, once a layer, and
-    `flash_attention.plan` the tiles of the doubled row."""
+    half keeps, and the outputs of its k and v projections (no gate in
+    this plan; q's is run again):
+    flash_attention_fwd runs once a layer, as often as _dq and _dkv;
+    `plan.remat_keep` says the names and the bytes, once a layer (no
+    output norms: the feed-forward half keeps no name and says nothing),
+    and `flash_attention.plan` the tiles of the doubled row."""
     from marian_tpu.obs import TRACER
     _, _, params, batch = tiny
     model, _ = _model(extra=["--transformer-flash-attention", "on"])
@@ -448,10 +452,15 @@ def test_a_checkpointed_gqa_half_keeps_the_flash_output(tiny):
     kept = [e["attrs"] for e in events if e["name"] == "plan.remat_keep"]
     assert [e["layer"] for e in kept] == ["decoder_l1", "decoder_l2"]
     cfg = model.cfg
+    assert not cfg.post_norms and not cfg.gqa_gate
     for e in kept:
-        assert e["names"] == P._FLASH_KEEPS
-        # the kernel's own shapes: 154 indices padded to one tile of 256
-        assert e["bytes"] == 3 * cfg.heads * 256 * (cfg.gqa_dim_head * 4 + 4)
+        assert e["half"] == "mixing"
+        assert e["names"] == P._keeps(cfg, "gqa") \
+            == P._FLASH_KEEPS + P._PROJECTION_KEEPS
+        # the kernel's own shapes: 154 indices padded to one tile of 256;
+        # k and v of the doubled rows as the matmuls leave them
+        assert e["bytes"] == 3 * cfg.heads * 256 * (cfg.gqa_dim_head * 4 + 4) \
+            + 3 * 154 * 2 * cfg.gqa_kv_heads * cfg.gqa_dim_head * 4
     plans = [e["attrs"] for e in events
              if e["name"] == "flash_attention.plan"]
     assert plans and all(
@@ -468,9 +477,18 @@ def test_a_checkpointed_gqa_half_keeps_the_flash_output(tiny):
 # expert layer's pool is a loop of one or two batches and counts three more
 # things a step. And ON PURPOSE by PR 42 from its own tree (parent a59d2bb):
 # `gqa`'s rotation forms a pair's other channel by a matmul, with a backward
-# of its own (the other two digests, whose plans do not rotate, held).
+# of its own (the other two digests, whose plans do not rotate, held). And ON
+# PURPOSE by PR 47 from its own tree (parent 1b2dfbf): a checkpointed `gqa`
+# half keeps its k and v projections' outputs by name, so its backward holds
+# two matmuls fewer a layer (the other two digests, whose plans hold no `gqa`
+# layer, held). That is ALL that moved: with those names not kept the plan
+# lowers to the PARENT's text but for the numbers at the end of private
+# functions' symbols (`@_where_123`), so the second digest is the parent's,
+# taken from the parent's tree over the text with those numbers cut off.
 _THIS_PLAN_SHA256 = \
-    "a83eff13db818a9b76554998846c9a1573199eedb144ce469989de5e8750e202"
+    "4c8e67a5cde2d73e54abaa0faa47e61e0527370bfd7651e7251b83d665aaaea1"
+_PARENT_UNNUMBERED_SHA256 = \
+    "4ee8e02f36bcbb75fea48f2126b64149b69f8e3a94c039b0358096f950bcffea"
 
 
 def _lowered(model, batch):
@@ -486,3 +504,13 @@ def test_this_plan_lowers_to_the_program_it_was(tiny):
     model, _, _, batch = tiny
     text = _lowered(model, batch)
     assert hashlib.sha256(text.encode()).hexdigest() == _THIS_PLAN_SHA256
+
+
+@time_limit(300)
+def test_the_kept_projections_are_all_that_moved_the_program(tiny,
+                                                             monkeypatch):
+    model, _, _, batch = tiny
+    monkeypatch.setattr(P, "_PROJECTION_KEEPS", ())
+    text = re.sub(r"(@\w+?)_\d+\b", r"\1", _lowered(model, batch))
+    assert hashlib.sha256(text.encode()).hexdigest() \
+        == _PARENT_UNNUMBERED_SHA256

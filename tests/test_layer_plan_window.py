@@ -16,7 +16,9 @@ sigmoid gate on the heads' output and the two output norms of a block
   one precision lower is caught   bfloat16 angles, a bfloat16 router, a
       bfloat16 gate sigmoid
   the pair counters against a brute-force count; what a checkpointed half
-      keeps; the validator's refusal
+      keeps (its projections, the branch's output under an output norm:
+      no gradient moves, the backward runs no matmul of the branch's again,
+      the tracer hears the names and bytes); the validator's refusal
 """
 
 import copy
@@ -24,6 +26,7 @@ import dataclasses
 import importlib
 import json
 import os
+import re
 import subprocess
 
 import jax
@@ -281,9 +284,11 @@ def test_the_pair_counters_are_a_brute_force_count(tiny, rows, width):
 @time_limit(300)
 def test_the_counters_and_the_kept_halves_reach_the_tracer(tiny, monkeypatch):
     """The step's lazy vector ends with the two pair counters, fetched
-    with the routing counters; every checkpointed attention half, window
-    or global, keeps the flash kernel's output and statistics by name,
-    and the plan event names the window."""
+    with the routing counters; every checkpointed half says what it keeps
+    by name: an attention half, window or global, the flash kernel's
+    output and statistics, the outputs of its k, v and gate projections
+    and the branch's output, a feed-forward half the branch's output; and
+    the plan event names the window."""
     from marian_tpu.obs import TRACER
     _, dims, params, batch = tiny
     monkeypatch.setenv("MARIAN_FLASH_BLOCK_Q", "128")
@@ -305,11 +310,24 @@ def test_the_counters_and_the_kept_halves_reach_the_tracer(tiny, monkeypatch):
     assert counters["moe.assignments"] == 2 * 4 * float(
         batch["trg_mask"].sum())
     kept = [e["attrs"] for e in events if e["name"] == "plan.remat_keep"]
-    assert [k["layer"] for k in kept] == ["decoder_l1", "decoder_l2",
-                                          "decoder_l3"]
-    # out [3, 8, 256, 16] and its row statistics [3, 8, 256], float32, at
-    # the kernels' padded width
-    assert all(k["bytes"] == 3 * 8 * 256 * 17 * 4 for k in kept)
+    assert [(k["layer"], k["half"]) for k in kept] == [
+        (f"decoder_l{n}", half) for n in (1, 2, 3)
+        for half in ("mixing", "feed-forward")]
+    # float32 at the rehearsal's widths. The branch's output [3, 150, 64];
+    # the gate [3, 150, 8 x 16], k and v [3, 150, 2 x 16]; out
+    # [3, 8, 256, 16] and its row statistics [3, 8, 256] at the kernels'
+    # padded width
+    branch, projections = 3 * WIDTH * 64 * 4, 3 * WIDTH * (128 + 2 * 32) * 4
+    for k in kept:
+        if k["half"] == "mixing":
+            assert k["names"] == P._FLASH_KEEPS + P._PROJECTION_KEEPS \
+                + (P.BRANCH_OUT,) == (
+                    "flash_attention_out", "flash_attention_lse", "gqa_k",
+                    "gqa_v", "gqa_gate", "plan_branch_out")
+            assert k["bytes"] == 3 * 8 * 256 * 17 * 4 + projections + branch
+        else:
+            assert k["names"] == (P.BRANCH_OUT,)
+            assert k["bytes"] == branch
     # (a half is traced once more to count what it keeps, so a layer's
     # event comes more than once): two window layers, then the global one
     plans = [(a["rule"], a["tiles_live"], a["tiles_whole"], a["tiles"])
@@ -317,3 +335,89 @@ def test_the_counters_and_the_kept_halves_reach_the_tracer(tiny, monkeypatch):
                        if e["name"] == "flash_attention.plan")]
     assert set(plans) == {("window(8)", 3, 0, 4), ("causal", 3, 1, 4)}
     assert plans[0][0] == "window(8)" and plans[-1][0] == "causal"
+
+
+@pytest.mark.parametrize("flash", ["off", "on"])
+@time_limit(600)
+def test_what_a_half_keeps_changes_no_gradient(tiny, monkeypatch, flash):
+    """The cost and every leaf's gradient with the projections and the
+    branches' outputs kept are those with every keep emptied (each half a
+    plain checkpoint that runs its whole forward again), to float32
+    limits: a kept value is the value the forward computed. Dense and
+    through the flash kernels."""
+    _, _, params, batch = tiny
+    extra = []
+    if flash == "on":
+        monkeypatch.setenv("MARIAN_FLASH_BLOCK_Q", "128")
+        monkeypatch.setenv("MARIAN_FLASH_BLOCK_K", "128")
+        extra = ["--transformer-flash-attention", "on"]
+
+    model, _ = _model(extra=extra)
+    assert model.cfg.gradient_checkpointing
+    assert P._keeps(model.cfg, "swa") == P._FLASH_KEEPS \
+        + P._PROJECTION_KEEPS + (P.BRANCH_OUT,)
+
+    def gradients():                    # traced anew at every call
+        return jax.value_and_grad(lambda p: model.loss(
+            p, batch, jax.random.PRNGKey(11), True)[0])(params)
+
+    cost, kept = gradients()
+    monkeypatch.setattr(P, "_keeps", lambda cfg, kind: ())
+    cost_again, again = gradients()
+    np.testing.assert_allclose(cost, cost_again, rtol=1e-6)
+    assert set(kept) == set(again) == set(params)
+    for name in sorted(params):
+        scale = float(jnp.abs(again[name]).max())
+        assert scale > 0 or name.endswith("_experts_router"), name
+        np.testing.assert_allclose(kept[name], again[name],
+                                   atol=2e-6 * scale, err_msg=name)
+
+
+def _loops_and_dots(cfg, params, n):
+    """(`while` loops, `dot`s) of the COMPILED cost and gradient of layer
+    n alone, its halves checkpointed, at [3, WIDTH]: the cost weighs the
+    layer's output by an argument, so no cotangent is a constant that the
+    compiler could fold a matmul into."""
+    lp, kinds = f"decoder_l{n}", cfg.plan[n - 1]
+    own = {k: v for k, v in params.items() if k.startswith(lp + "_")}
+    x = jax.random.normal(jax.random.PRNGKey(1), (3, WIDTH, cfg.dim_emb))
+    mask = jnp.ones((3, WIDTH), jnp.float32)
+
+    def cost(p, x, weights):
+        return jnp.sum(P._layer(cfg, kinds, lp, p, x, mask, True)[0]
+                       * weights)
+    text = jax.jit(jax.value_and_grad(cost, argnums=(0, 1))).lower(
+        own, x, x).compile().as_text()
+    return tuple(len(re.findall(rf" {op}\(", text))
+                 for op in ("while", "dot"))
+
+
+@pytest.mark.parametrize("n", [1, 2, 3])
+@time_limit(300)
+def test_an_output_norm_makes_the_backward_run_no_branch_again(
+        tiny, monkeypatch, n):
+    """STRUCTURE, a layer of each kind of the plan (window + dense, window
+    + experts, global + experts): under `post_norms` the compiled cost and
+    gradient of a checkpointed layer hold as many `while` loops (the held
+    experts' pool and block loops: forward and ops/experts.py's own
+    backward, NOT a second forward between them) and as many `dot`s as the
+    same layer without output norms, whose backward never asked for a
+    branch's output. With nothing kept for the norm the layer with output
+    norms holds the experts' loops a third time and W_o, W_d and the shared
+    W_d again; with the projections not kept either, three matmuls more
+    in both."""
+    model, _, params, _ = tiny
+    plain = dataclasses.replace(model.cfg, post_norms=False)
+    normed = _loops_and_dots(model.cfg, params, n)
+    assert normed == _loops_and_dots(plain, params, n)
+    assert (normed[0] > 0) == (model.cfg.plan[n - 1][1] == "experts")
+    monkeypatch.setattr(P, "_PROJECTION_KEEPS", ())
+    unprojected = _loops_and_dots(plain, params, n)
+    assert unprojected == (normed[0], normed[1] + 3)
+    assert _loops_and_dots(model.cfg, params, n) == unprojected
+    keeps = P._keeps
+    monkeypatch.setattr(P, "_keeps", lambda cfg, kind: tuple(
+        name for name in keeps(cfg, kind) if name != P.BRANCH_OUT))
+    loops, dots = _loops_and_dots(model.cfg, params, n)
+    assert loops == unprojected[0] * 3 // 2
+    assert dots >= unprojected[1] + 2
