@@ -285,24 +285,6 @@ class Smoke:
             r"Cost ([-+.\de]+|nan|inf)", self.read(self.logs, f"{tag}.log"))]
 
     # -- phases -------------------------------------------------------------
-    def native(self):
-        """The C++ data loader builds on demand with g++ and is opt-in
-        (--data-backend native); the phases below use the default python
-        loader either way. Say which this machine would get."""
-        so = os.path.join(ROOT, "marian_tpu", "native", "libmarian_data.so")
-        had = os.path.exists(so)
-        gxx = shutil.which("g++")
-        t0 = time.time()
-        r = subprocess.run(
-            [sys.executable, "-c",
-             "import marian_tpu.native as n; n.build_library()"],
-            cwd=ROOT, capture_output=True, text=True)
-        how = ("prebuilt" if had else f"built in {time.time() - t0:.1f}s")
-        say(f"native: g++ {gxx or 'absent'}; libmarian_data.so "
-            + (how if r.returncode == 0 else
-               "unavailable — --data-backend native would fall back to the "
-               "python loader") + "; phases use the default python loader")
-
     def train(self):
         tag, n = "train", self.dims["updates"]
         stats = self.run("train", tag, self.train_args(tag, n, max(1, n // 6)))
@@ -463,7 +445,6 @@ class Smoke:
         if self.args.chips == 4:
             self.zero1()
         else:
-            self.native()
             self.train()
             self.decode(6)
             greedy = self.decode(1)

@@ -299,7 +299,6 @@ _TRAINING = [
     # devices
     _f("devices", str, ["0"], "Device ids (GPU compat) or tpu:N..M mesh spec", "training", "+"),
     _f("num-devices", int, 0, "Number of devices (0 = all visible)", "training"),
-    _f("data-backend", str, "python", "Batch pipeline: python, or native (C++ tokenizer+batcher, marian_tpu/native) (TPU extension)", "training"),
     _f("no-nccl", bool, False, "(GPU compat; ignored — ICI collectives are always used)", "training"),
     _f("sharding", str, "global", "Optimizer sharding domain: global (ZeRO-1 over all devices) or local", "training"),
     _f("sync-freq", str, "200u", "Param sync frequency for local sharding", "training"),

@@ -55,12 +55,15 @@ class CorpusState:
     position: int = 0   # sentences already yielded in this epoch
     seed: int = 1
 
+    # Positions are the loader's own: this one counts raw corpus lines,
+    # the C++ loader of earlier versions ("native") indexed its
+    # length-filtered order. The tag lets resume tell a checkpoint of
+    # another loader instead of silently seeking to the wrong sentence
+    # (ADVICE r1). No annotation: a constant, not a field.
+    BACKEND = "python"
+
     def as_dict(self):
-        # Positions are backend-specific: python counts raw corpus lines,
-        # native indexes its length-filtered order. The tag lets resume
-        # detect a --data-backend switch instead of silently seeking to the
-        # wrong sentence (ADVICE r1).
-        return {**dataclasses.asdict(self), "backend": "python"}
+        return {**dataclasses.asdict(self), "backend": self.BACKEND}
 
     @classmethod
     def from_dict(cls, d):

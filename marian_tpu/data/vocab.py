@@ -146,10 +146,6 @@ class DefaultVocab(VocabBase):
     def id_to_word(self, i: int) -> str:
         return self._i2w.get(int(i), DEFAULT_UNK_STR)
 
-    def word_to_id_map(self) -> Dict[str, int]:
-        """Full word→id mapping (consumed by the native data loader)."""
-        return dict(self._w2i)
-
 
 def create_vocab(path: Optional[str], options=None, stream_index: int = 0,
                  train_paths: Optional[List[str]] = None,

@@ -1,12 +1,14 @@
 """Native (C++) runtime components and their ctypes bindings.
 
 The reference is 100% native (SURVEY.md §2: C++/CUDA throughout); here the
-DEVICE side is XLA's domain, but the host-side hot paths around it are native
+DEVICE side is XLA's domain, and one host-side hot path beside it is native
 C++ like the reference's:
 
-- data_loader.cpp — corpus tokenization + maxi-batch/token-budget batching
-  (reference src/data/corpus.cpp + batch_generator.h), bound below as
-  NativeBatchGenerator (opt-in via --data-backend native).
+- bpe_encoder.cpp — the subword tokenization hot path for in-repo BPE
+  models (reference: vendored C++ SentencePiece), bound below as
+  NativeBPEEncoder. Deterministic greedy path only; BPE-dropout sampling
+  stays in Python. data/bpe_vocab.py uses it where the library builds and
+  its own Python encoder where it does not.
 
 The shared library builds on demand with g++ (no pybind11 in this image;
 plain C ABI + ctypes). Build artifacts land next to the sources.
@@ -17,48 +19,16 @@ from __future__ import annotations
 import ctypes
 import os
 import subprocess
-import threading
-from typing import List, Optional
+from typing import List
 
 from ..common import lockdep
 
-import numpy as np
-
 _DIR = os.path.dirname(os.path.abspath(__file__))
-_SO = os.path.join(_DIR, "libmarian_data.so")
-_SRC = os.path.join(_DIR, "data_loader.cpp")
 _LOCK = lockdep.make_lock("marian_tpu.native._LOCK")
-_LIB = None
-
-MAX_STREAMS = 8
-
-
-class _MtdBatch(ctypes.Structure):
-    _fields_ = [
-        ("n_streams", ctypes.c_int),
-        ("batch_size", ctypes.c_int),
-        ("real_size", ctypes.c_int),
-        ("widths", ctypes.c_int * MAX_STREAMS),
-        ("ids", ctypes.POINTER(ctypes.c_int32) * MAX_STREAMS),
-        ("mask", ctypes.POINTER(ctypes.c_float) * MAX_STREAMS),
-        ("sent_ids", ctypes.POINTER(ctypes.c_int64)),
-    ]
-
-
-class _BatchConfig(ctypes.Structure):
-    _fields_ = [
-        ("mini_batch", ctypes.c_int),
-        ("mini_batch_words", ctypes.c_int),
-        ("maxi_batch", ctypes.c_int),
-        ("sort_key", ctypes.c_int),
-        ("batch_multiple", ctypes.c_int),
-        ("shuffle_batches", ctypes.c_int),
-    ]
 
 
 def _build_so(src: str, so: str, force: bool = False) -> str:
-    """Compile one native component → .so (g++ -O3, on demand; shared by
-    every native module so build flags stay in one place)."""
+    """Compile one native component → .so (g++ -O3, on demand)."""
     if not force and os.path.exists(so) and \
             os.path.getmtime(so) >= os.path.getmtime(src):
         return so
@@ -68,162 +38,6 @@ def _build_so(src: str, so: str, force: bool = False) -> str:
         raise RuntimeError(f"native build failed: {proc.stderr[-2000:]}")
     return so
 
-
-def build_library(force: bool = False) -> str:
-    """Compile data_loader.cpp → libmarian_data.so."""
-    return _build_so(_SRC, _SO, force)
-
-
-def _lib():
-    global _LIB
-    with _LOCK:
-        if _LIB is None:
-            lib = ctypes.CDLL(build_library())
-            lib.mtd_create.restype = ctypes.c_void_p
-            lib.mtd_create.argtypes = [ctypes.c_int]
-            lib.mtd_destroy.argtypes = [ctypes.c_void_p]
-            lib.mtd_error.restype = ctypes.c_char_p
-            lib.mtd_error.argtypes = [ctypes.c_void_p]
-            lib.mtd_set_vocab.argtypes = [ctypes.c_void_p, ctypes.c_int,
-                                          ctypes.c_char_p, ctypes.c_int64]
-            lib.mtd_load_corpus.restype = ctypes.c_int64
-            lib.mtd_load_corpus.argtypes = [
-                ctypes.c_void_p, ctypes.POINTER(ctypes.c_char_p),
-                ctypes.c_int, ctypes.c_int]
-            lib.mtd_start_epoch.argtypes = [ctypes.c_void_p, ctypes.c_int,
-                                            ctypes.c_uint64]
-            lib.mtd_position.restype = ctypes.c_uint64
-            lib.mtd_position.argtypes = [ctypes.c_void_p]
-            lib.mtd_seek.argtypes = [ctypes.c_void_p, ctypes.c_uint64]
-            lib.mtd_next_batch.restype = ctypes.c_int
-            lib.mtd_next_batch.argtypes = [ctypes.c_void_p,
-                                           ctypes.POINTER(_BatchConfig),
-                                           ctypes.POINTER(_MtdBatch)]
-            _LIB = lib
-    return _LIB
-
-
-def available() -> bool:
-    try:
-        _lib()
-        return True
-    except Exception:
-        return False
-
-
-class NativeBatchGenerator:
-    """C++-backed BatchGenerator: same CorpusBatch iterator contract as
-    data/batch_generator.py (reference: BatchGenerator<Corpus> running its
-    fetchBatches work off the interpreter).
-
-    Limitations vs the Python generator (falls back there): no guided
-    alignment / data-weighting streams, whole corpus tokenized in RAM
-    (the reference's default in-RAM shuffle mode).
-    """
-
-    def __init__(self, paths: List[str], vocabs, options=None,
-                 mini_batch: int = 64, mini_batch_words: int = 0,
-                 maxi_batch: int = 100, maxi_batch_sort: str = "trg",
-                 shuffle: bool = True, batch_multiple: int = 8,
-                 max_length: int = 0, max_length_crop: bool = False,
-                 seed: int = 1):
-        if options is not None:
-            mini_batch = int(options.get("mini-batch", mini_batch) or mini_batch)
-            mini_batch_words = int(options.get("mini-batch-words", 0) or 0)
-            maxi_batch = int(options.get("maxi-batch", maxi_batch) or 1)
-            maxi_batch_sort = options.get("maxi-batch-sort", maxi_batch_sort)
-            shuffle = options.get("shuffle", "data") != "none"
-            max_length = int(options.get("max-length", max_length) or 0)
-            max_length_crop = bool(options.get("max-length-crop", False))
-            seed = int(options.get("seed", seed) or seed)
-        self._lib = _lib()
-        self.n_streams = len(paths)
-        self._h = self._lib.mtd_create(self.n_streams)
-        if not self._h:
-            raise RuntimeError("mtd_create failed")
-        for i, v in enumerate(vocabs):
-            buf = "".join(f"{w}\t{wid}\n"
-                          for w, wid in v.word_to_id_map().items()
-                          ).encode("utf-8")
-            self._lib.mtd_set_vocab(self._h, i, buf, len(buf))
-        arr = (ctypes.c_char_p * self.n_streams)(
-            *[p.encode("utf-8") for p in paths])
-        # +1: the Python Corpus counts the appended EOS in max-length
-        n = self._lib.mtd_load_corpus(self._h, arr, max_length + 1 if max_length
-                                      else 0, 1 if max_length_crop else 0)
-        if n < 0:
-            raise RuntimeError(self._lib.mtd_error(self._h).decode())
-        self.n_sentences = int(n)
-        self._cfg = _BatchConfig(
-            mini_batch=max(1, mini_batch),
-            mini_batch_words=mini_batch_words,
-            maxi_batch=max(1, maxi_batch),
-            sort_key={"none": 0, "src": 1, "trg": 2}.get(maxi_batch_sort, 2),
-            batch_multiple=batch_multiple,
-            shuffle_batches=1 if shuffle else 0)
-        self._shuffle = shuffle
-        self._seed = seed
-        self.epoch = 1
-        self._pending_seek: Optional[int] = None
-
-    def __del__(self):
-        try:
-            if getattr(self, "_h", None):
-                self._lib.mtd_destroy(self._h)
-                self._h = None
-        except Exception:
-            pass
-
-    # -- iterator (one epoch, like BatchGenerator) ---------------------------
-    def __iter__(self):
-        from ..data.batch_generator import CorpusBatch, SubBatch
-
-        self._lib.mtd_start_epoch(self._h, 1 if self._shuffle else 0,
-                                  (self._seed + self.epoch) & (2**64 - 1))
-        if self._pending_seek is not None:
-            self._lib.mtd_seek(self._h, self._pending_seek)
-            self._pending_seek = None
-        out = _MtdBatch()
-        while self._lib.mtd_next_batch(self._h, ctypes.byref(self._cfg),
-                                       ctypes.byref(out)):
-            subs = []
-            bsz = out.batch_size
-            for s in range(out.n_streams):
-                w = out.widths[s]
-                ids = np.ctypeslib.as_array(out.ids[s], (bsz, w)).copy()
-                mask = np.ctypeslib.as_array(out.mask[s], (bsz, w)).copy()
-                subs.append(SubBatch(ids, mask))
-            sent_ids = np.ctypeslib.as_array(out.sent_ids, (bsz,)).copy()
-            state = {"epoch": self.epoch,
-                     "position": int(self._lib.mtd_position(self._h))}
-            yield CorpusBatch(subs, sent_ids, None, None, state)
-        self.epoch += 1
-
-    def state_dict(self) -> dict:
-        """CorpusState-compatible snapshot for the training checkpoint."""
-        return {"epoch": self.epoch,
-                "position": int(self._lib.mtd_position(self._h)),
-                "seed": self._seed, "backend": "native"}
-
-    # -- resume ---------------------------------------------------------------
-    def seek(self, epoch: int, position: int,
-             seed: Optional[int] = None) -> None:
-        """Resume mid-epoch: the epoch's shuffle permutation is recreated
-        from (seed + epoch) on the next __iter__, then skipped to position
-        (the role of the reference's SQLite corpus / corpus-position restore).
-        `seed` restores the checkpoint's shuffle seed so the permutation
-        matches the interrupted run even if --seed changed."""
-        if seed is not None:
-            self._seed = int(seed)
-        self.epoch = epoch
-        self._pending_seek = position
-
-
-# ---------------------------------------------------------------------------
-# Native BPE encoder (bpe_encoder.cpp) — the subword tokenization hot
-# path for in-repo BPE models (reference: vendored C++ SentencePiece).
-# Deterministic greedy path only; BPE-dropout sampling stays in Python.
-# ---------------------------------------------------------------------------
 
 _BPE_SO = os.path.join(_DIR, "libmarian_bpe.so")
 _BPE_SRC = os.path.join(_DIR, "bpe_encoder.cpp")

@@ -89,16 +89,12 @@ def replicated(mesh: Mesh) -> NamedSharding:
     return NamedSharding(mesh, P())
 
 
-def batch_leaf_spec(name: str, ndim: int, micro: bool = False) -> P:
+def batch_leaf_spec(name: str, ndim: int) -> P:
     """Per-leaf batch sharding by NAME: token id/mask streams [B, T] shard
     (data, seq); other leaves — 'guided' alignment [B, Tt, Ts] and
     'data_weights' [B, Tt] or [B, 1] — shard only the batch dim (their
     trailing dims are not bucket-padded, so 'seq' divisibility isn't
-    guaranteed). `micro` marks a leading --optimizer-delay micro-batch axis,
-    which stays unsharded."""
-    if micro:
-        inner = batch_leaf_spec(name, ndim - 1)
-        return P(*((None,) + tuple(inner)))
+    guaranteed)."""
     if (name.endswith("_ids") or name.endswith("_mask")
             or name.endswith("_tok")) and ndim == 2:
         return P("data", "seq")
@@ -130,15 +126,12 @@ def replicate_tree(tree, mesh: Mesh):
     return jax.device_put(tree, replicated(mesh))
 
 
-def shard_batch(batch, mesh: Mesh, micro: bool = False):
-    """Place batch leaves on the mesh with name-aware specs. `micro=True`
-    for stacked [delay, B, T] micro-batches (build_train_step delay>1)."""
+def shard_batch(batch, mesh: Mesh):
+    """Place batch leaves on the mesh with name-aware specs."""
     with obs_trace.span("train.h2d") as sp:
         if sp:
             sp.set_attrs(bytes=sum(int(getattr(v, "nbytes", 0))
                                    for v in batch.values()))
-        return {k: jax.device_put(
-                    v, NamedSharding(mesh,
-                                     batch_leaf_spec(k, getattr(v, "ndim", 2),
-                                                     micro)))
+        return {k: jax.device_put(v, NamedSharding(
+                    mesh, batch_leaf_spec(k, getattr(v, "ndim", 2))))
                 for k, v in batch.items()}

@@ -21,8 +21,7 @@ collective-free — single-chip and pod training share one code path.
 
 from __future__ import annotations
 
-from functools import partial
-from typing import Any, Dict, Optional, Tuple
+from typing import Dict
 
 import jax
 import jax.numpy as jnp
@@ -38,8 +37,8 @@ Params = Dict[str, jax.Array]
 
 def finalize_update(opt_cfg: OptimizerConfig, opt_state, p, grads,
                     lr, labels, denom):
-    """The shared tail of every update path (fused step AND the
-    heterogeneous-delay host loop): cost normalization →
+    """The shared tail of both update programs (the fused step and
+    GraphGroup's update of an accumulated sum): cost normalization →
     --normalize-gradient → --dynamic-gradient-scaling (stats in
     opt_state['gstat']; outliers scaled down to factor x windowed
     average) → --clip-norm (sees the scaled norm, so the cap composes
@@ -119,11 +118,12 @@ def expand_compact_batch(batch):
 
 class _GradMachinery:
     """The gradient producer shared by the fused train step and the
-    heterogeneous-delay host loop (GraphGroup._grad_fn): per-device
-    fwd/bwd + the explicit scatter-reduce cycle. ONE implementation so the
-    two paths fold dropout keys and reduce gradients identically."""
+    accumulating gradient program (build_grad_fn): per-device fwd/bwd +
+    the explicit scatter-reduce cycle. ONE implementation, so a
+    micro-batch's gradients are reduced and laid out as a whole
+    update's."""
 
-    def __init__(self, model, mesh: Mesh, params: Params, delay: int = 1,
+    def __init__(self, model, mesh: Mesh, params: Params,
                  frozen=(), dim_emb: int = 0, force_gspmd: bool = False,
                  grad_dtype=None):
         """``force_gspmd`` routes even pure-DP meshes through the GSPMD
@@ -142,7 +142,6 @@ class _GradMachinery:
         the compute dtype (ops/ops.py logits_matmul — the bf16 MXU-rate
         fix applies regardless of this setting; docs/PERFORMANCE.md)."""
         self.mesh = mesh
-        self.delay = delay
         self.n_data = mesh.shape["data"]
         # Explicit scatter-reduce runs on pure-DP meshes (the reference's
         # only parallelism and the north-star config); meshes with TP/SP/
@@ -161,7 +160,7 @@ class _GradMachinery:
         self.model = model
         # counts a model family takes inside the step (routing counts of
         # an expert layer): one float32 vector beside the loss, summed
-        # over micro-batches and devices; length 0 for most models
+        # over devices; length 0 for most models
         self.n_counters = len(getattr(model, "step_counters", ()))
         gd = None if grad_dtype in (None, "float32") else jnp.dtype(grad_dtype)
         if gd is not None and gd == jnp.dtype(jnp.float32):
@@ -235,33 +234,11 @@ class _GradMachinery:
         return g, aux
 
     def _local_grads(self, p, batch, rng):
-        """GSPMD-path fwd/bwd (+ --optimizer-delay accumulation):
-        logically global gradients; the partitioner places the
-        cross-device sums (graph_group_sync.cpp's per-device backward,
-        expressed as annotations). Per-micro dropout keys fold exactly
-        like the host accumulation loop (GraphGroup.update), so the two
-        delay paths are numerically interchangeable."""
-        if self.delay > 1:
-            def body(carry, sl):
-                acc, tot, lab, cnt = carry
-                micro, i = sl
-                g, aux = self._grads_of(p, micro,
-                                        jax.random.fold_in(rng, i))
-                acc = jax.tree_util.tree_map(jnp.add, acc, g)
-                return (acc, tot + aux["ce_sum"], lab + aux["labels"],
-                        cnt + self._counters(aux)), None
-            zeros = jax.tree_util.tree_map(
-                lambda x: jnp.zeros(x.shape, jnp.float32), p)
-            (grads, ce_sum, labels, counters), _ = jax.lax.scan(
-                body, (zeros, jnp.zeros((), jnp.float32),
-                       jnp.zeros((), jnp.float32),
-                       jnp.zeros((self.n_counters,), jnp.float32)),
-                (batch, jnp.arange(self.delay)))
-        else:
-            grads, aux = self._grads_of(p, batch, rng)
-            ce_sum, labels = aux["ce_sum"], aux["labels"]
-            counters = self._counters(aux)
-        return grads, ce_sum, labels, counters
+        """GSPMD-path fwd/bwd: logically global gradients; the
+        partitioner places the cross-device sums (graph_group_sync.cpp's
+        per-device backward, expressed as annotations)."""
+        grads, aux = self._grads_of(p, batch, rng)
+        return grads, aux["ce_sum"], aux["labels"], self._counters(aux)
 
     def _constrain(self, grads):
         """GSPMD path: pin each gradient leaf to its combined TP+ZeRO-1
@@ -291,50 +268,17 @@ class _GradMachinery:
         varying-manual-axes typing (check_vma=True) shard_map's autodiff
         would instead insert its own full-size psum for unvarying inputs —
         double-counting ahead of psum_scatter — and unvarying lax.scan
-        carries inside the models (RNN hidden states, delay accumulators)
-        would need pcast plumbing throughout.
-
-        --optimizer-delay accumulates SHARD-sized: each micro-batch's
-        local gradients are reduce-scattered inside the scan and the
-        shards summed, so (a) the accumulator costs 1/N of the full
-        gradient HBM, (b) micro i's collective overlaps micro i+1's
-        compute, and (c) the summation order (Σ_micro RS(g_i)) is the
-        SAME as the heterogeneous-shape host loop's, keeping the two
-        delay paths bit-for-bit-ish interchangeable."""
+        carries inside the models (RNN hidden states) would need pcast
+        plumbing throughout."""
         # independent per-device dropout streams (reference: per-device
         # cuRAND generators); with dropout off the key is never consumed
-        axis_fold = jax.lax.axis_index("data")
-
-        def _k(key, i=None):
-            if i is not None:
-                key = jax.random.fold_in(key, i)
-            return jax.random.fold_in(key, axis_fold)
-
-        if self.delay > 1:
-            def body(carry, sl):
-                acc, tot, lab, cnt = carry
-                micro, i = sl
-                g, aux = self._grads_of(p, micro, _k(rng, i))
-                with jax.named_scope("collectives"):
-                    g = self._scatter(g)
-                acc = jax.tree_util.tree_map(jnp.add, acc, g)
-                return (acc, tot + aux["ce_sum"], lab + aux["labels"],
-                        cnt + self._counters(aux)), None
-            zeros = {k: jnp.zeros(self._shard_shape(k), jnp.float32)
-                     for k in p}
-            (grads, ce_sum, labels, counters), _ = jax.lax.scan(
-                body, (zeros, jnp.zeros((), jnp.float32),
-                       jnp.zeros((), jnp.float32),
-                       jnp.zeros((self.n_counters,), jnp.float32)),
-                (batch, jnp.arange(self.delay)))
-        else:
-            g, aux = self._grads_of(p, batch, _k(rng))
-            with jax.named_scope("collectives"):
-                grads = self._scatter(g)
-            ce_sum, labels = aux["ce_sum"], aux["labels"]
-            counters = self._counters(aux)
-        return (grads, jax.lax.psum(ce_sum, "data"),
-                jax.lax.psum(labels, "data"),
+        key = jax.random.fold_in(rng, jax.lax.axis_index("data"))
+        g, aux = self._grads_of(p, batch, key)
+        with jax.named_scope("collectives"):
+            grads = self._scatter(g)
+        counters = self._counters(aux)
+        return (grads, jax.lax.psum(aux["ce_sum"], "data"),
+                jax.lax.psum(aux["labels"], "data"),
                 jax.lax.psum(counters, "data"))
 
     def _scatter(self, grads):
@@ -351,22 +295,13 @@ class _GradMachinery:
                     g, "data", scatter_dimension=ax, tiled=True)
         return out
 
-    def _shard_shape(self, k):
-        """LOCAL shape of gradient leaf k after _scatter (inside the
-        manual region): the ZeRO-1 axis divided by the data-axis size."""
-        shape = list(self._shapes[k])
-        ax = self.data_axes[k]
-        if ax is not None:
-            shape[ax] //= self.n_data
-        return tuple(shape)
-
     @staticmethod
     def _data_only(spec: P) -> P:
         return P(*tuple(s if s == "data" else None for s in spec))
 
     def _sharded_grads(self, p, batch, rng):
         b_specs = {k: self._data_only(M.batch_leaf_spec(
-                       k, getattr(v, "ndim", 2), micro=self.delay > 1))
+                       k, getattr(v, "ndim", 2)))
                    for k, v in batch.items()}
         g_out = {k: (P() if ax is None else P(*([None] * ax + ["data"])))
                  for k, ax in self.data_axes.items()}
@@ -377,64 +312,87 @@ class _GradMachinery:
             check_vma=False)(p, batch, rng)
 
 
+def _step_key(rng, step):
+    """(the update's dropout key, the step as int32) from the RAW
+    training stream key: fold_in(rng, step - 1), folded ON DEVICE by the
+    absolute step number, so the host dispatches no tiny
+    _threefry_fold_in program between steps. GraphGroup passes step as
+    int32 so the fold index is EXACT at any step count; a float step
+    (direct callers) is tolerated but its fold saturates f32's 2^24
+    integer range."""
+    step = jnp.asarray(step)
+    step_i = (step if jnp.issubdtype(step.dtype, jnp.integer)
+              else step.astype(jnp.int32))
+    return jax.random.fold_in(rng, step_i - 1), step_i
+
+
 def build_grad_fn(model, mesh: Mesh, params: Params, frozen=(),
-                  dim_emb: int = 0, grad_dtype=None):
-    """Jitted (params, batch, rng) → (grads, aux) for the heterogeneous-
-    delay host loop (GraphGroup._grad_fn): the SAME gradient machinery as
-    the fused step — per-device backward, explicit scatter-reduce, matching
-    dropout-key folds — so host-loop and fused accumulation stay
-    numerically interchangeable. Gradients come out ZeRO-1 sharded, ready
-    for the sharded update tail."""
-    m = _GradMachinery(model, mesh, params, delay=1, frozen=frozen,
+                  dim_emb: int = 0, grad_dtype=None, donate: bool = True):
+    """The two programs an --optimizer-delay update accumulates with
+    (GraphGroup.update, a list of micro-batches), over the SAME gradient
+    machinery as the fused step:
+
+    - zero_sum() -> the running sum at its start: float32 zeros per
+      gradient leaf, laid out ZeRO-1 sharded by grad_shardings(), and
+      zero `ce_sum` / `labels`;
+    - accumulate(params, sum, batch, step, i, rng) -> (sum, counters):
+      micro-batch i's gradients under the key fold_in(fold_in(rng,
+      step - 1), i), added INTO the donated sum (acc + g.astype(float32),
+      float32 whatever --gradient-dtype says: bfloat16 adds would absorb
+      a late micro-batch's small terms), with its `ce_sum` and `labels`;
+      `counters` is the model family's lazy step-counter vector, None
+      where it keeps none. One program per batch shape."""
+    m = _GradMachinery(model, mesh, params, frozen=frozen,
                        dim_emb=dim_emb, grad_dtype=grad_dtype)
+    rep = M.replicated(mesh)
+    sum_shardings = {"grads": m.grad_shardings(), "ce_sum": rep,
+                     "labels": rep}
 
-    def grad_step(p, batch, rng):
+    def zero_sum():
+        zero = jnp.zeros((), jnp.float32)
+        return {"grads": {k: jnp.zeros(shape, jnp.float32)
+                          for k, shape in m._shapes.items()},
+                "ce_sum": zero, "labels": zero}
+
+    def accumulate(p, total, batch, step, i, rng):
+        key, _ = _step_key(rng, step)
+        rng = jax.random.fold_in(key, i)
         batch = expand_compact_batch(batch)
-        grads, ce_sum, labels, counters = m.grads(p, batch, rng)
-        aux = {"ce_sum": ce_sum, "labels": labels}
-        if m.n_counters:
-            aux["counters"] = counters
-        return grads, aux
+        with jax.named_scope("grads"):
+            grads, ce_sum, labels, counters = m.grads(p, batch, rng)
+        total = {"grads": {k: acc + grads[k].astype(jnp.float32)
+                           for k, acc in total["grads"].items()},
+                 "ce_sum": total["ce_sum"] + ce_sum,
+                 "labels": total["labels"] + labels}
+        return total, (counters if m.n_counters else None)
 
-    return jax.jit(grad_step, out_shardings=(m.grad_shardings(), None))
+    return (jax.jit(zero_sum, out_shardings=sum_shardings),
+            jax.jit(accumulate, out_shardings=(sum_shardings, None),
+                    donate_argnums=(1,) if donate else ()))
 
 
 def build_train_step(model, opt_cfg: OptimizerConfig, schedule, cost_type: str,
                      mesh: Mesh, params: Params, opt_state,
-                     delay: int = 1, donate: bool = True, shardings=None,
+                     donate: bool = True, shardings=None,
                      frozen=(), force_gspmd: bool = False,
                      grad_dtype=None):
-    """Returns a jitted fn(params, opt_state, batch, step) →
-    (params, opt_state, metrics) with SyncGraphGroup semantics.
+    """Returns a jitted fn(params, opt_state, batch, step, rng) →
+    (params, opt_state, metrics) with SyncGraphGroup semantics: ONE batch,
+    one update (--optimizer-delay accumulates outside it: build_grad_fn).
 
-    `batch` leaves carry a leading micro-batch axis of size `delay` when
-    delay > 1 (accumulation by lax.scan inside the step — no host round-trip
-    per micro-batch, unlike the reference's per-delay-loop host logic).
     Inputs must arrive committed: params/opt_state via place(), batches via
-    mesh.shard_batch (per-leaf name-aware specs; pass micro=True there when
-    delay > 1 so the leading micro axis stays unsharded). Only the outputs
+    mesh.shard_batch (per-leaf name-aware specs). Only the outputs
     are pinned here so donation layouts match. `shardings` optionally passes
     precomputed (param_shardings, opt_state_shardings) to avoid recomputing.
     """
-    machinery = _GradMachinery(model, mesh, params, delay=delay,
+    machinery = _GradMachinery(model, mesh, params,
                                frozen=frozen, force_gspmd=force_gspmd,
                                grad_dtype=grad_dtype)
     g_specs = machinery.g_specs
 
     def one_update(p, opt_state, batch, step, rng):
-        # rng is the RAW training stream key; the per-step fold happens
-        # HERE, on device, by the absolute step number — the host used to
-        # dispatch a separate tiny _threefry_fold_in program every step
-        # (visible as ~2 extra dispatches/step in the r4 TPU trace). Key
-        # derivation is bit-identical to the old host-side
-        # fold_in(train_key, step-1). GraphGroup passes step as int32 so
-        # the fold index is EXACT at any step count; a float step (legacy
-        # direct callers) is tolerated but its fold saturates f32's 2^24
-        # integer range.
-        step = jnp.asarray(step)
-        step_i = (step if jnp.issubdtype(step.dtype, jnp.integer)
-                  else step.astype(jnp.int32))
-        rng = jax.random.fold_in(rng, step_i - 1)
+        # rng is the RAW training stream key
+        rng, step_i = _step_key(rng, step)
         step = step_i.astype(jnp.float32)     # schedule/metrics math
         with jax.named_scope("expand_batch"):
             batch = expand_compact_batch(batch)
@@ -448,9 +406,7 @@ def build_train_step(model, opt_cfg: OptimizerConfig, schedule, cost_type: str,
         if cost_type in ("ce-mean-words", "perplexity"):
             denom = jnp.maximum(labels, 1.0)
         elif cost_type == "ce-mean":
-            bsz = (batch["trg_ids"].shape[0] if delay == 1
-                   else batch["trg_ids"].shape[0] * batch["trg_ids"].shape[1])
-            denom = jnp.asarray(bsz, jnp.float32)
+            denom = jnp.asarray(batch["trg_ids"].shape[0], jnp.float32)
         else:
             denom = jnp.asarray(1.0, jnp.float32)
         with jax.named_scope("optimizer"):
@@ -483,9 +439,7 @@ def build_train_step(model, opt_cfg: OptimizerConfig, schedule, cost_type: str,
     if machinery.n_counters:
         metrics_shardings["counters"] = rep
 
-    return jax.jit(  # mtlint: ok -- built once per training launch:
-        # delay is a launch flag (--optimizer-delay), not a per-request
-        # key, so the domain is one value per process
+    return jax.jit(
         one_update,
         out_shardings=(p_shardings, o_shardings, metrics_shardings),
         donate_argnums=(0, 1) if donate else ())
@@ -570,7 +524,7 @@ def dryrun(n_devices: int, options, batch_maker, vocab: int = 256) -> None:
     schedule = LRSchedule.from_options(options)
     step = build_train_step(model, opt_cfg, schedule,
                             options.get("cost-type", "ce-sum"), mesh,
-                            params, opt_state, delay=1, donate=False)
+                            params, opt_state, donate=False)
     batch = batch_maker(8 * max(1, mesh.shape["data"]), 16, 16, vocab)
     batch = M.shard_batch(batch, mesh)
     p2, o2, metrics = step(params, opt_state,

@@ -10,10 +10,11 @@ the whole cycle and GSPMD inserts the identical collectives over ICI
 SingletonGraph (graph_group_singleton.cpp) is not a separate code path.
 
 Semantics carried over exactly:
-- --optimizer-delay N: accumulate N micro-batch gradients, then one update;
-  gradient normalization follows the cost-type (ce-mean-words divides the
-  accumulated gradient by the accumulated label count, like Marian's
-  costScaleFactor);
+- --optimizer-delay N: `update` is handed N micro-batches of any shapes and
+  dispatches one gradient program per micro-batch, each adding into a
+  donated float32 sum, then one update program. Gradient normalization
+  follows the cost-type over the whole sum (ce-mean-words divides by the
+  accumulated label count, like Marian's costScaleFactor);
 - clip-then-update order: global-norm clip on the FULL gradient before the
   sharded optimizer update;
 - EMA (exponential smoothing) updated after each optimizer step, stored with
@@ -29,23 +30,22 @@ import dataclasses
 import itertools
 import math
 from concurrent.futures import ThreadPoolExecutor
-from typing import Any, Dict, List, Optional, Tuple
+from typing import Any, Dict, Optional
 
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.sharding import NamedSharding, PartitionSpec as P
 
 from ..common import logging as log
 from ..data.batch_generator import budget_shapes
 from ..models.encoder_decoder import EncoderDecoder
 from ..obs import trace as obs_trace
-from ..optimizers.optimizers import (OptimizerConfig, apply_update, init_state,
+from ..optimizers.optimizers import (OptimizerConfig, init_state,
                                      smoothed_params)
 from ..optimizers.schedule import LRSchedule
-from ..ops.ops import clip_by_global_norm, global_norm
 from ..parallel import mesh as M
-from ..parallel.zero import build_train_step, place
+from ..parallel.zero import (build_grad_fn, build_train_step,
+                             finalize_update, place)
 from . import hbm
 
 Params = Dict[str, jax.Array]
@@ -97,7 +97,9 @@ class GraphGroup:
         self.opt_state: Optional[Dict[str, Any]] = None
         self._donate = donate
         self._fused = None
-        self._fused_delay = None         # delay>1 in-jit micro-batch scan
+        # a list of micro-batches: the sum's zeros, one micro-batch's
+        # gradients added into it, the update from the sum
+        self._zero_sum = None
         self._grad_fn = None
         self._update_fn = None
         self._fix_src = bool(options.get("embedding-fix-src", False))
@@ -243,64 +245,61 @@ class GraphGroup:
         o_sh = T.opt_state_shardings(self.opt_state, p_specs, mesh)
         model, opt_cfg, schedule = self.model, self.opt_cfg, self.schedule
 
-        # fused single-batch step (the hot path; delay==1)
+        # fused single-batch step (the hot path)
         frozen = self._frozen_names()
         grad_dtype = self.options.get("gradient-dtype", "float32")
         self._fused = build_train_step(model, opt_cfg, schedule,
                                        self.cost_type, mesh, self.params,
-                                       self.opt_state, delay=1,
+                                       self.opt_state,
                                        donate=self._donate,
                                        shardings=(p_sh, o_sh), frozen=frozen,
                                        grad_dtype=grad_dtype)
-        self._fused_delay = None
         self._ahead = None              # executables of the step before
         self._programs = {}
-        if self.delay > 1:
-            # in-jit micro-batch accumulation (one dispatch, one gradient
-            # accumulator in HBM) for the common case of shape-uniform
-            # micro-batches; heterogeneous shapes use the host loop below
-            self._fused_delay = build_train_step(
-                model, opt_cfg, schedule, self.cost_type, mesh,
-                self.params, self.opt_state, delay=self.delay,
-                donate=self._donate, shardings=(p_sh, o_sh), frozen=frozen,
-                grad_dtype=grad_dtype)
 
-        # split path for --optimizer-delay with heterogeneous batch shapes.
-        # Batches arrive committed via M.shard_batch (per-leaf name-aware
-        # specs), so no in_shardings here. Shares the fused step's gradient
-        # machinery (per-device backward + explicit scatter-reduce,
-        # identical dropout-key folds), so host-loop accumulation matches
-        # the in-jit lax.scan bit-for-bit-ish; grads come out ZeRO-1
-        # sharded for the sharded update tail.
-        from ..parallel.zero import build_grad_fn
-        self._grad_fn = build_grad_fn(model, mesh, self.params,
-                                      frozen=frozen, grad_dtype=grad_dtype)
+        # --optimizer-delay: the accumulating gradient program over the
+        # fused step's gradient machinery (per-device backward + explicit
+        # scatter-reduce; the sum stays ZeRO-1 sharded for the sharded
+        # update tail). Batches arrive committed via M.shard_batch
+        # (per-leaf name-aware specs), so no in_shardings here.
+        self._zero_sum, self._grad_fn = build_grad_fn(
+            model, mesh, self.params, frozen=frozen, grad_dtype=grad_dtype,
+            donate=self._donate)
 
         # hoisted: the branch below is resolved AT TRACE TIME, so the
         # traced fn must not read self.cost_type through its closure — a
         # later rebind would silently retrace (MT-JIT-CLOSURE-VARYING)
         cost_type = self.cost_type
 
-        def update_step(p, opt_state, grads, step, labels, n_sents):
+        def update_step(p, opt_state, total, step, n_sents):
+            labels = total["labels"]
             if cost_type in ("ce-mean-words", "perplexity"):
                 denom = jnp.maximum(labels, 1.0)
             elif cost_type == "ce-mean":
                 denom = jnp.maximum(n_sents, 1.0)
             else:
                 denom = jnp.asarray(1.0, jnp.float32)
-            lr = schedule(step)
             # shared tail (zero.py finalize_update): normalize-gradient,
-            # dynamic scaling, clip-as-min, nan-skip — the heterogeneous-
-            # delay fallback must not silently drop those flags
-            from ..parallel.zero import finalize_update
+            # dynamic scaling, clip-as-min, nan-skip
             new_p, new_opt, gnorm, skipped = finalize_update(
-                opt_cfg, opt_state, p, grads, lr, labels, denom)
-            return new_p, new_opt, gnorm, lr, skipped
+                opt_cfg, opt_state, p, total["grads"], schedule(step),
+                labels, denom)
+            ce_sum = total["ce_sum"]
+            metrics = {"gnorm": gnorm}
+            if opt_cfg.check_gradient_nan:
+                # as the fused step: a skipped update must not poison the
+                # display window's cost
+                metrics["skipped"] = skipped
+                ce_sum = jnp.where(skipped > 0, 0.0, ce_sum)
+                labels = jnp.where(skipped > 0, 0.0, labels)
+            return new_p, new_opt, dict(metrics, ce_sum=ce_sum,
+                                        labels=labels)
 
+        # the sum is not donated: no output has its shape to take it
         self._update_fn = jax.jit(
             update_step,
-            out_shardings=(p_sh, o_sh, rep, rep, rep),
-            donate_argnums=(0, 1, 2) if self._donate else ())
+            out_shardings=(p_sh, o_sh, rep),
+            donate_argnums=(0, 1) if self._donate else ())
 
     # -- one (macro-)update --------------------------------------------------
     def _output(self, metrics) -> TrainOutput:
@@ -366,7 +365,7 @@ class GraphGroup:
         """Which step program a batch runs: rows x width of its 2-D
         leaves ("10x1536"; both, where two streams differ)."""
         if batch is None:
-            return "update"         # the split delay path's optimizer tail
+            return "update"         # the update from an accumulated sum
         return "+".join(dict.fromkeys(
             "x".join(map(str, v.shape))
             for _, v in sorted(batch.items()) if v.ndim >= 2)) or "-"
@@ -518,96 +517,59 @@ class GraphGroup:
             del self._ahead[key]
             return self._fused
 
+    def _dump_hlo_once(self, fn, *args) -> None:
+        """--dump-hlo: the first program `update` dispatches, lowered
+        with its own arguments (a delayed update's gradient program, the
+        compute-heavy one of its two)."""
+        if self._dump_hlo:
+            from ..common.profiling import dump_lowered
+            dump_lowered(self._dump_hlo, fn.lower(*args))
+            self._dump_hlo = None
+
     def update(self, batches, step: int, rng) -> TrainOutput:
-        """batches: one batch dict, or a list of `delay` micro-batch
-        dicts. `rng` is the RAW training stream key — the per-step fold
-        (by absolute step number, fold_in(rng, step-1)) happens inside
-        the jitted step, saving 2-3 tiny host dispatches per step (the
-        r4 TPU trace showed separate _threefry_fold_in +
+        """One update from one batch dict (the fused step), or from a list
+        of --optimizer-delay micro-batch dicts of any shapes: micro-batch
+        i's gradients, under the key fold_in(fold_in(rng, step - 1), i),
+        are added into one donated float32 sum by one dispatch each, and
+        one more dispatch updates from the sum.
+
+        `rng` is the RAW training stream key — the folds happen inside
+        the jitted programs, saving 2-3 tiny host dispatches per step
+        (the r4 TPU trace showed separate _threefry_fold_in +
         convert_element_type programs between steps). The plain np.int32
         step scalar avoids a compiled scalar-convert dispatch and keeps
-        the fold index exact at any step count."""
+        the fold index exact at any step count (a f32 step would
+        saturate fold indices past 2^24)."""
         if isinstance(batches, dict):
             batches = [batches]
-        # int32 step: the in-jit rng fold index stays exact at any step
-        # count (a f32 step would saturate fold indices past 2^24)
-        step_f = np.int32(step)
+        step_i = np.int32(step)
         if len(batches) == 1:
             b = M.shard_batch(batches[0], self.mesh)
-            if self._dump_hlo:
-                from ..common.profiling import dump_lowered
-                dump_lowered(self._dump_hlo, self._fused.lower(
-                    self.params, self.opt_state, b, step_f, rng))
-                self._dump_hlo = None
+            self._dump_hlo_once(self._fused, self.params, self.opt_state, b,
+                                step_i, rng)
             self.params, self.opt_state, metrics = self._dispatch(
-                self._step_for(b, step_f, rng), step, self.params,
-                self.opt_state, b, step_f, rng, batch=b)
+                self._step_for(b, step_i, rng), step, self.params,
+                self.opt_state, b, step_i, rng, batch=b)
             return self._output(metrics)
-        if (self._fused_delay is not None and len(batches) == self.delay
-                and all(b.keys() == batches[0].keys()
-                        and all(v.shape == batches[0][k].shape
-                                for k, v in b.items())
-                        for b in batches[1:])):
-            # stack micro-batches on a leading [delay] axis → ONE jitted
-            # call (lax.scan accumulates grads on-device; SyncGraphGroup
-            # delay semantics preserved — see build_train_step)
-            stacked = {k: jnp.stack([b[k] for b in batches])
-                       for k in batches[0]}
-            stacked = M.shard_batch(stacked, self.mesh, micro=True)
-            if self._dump_hlo:
-                from ..common.profiling import dump_lowered
-                dump_lowered(self._dump_hlo, self._fused_delay.lower(
-                    self.params, self.opt_state, stacked, step_f, rng))
-                self._dump_hlo = None
-            self.params, self.opt_state, metrics = self._dispatch(
-                self._fused_delay, step, self.params, self.opt_state,
-                stacked, step_f, rng, batch=stacked)
-            return self._output(metrics)
-        total_loss = total_labels = 0.0
-        n_sents = 0.0
-        grads_acc = None
-        # heterogeneous-shape host loop: reproduce the fused paths' key
-        # derivation (fold by absolute step, then by micro index)
-        base_key = jax.random.fold_in(rng, step - 1)
+        total = self._zero_sum()
+        n_sents = 0
         for i, b in enumerate(batches):
-            r = jax.random.fold_in(base_key, i)
-            if self._dump_hlo:
-                # delay>1 path: dump the gradient step (the compute-heavy
-                # half of the accumulation cycle)
-                from ..common.profiling import dump_lowered
-                dump_lowered(self._dump_hlo, self._grad_fn.lower(
-                    self.params, M.shard_batch(b, self.mesh), r))
-                self._dump_hlo = None
-            sharded = M.shard_batch(b, self.mesh)
-            grads, aux = self._dispatch(self._grad_fn, step, self.params,
-                                        sharded, r, batch=sharded)
-            if "counters" in aux:
+            b = M.shard_batch(b, self.mesh)
+            args = (self.params, total, b, step_i, np.int32(i), rng)
+            self._dump_hlo_once(self._grad_fn, *args)
+            total, counters = self._dispatch(self._grad_fn, step, *args,
+                                             batch=b)
+            if counters is not None:
                 obs_trace.TRACER.count_lazy(self.model.step_counters,
-                                            aux["counters"])
-            total_loss = total_loss + aux["ce_sum"]        # lazy device adds
-            total_labels = total_labels + aux["labels"]
+                                            counters)
             # rows from whichever target form shipped (compact batches
             # carry trg_tok/trg_len instead of trg_ids/trg_mask)
             trg = b["trg_ids"] if "trg_ids" in b else b["trg_tok"]
             n_sents += int(trg.shape[0])
-            # f32 accumulation regardless of --gradient-dtype: the in-jit
-            # delay paths accumulate into explicit f32 accumulators, and
-            # the two delay paths must stay numerically interchangeable
-            # (bf16 adds would absorb late micro-batches' small terms)
-            grads_acc = (
-                jax.tree_util.tree_map(
-                    lambda g: g.astype(jnp.float32), grads)
-                if grads_acc is None else
-                jax.tree_util.tree_map(
-                    lambda a, g: a + g.astype(jnp.float32),
-                    grads_acc, grads))
-        self.params, self.opt_state, gnorm, _lr, skipped = self._dispatch(
-            self._update_fn, step, self.params, self.opt_state, grads_acc,
-            np.float32(step), jnp.asarray(total_labels, jnp.float32),
-            jnp.asarray(n_sents, jnp.float32))
-        return TrainOutput(
-            total_loss, total_labels, gnorm,
-            skipped if self.opt_cfg.check_gradient_nan else None)
+        self.params, self.opt_state, metrics = self._dispatch(
+            self._update_fn, step, self.params, self.opt_state, total,
+            np.float32(step), np.float32(n_sents))
+        return self._output(metrics)
 
     # -- EMA access for validation/saving -----------------------------------
     def smoothed(self) -> Params:

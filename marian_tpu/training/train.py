@@ -21,7 +21,7 @@ import numpy as np
 from ..common import faultpoints as fp
 from ..common import logging as log
 from ..common import prng, signal_handling
-from ..data import BatchGenerator, Corpus, create_vocab
+from ..data import BatchGenerator, Corpus, CorpusState, create_vocab
 from ..models.encoder_decoder import batch_to_arrays, create_model
 from . import bundle as bdl
 from .checkpoint import load_checkpoint, save_checkpoint
@@ -160,7 +160,6 @@ class Train:
         log.info("Vocabulary sizes: {}", " ".join(str(len(v)) for v in vocabs))
 
         corpus = Corpus(train_sets, vocabs, opts)
-        native_bg = _native_batch_generator(opts, train_sets, vocabs)
 
         # -- model + graph group -------------------------------------------
         if opts.get("auto-tune", False):
@@ -191,23 +190,19 @@ class Train:
                 state = loaded_state
                 if not opts.get("no-restore-corpus", False) and state.corpus:
                     saved_be = state.corpus.get("backend")
-                    active_be = "native" if native_bg is not None else "python"
-                    if saved_be is not None and saved_be != active_be:
-                        # positions are not portable across backends (python
-                        # counts raw lines, native its filtered order) —
-                        # restart the epoch rather than seek to the wrong
-                        # sentence (ADVICE r1)
+                    if saved_be not in (None, CorpusState.BACKEND):
+                        # a checkpoint is input from outside: positions are
+                        # not portable across loaders (this one counts raw
+                        # lines, the C++ loader of earlier versions its
+                        # filtered order) — restart the epoch rather than
+                        # seek to the wrong sentence (ADVICE r1)
                         log.warn(
                             "Corpus state was saved by the '{}' data backend "
                             "but '{}' is active; restarting epoch {} from "
-                            "the beginning", saved_be, active_be,
+                            "the beginning", saved_be, CorpusState.BACKEND,
                             state.corpus.get("epoch"))
                         state.corpus = {**state.corpus, "position": 0}
                     corpus.restore(state.corpus)
-                    if native_bg is not None:
-                        native_bg.seek(int(state.corpus.get("epoch", 1) or 1),
-                                       int(state.corpus.get("position", 0)),
-                                       seed=state.corpus.get("seed"))
                     log.info("Restored corpus position: epoch {}, sent {}",
                              state.corpus.get("epoch"), state.corpus.get("position"))
         elif opts.get("pretrained-model", None):
@@ -288,8 +283,7 @@ class Train:
         last_corpus_state: List[dict] = [corpus.state.as_dict()]
 
         def do_save(suffix: str = "") -> None:
-            state.corpus = (native_bg.state_dict() if native_bg is not None
-                            else last_corpus_state[0])
+            state.corpus = last_corpus_state[0]
             smooth = gg.smoothed() if gg.opt_cfg.smoothing > 0 else None
             # without --overwrite, an iteration-numbered copy of every
             # periodic checkpoint is written in the SAME save unit
@@ -333,10 +327,6 @@ class Train:
             from .batch_fit import fit_mini_batch_words
             fitted = fit_mini_batch_words(gg, opts, len(vocabs[-1]))
             opts.set("mini-batch-words", fitted)
-            if native_bg is not None:
-                # the native generator captured the pre-fit budget at
-                # construction — rebuild it with the fitted value
-                native_bg = _native_batch_generator(opts, train_sets, vocabs)
 
         # --mini-batch-track-lr: scale LR with the actual batch size by
         # anchoring Marian's reference-batch mechanism at the (possibly
@@ -490,9 +480,7 @@ class Train:
         def _epoch_loop() -> Optional[str]:
             nonlocal stop
             while scheduler.keep_going() and not stop:
-                bg = native_bg if native_bg is not None \
-                    else BatchGenerator(corpus, opts,
-                                        budget_scale=budget_scale)
+                bg = BatchGenerator(corpus, opts, budget_scale=budget_scale)
                 micro: List = []
                 for batch in bg:
                     if watchdog is not None:
@@ -595,14 +583,9 @@ class Train:
             # (it parks on its bounded queue; daemon, leaked once per
             # rollback, bounded by --divergence-retries) — reusing that
             # object would race the restore.
-            if native_bg is None:
-                corpus = Corpus(train_sets, vocabs, opts)
-                if state.corpus:
-                    corpus.restore(state.corpus)
-            elif state.corpus:
-                native_bg.seek(int(state.corpus.get("epoch", 1) or 1),
-                               int(state.corpus.get("position", 0)),
-                               seed=state.corpus.get("seed"))
+            corpus = Corpus(train_sets, vocabs, opts)
+            if state.corpus:
+                corpus.restore(state.corpus)
             last_corpus_state[0] = corpus.state.as_dict()
             train_key = jax.random.fold_in(base_train_key, n)
             scheduler.reset_divergence_window()
@@ -654,40 +637,6 @@ def _warmup_updates(opts) -> int:
             f"--mini-batch-warmup {raw}: only update-counted warmup "
             f"(e.g. 4000 or 4000u) is supported")
     return wu.n
-
-
-def _native_batch_generator(opts, train_sets, vocabs):
-    """Opt-in C++ data loader (--data-backend native; marian_tpu/native/).
-    Falls back to the Python BatchGenerator when the config needs features
-    the native path doesn't cover (subword/factored vocabs, guided
-    alignment, data weighting) or the library can't build."""
-    if str(opts.get("data-backend", "python") or "python") != "native":
-        return None
-    from ..data.vocab import DefaultVocab
-    ga = opts.get("guided-alignment", "none")
-    supported = (all(type(v) is DefaultVocab for v in vocabs)
-                 and not opts.get("tsv", False)   # TSV split is python-side
-                 and (not ga or ga == "none")
-                 and not opts.get("data-weighting", None)
-                 # text augmentation hooks live only in the Python Corpus
-                 and not int(opts.get("all-caps-every", 0) or 0)
-                 and not int(opts.get("english-title-case-every", 0) or 0)
-                 # batch-size ramp-up needs the Python budget_scale hook
-                 # (default is the string "0" = off — parse, don't truth-test)
-                 and not _warmup_updates(opts))
-    if not supported:
-        log.warn("--data-backend native does not support this data config "
-                 "(needs plain word vocabs, no alignment/weighting); "
-                 "falling back to the python pipeline")
-        return None
-    try:
-        from ..native import NativeBatchGenerator
-        bg = NativeBatchGenerator(train_sets, vocabs, opts)
-        log.info("Native data backend: {} sentences in RAM", bg.n_sentences)
-        return bg
-    except Exception as e:  # toolchain missing etc.
-        log.warn("Native data backend unavailable ({}); using python", e)
-        return None
 
 
 def train_main(options) -> None:

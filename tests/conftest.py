@@ -80,7 +80,6 @@ SLOW_TESTS = {
     "test_compact_equivalent_on_composed_mesh",
     # end-to-end training runs (test_training.py)
     "test_exact_resume",
-    "test_optimizer_delay_equivalent_to_big_batch",
     "test_loss_decreases_and_decodes",
     "test_ema_saved",
     "test_sigterm_like_save",
@@ -105,7 +104,6 @@ SLOW_TESTS = {
     "test_params_have_two_encoders_and_two_context_blocks",
     "test_second_source_changes_output",
     "test_loss_and_grads",
-    "test_train_with_native_backend",
     "test_convert_and_decode",
     # crash-resume kill sweep over the full fault-point catalog (each
     # variant is one killed trainer subprocess + one in-process resume;

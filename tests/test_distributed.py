@@ -63,7 +63,7 @@ def run_steps(n_devices, n_steps=4, vocab=19, force_gspmd=False):
     params, opt_state = place(params, opt_state, mesh)
     schedule = LRSchedule.from_options(o)
     step = build_train_step(model, opt_cfg, schedule, "ce-mean-words", mesh,
-                            params, opt_state, delay=1, donate=False,
+                            params, opt_state, donate=False,
                             force_gspmd=force_gspmd)
     losses = []
     for i in range(n_steps):
@@ -194,7 +194,7 @@ class TestZero1CollectivePattern:
         params, opt_state = place(params, opt_state, mesh)
         step = build_train_step(model, opt_cfg, LRSchedule.from_options(o),
                                 "ce-mean-words", mesh, params, opt_state,
-                                delay=1, donate=False)
+                                donate=False)
         b = M.shard_batch(batch(vocab, b=16, ts=8, tt=8), mesh)
         txt = step.lower(params, opt_state, b,
                          jnp.asarray(1.0, jnp.float32),
@@ -321,7 +321,7 @@ class TestGradientDtype:
         params, opt_state = place(params, opt_state, mesh)
         step = build_train_step(model, opt_cfg, LRSchedule.from_options(o),
                                 "ce-mean-words", mesh, params, opt_state,
-                                delay=1, donate=False,
+                                donate=False,
                                 grad_dtype=grad_dtype)
         losses = []
         for i in range(n_steps):

@@ -198,19 +198,19 @@ class TestShardedEquivalence:
         assert any(":encoder_l2_" in k or k.startswith("m:encoder_l2_")
                    for k in opt.files)
 
-    def test_pipe_with_fused_delay(self, rng):
-        """Depth-stacked storage composes with the in-jit --optimizer-delay
-        micro-batch scan (stacked params inside the delay scan body)."""
-        b = _batch(rng)
-        b2 = {k: jnp.roll(v, 1, axis=0) for k, v in b.items()}
+    def test_pipe_with_delay(self, rng):
+        """Depth-stacked storage composes with --optimizer-delay: two
+        micro-batches of different widths are accumulated over the stacked
+        parameters (the sum keeps their stacked, pipe-sharded layout) and
+        the second update's cost, which the first update's parameters
+        make, is the single device's."""
+        micro = [_batch(rng), _batch(rng, ts=9, tt=10)]
         single, _ = self._loss_after(
-            _opts(n=1, **{"optimizer-delay": 2}), [dict(b), dict(b2)],
-            steps=1, micro=True)
+            _opts(n=1, **{"optimizer-delay": 2}), micro, micro=True)
         piped, gg = self._loss_after(
             _opts(mesh=["data:2", "model:2", "pipe:2"], n=8,
-                  **{"optimizer-delay": 2}), [dict(b), dict(b2)],
-            steps=1, micro=True)
-        assert gg._stacked and gg._fused_delay is not None
+                  **{"optimizer-delay": 2}), micro, micro=True)
+        assert gg._stacked and gg.delay == 2
         assert abs(single - piped) / abs(single) < 1e-5
 
     def test_pipe_refuses_tied_layers(self):

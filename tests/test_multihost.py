@@ -56,7 +56,7 @@ WORKER = textwrap.dedent("""
     params, opt_state = place(params, opt_state, mesh)
     step = build_train_step(model, opt_cfg, LRSchedule.from_options(opts),
                             "ce-mean-words", mesh, params, opt_state,
-                            delay=1, donate=False)
+                            donate=False)
     r = np.random.RandomState(5)
     host = {
         "src_ids": r.randint(2, 31, (8, 6)).astype("int32"),

@@ -66,7 +66,7 @@ def _run_step(mesh_spec, devices, optimizer="adam", sp="none"):
     params, opt_state = place(params, opt_state, mesh)
     step = build_train_step(model, opt_cfg, LRSchedule.from_options(opts),
                             "ce-mean-words", mesh, params, opt_state,
-                            delay=1, donate=False)
+                            donate=False)
     batch = M.shard_batch(_batch(), mesh)
     p2, _, metrics = step(params, opt_state, batch,
                           jnp.asarray(1.0, jnp.float32), jax.random.key(1))

@@ -53,7 +53,7 @@ def _timed_step(n_dev, vocab=64, n_steps=6):
     params, st = place(params, st, mesh)
     step = build_train_step(model, cfg, LRSchedule.from_options(o),
                             "ce-mean-words", mesh, params, st,
-                            delay=1, donate=False)
+                            donate=False)
     rs = np.random.RandomState(0)
     b = M.shard_batch({
         "src_ids": jnp.asarray(rs.randint(2, vocab, (PER_DEV_B * n_dev, T)),

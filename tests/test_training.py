@@ -4,6 +4,7 @@ SURVEY.md §4)."""
 
 import math
 import os
+import pathlib
 
 import jax
 import numpy as np
@@ -18,6 +19,8 @@ from marian_tpu.optimizers.schedule import LRSchedule
 from marian_tpu.optimizers.optimizers import OptimizerConfig, init_state, apply_update
 from marian_tpu.training import Train, GraphGroup, TrainingState
 from marian_tpu.translator.greedy import greedy_decode
+
+from tests.time_limit import time_limit
 
 
 def train_options(tmp_path, src, tgt, **over):
@@ -156,7 +159,7 @@ class TestTrainEndToEnd:
         assert st.batches < 1000  # stopped early but saved
 
 
-class TestEMAAndDelay:
+class TestEMA:
     def test_ema_saved(self, tmp_corpus, tmp_path):
         src, tgt, _ = tmp_corpus
         opts = train_options(tmp_path, src, tgt,
@@ -165,53 +168,6 @@ class TestEMAAndDelay:
         Train(opts).run()
         base = str(tmp_path / "model")
         assert os.path.exists(base + ".ema.npz")
-
-    def test_optimizer_delay_equivalent_to_big_batch(self, tmp_corpus, tmp_path):
-        """delay=2 with batch B must equal delay=1 with the two micro-batches
-        concatenated (SyncGraphGroup accumulation semantics) for ce-mean-words."""
-        import jax.numpy as jnp
-        src, tgt, _ = tmp_corpus
-        opts = train_options(tmp_path, src, tgt)
-        vs = DefaultVocab.build(open(src).read().splitlines())
-        vt = DefaultVocab.build(open(tgt).read().splitlines())
-        model = create_model(opts, len(vs), len(vt))
-        key = jax.random.key(0)
-
-        def run(delayed):
-            c = Corpus([src, tgt], [vs, vt],
-                       Options({"max-length": 64, "shuffle": "none"}))
-            bg = BatchGenerator(c, mini_batch=4, maxi_batch=1, prefetch=False,
-                                shuffle_batches=False, pad_batch=True,
-                                batch_multiple=8)
-            batches = [batch_to_arrays(b) for b in list(bg)[:2]]
-            o = opts.with_(**{"optimizer-delay": 2 if delayed else 1})
-            gg = GraphGroup(model, o, donate=False)
-            gg.initialize(key)
-            if delayed:
-                gg.update(batches, 1, jax.random.key(9))
-            else:
-                # concatenate along batch dim, padding time dims to match
-                def cat_key(k):
-                    a, b = batches[0][k], batches[1][k]
-                    w = max(a.shape[1], b.shape[1])
-                    a = jnp.pad(a, ((0, 0), (0, w - a.shape[1])))
-                    b = jnp.pad(b, ((0, 0), (0, w - b.shape[1])))
-                    return jnp.concatenate([a, b])
-                cat = {k: cat_key(k) for k in batches[0]}
-                gg.update([cat], 1, jax.random.key(9))
-            return gg.params
-
-        p_delay = run(True)
-        p_cat = run(False)
-        for k in p_delay:
-            if k.endswith("_bk"):
-                # attention key biases have structurally zero gradient
-                # (softmax shift invariance); Adam's sign-like first step
-                # amplifies pure float noise there — not a semantics issue
-                continue
-            np.testing.assert_allclose(np.asarray(p_delay[k]),
-                                       np.asarray(p_cat[k]),
-                                       rtol=5e-3, atol=5e-5, err_msg=k)
 
 
 class TestCompactTransfer:
@@ -299,53 +255,318 @@ class TestCompactTransfer:
         assert "trg_tok" in arrays
 
 
-class TestFusedDelay:
-    def test_fused_delay_matches_host_loop(self, tmp_corpus, tmp_path):
-        """Shape-uniform micro-batches take the in-jit lax.scan
-        accumulation; it must match the host-side loop bit-for-bit-ish,
-        including per-micro dropout key folding."""
+# ---------------------------------------------------------------------------
+# --optimizer-delay: ONE path for a list of micro-batches (ISSUE 48)
+# ---------------------------------------------------------------------------
+
+_V = 40          # both vocabularies of the delay tests' model
+
+
+def _micro_batch(seed, rows, width, compact=False):
+    """One hand-made micro-batch [rows, width] of ragged rows, in the
+    ids + mask form or, `compact`, as the loader's own batch through
+    batch_to_arrays (uint16 tokens + row lengths)."""
+    from marian_tpu.data.batch_generator import CorpusBatch, SubBatch
+    rs = np.random.RandomState(seed)
+    subs = []
+    for _ in range(2):
+        lens = rs.randint(2, width + 1, rows)
+        lens[0] = width
+        mask = (np.arange(width)[None] < lens[:, None]).astype(np.float32)
+        ids = (rs.randint(2, _V, (rows, width)) * mask).astype(np.int32)
+        subs.append(SubBatch(ids, mask))
+    batch = CorpusBatch(subs, np.arange(rows), None, None, None)
+    arrays = batch_to_arrays(batch, compact=compact, vocab_sizes=[_V, _V])
+    assert ("trg_tok" in arrays) == compact
+    return arrays, batch
+
+
+def _concatenated(batches):
+    """The micro-batches as ONE batch: rows stacked, widths padded."""
+    out = {}
+    for i, prefix in enumerate(("src", "trg")):
+        width = max(b.sub[i].ids.shape[1] for b in batches)
+        for field in ("ids", "mask"):
+            out[f"{prefix}_{field}"] = np.concatenate([
+                np.pad(getattr(b.sub[i], field),
+                       ((0, 0), (0, width - b.sub[i].ids.shape[1])))
+                for b in batches])
+    return out
+
+
+# rows x width of up to three micro-batches
+_MICRO_SHAPES = {"same": [(8, 9)] * 3,
+                 "widths": [(8, 7), (8, 11), (8, 9)],
+                 "rows": [(16, 9), (8, 9), (24, 9)]}
+
+
+class _DelayRig:
+    """One GraphGroup over the tiny model, not donating, so that every
+    case starts from the same parameters and the jitted programs of one
+    configuration compile once a process."""
+    _made = {}
+
+    def __init__(self, **over):
+        opts = train_options(pathlib.PurePath("unused"), "x", "y", **{
+            "enc-depth": 1, "dec-depth": 1, "optimizer": "sgd",
+            "learn-rate": 0.1, **over})
+        self.model = create_model(opts, _V, _V)
+        self.gg = GraphGroup(self.model, opts, donate=False)
+        self.gg.initialize(jax.random.key(0))
+        self.start = (self.gg.params, self.gg.opt_state)
+
+    @classmethod
+    def get(cls, **over):
+        key = tuple(sorted((k, str(v)) for k, v in over.items()))
+        if key not in cls._made:
+            cls._made[key] = cls(**over)
+        return cls._made[key].reset()
+
+    def reset(self):
+        self.gg.params, self.gg.opt_state = self.start
+        return self
+
+    def after(self, batches, step=1, rng=None):
+        """The parameters' CHANGE over one update from the start."""
+        self.reset().gg.update(batches, step, jax.random.key(9)
+                               if rng is None else rng)
+        return {k: np.asarray(v) - np.asarray(self.start[0][k])
+                for k, v in self.gg.params.items()}
+
+
+def _assert_same_change(got, want):
+    for k in want:
+        # one update of plain SGD is linear in the gradient: the two
+        # differ by float32 summation order only
+        np.testing.assert_allclose(got[k], want[k], rtol=2e-4, atol=2e-7,
+                                   err_msg=k)
+    assert max(np.abs(v).max() for v in want.values()) > 1e-4
+
+
+class TestDelay:
+    """A list of micro-batches has one path: one dispatch of the
+    accumulating gradient program per micro-batch, whatever its shape,
+    then one update from the float32 sum (SyncGraphGroup's accumulation
+    semantics)."""
+
+    @pytest.mark.parametrize("delay", [2, 3])
+    @pytest.mark.parametrize("shapes", sorted(_MICRO_SHAPES))
+    @time_limit(120)
+    def test_delayed_update_equals_the_concatenated_batch(self, shapes,
+                                                          delay):
+        """ce-mean-words: N micro-batches through ONE delayed update move
+        the parameters as one update on their concatenation does. The
+        `rows` cases run on the 8-device mesh (the sum is ZeRO-1 sharded)
+        and ship compact batches, as the trainer's loop does."""
+        wide = shapes == "rows"
+        rig = _DelayRig.get(**({} if wide else {"devices": [0]}))
+        assert rig.gg.mesh.shape["data"] == (8 if wide else 1)
+        micro = [_micro_batch(i, r, w, compact=wide) for i, (r, w)
+                 in enumerate(_MICRO_SHAPES[shapes][:delay])]
+        got = rig.after([a for a, _ in micro])
+        want = rig.after(_concatenated([b for _, b in micro]))
+        _assert_same_change(got, want)
+
+    @pytest.mark.parametrize("cost_type", ["ce-sum", "ce-mean",
+                                           "ce-mean-words"])
+    @time_limit(120)
+    def test_cost_types_normalize_over_the_whole_sum(self, cost_type):
+        """ce-sum divides by nothing, ce-mean by the SENTENCES of all
+        micro-batches, ce-mean-words by their labels: micro-batches of
+        other rows and widths against their concatenation; without the
+        clip, which would hide a wrong divisor."""
+        rig = _DelayRig.get(**{"devices": [0], "cost-type": cost_type,
+                               "clip-norm": 0.0, "learn-rate": 0.01})
+        micro = [_micro_batch(10 + i, r, w) for i, (r, w)
+                 in enumerate([(16, 7), (8, 12)])]
+        got = rig.after([a for a, _ in micro])
+        want = rig.after(_concatenated([b for _, b in micro]))
+        _assert_same_change(got, want)
+
+    @time_limit(120)
+    def test_dropout_keys_fold_by_step_then_by_micro_batch(self):
+        """With dropout on, micro-batch i's gradients are model.loss's
+        under fold_in(fold_in(rng, step - 1), i): a hand accumulation
+        through the update program gives the delayed update's result."""
         import jax.numpy as jnp
+        rig = _DelayRig.get(**{"devices": [0], "transformer-dropout": 0.1})
+        gg, (p0, o0) = rig.gg, rig.start
+        micro = [_micro_batch(20 + i, r, w)[0] for i, (r, w)
+                 in enumerate([(8, 9), (8, 6)])]
+        step, rng = 3, jax.random.key(5)
+        base = jax.random.fold_in(rng, step - 1)
+        total = {"grads": {k: jnp.zeros(v.shape, jnp.float32)
+                           for k, v in p0.items()},
+                 "ce_sum": 0.0, "labels": 0.0}
+        grads_of = jax.jit(jax.value_and_grad(
+            lambda p, b, key: rig.model.loss(p, b, key, train=True),
+            has_aux=True))
+        for i, b in enumerate(micro):
+            (_, aux), g = grads_of(p0, b, jax.random.fold_in(base, i))
+            total = {"grads": {k: total["grads"][k] + g[k] for k in g},
+                     "ce_sum": total["ce_sum"] + aux["ce_sum"],
+                     "labels": total["labels"] + aux["labels"]}
+        want_p, _, metrics = gg._update_fn(p0, o0, total, np.float32(step),
+                                           np.float32(16))
+        got = rig.after(micro, step=step, rng=rng)
+        _assert_same_change(got, {k: np.asarray(v) - np.asarray(p0[k])
+                                  for k, v in want_p.items()})
+        # and the keys matter: the same micro-batches at another step
+        other = rig.after(micro, step=step + 1, rng=rng)
+        assert any(np.abs(other[k] - got[k]).max() > 1e-6 for k in got)
+        assert float(metrics["labels"]) == sum(
+            float(b["trg_mask"].sum()) for b in micro)
+
+    @pytest.mark.parametrize("n", [2, 3])
+    @time_limit(120)
+    def test_n_micro_batches_are_n_plus_one_dispatches(self, n):
+        """Tracer on: an update of N micro-batches of mixed shapes opens
+        exactly N + 1 `train.dispatch` spans under its step number, and a
+        second update of the same shapes retraces nothing."""
+        from marian_tpu import obs
+        rig = _DelayRig.get(**{"devices": [0]})
+        micro = [_micro_batch(30 + i, r, w, compact=True)[0] for i, (r, w)
+                 in enumerate([(8, 9), (16, 5), (8, 9)][:n])]
+        rig.after(micro, step=1)            # compiles, tracer off
+        obs.TRACER.reset()
+        obs.TRACER.enable()
+        try:
+            rig.after(micro, step=2)
+            spans = [s for s in obs.TRACER.snapshot()[0]
+                     if s.name == "train.dispatch"]
+            h2d = obs.TRACER.totals()["train.h2d"]["calls"]
+        finally:
+            obs.TRACER.reset()
+        assert len(spans) == n + 1
+        assert [s.attrs["step"] for s in spans] == [2] * (n + 1)
+        assert [s.attrs["retraced"] for s in spans] == [0] * (n + 1)
+        assert h2d == n
+
+    @time_limit(120)
+    def test_the_running_sum_is_float32_under_bfloat16_gradients(self):
+        """--gradient-dtype bfloat16: gradients are produced in bfloat16
+        (after one micro-batch every element of the sum is a bfloat16
+        value) and summed in float32 (after two it is not)."""
+        import jax.numpy as jnp
+        rig = _DelayRig.get(**{"devices": [0], "gradient-dtype": "bfloat16",
+                               "precision": ["bfloat16", "float32"]})
+        gg = rig.gg
+        step, rng = np.int32(1), jax.random.key(9)
+        total = gg._zero_sum()
+        finer = []
+        for i in range(2):
+            b = _micro_batch(40 + i, 8, 9)[0]
+            total, _ = gg._grad_fn(gg.params, total, b, step, np.int32(i),
+                                   rng)
+            leaves = jax.tree_util.tree_leaves(total["grads"])
+            assert {v.dtype for v in leaves} == {jnp.dtype(jnp.float32)}
+            finer.append(any(
+                bool((v != v.astype(jnp.bfloat16).astype(jnp.float32)).any())
+                for v in leaves))
+        assert finer == [False, True]
+        out = rig.after([_micro_batch(40 + i, 8, 9)[0] for i in range(2)])
+        assert all(np.isfinite(v).all() for v in out.values())
+
+    @time_limit(120)
+    def test_a_skipped_delayed_update_costs_nothing(self):
+        """--check-gradient-nan: one poisoned micro-batch skips the whole
+        update, the parameters stay, and the update reports no cost and no
+        labels, as the fused step does (a NaN cost would read as the
+        divergence the skip just averted)."""
+        rig = _DelayRig.get(**{"devices": [0], "check-gradient-nan": True})
+        micro = [_micro_batch(60 + i, 8, 9)[0] for i in range(2)]
+        micro[1]["trg_mask"] = micro[1]["trg_mask"] * float("nan")
+        out = rig.gg.update(micro, 1, jax.random.key(9))
+        assert float(out.skipped) == 1.0
+        assert float(out.loss_sum) == 0.0 == float(out.labels)
+        for k, v in rig.gg.params.items():
+            np.testing.assert_array_equal(np.asarray(v),
+                                          np.asarray(rig.start[0][k]))
+        clean = rig.after([_micro_batch(60 + i, 8, 9)[0] for i in range(2)])
+        assert max(np.abs(v).max() for v in clean.values()) > 1e-4
+
+    @time_limit(180)
+    def test_step_counters_add_over_the_micro_batches(self):
+        """A layer-plan model's routing counts over one update of two
+        micro-batches are the sums of the two single-batch counts."""
+        from marian_tpu import obs
+        from marian_tpu.common.config_parser import parse_options
+        opts = parse_options([
+            "--type", "transformer-lm", "--transformer-layer-plan",
+            "mla:experts", "--dim-emb", "64", "--transformer-heads", "4",
+            "--transformer-dim-ffn", "128", "--plan-mla-dim-nope", "16",
+            "--plan-mla-dim-shared", "8", "--plan-mla-dim-v", "16",
+            "--plan-mla-latent", "32", "--plan-experts", "16",
+            "--plan-experts-held", "0", "8", "--plan-experts-top-k", "4",
+            "--plan-experts-dim-ffn", "32", "--precision", "float32",
+            "float32", "--train-sets", "x", "--vocabs", "v",
+            "--optimizer-delay", "2", "--devices", "0", "--quiet"],
+            mode="training")
+        model = create_model(opts, 96, 96)
+        gg = GraphGroup(model, opts)
+        gg.initialize(jax.random.key(0))
+        assert gg.delay == 2 and "moe.assignments" in model.step_counters
+
+        def lm_batch(seed, rows, width):
+            b = _micro_batch(seed, rows, width)[0]
+            return {"src_ids": b["trg_ids"], "src_mask": b["trg_mask"],
+                    "trg_ids": b["trg_ids"], "trg_mask": b["trg_mask"]}
+        micro = [lm_batch(50, 2, 24), lm_batch(51, 4, 16)]
+        want = sum(np.asarray(jax.jit(
+            lambda p, b: model.loss(p, b, None, True)[1]["counters"])(
+                gg.params, b)) for b in micro)
+        obs.TRACER.reset()
+        obs.TRACER.enable()
+        try:
+            gg.update(micro, 1, jax.random.key(9))
+            obs.TRACER.fetch_counters()
+            got = obs.TRACER.counters()
+        finally:
+            obs.TRACER.reset()
+        assert got == dict(zip(model.step_counters, want.tolist()))
+        labels = sum(float(b["trg_mask"].sum()) for b in micro)
+        assert got["moe.assignments"] == labels * 4 > 0
+
+
+class TestOneLoader:
+    """The Python BatchGenerator is the trainer's only loader."""
+
+    def test_the_parser_refuses_data_backend(self):
+        from marian_tpu.common.config_parser import parse_options
+        argv = ["--train-sets", "x", "--vocabs", "v"]
+        parse_options(argv, mode="training")
+        with pytest.raises(SystemExit, match="Unknown option.*data-backend"):
+            parse_options(argv + ["--data-backend", "native"],
+                          mode="training")
+
+    @time_limit(180)
+    def test_a_native_loaders_checkpoint_restarts_its_epoch(
+            self, tmp_corpus, tmp_path, monkeypatch):
+        """A checkpoint is input from outside: one whose corpus state the
+        C++ loader of earlier versions saved (`backend: native`, a position
+        in ITS order) resumes at position 0 of its epoch, with a warning,
+        never at the wrong sentence."""
+        from marian_tpu.training import train as T
         src, tgt, _ = tmp_corpus
-        opts = train_options(tmp_path, src, tgt).with_(
-            **{"optimizer-delay": 2, "transformer-dropout": 0.1})
-        vs = DefaultVocab.build(open(src).read().splitlines())
-        vt = DefaultVocab.build(open(tgt).read().splitlines())
-        model = create_model(opts, len(vs), len(vt))
-        rs = np.random.RandomState(3)
-        b = {
-            "src_ids": jnp.asarray(rs.randint(2, len(vs), (8, 9)), jnp.int32),
-            "src_mask": jnp.ones((8, 9), jnp.float32),
-            "trg_ids": jnp.asarray(rs.randint(2, len(vt), (8, 9)), jnp.int32),
-            "trg_mask": jnp.ones((8, 9), jnp.float32),
-        }
-        b2 = {k: jnp.roll(v, 1, axis=0) for k, v in b.items()}
+        opts = train_options(tmp_path, src, tgt, **{
+            "enc-depth": 1, "dec-depth": 1, "after-batches": 1,
+            "devices": [0]})
+        Train(opts).run()
+        load = T.load_checkpoint
 
-        def run(force_host):
-            gg = GraphGroup(model, opts, donate=False)
-            gg.initialize(jax.random.key(0))
-            if force_host:
-                gg._fused_delay = None
-            assert (gg._fused_delay is None) == force_host
-            gg.update([dict(b), dict(b2)], 1, jax.random.key(5))
-            return gg.params
-
-        p_fused = run(False)
-        p_host = run(True)
-        for k in p_host:
-            if k.endswith("_bk"):
-                continue    # see delay-equivalence test above
-            # both paths reduce in the SAME order — Σ_micro RS(g_i); the
-            # fused scan scatters each micro inside the loop (zero.py
-            # _scatter_reduce_body) — so elementwise they agree to
-            # ~1e-4 rel EXCEPT isolated near-zero-gradient coordinates,
-            # where Adam's step-1 m̂/(√v̂+ε) amplifies cross-program
-            # fusion-reassociation noise unboundedly in relative terms.
-            # Assert (a) almost all elements tight, (b) every element
-            # within a fraction of one Adam step (lr=1e-3 here): a
-            # dropout-key or scatter-axis bug perturbs MOST elements by
-            # O(lr) and fails both.
-            a, b = np.asarray(p_fused[k]), np.asarray(p_host[k])
-            loose = ~np.isclose(a, b, rtol=1e-4, atol=2e-6)
-            assert loose.mean() <= 2 / 1024, \
-                f"{k}: {loose.sum()}/{loose.size} elements off"
-            np.testing.assert_allclose(a, b, atol=5e-4, err_msg=k)
+        def saved_by_the_native_loader(path, gg):
+            params, extra, state = load(path, gg)
+            assert state.corpus["backend"] == "python"
+            state.corpus = dict(state.corpus, backend="native", position=5)
+            return params, extra, state
+        restore, restored, warned = T.Corpus.restore, [], []
+        monkeypatch.setattr(T, "load_checkpoint", saved_by_the_native_loader)
+        monkeypatch.setattr(T.Corpus, "restore", lambda self, d: (
+            restored.append(dict(d)), restore(self, d))[1])
+        monkeypatch.setattr(T.log, "warn", lambda msg, *a: warned.append(
+            msg.format(*a)))
+        Train(opts.with_(**{"after-batches": 2})).run()
+        assert restored and restored[0]["position"] == 0
+        assert restored[0]["epoch"] >= 1
+        assert any("saved by the 'native' data backend" in w
+                   and "restarting epoch" in w for w in warned), warned
