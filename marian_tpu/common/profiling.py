@@ -19,6 +19,7 @@ obs/trace.py, live under either of them):
 from __future__ import annotations
 
 import os
+from typing import Optional
 
 from . import logging as log
 
@@ -55,6 +56,21 @@ def enable_compilation_cache() -> str:
     # hit under another directory
     jax.config.update("jax_persistent_cache_enable_xla_caches", "none")
     return path
+
+
+def program_store_dir() -> Optional[str]:
+    """Where the trainer keeps the step programs --precompile-buckets
+    builds (training/program_store.py): ``programs/`` inside the
+    persistent cache's directory, so one setting moves both and JAX's own
+    eviction, which reads the directory's top level only, never sees an
+    entry. None where this process has no persistent cache (neither
+    :func:`enable_compilation_cache` nor $JAX_COMPILATION_CACHE_DIR, or
+    ``jax_enable_compilation_cache`` off): then nothing is kept."""
+    import jax
+    path = jax.config.jax_compilation_cache_dir
+    if not path or not jax.config.jax_enable_compilation_cache:
+        return None
+    return os.path.join(path, "programs")
 
 
 def maybe_start_profile_server(options) -> bool:

@@ -307,8 +307,20 @@ class Tracer:
                 self._counters[name] = self._counters.get(name, 0.0) \
                     + float(sum(column))
 
+    def count(self, name: str, n: float = 1) -> None:
+        """Add `n` to a counter the HOST keeps (a start-up's programs
+        taken from the store): read beside the step counters. Live
+        exactly when spans are; otherwise two flag reads."""
+        if not self._enabled and not profiler_collecting():
+            return
+        with self._lock:
+            if self._counters is None:
+                self._counters = {}
+            self._counters[name] = self._counters.get(name, 0.0) + float(n)
+
     def counters(self) -> Dict[str, float]:
-        """Fetched step counters by name, summed since reset()."""
+        """Counters by name, summed since reset(): the fetched step
+        counters and the host's own."""
         with self._lock:
             return dict(self._counters or {})
 

@@ -29,6 +29,8 @@ from __future__ import annotations
 import dataclasses
 import itertools
 import math
+import threading
+import time
 from concurrent.futures import ThreadPoolExecutor
 from typing import Any, Dict, Optional
 
@@ -47,6 +49,8 @@ from ..parallel import mesh as M
 from ..parallel.zero import (build_grad_fn, build_train_step,
                              finalize_update, place)
 from . import hbm
+from . import program_store
+from .program_store import ProgramStore, Refused, describe
 
 Params = Dict[str, jax.Array]
 
@@ -58,8 +62,24 @@ _PROGRAM_COSTS = (("code", "generated_code_size_in_bytes"),
                   ("alias", "alias_size_in_bytes"))
 
 
+def _costs_of(cost) -> Dict[str, int]:
+    return {field: int(getattr(cost, attr, 0))
+            for field, attr in _PROGRAM_COSTS}
+
+
 def _mb(n: Optional[int]) -> str:
     return "?" if n is None else f"{n / 1e6:.1f} MB"
+
+
+def _how_built(record: Dict[str, Any]) -> str:
+    """One step program's start-up record (`_compile_ahead`) in words."""
+    if record["source"] == "store":
+        return (f"loaded from the program store in {record['load_s']:.1f} s "
+                f"({_mb(record['bytes'])})")
+    return (f"trace {record['trace_s']:.1f} s, lower "
+            f"{record['lower_s']:.1f} s, compile or cache load "
+            f"{record['compile_s']:.1f} s; {_mb(record.get('bytes', 0))} "
+            f"kept in the program store")
 
 
 @dataclasses.dataclass
@@ -428,6 +448,28 @@ class GraphGroup:
                      + _mb(mem["limit"] - mem["in_use"] - temp["temp"]))
         return line
 
+    def _program_store(self) -> Optional[ProgramStore]:
+        """Where this trainer's step programs are kept between starts:
+        beside the persistent cache, so only where the process has one
+        (common/profiling.py::program_store_dir), on a platform whose
+        serialized programs load whole (not the CPU's), and in one
+        process: what a program serialized for devices of several hosts
+        loads as is not tried. The schedule's and the optimizer's fields
+        are traced into the step as they stand NOW (`rebuild`), the
+        options aside."""
+        from ..common.profiling import program_store_dir
+        directory = program_store_dir()
+        if directory is None or jax.process_count() > 1 or \
+                self.mesh.devices.flat[0].platform in \
+                program_store.OFF_PLATFORMS:
+            return None
+        return ProgramStore(directory, self.mesh, self.options, {
+            "schedule": dataclasses.asdict(self.schedule),
+            "optimizer": dataclasses.asdict(self.opt_cfg),
+            "model": [type(self.model).__name__,
+                      repr(getattr(self.model, "cfg", None))],
+            "frozen": sorted(self._frozen_names())})
+
     @staticmethod
     def _shape_key(batch) -> tuple:
         return tuple(sorted((k, tuple(v.shape), str(v.dtype))
@@ -459,28 +501,88 @@ class GraphGroup:
         fused = self._fused
         programs = self._programs = {}
 
-        def compile_one(key, b):
-            exe = fused.lower(p, o, b, step, rng).compile()
+        store = self._program_store()
+        donate = (0, 1) if self._donate else ()
+        if store is not None:
+            state = {"params": describe(p), "opt_state": describe(o),
+                     "step": describe(step), "rng": describe(rng)}
+        # one record a program, appended by its compile thread: where the
+        # executable came from, the seconds and the bytes
+        records = []
+
+        def build(name, b, mark, inputs):
+            """The executable for batch `b`, from the store where it holds
+            one under `mark`; what it says it costs the device; its
+            record."""
+            args, entry, refused = (p, o, b, step, rng), None, 0
+            t0 = time.perf_counter()
+            if store is not None:
+                try:
+                    entry = store.load(mark, inputs)
+                except Refused as e:
+                    log.warn("Step program {}: its entry in the program "
+                             "store is refused and compiled over: {} ({})",
+                             name, e, store.path(mark))
+                    refused = 1
+            if entry is not None:
+                record = {"source": "store", "bytes": entry.bytes,
+                          "load_s": time.perf_counter() - t0}
+                cost = entry.exe.memory_analysis()
+                return (entry.exe, entry.costs if cost is None
+                        else _costs_of(cost), record)
+            t0 = time.perf_counter()
+            traced = fused.trace(*args)
+            t1 = time.perf_counter()
+            lowered = traced.lower()
+            t2 = time.perf_counter()
+            exe = lowered.compile()
+            t3 = time.perf_counter()  # mtlint: ok -- host work: a trace, a lowering and a compile dispatch nothing
+            record = {"source": "compiled", "trace_s": t1 - t0,
+                      "lower_s": t2 - t1, "compile_s": t3 - t2,
+                      "refused": refused}
             # what the compiler says the executable costs the device
             cost = exe.memory_analysis()
-            if cost is not None:
-                programs[key] = {"name": self._program_name(b), **{
-                    field: int(getattr(cost, attr, 0)) for field, attr in
-                    _PROGRAM_COSTS}}
+            costs = None if cost is None else _costs_of(cost)
+            if store is not None:
+                record["bytes"] = store.save(mark, inputs, name, exe,
+                                             lowered.as_text(), costs or {})
+            return exe, costs, record
+
+        def compile_one(key, b, mark, inputs):
+            name = self._program_name(b)
+            thread = threading.current_thread().name
+            with obs_trace.span("train.compile_ahead", program=name,
+                                thread=thread) as sp:
+                exe, costs, record = build(name, b, mark, inputs)
+                sp.set_attrs(**record)
+            if store is not None:
+                count = obs_trace.TRACER.count
+                count("startup.store_hits", record["source"] == "store")
+                count("startup.store_misses", record["source"] != "store")
+                count("startup.store_refused", record.get("refused", 0))
+            records.append(record)
+            log.info("Step program {} on {}: {}", name, thread,
+                     _how_built(record))
+            if costs:
+                programs[key] = {"name": name, **costs}
             return exe
 
         pool = ThreadPoolExecutor(self._ahead_threads,
                                   thread_name_prefix="precompile")
-        ahead = {}
+        ahead, held = {}, 0
         for width, rows in shapes:
             b = {k: like(v, (rows, width) if v.ndim == 2
                          else (rows,) + v.shape[1:], v.sharding)
                  for k, v in batch.items()}
+            # the fingerprint costs milliseconds and needs no trace
+            mark, inputs = (None, None) if store is None else \
+                store.fingerprint(dict(state, batch=describe(b)), donate)
+            held += store is not None and store.holds(mark)
             # the futures outlive the pool's handle; its threads end with
             # the last of them (shutdown below)
             key = self._shape_key(b)
             ahead[key] = pool.submit(  # mtlint: transfers
-                compile_one, key, b)
+                compile_one, key, b, mark, inputs)
         pool.shutdown(wait=False)       # the queued compiles still run
         # the ledger's line when the last of them is done, on its compile
         # thread. A future `_step_for` cancels is done on the update path:
@@ -489,11 +591,25 @@ class GraphGroup:
 
         def one_done(future):
             if next(done) == last and not future.cancelled():
+                def total(field):
+                    return sum(r.get(field, 0) for r in records)
+                stored = [r for r in records if r["source"] == "store"]
+                log.info("Step programs ahead: {} from the program store "
+                         "(load {:.1f} s), {} compiled (trace {:.1f} s, "
+                         "lower {:.1f} s, compile or cache load {:.1f} s), "
+                         "{} entries refused; {} in the store's files",
+                         len(stored), total("load_s"),
+                         len(records) - len(stored), total("trace_s"),
+                         total("lower_s"), total("compile_s"),
+                         total("refused"), _mb(total("bytes")))
                 log.info("{}", self._memory_line(hbm.device_memory()))
         for future in list(ahead.values()):
             future.add_done_callback(one_done)
         log.info("Compiling the train step ahead for {} shapes on {} "
-                 "threads", len(ahead), self._ahead_threads)
+                 "threads ({})", len(ahead), self._ahead_threads,
+                 "no program store: no persistent cache" if store is None
+                 else f"{held} of them are in the program store "
+                      f"{store.directory}: loaded, not traced")
         return ahead
 
     def _step_for(self, batch, step, rng):
