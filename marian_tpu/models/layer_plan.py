@@ -667,8 +667,8 @@ def _experts(cfg: PlanConfig, p: Params, lp: str, x, mask):
             flat, mask.reshape(-1), idx, weights, p[f"{lp}_experts_Wg"],
             p[f"{lp}_experts_Wu"], p[f"{lp}_experts_Wd"],
             cfg.experts_first,
-            pool=X.pool_rows(bsz * t, cfg.experts_top_k, cfg.experts_held,
-                             cfg.experts))
+            X.pool_rows(bsz * t, cfg.experts_top_k, cfg.experts_held,
+                        cfg.experts))
     if cfg.experts_shared:
         with jax.named_scope("experts.shared"):
             y = y + X.gated_mlp(flat, p[f"{lp}_shared_Wg"],

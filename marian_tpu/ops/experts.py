@@ -9,68 +9,49 @@ layer. On one chip the layer runs without its exchange.
 
 No [tokens, experts, capacity] one-hots and nothing dropped: the
 assignments that landed here are bucketed by expert, bucket after
-bucket, and that list is computed in three parts, whichever experts
-its rows name.
+bucket, and that list is computed in ONE loop of equal batches whose
+trip count is the list's own length, whichever experts its rows name.
 
-  the FIRST POOL, its first `FIRST_SHARES` even shares (`pool_rows`):
-      one gather, three grouped matmuls (`jax.lax.ragged_dot`:
-      consecutive groups of rows, each against its own expert's matrix)
-      and one scatter-add, all of a fixed shape and ALWAYS run; rows
-      past the end of the list weigh zero. An even share is what even
-      routing would send here, and the pool is shared: one crowded
-      expert uses what the others leave. Every list the records hold
-      fits (the evidence is over FIRST_SHARES), so this part is the
-      whole layer, at the matmuls' own speed and at a cost that does not
-      follow the routing.
-  the SECOND POOL, the next rows of the list up to `POOL_SHARES` shares
-      in all, run only when the list reaches them: the SAME batch once
-      more, one step further down the list. Both are one loop of one or
-      two trips, so the program holds the batch's code once, a list that
-      ends in the first pool runs nothing for the second, forward or
-      backward, and no branch hands back zero gradients to add.
-  what arrived beyond both pools, expert by expert: blocks of `block`
-      rows in a loop with a DYNAMIC trip count, the number of blocks
-      that arrived. Work follows what arrived, at any imbalance: every
-      token on one expert is more blocks, never a dropped token.
+  a BATCH is `rows` rows of the list (`pool_rows`: half of what even
+      routing would send here, from the call's shapes): one gather,
+      three grouped matmuls (`jax.lax.ragged_dot`: consecutive groups of
+      rows, each against its own expert's matrix) and one scatter-add,
+      all of a fixed shape, so the program holds the batch's code once.
+  the LOOP runs it ceil(arrived / rows) times, a trip count the step
+      computes from its routing. Work follows what arrived: a short list
+      is fewer trips, a crowded expert uses what the others leave, every
+      token on one expert is more trips of a matmul whose one group
+      holds every row, and nothing is ever dropped or run for nothing
+      but the last trip's rows past the end of the list, which weigh
+      zero and stay in the last group (the chip's grouped matmul reads
+      past its operand otherwise).
 
-All three are `_held`, whose backward is written out (a while loop has
-no reverse mode): it runs a pool's batch again for its gradients, and
-recomputes a block's activations, instead of keeping either. Under a
-checkpoint that costs nothing (the forward run again there feeds
-nothing and is dropped) UNLESS the caller's checkpoint must give back
-the layer's OUTPUT, which a norm on that output asks for: the forward
-then runs a third time to make it. models/layer_plan.py::_layer keeps
-that output by name across the backward, so the pool runs twice there
-too. Without a checkpoint it is one more forward of the layer for
-activations never held.
+The loop is `_held`, whose backward is written out (a while loop has no
+reverse mode): it runs the loop again, a trip's batch for its gradients,
+which go INTO the accumulators the loop carries (the tokens' gradient is
+scattered into in place, never built a trip at a time), and keeps no
+activations. Under a checkpoint that costs nothing (the forward run
+again there feeds nothing and is dropped) UNLESS the caller's checkpoint
+must give back the layer's OUTPUT, which a norm on that output asks for:
+the forward then runs a third time to make it. models/layer_plan.py::
+_layer keeps that output by name across the backward, so the loop runs
+twice there too. Without a checkpoint it is one more forward of the
+layer for activations never held.
 """
 
 from __future__ import annotations
 
 import functools
 import math
-from typing import Tuple
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 
-BLOCK = 256
-# both pools, in shares of the assignments that even routing sends to
-# the held experts; a block of the loop costs ~4x its matmuls, so the
-# loop is for what no deployment should see
-POOL_SHARES = 3
-# the first pool, which always runs. Sized from the counters: over every
-# line the records hold, a span's mean list (`moe.assignments_held` over
-# `moe.assignments`, in even shares) was 0.4 to 1.42 shares: 0.45-1.2 by
-# seed on a fresh router (PERF.md 6, PR 28), 1.42 at most (ledger, PR
-# 32), 0.4-1.35 where the pool is the whole feed-forward half (PRs
-# 34-38). What lies beyond is the second pool's, and conditional.
-FIRST_SHARES = 1.5
-_POOL_ROWS = 512          # a pool is whole tiles of the grouped matmul
+_POOL_ROWS = 512          # a batch is whole tiles of the grouped matmul
 COUNTERS = ("moe.assignments", "moe.assignments_held", "moe.load_max",
             "moe.load_mean", "moe.dropped", "moe.pool_calls",
-            "moe.second_pool", "moe.loop_rows")
+            "moe.pool_trips", "moe.pool_rows")
 
 SCORES = ("sigmoid", "softmax")
 
@@ -110,35 +91,17 @@ def _arrivals(idx, mask, first: int, count: int):
     return order, sizes, starts
 
 
-def pool_rows(tokens: int, top_k: int, held: int,
-              experts: int) -> Tuple[int, int]:
-    """(first, second): the rows of the pool that always runs,
-    `FIRST_SHARES` times the `tokens * top_k * held / experts`
-    assignments of even routing, and of the one that runs when the list
-    reaches it, the rest of `POOL_SHARES` shares; both in whole tiles."""
+def pool_rows(tokens: int, top_k: int, held: int, experts: int) -> int:
+    """The rows of one batch of the loop: HALF of the `tokens * top_k *
+    held / experts` assignments that even routing sends here, in whole
+    tiles. A trip costs its rows and, whatever they are, one pass over
+    the held experts' matrices, the stacks of their gradients and the
+    tokens' accumulator (on a v5e what ~1500 to ~2800 rows cost, 8 and
+    16 held: PERF.md 6, PR 50), so a batch much under half a share
+    spends on trips what it saves in rows past the list's end, and one
+    much over it computes them."""
     share = -(-tokens * top_k * held // experts)
-    first, both = (math.ceil(shares * share / _POOL_ROWS) * _POOL_ROWS
-                   for shares in (FIRST_SHARES, POOL_SHARES))
-    return first, both - first
-
-
-def _block_rows(e, j, order, sizes, starts, k, block, pooled):
-    """Block j of what expert e's bucket holds beyond its `pooled[e]`
-    rows in the pool: its tokens [block], their slots, and which rows
-    are real."""
-    row = pooled[e] + j * block + jnp.arange(block, dtype=jnp.int32)
-    flat = order[jnp.clip(starts[e] + row, 0, order.shape[0] - 1)]
-    return flat // k, flat % k, row < sizes[e]
-
-
-def _blocks(sizes, e, block, pooled):
-    return (sizes[e] - pooled[e] + block - 1) // block
-
-
-def _pooled(sizes, starts, lo, hi):
-    """Per expert, the rows of its bucket among rows [lo, hi) of the
-    list."""
-    return jnp.clip(starts + sizes, lo, hi) - jnp.clip(starts, lo, hi)
+    return max(1, math.ceil(share / 2 / _POOL_ROWS)) * _POOL_ROWS
 
 
 def _batch_groups(sizes, starts, rows, off, end):
@@ -146,151 +109,96 @@ def _batch_groups(sizes, starts, rows, off, end):
     that is computed up to row `end`: per expert its rows in the batch,
     the last group with the rows past the end besides, whose weight is
     zero. Every row of the batch is in some group: they sum to `rows`."""
-    pooled = _pooled(sizes, starts, off, jnp.clip(end, off, off + rows))
-    return pooled.at[-1].add(rows - jnp.sum(pooled))
+    hi = jnp.clip(end, off, off + rows)
+    mine = jnp.clip(starts + sizes, off, hi) - jnp.clip(starts, off, hi)
+    return mine.at[-1].add(rows - jnp.sum(mine))
 
 
-def _pool_part(y0, x, w, wg, wu, wd, order, sizes, starts, k, rows, off, end):
-    """Rows [off, off + rows) of the bucketed list, as far as they lie
-    before row `end`, in one batch of a fixed shape, added to y0 [T, d]
-    float32. Plain autodiff."""
-    f32 = jnp.float32
-    pos = off + jnp.arange(rows, dtype=jnp.int32)
+def _trips(sizes, rows: int):
+    return (jnp.sum(sizes) + rows - 1) // rows
+
+
+def _batch_rows(i, order, sizes, starts, k, rows):
+    """Batch i of the list: its rows' tokens and slots [rows], which of
+    them lie before the list's end (the others read a token that is
+    there and weigh zero), and its group sizes."""
+    end = jnp.sum(sizes)
+    pos = i * rows + jnp.arange(rows, dtype=jnp.int32)
     flat = order[jnp.minimum(pos, order.shape[0] - 1)]
-    tok, slot = flat // k, flat % k
-    end = jnp.minimum(end, jnp.sum(sizes))
-    dot = functools.partial(
-        jax.lax.ragged_dot, preferred_element_type=f32,
-        group_sizes=_batch_groups(sizes, starts, rows, off, end))
-    xb = x[tok]                                            # [rows, d]
-    h = (jax.nn.silu(dot(xb, wg)) * dot(xb, wu)).astype(x.dtype)
-    wt = jnp.where(pos < end, w[tok, slot], 0.0)
-    return y0.at[tok].add(wt[:, None] * dot(h, wd))
+    return (flat // k, flat % k, pos < end,
+            _batch_groups(sizes, starts, rows, i * rows, end))
 
 
-def _expert_block(xb, wg, wu, wd):
-    a = jnp.dot(xb, wg, preferred_element_type=jnp.float32)
-    u = jnp.dot(xb, wu, preferred_element_type=jnp.float32)
-    sg = jax.nn.sigmoid(a)
-    h = (a * sg * u).astype(xb.dtype)
-    return a, u, sg, h, jnp.dot(h, wd, preferred_element_type=jnp.float32)
+def _batch(xb, w_rows, wg, wu, wd, live, groups):
+    """A batch's rows through their experts, each at its weight:
+    xb [rows, d], w_rows [rows] -> [rows, d] float32. Plain autodiff."""
+    dot = functools.partial(jax.lax.ragged_dot, group_sizes=groups,
+                            preferred_element_type=jnp.float32)
+    h = (jax.nn.silu(dot(xb, wg)) * dot(xb, wu)).astype(xb.dtype)
+    return jnp.where(live, w_rows, 0.0)[:, None] * dot(h, wd)
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(9, 10, 11))
-def _held(x, w, wg, wu, wd, order, sizes, starts, pools, k, block, pool):
-    return _held_fwd(x, w, wg, wu, wd, order, sizes, starts, pools, k, block,
-                     pool)[0]
+@functools.partial(jax.custom_vjp, nondiff_argnums=(8, 9))
+def _held(x, w, wg, wu, wd, order, sizes, starts, k, rows):
+    return _held_fwd(x, w, wg, wu, wd, order, sizes, starts, k, rows)[0]
 
 
 # jitted for its trace cache alone: the primal, the forward rule and every
 # layer of a plan with the same shapes share ONE trace of it (set-up of a
 # plan's thirteen step programs is mostly tracing: PERF.md 7)
-@functools.partial(jax.jit, static_argnums=(9, 10, 11))
-def _held_fwd(x, w, wg, wu, wd, order, sizes, starts, pools, k, block, pool):
-    """The held experts' part of the output, [T, d]: `pools` (an int32
-    scalar, 1 or 2) batches of the first pool's shape over the first
-    rows of the list, the second as far as the second pool goes, and
-    what the buckets hold beyond both in the loop; with it the rows
-    computed."""
-    first, both = pool[0], sum(pool)
-    y = jnp.zeros(x.shape, jnp.float32)
-    if first:
-        y = jax.lax.fori_loop(
-            0, pools, lambda i, y: _pool_part(
-                y, x, w, wg, wu, wd, order, sizes, starts, k, first,
-                i * first, both), y)
-    # both pools are prefixes of the list, so of every bucket
-    pooled = _pooled(sizes, starts, 0, both)
+@functools.partial(jax.jit, static_argnums=(8, 9))
+def _held_fwd(x, w, wg, wu, wd, order, sizes, starts, k, rows):
+    """The held experts' part of the output, [T, d], and the assignments
+    computed: the list in batches of `rows`, as many as it is long."""
+    def batch(i, carry):
+        y, done = carry
+        tok, slot, live, groups = _batch_rows(i, order, sizes, starts, k,
+                                              rows)
+        yb = _batch(x[tok], w[tok, slot], wg, wu, wd, live, groups)
+        return y.at[tok].add(yb), done + jnp.sum(live, dtype=jnp.int32)
 
-    def expert(e, carry):
-        def body(j, carry):
-            y, done = carry
-            tok, slot, valid = _block_rows(e, j, order, sizes, starts, k,
-                                           block, pooled)
-            yb = _expert_block(x[tok], wg[e], wu[e], wd[e])[-1]
-            wt = jnp.where(valid, w[tok, slot], 0.0)
-            return (y.at[tok].add(wt[:, None] * yb),
-                    done + jnp.sum(valid, dtype=jnp.int32))
-        return jax.lax.fori_loop(0, _blocks(sizes, e, block, pooled), body,
-                                 carry)
-
-    y, done = jax.lax.fori_loop(0, wg.shape[0], expert,
-                                (y, jnp.sum(pooled, dtype=jnp.int32)))
-    return (y.astype(x.dtype), done), (x, w, wg, wu, wd, order, sizes,
-                                       starts, pools)
+    y, done = jax.lax.fori_loop(
+        0, _trips(sizes, rows), batch,
+        (jnp.zeros(x.shape, jnp.float32), jnp.zeros((), jnp.int32)))
+    return (y.astype(x.dtype), done), (x, w, wg, wu, wd, order, sizes, starts)
 
 
-@functools.partial(jax.jit, static_argnums=(0, 1, 2))
-def _held_bwd(k, block, pool, res, cts):
-    x, w, wg, wu, wd, order, sizes, starts, pools = res
-    first, both = pool[0], sum(pool)
-    dy = cts[0].astype(jnp.float32)
+@functools.partial(jax.jit, static_argnums=(0, 1))
+def _held_bwd(k, rows, res, cts):
+    x, w, wg, wu, wd, order, sizes, starts = res
     f32 = jnp.float32
-    pooled = _pooled(sizes, starts, 0, both)
-    # dx and dw gather in float32; the stacks of weight gradients are the
-    # weights' own type, as autodiff's sum of the parts' gradients is
-    acc = (jnp.zeros(x.shape, f32), jnp.zeros(w.shape, f32),
-           jnp.zeros_like(wg), jnp.zeros_like(wu), jnp.zeros_like(wd))
-    if first:
-        # a pool's batch again, and its gradients into the accumulators
-        # that the loop below goes on with
-        def batch(i, acc):
-            grads = jax.vjp(
-                lambda *a: _pool_part(jnp.zeros(x.shape, f32), *a, order,
-                                      sizes, starts, k, first, i * first,
-                                      both),
-                x, w, wg, wu, wd)[1](dy)
-            return tuple(a + g.astype(a.dtype) for a, g in zip(acc, grads))
-        acc = jax.lax.fori_loop(0, pools, batch, acc)
+    dy = cts[0]
 
-    def expert(e, carry):
-        # an expert's weight gradients gather in accumulators of their
-        # own and join the stacks once, after its last block
-        def body(j, carry):
-            dx, dw, dwg, dwu, dwd = carry
-            tok, slot, valid = _block_rows(e, j, order, sizes, starts, k,
-                                           block, pooled)
-            xb = x[tok]
-            a, u, sg, h, yb = _expert_block(xb, wg[e], wu[e], wd[e])
-            dyb = dy[tok]
-            dw = dw.at[tok, slot].add(
-                jnp.where(valid, jnp.sum(dyb * yb, axis=-1), 0.0))
-            dyb = (jnp.where(valid, w[tok, slot], 0.0)[:, None]
-                   * dyb).astype(x.dtype)
-            dh = jnp.dot(dyb, wd[e].T, preferred_element_type=f32)
-            da = (dh * u * sg * (1.0 + a * (1.0 - sg))).astype(x.dtype)
-            du = (dh * a * sg).astype(x.dtype)
-            dxb = jnp.dot(da, wg[e].T, preferred_element_type=f32) \
-                + jnp.dot(du, wu[e].T, preferred_element_type=f32)
-            return (dx.at[tok].add(jnp.where(valid[:, None], dxb, 0.0)), dw,
-                    dwg + jnp.dot(xb.T, da, preferred_element_type=f32),
-                    dwu + jnp.dot(xb.T, du, preferred_element_type=f32),
-                    dwd + jnp.dot(h.T, dyb, preferred_element_type=f32))
+    def batch(i, acc):
+        # the batch again, and its gradients into the accumulators: the
+        # rows' own into their tokens' places, the stacks' added whole
+        dx, dw, *stacks = acc
+        tok, slot, live, groups = _batch_rows(i, order, sizes, starts, k,
+                                              rows)
+        dxb, dw_rows, *grads = jax.vjp(
+            lambda *a: _batch(*a, live, groups),
+            x[tok], w[tok, slot], wg, wu, wd)[1](dy[tok].astype(f32))
+        return (dx.at[tok].add(dxb), dw.at[tok, slot].add(dw_rows),
+                *(s + g for s, g in zip(stacks, grads)))
 
-        dx, dw, dwg, dwu, dwd = carry
-        zeros = lambda a: jnp.zeros(a.shape, f32)      # noqa: E731
-        dx, dw, *own = jax.lax.fori_loop(
-            0, _blocks(sizes, e, block, pooled), body,
-            (dx, dw, zeros(wg[0]), zeros(wu[0]), zeros(wd[0])))
-        return (dx, dw) + tuple(
-            stack.at[e].add(g.astype(stack.dtype))
-            for stack, g in zip((dwg, dwu, dwd), own))
-
-    dx, dw, dwg, dwu, dwd = jax.lax.fori_loop(0, wg.shape[0], expert, acc)
+    # every accumulator is its input's own type, as autodiff's sum of the
+    # batches' gradients is (the tokens' [T, d] scattered into in x's: a
+    # float32 one costs a fifth more a row and a pass to convert)
+    dx, dw, *stacks = jax.lax.fori_loop(
+        0, _trips(sizes, rows), batch,
+        tuple(jnp.zeros_like(a) for a in (x, w, wg, wu, wd)))
     ints = tuple(np.zeros(a.shape, jax.dtypes.float0)  # mtlint: ok -- an integer input's cotangent IS a host float0 array; jnp has none
-                 for a in (order, sizes, starts, pools))
-    return (dx.astype(x.dtype), dw.astype(w.dtype), dwg, dwu, dwd) + ints
+                 for a in (order, sizes, starts))
+    return (dx, dw, *stacks) + ints
 
 
 _held.defvjp(_held_fwd, _held_bwd)
 
 
-def held_experts(x, mask, idx, weights, wg, wu, wd, first: int,
-                 block: int = BLOCK, pool: Tuple[int, int] = (0, 0)):
-    """The held experts' part of the layer's output: the first `pool[0]`
-    assignments that arrived in one fixed-shape batch, the next
-    `pool[1]` in another where the list reaches them, the rest in the
-    loop (pool (0, 0): all of it in the loop).
+def held_experts(x, mask, idx, weights, wg, wu, wd, first: int, rows: int):
+    """The held experts' part of the layer's output: the assignments
+    that arrived, bucketed, in ceil(arrived / rows) batches of `rows`
+    (`pool_rows`; whole tiles of the grouped matmul on the chip).
 
     x [T, d]; mask [T] (0 = padding, routed nowhere); idx, weights [T, k]
     from `route`; wg, wu [count, d, f], wd [count, f, d]: the gated MLPs
@@ -298,22 +206,17 @@ def held_experts(x, mask, idx, weights, wg, wu, wd, first: int,
     Returns (y [T, d], counters [8] float32 in the order of COUNTERS:
     assignments of real tokens, those that named a held expert, the
     largest and the mean group of a held expert, assignments that named
-    a held expert and were not computed — always 0 —, this call, whether
-    its second pool ran, and the assignments its loop computed)."""
-    if pool[1] > pool[0]:
-        raise ValueError(f"the second pool runs as one more batch of the "
-                         f"first's shape and cannot be larger: {pool}")
+    a held expert and were not computed — always 0 —, this call, the
+    batches it ran and the rows they computed)."""
     count = wg.shape[0]
     k = idx.shape[1]
     order, sizes, starts = _arrivals(idx, mask, first, count)
     arrived = jnp.sum(sizes)
-    second = (arrived > pool[0]) & (pool[1] > 0)
-    y, done = _held(x, weights, wg, wu, wd, order, sizes, starts,
-                    1 + second.astype(jnp.int32), k, block, tuple(pool))
+    y, done = _held(x, weights, wg, wu, wd, order, sizes, starts, k, rows)
+    trips = _trips(sizes, rows)
     counters = jnp.stack([
         jnp.sum(mask > 0) * k, arrived, jnp.max(sizes), arrived / count,
-        arrived - done, 1, second,
-        done - jnp.minimum(arrived, sum(pool))]).astype(jnp.float32)
+        arrived - done, 1, trips, trips * rows]).astype(jnp.float32)
     return y, counters
 
 

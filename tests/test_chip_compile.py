@@ -175,19 +175,18 @@ def _kda_prep(b, heads, t):
             ["kda_prep_fwd", "kda_prep_bwd"])
 
 
-def _held_experts(tokens):
-    """The layer plan's held experts at the cell's sizes (8 of 256, top
-    8, 2304 -> 1024), both pools of `pool_rows`' pair, the second under
-    its cond: the pools' grouped matmuls must stay the chip's own ragged
-    dot, not 8 dense matmuls under a mask."""
+def _held_experts(tokens, d, f, held, experts):
+    """The layer plan's held experts at a cell's sizes (top 8), the loop
+    of `pool_rows`' batches: its grouped matmuls must stay the chip's own
+    ragged dot, not a dense matmul an expert under a mask."""
     def loss(x, w, wg, wu, wd, idx):
         y, _ = held_experts(x, jnp.ones((tokens,), jnp.float32), idx, w,
                             wg, wu, wd, 0,
-                            pool=pool_rows(tokens, 8, 8, 256))
+                            pool_rows(tokens, 8, held, experts))
         return y.astype(jnp.float32).sum()
-    shapes = [((tokens, 2304), DT), ((tokens, 8), jnp.float32),
-              ((8, 2304, 1024), DT), ((8, 2304, 1024), DT),
-              ((8, 1024, 2304), DT), ((tokens, 8), jnp.int32)]
+    shapes = [((tokens, d), DT), ((tokens, 8), jnp.float32),
+              ((held, d, f), DT), ((held, d, f), DT),
+              ((held, f, d), DT), ((tokens, 8), jnp.int32)]
     return (jax.grad(loss, argnums=(0, 2, 3, 4)), shapes,
             ["ragged-dot-none"])
 
@@ -291,7 +290,16 @@ CASES = {
     "kda-prep-grad-2x8192": lambda: _kda_prep(2, 4, 8192),
     # an odd number of heads: one to a tile, [64, 64] matrices
     "kda-prep-grad-3-heads": lambda: _kda_prep(2, 3, 256),
-    "held-experts-grad-16384": lambda: _held_experts(16384),
+    # the four plan cells' widest update: kimi-linear, joyai-flash, sdar
+    # (the doubled row), trinity-mini
+    "held-experts-grad-16384": lambda: _held_experts(16384, 2304, 1024, 8,
+                                                     256),
+    "held-experts-grad-16384-16-of-256": lambda: _held_experts(
+        16384, 2048, 768, 16, 256),
+    "held-experts-grad-32768-16-of-128": lambda: _held_experts(
+        32768, 2048, 768, 16, 128),
+    "held-experts-grad-16384-8-of-128": lambda: _held_experts(
+        16384, 2048, 1024, 8, 128),
     # offline decoder: 64 sentences x beam 6, scalar and per-row positions
     "decode-r384-scalar-pos": lambda: _decode(64 * 6, False),
     "decode-r384-row-pos": lambda: _decode(64 * 6, True),

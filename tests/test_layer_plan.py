@@ -335,49 +335,54 @@ def _held_loop(x, router, wg, wu, wd, mask, first):
     return y
 
 
-def _held_layer(x, router, wg, wu, wd, mask, first, block=32, pool=(0, 0)):
+def _held_layer(x, router, wg, wu, wd, mask, first, rows=512):
     idx, w = X.route(x, router, K_, 2.5)
-    return X.held_experts(x, mask, idx, w, wg, wu, wd, first, block, pool)
+    return X.held_experts(x, mask, idx, w, wg, wu, wd, first, rows)
+
+
+def _whole_batches(n):
+    """The most batches (2 .. 10) that cut a list of n rows evenly."""
+    return max(d for d in range(2, 11) if n % d == 0)
 
 
 # 280 tokens x 4 picks over 16 experts are 70 rows an expert, so the
-# lists here hold ~210 (3 held) to 1120 (all 16) rows. (first pool,
-# second pool), whole or as shares of the list n, and what must run: all
-# of the list in the loop; a first pool that ends inside the second or
-# third bucket, no second; a first pool that holds them all; a list
-# shorter than the first pool (the second skipped); one that ends inside
-# the second; one that passes both, so that the loop runs too
-@pytest.mark.parametrize("pool,second,loop", [
-    ((0, 0), False, True), ((136, 0), False, True),
-    ((1536, 0), False, False), ((1536, 512), False, False),
-    ((0.6, 0.5), True, False), ((0.3, 0.25), True, True)],
-    ids=["loop", "first-loop", "first", "first-skip", "first-second",
-         "first-second-loop"])
+# lists here hold 194 (3 held) to 1120 (all 16) rows. A batch's rows,
+# given or from the list's length n, and the trips that must run: a list
+# shorter than one batch; one that ends exactly on a batch's edge (after
+# one batch, after several); one of several batches that ends inside the
+# last; batches smaller than a bucket, so that many trips run and a
+# bucket spans several of them (what a loop of blocks was once kept for)
+@pytest.mark.parametrize("rows,trips", [
+    (1536, 1), (lambda n: n, 1),
+    (lambda n: n // _whole_batches(n), _whole_batches),
+    (lambda n: -(-3 * n // 10), 4), (lambda n: -(-3 * n // 5), 2),
+    (136, None), (32, None)],
+    ids=["short", "edge-1", "edge-several", "several", "two", "many-136",
+         "many-32"])
 @pytest.mark.parametrize("first,count", [(0, 8), (8, 8), (0, 16), (5, 3)])
-def test_held_experts_are_the_masked_loop(first, count, pool, second, loop):
+def test_held_experts_are_the_masked_loop(first, count, rows, trips):
     x, router, wg, wu, wd, mask, kw = _expert_inputs()
     sl = slice(first, first + count)
     idx = X.route(x, router, K_, 2.5)[0]
     n = int(jnp.sum((idx >= first) & (idx < first + count)
                     & (mask[:, None] > 0)))
-    pool = tuple(int(p * n) if isinstance(p, float) else p for p in pool)
+    rows, trips = (f(n) if callable(f) else f for f in (rows, trips))
     want = _held_loop(x, router, wg[sl], wu[sl], wd[sl], mask, first)
     got, counters = _held_layer(x, router, wg[sl], wu[sl], wd[sl], mask,
-                                first, pool=pool)
+                                first, rows)
     np.testing.assert_allclose(got, want, atol=2e-5)
     counts = dict(zip(X.COUNTERS, np.asarray(counters).tolist()))
     assert counts["moe.assignments"] == 280 * K_ and counts["moe.dropped"] == 0
     assert counts["moe.assignments_held"] == n
     assert counts["moe.pool_calls"] == 1.0
-    assert counts["moe.second_pool"] == float(second) \
-        == float(n > pool[0] and pool[1] > 0)
-    assert counts["moe.loop_rows"] == max(n - sum(pool), 0.0)
-    assert (counts["moe.loop_rows"] > 0) == loop
+    assert counts["moe.pool_trips"] == -(-n // rows)
+    assert trips is None or counts["moe.pool_trips"] == trips
+    assert counts["moe.pool_rows"] == counts["moe.pool_trips"] * rows >= n
     w = jax.random.normal(kw, want.shape)
     grads = [jax.grad(lambda *a: jnp.sum(f(*a, mask, first) * w),
                       argnums=(0, 1, 2, 3, 4))(x, router, wg[sl], wu[sl],
                                                wd[sl])
-             for f in (lambda *a: _held_layer(*a, pool=pool)[0], _held_loop)]
+             for f in (lambda *a: _held_layer(*a, rows)[0], _held_loop)]
     for g_got, g_want in zip(*grads):
         np.testing.assert_allclose(g_got, g_want, atol=1e-4)
 
@@ -399,33 +404,44 @@ def test_a_batch_s_groups_hold_every_row_of_it(end):
         assert groups.tolist() == want and groups.min() >= 0
 
 
-def _ragged_dot_rows(jaxpr, inside=False, found=None):
-    """[(rows of a grouped matmul's left operand, whether it sits inside
-    a while loop's body)] over a jaxpr and everything it calls."""
-    found = [] if found is None else found
+def _held_grad_eqns(jaxpr=None, inside=False):
+    """(equation, whether it sits inside a while loop's body) over the
+    jaxpr of the held layer's gradient (a batch of 256 rows) and
+    everything it calls."""
+    if jaxpr is None:
+        x, router, wg, wu, wd, mask, _ = _expert_inputs()
+        grad = jax.grad(
+            lambda *a: jnp.sum(_held_layer(*a, mask, 0, 256)[0]),
+            argnums=(0, 2, 3, 4))
+        jaxpr = jax.make_jaxpr(grad)(x, router, wg, wu, wd).jaxpr
     for eqn in jaxpr.eqns:
-        if eqn.primitive.name.startswith("ragged_dot"):
-            found.append((eqn.invars[0].aval.shape[0], inside))
+        yield eqn, inside
         for sub in jax.core.jaxprs_in_params(eqn.params):
-            _ragged_dot_rows(sub, inside or eqn.primitive.name == "while",
-                             found)
-    return found
+            yield from _held_grad_eqns(
+                sub, inside or eqn.primitive.name == "while")
 
 
-def test_a_skipped_second_pool_runs_nothing():
-    """Both pools are ONE batch of the first's shape in a loop that runs
-    once or twice, forward (3 grouped matmuls) and backward (the 3 again
-    and their 6 transposes): a list that ends in the first pool runs the
-    batch once and nothing stands outside the loop for the second — no
-    second copy of the code, no zero gradients to add."""
-    x, router, wg, wu, wd, mask, _ = _expert_inputs()
-    pool = (1536, 512)             # 1120 rows at most: the second skipped
-    grad = jax.grad(lambda *a: jnp.sum(_held_layer(*a, mask, 0, pool=pool)[0]),
-                    argnums=(0, 2, 3, 4))
-    dots = _ragged_dot_rows(jax.make_jaxpr(grad)(x, router, wg, wu, wd).jaxpr)
-    assert dots == [(1536, True)] * 12
-    with pytest.raises(ValueError, match="cannot be larger"):
-        _held_layer(x, router, wg, wu, wd, mask, 0, pool=(512, 1024))
+def test_the_program_holds_the_batch_once():
+    """The list is ONE batch in a loop that runs as often as the list is
+    long, forward (3 grouped matmuls) and backward (the 3 again and
+    their 6 transposes), all of one batch's rows and all inside a while
+    loop's body: no second copy of the code for a longer list, nothing
+    outside the loop that runs whether or not a row arrived."""
+    dots = [(eqn.invars[0].aval.shape[0], inside)
+            for eqn, inside in _held_grad_eqns()
+            if eqn.primitive.name.startswith("ragged_dot")]
+    assert dots == [(256, True)] * 12
+
+
+def test_a_trip_s_gradients_go_into_the_carried_accumulators():
+    """A trip scatters its rows' output, and in the backward their
+    gradient, into the [T, d] accumulator its loop carries:
+    nothing else of the tokens' shape is built inside a loop's body (no
+    zeros to scatter into, no second array to add)."""
+    built = [eqn.primitive.name for eqn, inside in _held_grad_eqns()
+             if inside and any(getattr(v.aval, "shape", None) == (T_, D_)
+                               for v in eqn.outvars)]
+    assert built == ["scatter-add"] * 2, built      # forward, backward
 
 
 @pytest.mark.parametrize("experts,held,width,extra", [
@@ -474,37 +490,49 @@ def test_the_shares_add_up(experts, held, width, extra):
     assert models[0].cfg.experts_scale == (2.826 if extra else 2.446)
 
 
-@pytest.mark.parametrize("pool", [(0, 0), (64, 0)])
-def test_no_token_is_dropped_when_all_pick_one_expert(pool):
+@pytest.mark.parametrize("rows", [64, 20])
+def test_no_token_is_dropped_when_all_pick_one_expert(rows):
     x, _, wg, wu, wd, mask, _ = _expert_inputs()
     idx = jnp.tile(jnp.array([[3, 17, 18, 19]]), (T_, 1))   # 3 is held
     w = jnp.full((T_, 4), 0.25)
     y, counters = jax.jit(lambda: X.held_experts(
-        x, mask, idx, w, wg[:8], wu[:8], wd[:8], 0, 32, pool))()
+        x, mask, idx, w, wg[:8], wu[:8], wd[:8], 0, rows))()
     want = 0.25 * mask[:, None] * (
         (jax.nn.silu(x @ wg[3]) * (x @ wu[3])) @ wd[3])
     np.testing.assert_allclose(y, want, atol=2e-5)
-    # all 280 on one expert: 64 in the first pool and 216 in the loop,
-    # or all of them in the loop; no second pool either way
+    # all 280 on one expert: T k / R trips (5 of 64 rows, 14 of 20) of a
+    # grouped matmul whose one group holds every row
+    trips = -(-280 // rows)
     assert [float(c) for c in counters] == [
-        1120.0, 280.0, 280.0, 35.0, 0.0, 1.0, 0.0, 280.0 - pool[0]]
-    # an expert nobody picked costs no block: nothing arrives, nothing runs
+        1120.0, 280.0, 280.0, 35.0, 0.0, 1.0, trips, trips * rows]
+    # an expert nobody picked costs no trip: nothing arrives, nothing runs
     none, c0 = X.held_experts(x, mask, idx + 20, w, wg[:8], wu[:8], wd[:8],
-                              0, 32, pool)
-    assert float(jnp.abs(none).max()) == 0.0 and float(c0[1]) == 0.0
+                              0, rows)
+    assert float(jnp.abs(none).max()) == 0.0
+    assert [float(c0[i]) for i in (1, 6, 7)] == [0.0, 0.0, 0.0]
 
 
-def test_the_pool_is_a_few_even_shares_in_whole_tiles():
-    # the cell: 16384 tokens x top 8, 8 of 256 experts held: 4096 a share
-    # (first, second): 1.5 shares that always run, then up to 3 in all
-    assert X.pool_rows(16384, 8, 8, 256) == (6144, 6144)
-    assert X.pool_rows(11264, 8, 8, 256) == (4608, 4096)  # 8448 and up
-    assert X.pool_rows(48, 4, 8, 32) == (512, 0) == X.pool_rows(1, 1, 1, 64)
-    # 16 of 256 held at 16384 tokens; 16 of 128 at the doubled row
-    assert X.pool_rows(16384, 8, 16, 256) == (12288, 12288)
-    assert X.pool_rows(32768, 8, 16, 128) == (49152, 49152)
-    for sizes in ((16384, 8, 8, 256), (11264, 8, 8, 256), (48, 4, 8, 32)):
-        assert all(rows % 512 == 0 for rows in X.pool_rows(*sizes))
+def test_a_batch_is_half_a_share_in_whole_tiles():
+    # the four cells at their widest update (tokens, top k, held, experts):
+    # half of the even share, 256 rows a held expert or more, and a third
+    # of the 1.5 shares that once ALWAYS ran, so no list there is given
+    # more rows than it was
+    for sizes, rows in (((16384, 8, 8, 256), 2048),
+                        ((16384, 8, 16, 256), 4096),
+                        ((32768, 8, 16, 128), 16384),
+                        ((16384, 8, 8, 128), 4096)):
+        tokens, top_k, held, experts = sizes
+        share = tokens * top_k * held // experts
+        assert X.pool_rows(*sizes) == rows == share // 2
+        assert (3 * share // 2) % rows == 0 == rows % 512
+        assert rows >= 256 * held
+    # a narrower update's batch follows its share, rounded up to a tile;
+    # a call too small for one gets one
+    assert X.pool_rows(11264, 8, 8, 256) == 1536
+    assert X.pool_rows(48, 4, 8, 32) == 512 == X.pool_rows(1, 1, 1, 64)
+    # a plan that holds ALL its experts: a list of exactly one share on
+    # every call, two trips, where 1.5 shares ran
+    assert X.pool_rows(4096, 8, 64, 64) == 4096 * 8 // 2
 
 
 # ---------------------------------------------------------------------------
@@ -690,7 +718,8 @@ def test_counters_leave_the_step_lazily_and_reach_the_tracer(tiny):
     # every expert layer's call is counted; at this size the first pool
     # is one tile and there is no second: what passes it is the loop's
     assert counts["moe.pool_calls"] == layers
-    assert counts["moe.second_pool"] == 0.0 <= counts["moe.loop_rows"]
+    assert counts["moe.pool_calls"] <= counts["moe.pool_trips"]
+    assert counts["moe.pool_rows"] >= counts["moe.assignments_held"]
     TRACER.reset()
     TRACER.count_lazy(names, aux["counters"])          # off: not kept
     TRACER.fetch_counters()
