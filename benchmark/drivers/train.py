@@ -18,13 +18,14 @@ holds), so one driver serves them all.
 
 import itertools
 import os
+import re
 import shutil
 import tempfile
 import time
 
 import numpy as np
 
-from benchmark import corpus, jaxside, manifest
+from benchmark import corpus, jaxside, manifest, trace_reduce
 
 # what this driver reads from a configuration's file
 CONFIG_KEYS = ("reference", "task_flags", "streams", "kernels",
@@ -155,13 +156,14 @@ def place_held_experts(ctx, params, shapes):
     layer by layer in order, ONE pass: a layer is placed on the input
     that the layers placed before it give. Without it the cells' work
     followed the seed's draw of a router that is never trained (the
-    expert pool's second half runs only where a list passes 1.5 even
-    shares). The placement is made on the INITIALISER: the routers'
-    inputs train from the first update on, so over the window a layer's
-    held share still drifts from the even share (PERF.md 6, PR 43), and
-    what a list then does at the pool's 1.5 shares is the program's
-    (marian_tpu/ops/experts.py::FIRST_SHARES), not the traffic's. Returns
-    the parameters."""
+    held experts' list is computed in batches of half an even share, as
+    many as it is long: marian_tpu/ops/experts.py::pool_rows, PR 50).
+    The placement is made on the INITIALISER: the routers' inputs train
+    from the first update on, so over the window a layer's held share
+    still drifts from the even share (PERF.md 6, PR 43), and the work
+    follows the lists' lengths; how the program computes a list of a
+    given length is the program's, not the traffic's. Returns the
+    parameters."""
     ref = ctx.cell.reference
     if not hasattr(ref, "routed_layers"):
         return params
@@ -197,6 +199,49 @@ def place_held_experts(ctx, params, shapes):
             name, arrivals = walk.send(router)
     except StopIteration:
         return params
+
+
+def kernels_in_step(cell, dumped):
+    """Which of the configuration's kernel FAMILIES the compiled step
+    holds: `dumped` is every kernel name in the step programs JAX handed
+    the compiler (jaxside.kernels_dumped), and a kernel belongs to the
+    longest family its name holds. Returns (a note, problems).
+
+    A kernel's presence describes the path and proves nothing about the
+    result, so an absent family is REPORTED, not demanded (PR 51): which
+    kernels a step runs is the program's to change. One rule stays, the
+    one by which a claim is judged: a family that a roofline metric of
+    the cell totals may leave the step only where the cell also reports
+    the whole step's share of the chip's peak, a per-layer metric with
+    `mfu` as a part of its name that moves the same end-to-end metric;
+    its roofline then falls silent (the reader returns nothing) and the
+    step's share still bounds the claim. A cell that reports no such
+    share would lose its only reading of that work without a trace."""
+    families = tuple(cell.config["kernels"])
+    held = {}
+    for k in sorted(dumped):
+        held.setdefault(trace_reduce.kernel_of(k, families), []).append(k)
+    absent = [f for f in families if f not in held]
+    step_shares = {m["moves"] for m in cell.per_layer
+                   if "mfu" in re.split(r"[._-]", m["name"])}
+    unbounded = sorted({
+        f for m in cell.per_layer if m["moves"] not in step_shares
+        for f in manifest.load_layer_metric(m["name"], cell.root)["args"]
+        .get("kernels", ()) if f in absent})
+
+    def label(f):
+        return f if f in families else \
+            f"{f or 'no family'} (not the configuration's)"
+    note = "kernels in the step programs: " + ("; ".join(
+        f"{label(f)}: {', '.join(ks)}" for f, ks in held.items()) or "none")
+    if absent:
+        note += (f"; ABSENT of the configuration's families: "
+                 f"{', '.join(absent)} (reported, not demanded: a roofline "
+                 f"of an absent family reads nothing)")
+    problems = [f"kernels missing from the compiled step: {unbounded} "
+                f"(a roofline totals them, and the cell reports no `mfu` "
+                f"share of the whole step beside it)"] if unbounded else []
+    return note, problems
 
 
 def build_program(ctx, work, chips):
@@ -266,8 +311,8 @@ def run(ctx):
     from marian_tpu.training.training_state import TrainingState
 
     traffic, dims, config = ctx.cell.traffic, ctx.dims, ctx.cell.config
-    # the compiled step must hold these; the traced run totals their time
-    kernels_wanted = tuple(config["kernels"])
+    # kernel families: the traced run totals each one's device time
+    families = tuple(config["kernels"])
     step_flops = manifest.load_cost(config["train_flops"], ctx.cell.root)
     chips = len(devs)
     sync_every = int(traffic["sync_every"])
@@ -379,9 +424,7 @@ def run(ctx):
         finite = all(np.isfinite(c) for c in costs)
         q = max(1, len(costs) // 4)
         falling = float(np.mean(costs[-q:])) < float(np.mean(costs[:q]))
-        kernels = jaxside.kernels_dumped(ir_dir)
-        missing = [k for k in kernels_wanted if k not in kernels] \
-            if devs[0].platform == "tpu" else []
+        dumped = jaxside.kernels_dumped(ir_dir)
         n_compiles = compiles.count_between(wall0, wall1)
         real = sum(b.words for b in in_window)
         padded = sum(b.trg.batch_size * b.trg.batch_width
@@ -397,9 +440,10 @@ def run(ctx):
             problems.append(f"cost did not fall: first quarter "
                             f"{np.mean(costs[:q]):.4f}, last "
                             f"{np.mean(costs[-q:]):.4f}")
-        if missing:
-            problems.append(f"kernels missing from the compiled step: "
-                            f"{missing}")
+        if devs[0].platform == "tpu":   # elsewhere kernels are interpreted
+            note, missing = kernels_in_step(ctx.cell, dumped)
+            ctx.note(note)
+            problems += missing
         if n_compiles:
             problems.append(f"{n_compiles} compiles inside the window")
         ctx.note(f"{len(in_window)} updates in {window_s:.3f}s (waiting for "
@@ -431,7 +475,7 @@ def run(ctx):
                            if m["name"].split(".")[0] == "train_tok_s_chip"},
             "device": device,
             "obs": {"values": values,
-                    "trace": tw.reduce(kernels_wanted),
+                    "trace": tw.reduce(families),
                     "traced_work": traced_work},
         }
     finally:

@@ -34,8 +34,10 @@ and what the train driver reads (its CONFIG_KEYS):
 
   streams               2: a parallel corpus, two vocabularies; 1: one
                         file, one vocabulary, the lines as documents
-  kernels               the named kernels the compiled step must hold for
-                        `correct`; the traced run totals their time
+  kernels               the kernel FAMILIES of the step (`flash_attention`,
+                        not `flash_attention_dq`): the run notes which the
+                        compiled step holds, the traced run totals each
+                        one's device time; none is demanded for `correct`
   train_flops           the cost function of one step's model FLOPs
   built                 {key of the file: what the program built}, compared
                         before any run; a value is a field of the model's

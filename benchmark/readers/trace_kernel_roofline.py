@@ -1,9 +1,11 @@
 """A kernel's share of its roofline, in percent: the least time the chip's
 peaks allow for the calls' shapes (the metric file's `cost`, a function of
 benchmark/kernel_costs.py or `module:function`; the larger of operations
-over peak FLOP/s and bytes over peak bytes/s) over the kernel's summed
-device time in the trace. No clamp: a reading over 100 means the cost
-function or the matching is wrong."""
+over peak FLOP/s and bytes over peak bytes/s) over the summed device time
+of the kernel FAMILIES the metric file names (`args.kernels`; whichever
+kernels implement a family are charged the same work). A family that has
+left the step has no time and the reader returns nothing. No clamp: a
+reading over 100 means the cost function or the matching is wrong."""
 
 from benchmark import kernel_costs, manifest
 

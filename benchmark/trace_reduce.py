@@ -10,7 +10,10 @@ beside lines "XLA Modules" (one event per program run), "Async XLA Ops"
 and "TC Overlay". An op event's name is the whole HLO instruction text,
 "%fusion.12 = f32[...] fusion(...)"; the instruction's own name comes
 first, and a Pallas kernel's is made from its pallas_call `name=`
-("%fixture_kernel.1", "%jvp_packed_attention_fwd_.31"). Host threads are
+("%fixture_kernel.1", "%jvp_packed_attention_fwd_.31"). A kernel is told
+by the FAMILY its name holds (`flash_attention` in `flash_attention_dkv`),
+never by the name itself: which kernels implement a family is the
+program's to change (PR 51). Host threads are
 lines of the "/host:CPU" plane; TraceAnnotation spans (the drivers'
 `bench.*`) are events on the thread that opened them. In the recorded
 fixture the device's events lie about 1 ms EARLIER than the host calls
@@ -19,18 +22,19 @@ better, so a gap shorter than that may be named for the wrong span.
 """
 
 import glob
+import math
 import os
 import re
 
 WINDOW_SPAN = "bench.window"
 OP_LINE = "XLA Ops"
-# every named pallas_call of the program (PR 21): an op belongs to the
-# LONGEST of these its name holds, so `decode_attention` never claims a
-# `paged_decode_attention` op
-KNOWN_KERNELS = ("packed_attention_fwd", "packed_attention_bwd",
-                 "flash_attention_fwd", "flash_attention_dq",
-                 "flash_attention_dkv", "fused_ce_fwd", "fused_ce_dx",
-                 "fused_ce_dw", "decode_attention", "paged_decode_attention")
+# every kernel family of the program (its pallas_call names hold one
+# each): an op belongs to the LONGEST of these its name holds, so
+# `decode_attention` never claims a `paged_decode_attention` op, and
+# `kda_prep` is here so that it is told from `kda_chunk`
+KNOWN_KERNELS = ("packed_attention", "flash_attention", "fused_ce",
+                 "kda_chunk", "kda_prep", "decode_attention",
+                 "paged_decode_attention")
 
 
 def op_name(event_name):
@@ -40,9 +44,10 @@ def op_name(event_name):
     return re.sub(r"[._]*\d*$", "", head) or head
 
 
-def kernel_of(name, candidates):
-    """The longest candidate kernel name the op's name holds, or None."""
-    held = [k for k in candidates if k in name]
+def kernel_of(name, families=()):
+    """The longest family the op's or kernel's name holds, of `families`
+    and KNOWN_KERNELS, or None."""
+    held = [k for k in (*KNOWN_KERNELS, *families) if k in name]
     return max(held, key=len) if held else None
 
 
@@ -120,11 +125,14 @@ def _name_gap(gap, host):
 
 
 def reduce_trace(path, kernels=()):
-    """Reduce one .xplane.pb. `kernels`: pallas_call names to total.
-    Returns None when the trace holds no device op at all."""
+    """Reduce one .xplane.pb. `kernels`: the kernel families to total,
+    each the device time of every op whose name holds it (added with
+    `math.fsum`, so a total depends neither on the events' order nor on
+    how many kernels share it: the trace's whole nanoseconds add up
+    exactly, and a family's total IS the sum of its kernels'). Returns
+    None when the trace holds no device op at all."""
     from jax.profiler import ProfileData
     pd = ProfileData.from_file(path)
-    candidates = tuple(set(KNOWN_KERNELS) | set(kernels))
     host = _host_events(pd)
     window = None
     for s, e, name in host:
@@ -149,7 +157,7 @@ def reduce_trace(path, kernels=()):
     n = len(per_device)
     busy = 0.0
     ops, gaps = {}, []
-    kernel_s = {k: 0.0 for k in kernels}
+    kernel_ns = {k: [] for k in kernels}
     for i, events in enumerate(per_device):
         # everything below is of the window only: events clipped to it
         events = [(max(s, lo), min(e, hi), name) for s, e, name in events
@@ -159,9 +167,9 @@ def reduce_trace(path, kernels=()):
         for name, ns in self_times(events):
             ops[name] = ops.get(name, 0.0) + ns / 1e9 / n
         for s, e, name in events:
-            k = kernel_of(name, candidates)
-            if k in kernel_s:
-                kernel_s[k] += (e - s) / 1e9 / n
+            k = kernel_of(name, kernels)
+            if k in kernel_ns:
+                kernel_ns[k].append(e - s)
         if i == 0:       # gaps of the first chip stand for all
             edges = [lo] + [x for iv in merged for x in iv] + [hi]
             gaps = [(edges[j], edges[j + 1])
@@ -178,7 +186,8 @@ def reduce_trace(path, kernels=()):
         "window_s": (hi - lo) / 1e9,
         "busy_s": busy,
         "n_devices": n,
-        "kernel_s": kernel_s,
+        "kernel_s": {k: math.fsum(ns) / 1e9 / n
+                     for k, ns in kernel_ns.items()},
         "device_ops": [[k, v] for k, v in top_ops],
         "idle_gaps": [[k, v] for k, v in top_gaps],
         "longest_gap_s": (gaps[0][1] - gaps[0][0]) / 1e9 if gaps else 0.0,

@@ -180,8 +180,7 @@ def test_the_roofline_reader_takes_the_fixture_s_own_cost():
     dims = manifest.load_config("lm-base-cut", FIXTURE)
     work = [{"rows": 64, "src_width": 32, "trg_width": 32}]
     obs = {"trace": {"window_s": 2.0, "busy_s": 1.5,
-                     "kernel_s": {"packed_attention_fwd": 1e-4,
-                                  "packed_attention_bwd": 3e-4}},
+                     "kernel_s": {"packed_attention": 4e-4}},
            "traced_work": work, "dims": dims, "root": FIXTURE,
            "peaks": manifest.load_peaks("TPU v5 lite")}
     read = manifest.load_reader(spec["reader"], FIXTURE).read

@@ -104,7 +104,8 @@ def test_the_file_keeps_the_source_and_declares_its_cuts():
     assert len(plan) == body["num_hidden_layers"] \
         + body["num_nextn_predict_layers"] == 6
     assert plan[0] == "mla:dense" and plan[1:] == ["mla:experts"] * 5
-    assert body["streams"] == 1 and len(body["kernels"]) == 6
+    assert body["streams"] == 1 and body["kernels"] == [
+        "flash_attention", "fused_ce"]
     flags = body["task_flags"]
     assert "--gradient-checkpointing" in flags
 

@@ -79,7 +79,8 @@ def test_the_file_keeps_the_source_and_declares_its_cuts():
     assert len(plan) == body["num_hidden_layers"]
     assert plan[0] == "kda:dense" and plan.count("mla:experts") == 1 \
         and plan.count("kda:experts") == 3        # one whole 3 : 1 period
-    assert body["streams"] == 1 and len(body["kernels"]) == 8
+    assert body["streams"] == 1 and body["kernels"] == [
+        "kda_chunk", "flash_attention", "fused_ce"]
     flags = body["task_flags"]
     assert "--gradient-checkpointing" in flags
     assert flags[flags.index("--precision") + 1:][:2] == ["bfloat16",

@@ -102,7 +102,8 @@ def test_the_file_keeps_the_source_and_declares_its_cuts():
         == body["published"]["num_experts"] // body["num_experts"]
     assert body["router_width"] == 128            # no width is cut
     assert body["layer_plan"] == ["gqa:experts"] * 4
-    assert body["streams"] == 1 and len(body["kernels"]) == 6
+    assert body["streams"] == 1 and body["kernels"] == [
+        "flash_attention", "fused_ce"]
     assert (body["block_length"], body["noise_eps"], body["mask_token_id"]) \
         == (4, 1e-3, 1)
     flags = body["task_flags"]
@@ -170,8 +171,7 @@ def test_cost_functions_against_hand_worked_cases():
         big, whole)[0] / 4 < 7.8e12
     spec = manifest.load_layer_metric(METRIC)
     assert spec == {"reader": "trace_kernel_roofline", "args": {
-        "kernels": ["flash_attention_fwd", "flash_attention_dq",
-                    "flash_attention_dkv"],
+        "kernels": ["flash_attention"],
         "cost": "configs.sdar_costs:block_diffusion_attention_train"}}
     # nothing to read without a trace: no number, no error
     assert manifest.load_reader(spec["reader"]).read({}, spec["args"]) is None

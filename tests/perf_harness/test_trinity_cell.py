@@ -128,7 +128,8 @@ def test_the_file_keeps_the_source_and_declares_its_cuts():
         == [True] + [False] * 4
     assert body["layer_plan"] == ["swa:dense"] + ["swa:experts"] * 3 \
         + ["gqa:experts"]
-    assert body["streams"] == 1 and len(body["kernels"]) == 6
+    assert body["streams"] == 1 and body["kernels"] == [
+        "flash_attention", "fused_ce"]
     flags = body["task_flags"]
     assert "--gradient-checkpointing" in flags \
         and "--plan-gqa-gate" in flags and "--plan-post-norms" in flags
@@ -241,8 +242,7 @@ def test_the_costs_at_the_published_widths():
     assert flops == h * (4 * window + full) * 14 * dh
     assert nbytes == 5 * 2 * t * dh * (5 * h + 6 * 4)
     for name, want in ((ROOFLINE, {"reader": "trace_kernel_roofline", "args": {
-            "kernels": ["flash_attention_fwd", "flash_attention_dq",
-                        "flash_attention_dkv"],
+            "kernels": ["flash_attention"],
             "cost": "configs.trinity_mini_costs:window_attention_train"}}),
             (SHARE, {"reader": "program_counters", "args": {
                 "num": "attn.pairs_seen", "den": "attn.pairs_tiled",
