@@ -109,7 +109,7 @@ _MODEL = [
     _f("transformer-aan-nogate", bool, False, "Disable the AAN input/forget gate", "model"),
     _f("transformer-decoder-autoreg", str, "self-attention", "self-attention, average-attention, rnn", "model"),
     _f("transformer-flash-attention", str, "auto", "Pallas blockwise attention kernel: auto, on, off (TPU extension)", "model"),
-    _f("transformer-packed-attention", str, "auto", "Pallas head-packed short-sequence attention kernel, fills the 128x128 MXU tile with 128//dim-head heads per pass: auto (TPU only), on, off (TPU extension)", "model"),
+    _f("transformer-packed-attention", str, "auto", "Pallas head-packed short-sequence attention kernel, 128//dim-head heads per 128x128 MXU tile pass: auto = off (below the flash threshold attention is XLA's dense einsum, which beat the kernel 2.3-5.5x on a v5e), on forces the kernel up to its length cap, off (TPU extension)", "model"),
     _f("transformer-fused-decode-attention", str, "auto", "Pallas fused beam-gather + cache-update + attention decode step: auto (TPU only), on, off (TPU extension)", "model"),
     _f("fused-ce", str, "auto", "Streaming fused softmax cross-entropy kernel (logit blocks stay in VMEM): auto (TPU only), on, off (TPU extension)", "model"),
     _f("transformer-tied-layers", int, [], "Tie decoder layers to these encoder layers", "model", "*"),

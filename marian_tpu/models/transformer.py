@@ -161,9 +161,9 @@ class TransformerConfig:
     moe_aux_weight: float = 0.01
     flash_attention: str = "auto"             # auto | on | off (Pallas kernel)
     # head-packed short-sequence attention kernel (ops/pallas/
-    # packed_attention.py): fills the 128x128 MXU tile by packing
-    # g = 128//dh heads per pass — the r5 truth-table fix for the
-    # 21.7%/30.6% score/apply einsum geometry. auto = TPU backend only.
+    # packed_attention.py): packs g = 128//dh heads per 128x128 MXU
+    # tile pass. Only "on" selects it: it lost to XLA's dense einsum
+    # at every short shape measured on a v5e, so auto = dense (PR 52).
     packed_attention: str = "auto"            # auto | on | off
     # fused beam-gather + cache-update + attention decode step
     # (ops/pallas/decode_attention.py): folds the beam reorder into the

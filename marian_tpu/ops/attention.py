@@ -64,7 +64,7 @@ def attention(q: jax.Array, k: jax.Array, v: jax.Array,
               packed: str = "auto",
               packed_max_len: Optional[int] = None):
     """Attention dispatcher: dense (XLA-fused einsum) vs the two Pallas
-    kernels — flash (long sequences) and head-packed (short sequences).
+    kernels — flash (long sequences) and head-packed (only when forced).
 
     `mask` is the general [B,1,Tq,Tk] dense mask; `kv_mask` [B,Tk] + `causal`
     is the structured form both Pallas kernels understand. Callers that can,
@@ -81,12 +81,12 @@ def attention(q: jax.Array, k: jax.Array, v: jax.Array,
     structured mask describing the dense one, multi-query step), and (c) for
     "auto", worth it on its regime: flash when the sequence is long enough
     that streaming K/V blocks beats one fused dense batch matmul (crossover
-    measured on v5e ~1-2k); packed when the sequence is SHORT enough that
-    the dh=64-contraction einsums underfill the 128x128 MXU (the r5
-    truth-table 21.7%/30.6% geometry, docs/PERFORMANCE.md) and a head
-    group actually packs (g >= 2, i.e. dh <= 64). Packed 'auto' engages on
-    the TPU backend only — in interpret mode it would just be a slower
-    dense path. Flash owns the overlap: its gate is checked first."""
+    measured on v5e ~1-2k). Below it `auto` is the dense einsum on every
+    backend: the packed kernel costs a tile's latency whatever it holds and
+    lost to the einsum at every short shape read on a v5e, alone and in the
+    train step (the note under the flash return has the readings), so
+    packed="on" alone selects it, up to the auto-tuner's cap; it stays for
+    its tests. Flash owns the overlap: its gate is checked first."""
     if flash_min_len is None:
         # default crossover; --auto-tune rebinds it (ops/auto_tuner.py)
         from .auto_tuner import flash_threshold
@@ -110,16 +110,16 @@ def attention(q: jax.Array, k: jax.Array, v: jax.Array,
     group = q.shape[1] // k.shape[1]
     if group > 1:
         k, v = (jnp.repeat(a, group, axis=1) for a in (k, v))
-    if applicable and packed != "off":
+    # Short sequences, read on a v5e (PRs 31, 52; docs/PERFORMANCE.md):
+    # the einsum below beat the packed kernel 2.3-5.5 x forward + backward
+    # at 4096 words in widths 8-64, at 32 x 128 and at 8 heads of 32
+    # (scripts/attn_microbench.py), and by 8.3 % of big.train's rate in
+    # the step, so no shape class selects the kernel: packed="on" does.
+    if applicable and packed == "on":
         from .auto_tuner import packed_attention_max_t
-        from .pallas.packed_attention import pack_group
-        dh = q.shape[-1]
         cap = (packed_max_len if packed_max_len is not None
-               else packed_attention_max_t(dh))
-        fits = max(q.shape[-2], k.shape[-2]) <= cap
-        wins = pack_group(q.shape[1], dh) >= 2 \
-            and jax.default_backend() == "tpu"
-        if fits and (packed == "on" or wins):
+               else packed_attention_max_t(q.shape[-1]))
+        if max(q.shape[-2], k.shape[-2]) <= cap:
             from .pallas.packed_attention import packed_attention
             return packed_attention(q, k, v, kv_mask=kv_mask,
                                     causal=causal), None

@@ -86,7 +86,7 @@ class AutoTuner:
 KERNEL_BLOCKS = {
     # packed fwd tile peak ~ g*T x g*T f32 scores + operands; T=256 at
     # g=2/dh=64 is ~2.5 MB — comfortably under the kernel's VMEM budget,
-    # and the target regime (T 48-64) is far below the cap anyway
+    # and NMT sentence lengths (T 8-64) are far below the cap anyway
     "packed_attention": {"max_t": 256},
     # decode cell holds 2 x [L, dh] cache blocks + the [1, L] score row;
     # L=2048 at dh=64 f32 is ~1 MB/cache block
@@ -203,8 +203,8 @@ def kernel_block(kernel: str, key: str, dh: int) -> int:
 
 
 def packed_attention_max_t(dh: int) -> int:
-    """Longest (padded) sequence the packed kernel takes per cell; past
-    it the dispatcher leaves the shape to dense/flash.
+    """Longest (padded) sequence the packed kernel takes per cell under
+    packed="on"; past it the dispatcher leaves the shape to dense/flash.
 
     Two VMEM axes bound it: wide heads grow the [T, dh] operand blocks
     (the halving rule above), and NARROW heads grow the pack group g =

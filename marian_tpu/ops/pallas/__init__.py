@@ -4,9 +4,9 @@ The reference's equivalent layer is the hand-written CUDA kernel zoo in
 src/tensors/gpu/ (element.cu, tensor_operators.cu, prod.cpp). Here almost
 all of that collapses into XLA fusion; the kernels that remain are the ones
 where *blockwise scheduling across the memory hierarchy* (HBM->VMEM) is the
-win: flash attention for long sequences, head-packed attention for the
-short-sequence MXU-tile-geometry regime, and the fused beam-gather +
-cache-read decode step.
+win: flash attention for long sequences and the fused beam-gather +
+cache-read decode step. Head-packed attention for short sequences is
+kept behind `--transformer-packed-attention on`: it lost to XLA (PR 52).
 """
 
 from .decode_attention import decode_attention  # noqa: F401

@@ -1,13 +1,13 @@
-"""Head-packed attention as a Pallas TPU kernel — the short-sequence MXU fix.
+"""Head-packed attention as a Pallas TPU kernel — kept, not selected.
 
-The r5 GEMM truth table (docs/PERFORMANCE.md) measured the attention
-score/apply einsums at 21.7%/30.6% of MXU peak at bench shapes: a dh=64
-contraction fills only half the 128-deep systolic array, and a T=48-64
-output fills only ~37-50% of its lanes, so XLA's per-(b,h) batched dot
-burns a full 128x128 tile pass per head while using ~a fifth of it. No
-XLA flag changes tile geometry (the TVM line of work, PAPERS.md, shows
-graph compilers don't recover this class automatically) — the fix is to
-PACK head groups into one full tile, which this kernel does with
+MEASURED on a v5e (PRs 26, 31, 52; docs/PERFORMANCE.md, PERF.md 6): XLA's
+dense einsum beats this kernel 2.3-5.5 x forward + backward at every NMT
+batch shape (4096 words at widths 8-64, 32 x 128, dh 32), alone and in the
+train step (+8.3 % of big.train's rate without it), so since PR 52 only
+`--transformer-packed-attention on` runs it (ops/attention.py). The idea:
+a dh=64 contraction fills half the 128-deep systolic array and a T=48-64
+output ~37-50% of its lanes (the r5 table read those einsums at 21.7% /
+30.6% of MXU peak), so PACK head groups into one full tile with
 block-diagonal operand packing:
 
   scores, per group of g = 128//dh heads (g=2 at dh=64):
@@ -19,10 +19,10 @@ block-diagonal operand packing:
       [Tq, g*Tk] = [p_0 | p_1]  @  diag(v_0, v_1) [g*Tk, g*dh]
       -> [Tq, g*dh] = [o_0 | o_1]: contraction g*Tk = 128, output 128.
 
-The zero blocks double the nominal FLOPs, but the MXU pays per tile PASS,
-not per useful FLOP: two heads per pass at full geometry vs one head per
-pass at ~22% is the win (analytic ~2.3x on the score dot; not measured
-on the chip). The custom VJP keeps full tiles in all four backward dots:
+The zero blocks double the nominal FLOPs, and the MXU pays per tile PASS,
+not per useful FLOP; but a tile is a chain of small dependent ops that
+costs its LATENCY, 166-211 / 309-383 us a call forward / backward whatever
+it holds: that lost. The custom VJP keeps full tiles in all four backward dots:
 dp/dq pack the dh- and Tk-contractions exactly like the forward against
 the same block-diagonal K/V, dk/dv contract the packed probs against the
 lane-concatenated q/do over Tq and read each head's gradient off the
@@ -57,7 +57,7 @@ never written. A batch of 512 rows x 8 words is 512 tiles a call, not
 4096. Past 64 positions a tile is one row, padded to multiples of 64 in
 HBM, as before.
 
-This kernel owns the T <= packed-cap regime (NMT sentence lengths);
+Under "on" this kernel takes T <= packed-cap (NMT sentence lengths);
 flash_attention.py owns the long-sequence end. Same structured-mask
 interface as flash: kv_mask [B, Tk] (1.0 = attend) and/or causal.
 Attention dropout and returned weights fall back to the dense path via

@@ -633,6 +633,37 @@ class TestDispatcherGate:
                          flash="off", packed="auto", return_weights=True)
         assert w is not None
 
+    # big.train's six batches of 4096 words (rows x width) as encoder /
+    # decoder self-attention, and three cross shapes (rows, Tq, Tk)
+    TRAINER_SHAPES = [(rows, t, t, causal)
+                      for rows, t in ((512, 8), (256, 16), (168, 24),
+                                      (128, 32), (80, 48), (64, 64))
+                      for causal in (False, True)] + [
+        (256, 16, 8, False), (80, 32, 48, False), (64, 64, 40, False)]
+
+    @pytest.mark.parametrize("rows,tq,tk,causal", TRAINER_SHAPES)
+    def test_auto_is_dense_on_tpu_and_on_forces_the_kernel(
+            self, monkeypatch, rows, tq, tk, causal):
+        """PR 52: on a v5e the einsum beat the kernel 2.3-5.5 x at every
+        one of these shapes, so 'auto' picks the kernel for none of them
+        on ANY backend; 'on' still does."""
+        monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+        q = jax.ShapeDtypeStruct((rows, 16, tq, 64), jnp.bfloat16)
+        kv = jax.ShapeDtypeStruct((rows, 16, tk, 64), jnp.bfloat16)
+        m = jax.ShapeDtypeStruct((rows, tk), jnp.float32)
+
+        def traced(packed):
+            def f(q, k, v, m):
+                mask = m[:, None, None, :]
+                if causal:
+                    mask = combine_masks(mask, causal_mask(tq))
+                return attention(q, k, v, mask=mask, kv_mask=m,
+                                 causal=causal, flash="off",
+                                 packed=packed)[0]
+            return str(jax.make_jaxpr(f)(q, kv, kv, m))
+        assert "pallas_call" not in traced("auto")
+        assert traced("on").count("pallas_call") == 1
+
     def test_return_weights_forces_dense(self, rng):
         b, h, t, dh = 1, 2, 48, 64
         q, k, v = (_rand(rng, b, h, t, dh), _rand(rng, b, h, t, dh),
