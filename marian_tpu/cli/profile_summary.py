@@ -39,7 +39,7 @@ LEVEL2 = ("encoder", "decoder", "embed", "self_attn", "cross_attn", "ffn",
           "pre_post", "output", "loss", "cast", "clip", "adam", "ema",
           # a layer plan's scopes (models/layer_plan.py)
           "kda", "mla", "mla.rope", "gqa", "gqa.rope", "swa", "swa.rope",
-          "attn.gate", "diffusion.noise",
+          "attn.gate", "conv", "conv.core", "diffusion.noise",
           "experts.route", "experts.compute", "experts.shared", "mtp")
 
 

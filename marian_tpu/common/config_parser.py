@@ -144,7 +144,7 @@ _MODEL = [
     _f("moe-capacity-factor", float, 1.25, "MoE expert capacity factor (tokens beyond capacity fall through the residual)", "model"),
     _f("moe-aux-weight", float, 0.01, "Weight of the MoE load-balancing auxiliary loss", "training"),
     # a decoder-only stack whose layers differ (models/layer_plan.py; TPU extension)
-    _f("transformer-layer-plan", str, [], "--type transformer-lm only: one <mixing>:<feed-forward> entry per layer, mixing kda (delta rule with per-channel decay), mla (latent attention), gqa (grouped-query attention) or swa (gqa under a sliding window), feed-forward dense (gated MLP of --transformer-dim-ffn) or experts; pre-norm RMSNorm, no positions, untied tables", "model", "*"),
+    _f("transformer-layer-plan", str, [], "--type transformer-lm only: one <mixing>:<feed-forward> entry per layer, mixing kda (delta rule with per-channel decay), mla (latent attention), gqa (grouped-query attention), swa (gqa under a sliding window) or conv (a doubly gated short convolution), feed-forward dense (gated MLP of --transformer-dim-ffn) or experts; pre-norm RMSNorm, positions only through a layer's own rotation, the output table a matrix of its own or, under --tied-embeddings, the input table", "model", "*"),
     _f("plan-norm-eps", float, 1e-5, "RMSNorm epsilon of a layer plan", "model"),
     _f("plan-kda-dim-head", int, 128, "kda: key and value channels per head", "model"),
     _f("plan-kda-conv", int, 4, "kda: width of the depthwise causal convolution on q, k, v", "model"),
@@ -165,12 +165,14 @@ _MODEL = [
     _f("plan-experts-shared", int, 1, "experts: shared experts added to every token", "model"),
     _f("plan-experts-scale", float, 1.0, "experts: factor on the renormalised routing weights", "model"),
     _f("plan-experts-score", str, "sigmoid", "experts: the router's scores over all experts, before the top k and their renormalisation: sigmoid (of each logit) or softmax (over them)", "model"),
+    _f("plan-experts-bias-rate", float, 0.0, "experts: > 0 gives every router a selection bias per expert, added to the scores for the top-k choice alone (the routing weights are the scores without it); no gradient reaches it: each update moves it by this much AGAINST the sign of (the expert's load - the mean load) over the update's tokens, summed over the chips that share the data (0: no bias)", "model"),
     _f("plan-gqa-kv-heads", int, 0, "gqa: key/value heads, each read by --transformer-heads / N query heads (0: as many as query heads)", "model"),
     _f("plan-gqa-dim-head", int, 128, "gqa: channels per head of queries, keys and values; queries and keys are RMS-normed per head with a learned scale", "model"),
     _f("plan-gqa-rope-theta", float, 1e6, "gqa: base of the rotation by position of every query and key head, whole heads in half-split pairs (i, i + dim/2), float32 angles (0: gqa layers are not rotated)", "model"),
     _f("plan-gqa-gate", bool, False, "gqa and swa: multiply the heads' output, channel by channel, by sigmoid(W_gate x) before W_o (W_gate [dim-emb, heads x dim-head]; the sigmoid in float32)", "model"),
     _f("plan-swa-window", int, 0, "swa: a gqa layer under a sliding window, a query sees the last N keys up to its own; its sizes are gqa's", "model"),
     _f("plan-swa-rope-theta", float, 1e4, "swa: base of the rotation of its query and key heads, as --plan-gqa-rope-theta is for gqa layers (0: not rotated)", "model"),
+    _f("plan-conv-taps", int, 3, "conv: taps of the depthwise causal convolution between the layer's two gates, the last on the current token (at least 1); the layer's width is --dim-emb", "model"),
     _f("plan-post-norms", bool, False, "a layer plan's block norms each branch's OUTPUT too before the residual add, x + norm(mixing(norm(x))): four RMSNorms a block", "model"),
     _f("plan-diffusion-block", int, 0, "train a plan of gqa layers by diffusion over blocks of N positions: the stack runs over [noised copy ; clean copy] of each row under the block rule and the cost is the masked positions' cross-entropy over the row's noise level (0: next-token training)", "model"),
 ]

@@ -117,6 +117,15 @@ class EncoderDecoder:
         return self._mod.init_params(self.cfg, key)
 
     @property
+    def load_moved(self) -> Tuple[str, float]:
+        """(suffix, rate) of the parameters that the step moves by their
+        load signal and the optimizer leaves alone (parallel/zero.py::
+        take_load_signals); rate 0.0 for every family but a layer plan
+        with --plan-experts-bias-rate."""
+        moved = getattr(self._mod, "load_moved", None)
+        return moved(self.cfg) if moved else ("", 0.0)
+
+    @property
     def step_counters(self) -> Tuple[str, ...]:
         """Names of the counts `loss` returns as aux["counters"] (one lazy
         float32 vector): the family's own, summed over its layers, then

@@ -31,21 +31,39 @@ its feed-forward kind, as data:
                          With --plan-gqa-gate both kinds multiply the
                          heads' output by sigmoid(W_gate x), channel by
                          channel, before W_o
+                conv     a doubly gated short convolution: one projection
+                         of the normed input to three streams of the
+                         model's width, [B, C, h] = split(W_in x); a gate,
+                         z = B * h; a depthwise causal convolution of
+                         --plan-conv-taps taps along time (`short_conv`
+                         of ops/ops.py, the one `kda` runs on q, k, v);
+                         a second gate and one projection back,
+                         W_out(C * conv(z)). No softmax, no activation,
+                         no norm inside, no state beyond the taps' reach
   feed-forward  dense    gated MLP, W_d(SiLU(W_g x) * W_u x)
                 experts  a router over all experts (sigmoid or softmax
                          scores, --plan-experts-score), the held ones
                          computed without dropping (ops/experts.py),
-                         plus shared experts on every token, if any
+                         plus shared experts on every token, if any.
+                         Under --plan-experts-bias-rate the top k are
+                         chosen by score + a per-expert bias and weighed
+                         by the scores without it; no gradient reaches
+                         the bias: an update moves it by the rate against
+                         the sign of its expert's load less the mean
+                         load (`load_moved`, parallel/zero.py)
 
 The block is pre-norm with RMSNorm (scale only) and a residual add,
 x + mixing(norm(x)) then x + feed-forward(norm(x)); with --plan-post-norms
 each branch's OUTPUT is normed too before it is added (four norms a
-block: x + norm(mixing(norm(x)))). Input and output tables untied; a
-final RMSNorm before the output projection. The only positional signal
-is the rotation inside `mla`, `gqa` and `swa`, each where its theta asks
-for it (and, under `swa`, how far back a query sees): the delta rule has
-none, a `gqa` layer at theta 0 has none, and a plan without a rotated
-layer has none anywhere.
+block: x + norm(mixing(norm(x)))). Input and output tables are two
+matrices, or ONE under --tied-embeddings (the output table is the input
+table's transpose, and its gradient arrives from both ends through
+transformer.py's tied path); a final RMSNorm before the output
+projection. The only positional signal is the rotation inside `mla`,
+`gqa` and `swa`, each where its theta asks for it (and, under `swa`, how
+far back a query sees): the delta rule has none, a `conv` layer none but
+its taps' order, a `gqa` layer at theta 0 none, and a plan without a
+rotated layer has none anywhere.
 
 Under --gradient-checkpointing each half of a block is rematerialised in
 the backward on its own, and keeps by name what that would run again on
@@ -56,7 +74,9 @@ projections too (2 x (heads + 2 kv heads) x dim_head; q's, the widest
 where there is no gate, is run again: holding it cost a plan of doubled
 rows the memory its step programs need); either half of a block with
 output norms the branch's output (2 x dim_emb), which the norm's
-backward reads. A `kda` or `mla` half keeps no projection (low rank:
+backward reads; a `conv` half the output of W_in (6 x dim_emb: the one
+matmul its backward would run again, the gates and taps after it are
+element-wise). A `kda` or `mla` half keeps no projection (low rank:
 cheap to run again, as dear to hold). What is kept follows the layer's
 kind and `post_norms`, which the plan states; there is no knob.
 
@@ -83,9 +103,11 @@ These are the families of Kimi Linear
 (https://huggingface.co/moonshotai/Kimi-Linear-48B-A3B-Instruct), of
 DeepSeek-V3 (arXiv:2412.19437, 2.1.1 latent attention, 2.2 multi-token
 prediction), of Qwen3's sparse models trained as block-diffusion
-models (arXiv:2505.09388; arXiv:2503.09573) and of decoders that mix
+models (arXiv:2505.09388; arXiv:2503.09573), of decoders that mix
 window and global attention layers behind an output gate and output
-norms; the sizes come from flags, nothing here knows a model's name.
+norms, and of hybrids whose layers are mostly doubly gated short
+convolutions with a grouped-query attention layer every few; the sizes
+come from flags, nothing here knows a model's name.
 
 The module is one more function family behind models/encoder_decoder.py
 (`init_params`, `encode`, `decode_train`, `output_logits`), next to
@@ -112,14 +134,14 @@ from ..layers import initializers as inits
 from ..ops import experts as X
 from ..ops import kda as K
 from ..ops.attention import attention, causal_mask
-from ..ops.ops import rms_norm
+from ..ops.ops import rms_norm, short_conv
 from ..ops.pallas.flash_attention import (RESIDUAL_LSE, RESIDUAL_OUT,
                                           BlockDiffusion, Window, tile_plan)
 from . import transformer as T
 
 Params = Dict[str, jax.Array]
 
-MIXINGS = ("kda", "mla", "gqa", "swa")
+MIXINGS = ("kda", "mla", "gqa", "swa", "conv")
 # grouped-query attention, causal or under a window: one set of parameters
 # (`_gqa_*`), one function; with `mla` the mixings that are softmax
 # attention through ops/attention.py
@@ -129,8 +151,13 @@ FEED_FORWARDS = ("dense", "experts")
 # what the step carries out beside the loss, summed over the layers
 COUNTERS = X.COUNTERS
 # kept in the optimizer's float32 whatever the compute type: the router
-# decides WHICH experts run, and the decay's rate sits in an exponent
-_FLOAT32_SUFFIXES = ("_experts_router", "_kda_A_log", "_kda_dt_bias")
+# and its selection bias decide WHICH experts run, the decay's rate sits
+# in an exponent, and a `conv` half's few taps multiply float32 products
+_FLOAT32_SUFFIXES = ("_experts_router", "_experts_bias", "_kda_A_log",
+                     "_kda_dt_bias", "_conv_taps")
+# the one leaf no gradient reaches: the step moves it by its experts' load
+# (`load_moved`)
+_LOAD_MOVED = "_experts_bias"
 # What a checkpointed half keeps across the backward beside its input, by
 # NAME (`_keeps`). An `mla`, `gqa` or `swa` half: the flash kernel's output
 # and row statistics
@@ -138,6 +165,8 @@ _FLASH_KEEPS = (RESIDUAL_OUT, RESIDUAL_LSE)
 # a `gqa` or `swa` half besides: three of its four projections' outputs, k
 # before its norm, v, and the gate's before its sigmoid (not q's: `_layer`)
 _PROJECTION_KEEPS = ("gqa_k", "gqa_v", "gqa_gate")
+# a `conv` half: the output of W_in, the three streams before their gates
+_CONV_KEEPS = ("conv_bcx",)
 # either half under `post_norms`: the branch's output, which the output
 # norm's backward reads
 BRANCH_OUT = "plan_branch_out"
@@ -180,6 +209,8 @@ class PlanConfig(T.TransformerConfig):
     swa_window: int = 0
     swa_rope_theta: float = 1e4       # 0: not rotated
     post_norms: bool = False          # a norm on each branch's output
+    # conv: taps of the depthwise causal convolution between its two gates
+    conv_taps: int = 3
     # diffusion over blocks of this many positions; 0: next-token training
     diffusion_block: int = 0
     # experts
@@ -191,6 +222,9 @@ class PlanConfig(T.TransformerConfig):
     experts_score: str = "sigmoid"
     experts_first: int = 0            # the held set: first, count
     experts_held: int = 0
+    # > 0: a selection bias per expert, moved by this much an update
+    # against the sign of (its load - the mean load); 0: no such leaf
+    experts_bias_rate: float = 0.0
 
 
 def _blocks(cfg: PlanConfig):
@@ -251,14 +285,27 @@ def config_from_options(options, src_vocab, trg_vocab, for_inference=False,
     if window < 1 and any(m == "swa" for m, _ in plan):
         raise ValueError(f"--plan-swa-window {window}: a `swa` layer sees "
                          f"1 key or more")
+    taps = int(g("plan-conv-taps", 3))
+    if taps < 1:
+        raise ValueError(f"--plan-conv-taps {taps}: a `conv` layer's "
+                         f"convolution has 1 tap or more (the last weighs "
+                         f"the current token)")
     score = str(g("plan-experts-score", "sigmoid") or "sigmoid")
     if score not in X.SCORES:
         raise ValueError(f"--plan-experts-score {score!r}: one of "
                          f"{X.SCORES}")
+    bias_rate = float(g("plan-experts-bias-rate", 0.0) or 0.0)
+    if bias_rate < 0:
+        raise ValueError(f"--plan-experts-bias-rate {bias_rate}: the "
+                         f"selection bias moves AGAINST its expert's "
+                         f"excess load by this much an update (0: a plan "
+                         f"without the bias)")
     fields = {f.name: getattr(base, f.name)
               for f in dataclasses.fields(base)}
     fields.update(
-        lm=True, dec_depth=len(plan) - ahead, tied_embeddings=False,
+        lm=True, dec_depth=len(plan) - ahead,
+        # `tied_embeddings` stays the base's (--tied-embeddings: the output
+        # table is the input table); there is no source side to tie
         tied_embeddings_all=False, tied_embeddings_src=False,
         output_omit_bias=True)
     return PlanConfig(
@@ -282,6 +329,7 @@ def config_from_options(options, src_vocab, trg_vocab, for_inference=False,
         swa_window=window,
         swa_rope_theta=float(g("plan-swa-rope-theta", 1e4) or 0.0),
         post_norms=bool(g("plan-post-norms", False)),
+        conv_taps=taps,
         diffusion_block=int(g("plan-diffusion-block", 0) or 0),
         experts=n_experts,
         experts_top_k=int(g("plan-experts-top-k", 8)),
@@ -289,7 +337,8 @@ def config_from_options(options, src_vocab, trg_vocab, for_inference=False,
         experts_shared=int(g("plan-experts-shared", 1)),
         experts_scale=float(g("plan-experts-scale", 1.0)),
         experts_score=score,
-        experts_first=first, experts_held=count)
+        experts_first=first, experts_held=count,
+        experts_bias_rate=bias_rate)
 
 
 # ---------------------------------------------------------------------------
@@ -309,7 +358,8 @@ def init_params(cfg: PlanConfig, key: jax.Array) -> Params:
         return inits.ones((1, n))
 
     p["decoder_Wemb"] = glorot(cfg.trg_vocab, d)
-    p["decoder_ff_logit_out_W"] = glorot(d, cfg.trg_vocab)
+    if not cfg.tied_embeddings:
+        p["decoder_ff_logit_out_W"] = glorot(d, cfg.trg_vocab)
     p["decoder_top_norm_scale"] = ones(d)
     for lp, (mix, ffn) in blocks:
         p[f"{lp}_mix_norm_scale"] = ones(d)
@@ -351,6 +401,15 @@ def init_params(cfg: PlanConfig, key: jax.Array) -> Params:
             p[f"{lp}_gqa_Wo"] = glorot(h * dh, d)
             if cfg.gqa_gate:
                 p[f"{lp}_gqa_Wgate"] = glorot(d, h * dh)
+        elif mix == "conv":
+            # three streams of the model's width out of one matrix, each
+            # scaled as a [d, d] projection of its own
+            p[f"{lp}_conv_Win"] = glorot(d, 3 * d, fan_in=d, fan_out=d)
+            # a short filter that starts near the identity, as `kda`'s
+            p[f"{lp}_conv_taps"] = jax.random.uniform(
+                next(keys), (cfg.conv_taps, d), jnp.float32,
+                -0.5, 0.5).at[-1].add(1.0)
+            p[f"{lp}_conv_Wout"] = glorot(d, d)
         else:
             dq = cfg.mla_dim_nope + cfg.mla_dim_shared
             if cfg.mla_q_rank:
@@ -373,6 +432,8 @@ def init_params(cfg: PlanConfig, key: jax.Array) -> Params:
         else:
             f, n = cfg.experts_dim_ffn, cfg.experts_held
             p[f"{lp}_experts_router"] = glorot(d, cfg.experts)
+            if cfg.experts_bias_rate:
+                p[f"{lp}{_LOAD_MOVED}"] = inits.zeros((1, cfg.experts))
             p[f"{lp}_experts_Wg"] = glorot(n, d, f, fan_in=d, fan_out=f)
             p[f"{lp}_experts_Wu"] = glorot(n, d, f, fan_in=d, fan_out=f)
             p[f"{lp}_experts_Wd"] = glorot(n, f, d, fan_in=f, fan_out=d)
@@ -387,6 +448,14 @@ def init_params(cfg: PlanConfig, key: jax.Array) -> Params:
         p[f"{lp}_Weh"] = glorot(2 * d, d)
         p[f"{lp}_top_norm_scale"] = ones(d)
     return p
+
+
+def load_moved(cfg: PlanConfig) -> Tuple[str, float]:
+    """(suffix, rate): the leaves of that suffix are moved by `rate` an
+    update against the sign of the load signal that the backward leaves
+    in their gradient's place (ops/experts.py::load_signal), and by
+    nothing else; rate 0: the plan has no such leaf."""
+    return _LOAD_MOVED, cfg.experts_bias_rate
 
 
 def cast_params(params: Params, dtype) -> Params:
@@ -454,7 +523,7 @@ def _kda(cfg: PlanConfig, p: Params, lp: str, x):
 
     def group(acc, w):
         def branch(n_):
-            y = K.short_conv(
+            y = short_conv(
                 jnp.dot(x, w[f"W{n_}"], preferred_element_type=f32),
                 w[f"conv_{n_}"].astype(f32))
             return _heads(jax.nn.silu(y), hg)
@@ -647,13 +716,34 @@ def _gqa(cfg: PlanConfig, p: Params, lp: str, x, mask, rule=None,
         return jnp.dot(o, p[f"{lp}_gqa_Wo"])
 
 
+def _conv(cfg: PlanConfig, p: Params, lp: str, x):
+    """[B, C, h] = W_in x split three ways along the channels, in that
+    order; z = B * h; c_t = sum_j taps[j] * z_{t - (K - 1) + j}, channel
+    by channel, positions before the row's first counting as zero
+    (`short_conv`: the last tap weighs the current token); W_out(C * c).
+    The two matmuls in the compute type; the core between them (two
+    products, K taps) in float32 with one rounding, as `_gate`'s. Rows
+    are padded on the right and a position reads nothing after itself, so
+    no mask is asked for: a padded tail changes no real position."""
+    d = cfg.dim_emb
+    with jax.named_scope("conv"):
+        bcx = checkpoint_name(jnp.dot(x, p[f"{lp}_conv_Win"]), "conv_bcx")
+        with jax.named_scope("conv.core"):
+            b, c, h = (bcx[..., i * d:(i + 1) * d].astype(jnp.float32)
+                       for i in range(3))
+            y = c * short_conv(b * h,
+                               p[f"{lp}_conv_taps"].astype(jnp.float32))
+        return jnp.dot(y.astype(x.dtype), p[f"{lp}_conv_Wout"])
+
+
 def _experts(cfg: PlanConfig, p: Params, lp: str, x, mask):
     bsz, t, d = x.shape
     flat = x.reshape(bsz * t, d)
+    bias = p.get(f"{lp}{_LOAD_MOVED}")
     with jax.named_scope("experts.route"):
         idx, weights = X.route(flat, p[f"{lp}_experts_router"],
                                cfg.experts_top_k, cfg.experts_scale,
-                               cfg.experts_score)
+                               cfg.experts_score, bias)
         if cfg.experts_held < cfg.experts:
             # A share of the layer's output carries a share of the
             # router's gradient, and that share alone teaches the router
@@ -669,6 +759,13 @@ def _experts(cfg: PlanConfig, p: Params, lp: str, x, mask):
             cfg.experts_first,
             X.pool_rows(bsz * t, cfg.experts_top_k, cfg.experts_held,
                         cfg.experts))
+    if bias is not None:
+        # the bias is moved by the load over ALL experts of this chip's
+        # tokens: the term this chip adds to the sum over the chips that
+        # share the layer (the gradients' sum over `data`)
+        with jax.named_scope("experts.route"):
+            y = X.load_signal(y, bias, X.loads(idx, mask.reshape(-1),
+                                               cfg.experts))
     if cfg.experts_shared:
         with jax.named_scope("experts.shared"):
             y = y + X.gated_mlp(flat, p[f"{lp}_shared_Wg"],
@@ -681,6 +778,8 @@ def _mix(cfg: PlanConfig, kind: str, lp: str, p: Params, x, mask,
     pre = rms_norm(x, p[f"{lp}_mix_norm_scale"], eps=cfg.norm_eps)
     if kind in _GROUPED:
         out = _gqa(cfg, p, lp, pre, mask, rule, kind)
+    elif kind == "conv":
+        out = _conv(cfg, p, lp, pre)
     else:
         out = _kda(cfg, p, lp, pre) if kind == "kda" \
             else _mla(cfg, p, lp, pre, mask)
@@ -725,6 +824,7 @@ def _keeps(cfg: PlanConfig, kind: str) -> Tuple[str, ...]:
     """The names a checkpointed half of this kind keeps (see `_layer`)."""
     return (_FLASH_KEEPS if kind in _ATTENTION else ()) \
         + (_PROJECTION_KEEPS if kind in _GROUPED else ()) \
+        + (_CONV_KEEPS if kind == "conv" else ()) \
         + ((BRANCH_OUT,) if cfg.post_norms else ())
 
 
@@ -766,6 +866,10 @@ def _layer(cfg: PlanConfig, kinds, lp: str, p: Params, x, mask, remat,
           under a gigabyte of headroom (PERF.md 6, PR 47). An `mla` or
           `kda` half keeps none of its projections: they are low-rank,
           cheap to run again and as dear to hold;
+      a `conv` half                   the output of W_in (_CONV_KEEPS:
+          6 x d, 12 KB at width 2048): W_in, three quarters of the
+          half's matmul work, does not run again; the gates and the taps
+          do, and W_out's forward is read by no backward;
       either half under `post_norms`  the branch's output (BRANCH_OUT:
           d x 2), which the output norm's backward reads: without it the
           backward regenerates the whole branch to get it (W_o, the dense

@@ -56,17 +56,61 @@ COUNTERS = ("moe.assignments", "moe.assignments_held", "moe.load_max",
 SCORES = ("sigmoid", "softmax")
 
 
-def route(x, w_router, top_k: int, scale: float, score: str = "sigmoid"):
+def route(x, w_router, top_k: int, scale: float, score: str = "sigmoid",
+          bias=None):
     """Scores over all experts in float32 (`score`: a sigmoid of each
     logit, or a softmax over them), the top k of them, renormalised to
     sum 1 and scaled: x [T, d], w_router [d, E] -> (idx [T, k] int32,
-    weights [T, k] float32)."""
+    weights [T, k] float32). With a `bias` [.., E] the top k are chosen
+    by score + bias and weighted by their scores WITHOUT it: the bias
+    says where a token goes, never how much of an expert it gets."""
     logits = jnp.dot(x.astype(jnp.float32), w_router.astype(jnp.float32),
                      precision=jax.lax.Precision.HIGHEST)
     s = jax.nn.sigmoid(logits) if score == "sigmoid" \
         else jax.nn.softmax(logits, axis=-1)
-    vals, idx = jax.lax.top_k(s, top_k)
+    if bias is None:
+        vals, idx = jax.lax.top_k(s, top_k)
+    else:
+        idx = jax.lax.top_k(s + bias.reshape(-1).astype(jnp.float32),
+                            top_k)[1]
+        vals = jnp.take_along_axis(s, idx, axis=-1)
     return idx, vals / jnp.sum(vals, axis=-1, keepdims=True) * scale
+
+
+def loads(idx, mask, experts: int):
+    """How many of the real tokens' choices named each expert of ALL the
+    router scores, held here or not: idx [T, k], mask [T] -> [experts]
+    float32."""
+    real = jnp.repeat(mask, idx.shape[1]) > 0
+    hot = real[:, None] & (idx.reshape(-1, 1) == jnp.arange(experts)[None, :])
+    return jnp.sum(hot, axis=0, dtype=jnp.float32)
+
+
+@jax.custom_vjp
+def load_signal(y, bias, load):
+    """`y` as it is. What the backward hands `bias` is NOT a derivative:
+    it is `load` less its mean, how far over (+) or under (-) the even
+    load each expert stood in this call, in bias's shape. It rides the
+    gradients' road because that road already does what the signal
+    needs: it leaves the checkpointed step, adds up over micro-batches
+    and over the chips that share the data. Whoever updates the
+    parameters takes it OUT of the gradients before they are normed and
+    moves the bias against its sign (parallel/zero.py::take_load_signals,
+    move_by_load); to an optimizer it must never look like a gradient."""
+    return y
+
+
+def _load_signal_fwd(y, bias, load):
+    return y, (bias, load)
+
+
+def _load_signal_bwd(res, dy):
+    bias, load = res
+    over = (load - jnp.mean(load)).reshape(bias.shape).astype(bias.dtype)
+    return dy, over, jnp.zeros_like(load)
+
+
+load_signal.defvjp(_load_signal_fwd, _load_signal_bwd)
 
 
 def _arrivals(idx, mask, first: int, count: int):

@@ -47,16 +47,6 @@ _HI = jax.lax.Precision.HIGHEST
 _MID = jax.lax.Precision.HIGH
 
 
-def short_conv(x: jax.Array, w: jax.Array) -> jax.Array:
-    """Depthwise causal convolution along time: x [B, T, D], w [K, D];
-    y_t = sum_j w[j] * x_{t-(K-1)+j}, so w[K-1] weighs the current token
-    and positions before the first count as zero."""
-    k = w.shape[0]
-    t = x.shape[1]
-    xp = jnp.pad(x, ((0, 0), (k - 1, 0), (0, 0)))
-    return sum(xp[:, j:j + t, :] * w[j] for j in range(k))
-
-
 def l2_normalize(x: jax.Array, eps: float = 1e-6) -> jax.Array:
     x32 = x.astype(jnp.float32)
     return x32 * jax.lax.rsqrt(jnp.sum(x32 * x32, axis=-1, keepdims=True)

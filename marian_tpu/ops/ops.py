@@ -67,6 +67,18 @@ def rms_norm(x: jax.Array, scale: jax.Array, bias: Optional[jax.Array] = None,
     return y.astype(dtype)
 
 
+def short_conv(x: jax.Array, w: jax.Array) -> jax.Array:
+    """Depthwise causal convolution along time: x [B, T, D], w [K, D];
+    y_t = sum_j w[j] * x_{t-(K-1)+j}, so w[K-1] weighs the current token
+    and positions before the first count as zero. What the layer plan's
+    `kda` half runs on q, k and v, and the whole of its `conv` half's
+    mixing along time (models/layer_plan.py)."""
+    k = w.shape[0]
+    t = x.shape[1]
+    xp = jnp.pad(x, ((0, 0), (k - 1, 0), (0, 0)))
+    return sum(xp[:, j:j + t, :] * w[j] for j in range(k))
+
+
 def dropout(x: jax.Array, rate: float, key: Optional[jax.Array],
             deterministic: bool = False) -> jax.Array:
     """Inverted dropout with explicit key (reference: dropout nodes backed by

@@ -371,6 +371,32 @@ def build_grad_fn(model, mesh: Mesh, params: Params, frozen=(),
                     donate_argnums=(1,) if donate else ()))
 
 
+def take_load_signals(model, grads):
+    """(grads, signals): the leaves that `model.load_moved` names hold
+    no gradient but their experts' excess load (ops/experts.py::
+    load_signal), summed over micro-batches and chips as gradients are.
+    They leave the gradients as zeros BEFORE anything norms, clips or
+    applies them, so the optimizer does not move those leaves and the
+    global norm does not hold them; `move_by_load` does the moving."""
+    suffix, rate = model.load_moved
+    if not rate:
+        return grads, {}
+    signals = {k: g for k, g in grads.items() if k.endswith(suffix)}
+    return {k: (jnp.zeros_like(g) if k in signals else g)
+            for k, g in grads.items()}, signals
+
+
+def move_by_load(model, p, new_p, signals):
+    """The updated parameters with each load-moved leaf stepped from its
+    OLD value by the model's rate against the sign of its signal: an
+    expert over the mean load loses `rate` of selection bias, one under
+    it gains as much, one at it stays."""
+    rate = model.load_moved[1]
+    return {**new_p, **{
+        k: p[k] - rate * jnp.sign(s).astype(p[k].dtype).reshape(p[k].shape)
+        for k, s in signals.items()}}
+
+
 def build_train_step(model, opt_cfg: OptimizerConfig, schedule, cost_type: str,
                      mesh: Mesh, params: Params, opt_state,
                      donate: bool = True, shardings=None,
@@ -409,10 +435,12 @@ def build_train_step(model, opt_cfg: OptimizerConfig, schedule, cost_type: str,
             denom = jnp.asarray(batch["trg_ids"].shape[0], jnp.float32)
         else:
             denom = jnp.asarray(1.0, jnp.float32)
+        grads, signals = take_load_signals(model, grads)
         with jax.named_scope("optimizer"):
             lr = schedule(step)
             new_p, new_opt, gnorm, skipped = finalize_update(
                 opt_cfg, opt_state, p, grads, lr, labels, denom)
+            new_p = move_by_load(model, p, new_p, signals)
         metrics = {"ce_sum": ce_sum, "labels": labels, "gnorm": gnorm,
                    "lr": lr}
         if machinery.n_counters:

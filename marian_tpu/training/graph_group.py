@@ -47,7 +47,8 @@ from ..optimizers.optimizers import (OptimizerConfig, init_state,
 from ..optimizers.schedule import LRSchedule
 from ..parallel import mesh as M
 from ..parallel.zero import (build_grad_fn, build_train_step,
-                             finalize_update, place)
+                             finalize_update, move_by_load, place,
+                             take_load_signals)
 from . import hbm
 from . import program_store
 from .program_store import ProgramStore, Refused, describe
@@ -301,9 +302,11 @@ class GraphGroup:
                 denom = jnp.asarray(1.0, jnp.float32)
             # shared tail (zero.py finalize_update): normalize-gradient,
             # dynamic scaling, clip-as-min, nan-skip
+            grads, signals = take_load_signals(model, total["grads"])
             new_p, new_opt, gnorm, skipped = finalize_update(
-                opt_cfg, opt_state, p, total["grads"], schedule(step),
+                opt_cfg, opt_state, p, grads, schedule(step),
                 labels, denom)
+            new_p = move_by_load(model, p, new_p, signals)
             ce_sum = total["ce_sum"]
             metrics = {"gnorm": gnorm}
             if opt_cfg.check_gradient_nan:

@@ -147,6 +147,18 @@ def _window_attn(b, t, window=2048):
     return jax.grad(loss, argnums=(0, 1, 2)), shapes, list(_FLASH[1:])
 
 
+def _grouped_attn_64(b, t):
+    """The layer plan's causal grouped-query attention at heads of 64:
+    32 query heads on 8 key/value heads, with the forward and both
+    backward kernels (`pick_blocks`' dh <= 64 branch)."""
+    def loss(q, k, v, m):
+        return flash_attention(q, k, v, kv_mask=m, causal=True,
+                               interpret=False).astype(jnp.float32).sum()
+    shapes = [((b, 32, t, 64), DT), ((b, 8, t, 64), DT),
+              ((b, 8, t, 64), DT), ((b, t), jnp.float32)]
+    return jax.grad(loss, argnums=(0, 1, 2)), shapes, list(_FLASH[1:])
+
+
 def _kda_carry(b, heads, chunks):
     """The delta rule's state carry (chunks of 64, 128 x 128 state a
     head, float32), forward and backward."""
@@ -284,6 +296,10 @@ CASES = {
     # narrowest and the widest batch of 16384 tokens
     "flash-grad-window-4x4096": lambda: _window_attn(4, 4096),
     "flash-grad-window-1x16384": lambda: _window_attn(1, 16384),
+    # causal attention at heads of 64, 4 query heads a key/value head
+    # (lfm2.train-docs8k): the narrowest and the widest batch
+    "flash-grad-gqa64-16x1024": lambda: _grouped_attn_64(16, 1024),
+    "flash-grad-gqa64-2x8192": lambda: _grouped_attn_64(2, 8192),
     "kda-carry-grad-16x1024": lambda: _kda_carry(16, 4, 16),
     "kda-carry-grad-2x8192": lambda: _kda_carry(2, 4, 128),
     "kda-prep-grad-16x1024": lambda: _kda_prep(16, 4, 1024),

@@ -34,6 +34,7 @@ from marian_tpu.models.encoder_decoder import create_model
 from marian_tpu.ops import experts as X
 from marian_tpu.ops import kda
 from marian_tpu.ops.attention import dense_attention
+from marian_tpu.ops.ops import short_conv
 from marian_tpu.ops.pallas import kda_chunk, kda_prep
 from marian_tpu.ops.pallas.flash_attention import flash_attention
 from test_flash_attention import _equations
@@ -213,11 +214,11 @@ def test_kda_chunked_on_both_kernel_pairs_is_the_recurrence(case):
 def test_short_conv_is_causal():
     x = jax.random.normal(jax.random.PRNGKey(0), (2, 9, 5))
     w = jax.random.normal(jax.random.PRNGKey(1), (4, 5))
-    y = kda.short_conv(x, w)
+    y = short_conv(x, w)
     want = sum(w[j] * jnp.pad(x, ((0, 0), (3 - j, 0), (0, 0)))[:, :9]
                for j in range(4))
     np.testing.assert_allclose(y, want, atol=1e-6)
-    y2 = kda.short_conv(x.at[:, 5:].set(0.0), w)
+    y2 = short_conv(x.at[:, 5:].set(0.0), w)
     np.testing.assert_allclose(y2[:, :5], y[:, :5], atol=1e-6)
 
 
@@ -674,7 +675,7 @@ def _bf16_state(qg, wk, wv, kd, gc, p):
     return jnp.stack(out, 2)
 
 
-def _bf16_route(x, w_router, top_k, scale, score="sigmoid"):
+def _bf16_route(x, w_router, top_k, scale, score="sigmoid", bias=None):
     s = jax.nn.sigmoid(jnp.dot(x.astype(jnp.bfloat16),
                                w_router.astype(jnp.bfloat16)))
     vals, idx = jax.lax.top_k(s, top_k)

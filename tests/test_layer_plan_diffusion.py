@@ -202,7 +202,7 @@ def test_the_evaluation_rule_through_data_weights(tiny):
                                rtol=1e-4, atol=1e-3)
 
 
-def _bf16_router(x, w_router, top_k, scale, score="sigmoid"):
+def _bf16_router(x, w_router, top_k, scale, score="sigmoid", bias=None):
     s = jax.nn.softmax(jnp.dot(x.astype(jnp.bfloat16),
                                w_router.astype(jnp.bfloat16)
                                ).astype(jnp.float32), axis=-1)
@@ -210,7 +210,7 @@ def _bf16_router(x, w_router, top_k, scale, score="sigmoid"):
     return idx, vals / jnp.sum(vals, -1, keepdims=True) * scale
 
 
-def _bf16_softmax(x, w_router, top_k, scale, score="sigmoid"):
+def _bf16_softmax(x, w_router, top_k, scale, score="sigmoid", bias=None):
     logits = jnp.dot(x.astype(jnp.float32), w_router.astype(jnp.float32),
                      precision=jax.lax.Precision.HIGHEST)
     s = jax.nn.softmax(logits.astype(jnp.bfloat16), axis=-1)

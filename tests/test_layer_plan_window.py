@@ -229,7 +229,7 @@ def test_one_mechanism_lower_is_caught(tiny, monkeypatch, lower):
     assert _token_error(model, dims, params, batch) > 10 * F32_LIMIT
 
 
-def _bf16_router(x, w_router, top_k, scale, score="sigmoid"):
+def _bf16_router(x, w_router, top_k, scale, score="sigmoid", bias=None):
     s = jax.nn.sigmoid(jnp.dot(x.astype(jnp.bfloat16),
                                w_router.astype(jnp.bfloat16)
                                ).astype(jnp.float32))
